@@ -26,6 +26,8 @@ from horovod_tpu.models.mlp import init_mlp, mlp_forward, softmax_cross_entropy
 
 
 def main():
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--total-batches", type=int, default=500)
     ap.add_argument("--batch-size", type=int, default=64)

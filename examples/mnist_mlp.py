@@ -33,6 +33,8 @@ def synthetic_mnist(rank: int, n: int = 4096):
 
 
 def main():
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--batch-size", type=int, default=128)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import horovod_tpu as hvd  # installs the jax compat shims first
+import horovod_tpu as hvd
 from jax import shard_map
 from horovod_tpu import optimizer as hvd_opt
 from horovod_tpu.models.resnet import ResNet50
@@ -139,6 +139,8 @@ def run_eager(args):
 
 
 if __name__ == "__main__":
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     args = parse_args()
     if args.mode == "spmd":
         run_spmd(args)

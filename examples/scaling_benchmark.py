@@ -43,11 +43,12 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     import jax.numpy as jnp
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import horovod_tpu  # installs the jax compat shims first
     from jax import shard_map
 
     from horovod_tpu import optimizer as hvd_opt

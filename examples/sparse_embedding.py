@@ -27,6 +27,8 @@ from horovod_tpu.optimizer import DistributedEagerOptimizer
 
 
 def main():
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--vocab", type=int, default=50_000)
     ap.add_argument("--dim", type=int, default=64)

@@ -69,6 +69,8 @@ def main():
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
     from horovod_tpu.models.transformer import (TransformerConfig,
                                                 init_params, lean_lm_loss,
                                                 make_train_step,

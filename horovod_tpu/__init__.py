@@ -18,13 +18,12 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 # XLA latency-hiding-scheduler knob (HOROVOD_TPU_XLA_LHS=1) must land in
-# XLA_FLAGS before anything touches a jax backend; compat's jax import
-# below is safe (flags are parsed at backend init, not import), but this
-# still runs first so the ordering is self-evident.
+# XLA_FLAGS before anything touches a jax backend (flags are parsed at
+# backend init, not at jax import; nothing in this package's import
+# initialises a backend).
 from .common.env import apply_xla_lhs as _apply_xla_lhs
 _apply_xla_lhs()
 
-from . import compat as _compat  # noqa: F401  (jax version shims, first)
 from .common.reduce_ops import (ReduceOp, Average, Sum, Adasum, Min, Max, Product,
                                 handle_average_backwards_compatibility)
 from .common.exceptions import (HorovodInternalError, HostsUpdatedInterrupt,

@@ -65,7 +65,6 @@ HOROVOD_TPU_SHUTDOWN_TIMEOUT = "HOROVOD_TPU_SHUTDOWN_TIMEOUT"
 # disconnect flags before shutting the coordination service
 HOROVOD_TPU_SHUTDOWN_ORDER_TIMEOUT = "HOROVOD_TPU_SHUTDOWN_ORDER_TIMEOUT"
 HOROVOD_TPU_DEBUG_CONSISTENCY = "HOROVOD_TPU_DEBUG_CONSISTENCY"
-HOROVOD_TPU_PLATFORM = "HOROVOD_TPU_PLATFORM"                 # cpu|tpu override (tests)
 # steady-state metadata cache (the ResponseCache role for allgather sizes /
 # alltoall splits, response_cache.h:45-102): after WARMUP identical blocking
 # exchanges per name, the exchange goes fire-and-forget with a deferred
@@ -362,6 +361,34 @@ def _get_choice(name: str, default: str, choices) -> str:
     return v
 
 
+JAX_COMPILATION_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def compile_cache_dir() -> str:
+    """Where this checkout keeps JAX's persistent compilation cache: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+    ``<checkout>/.jax_cache`` — a fixed path, because the path is part of
+    what a later run must find again. Touches no jax: the launcher calls
+    it to hand every worker the same directory."""
+    return os.environ.get(JAX_COMPILATION_CACHE_DIR) or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
+    and return that directory. With ``JAX_COMPILATION_CACHE_DIR`` set this
+    sets nothing (jax reads the variable itself). Call before the first
+    compile; every entry script (chip_smoke.py, bench.py, bench_kernels.py,
+    the examples, the tools/ probes) calls it, and workers under the
+    launcher inherit the directory through the variable."""
+    path = compile_cache_dir()
+    if not os.environ.get(JAX_COMPILATION_CACHE_DIR):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def apply_xla_lhs() -> bool:
     """ISSUE 6 satellite: ``HOROVOD_TPU_XLA_LHS=1`` appends
     ``--xla_tpu_enable_latency_hiding_scheduler=true`` to ``XLA_FLAGS``.
@@ -370,9 +397,9 @@ def apply_xla_lhs() -> bool:
     this must run before the first backend touch — it is called from
     ``horovod_tpu/__init__`` at import. If a jax backend already exists
     the append would be silently ignored; that case gets a loud WARNING
-    and a no-op instead (the probe-documented footgun,
-    tools/probe_resnet_overlap.py: on remote-compile rigs use per-compile
-    ``compiler_options`` — this knob is for local-backend runs).
+    and a no-op instead (tools/probe_resnet_overlap.py passes the same
+    flag per compile through ``compiler_options``, which works at any
+    time).
 
     Returns True when the flag is (already or newly) in effect."""
     import logging
@@ -405,8 +432,7 @@ def apply_xla_lhs() -> bool:
                 "HOROVOD_TPU_XLA_LHS=1 but a jax backend is already "
                 "initialized; XLA_FLAGS changes no longer take effect. "
                 "Set the env var before the first jax backend touch (or "
-                "use per-compile compiler_options on remote-compile "
-                "rigs). Ignoring the knob.")
+                "pass per-compile compiler_options). Ignoring the knob.")
             return False
         if not probed:
             logging.getLogger("horovod_tpu").warning(

@@ -607,10 +607,6 @@ KNOB_SPECS: Dict[str, dict] = {
         "type": "int", "default": "0", "internal": True,
         "help": "Elastic world version the rendezvous stamps on every "
                 "re-init; replay and prefetch invalidate when it bumps."},
-    "HOROVOD_TPU_PLATFORM": {
-        "type": "str", "default": "", "internal": True,
-        "help": "Backend platform override (cpu|tpu) for tests and "
-                "dryruns."},
     "HOROVOD_TPU_HEARTBEAT_TIMEOUT": {
         "type": "int", "default": "100 (10 when elastic)",
         "internal": True,

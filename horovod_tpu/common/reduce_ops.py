@@ -7,6 +7,8 @@ import enum
 
 
 class ReduceOp(enum.IntEnum):
+    """How a collective combines the ranks' tensors (the values travel on
+    the wire in join metadata, so they are part of the protocol)."""
     AVERAGE = 0
     SUM = 1
     ADASUM = 2

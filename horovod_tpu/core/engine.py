@@ -102,10 +102,7 @@ class LaunchGroup:
         # lockcheck: ignore[double-checked fast path: _done only transitions False->True, a stale read just re-polls]
         if self._done:
             return True
-        if hasattr(self._rep, "is_ready"):
-            ok = _translate_failure(self._rep.is_ready)
-        else:  # older jax without is_ready
-            ok = True
+        ok = _translate_failure(self._rep.is_ready)
         if ok:
             # lockcheck: ignore[monotonic latch: concurrent True writes are idempotent]
             self._done = True
@@ -1797,7 +1794,7 @@ class Engine:
         ef_by_bucket = {row[0]: row for row in ef_info}
         mesh = self.backend.group_mesh
         hier_local = self.topology.local_size
-        from ..ops.pallas_kernels import pack_pallas
+        from ..ops.pallas_kernels import pack_pallas, pack_pallas_supported
         pm = self.parameter_manager
         use_pallas_pack = (pm.categorical_value("pallas_pack")
                            if pm is not None and pm.tunes("pallas_pack")
@@ -1809,9 +1806,7 @@ class Engine:
             # carrying the (1, ...) block dim so the global lift is pure
             # metadata), then one reduce+unpack program for every bucket —
             # where the per-bucket form cost 2·n_buckets dispatches plus
-            # ~2 eager lift dispatches per tensor. On a tunneled /
-            # high-dispatch-overhead runtime that difference IS the
-            # eager-vs-SPMD gap.
+            # ~2 eager lift dispatches per tensor.
             shapes = tuple(tuple(t.shape) for t in tensors)
             dtypes = tuple(str(t.dtype) for t in tensors)
             bkey = tuple(tuple(b) for b in buckets)
@@ -1859,7 +1854,7 @@ class Engine:
                 algo = algos[b]
                 bcodec = codecs[b]
                 self._count_dispatch()
-                if use_pallas_pack:
+                if use_pallas_pack and pack_pallas_supported(shapes, dtype):
                     packed = _translate_failure(pack_pallas, bucket)
                 else:
                     pack_fn = self._builder(

@@ -18,7 +18,7 @@ from ..common import env as env_mod
 from ..runner import safe_shell_exec
 from ..runner.hosts import SlotInfo
 from ..runner.launch import (COORDINATOR_VIA_RENDEZVOUS, _driver_ip,
-                             is_local_host, slot_command)
+                             accelerator_env, is_local_host, slot_command)
 from .discovery import FixedHosts, HostDiscoveryScript
 from .driver import ElasticDriver
 from .rendezvous import ElasticRendezvousServer
@@ -29,7 +29,8 @@ _LOG = logging.getLogger("horovod_tpu.elastic")
 def make_elastic_worker_env(slot: SlotInfo, rendezvous_addr: str,
                             rendezvous_port: int,
                             base_env: Optional[Dict[str, str]] = None,
-                            rendezvous_endpoints: Optional[str] = None
+                            rendezvous_endpoints: Optional[str] = None,
+                            tpu_chips: Optional[int] = None
                             ) -> Dict[str, str]:
     """Worker env for elastic mode: identity is (hostname, local_rank); the
     global rank/size are *not* pinned — the worker re-fetches its SlotInfo
@@ -40,8 +41,15 @@ def make_elastic_worker_env(slot: SlotInfo, rendezvous_addr: str,
     control plane is replicated — every worker KV consumer resolves it
     onto the shared Endpoints failover set (sticky primary, epoch-aware
     redirects, circuit breakers), so a driver failover never strands a
-    worker on a dead address."""
+    worker on a dead address.
+
+    On a TPU host the slot is bound to its own chip exactly as in the
+    static launch (``runner.launch.accelerator_env``; ``tpu_chips`` as
+    there). libtpu reads the binding once per process, so it describes the
+    world the job STARTS with: a resize that changes ``local_size`` needs
+    fresh worker processes on such a host."""
     env = dict(base_env if base_env is not None else os.environ)
+    env.update(accelerator_env(slot, env, tpu_chips))
     env.update({
         env_mod.HOROVOD_ELASTIC: "1",
         env_mod.HOROVOD_HOSTNAME: slot.hostname,

@@ -103,8 +103,14 @@ class HBMSampler:
         if not isinstance(stats, dict):
             if self._supported is None:
                 self._supported = False
-                _LOG.debug("device memory stats unavailable; "
-                           "HBM telemetry disabled")
+                import jax
+                # a TPU always reports memory_stats: missing there is a
+                # fault worth a warning; CPU never has them
+                level = (logging.WARNING
+                         if jax.default_backend() == "tpu"
+                         else logging.DEBUG)
+                _LOG.log(level, "device memory stats unavailable; "
+                         "HBM telemetry disabled")
             return None
         self._supported = True
         in_use = stats.get("bytes_in_use")

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat as _compat  # noqa: F401  (aliases jax.shard_map)
 from jax import shard_map
 
 

@@ -47,14 +47,6 @@ def _pad_to_grid(v: jax.Array):
     return v.reshape(rows, _LANES), n
 
 
-def _tpu_compiler_params(pltpu, **kw):
-    """pltpu.CompilerParams across the jax rename (older jax spells it
-    TPUCompilerParams)."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
 def _triple_kernel(a_ref, b_ref, acc_ref):
     """Grid-accumulated [dot(a,b), |a|², |b|²] in fp32 — one read of each
     operand for all three reductions (adasum.h:338-398 computes the same
@@ -135,28 +127,41 @@ def adasum_pallas_enabled() -> bool:
 # ---------------------------------------------------------------------------
 
 
+def pack_pallas_supported(shapes, dtype) -> bool:
+    """Whether :func:`pack_pallas` compiles for a bucket of these shapes.
+    Mosaic copies whole 1-D tiles (128 lanes x 8 sublanes of 32 bits), so
+    every tensor must hold a whole number of them; it refuses a ragged
+    tensor outright ("infer-vector-layout: unsupported shape cast",
+    v5e / libtpu 0.0.34, PR 21's chip run). The engine offers the kernel
+    for aligned buckets only and packs the others with XLA's concat."""
+    tile = _LANES * 8 * 4 // np.dtype(dtype).itemsize
+    return all(int(np.prod(s)) % tile == 0 for s in shapes)
+
+
 def pack_pallas(tensors):
     """Pallas fusion packer: one kernel, one DMA-style copy per tensor into
     the flat buffer (evaluated against the jitted-concat pack; see
     bench_kernels.py — XLA's fused concat has been faster in practice, so
-    this stays opt-in via HOROVOD_PALLAS_PACK)."""
+    this stays opt-in via HOROVOD_PALLAS_PACK). Tensors are flattened
+    before the kernel (free in XLA; Mosaic has no general in-kernel shape
+    cast). Shapes must satisfy :func:`pack_pallas_supported`."""
     from jax.experimental import pallas as pl
 
-    sizes = [int(np.prod(t.shape)) if t.ndim else 1 for t in tensors]
+    flat = [jnp.ravel(jnp.asarray(t)) for t in tensors]
+    sizes = [int(f.shape[0]) for f in flat]
     offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
-    total = int(sum(sizes))
-    dtype = tensors[0].dtype
+    dtype = flat[0].dtype
 
     def kernel(*refs):
         o_ref = refs[-1]
         for i, (off, sz) in enumerate(zip(offsets, sizes)):
-            o_ref[pl.dslice(int(off), sz)] = refs[i][...].reshape(sz)
+            o_ref[pl.dslice(int(off), sz)] = refs[i][...]
 
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((total,), dtype),
+        out_shape=jax.ShapeDtypeStruct((int(sum(sizes)),), dtype),
         interpret=_interpret(),
-    )(*[jnp.asarray(t) for t in tensors])
+    )(*flat)
 
 
 def pack_pallas_enabled() -> bool:
@@ -241,8 +246,8 @@ def bn_stats_pallas(x2d: jax.Array):
                    pl.BlockSpec((1, c), lambda mi: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(x2d)
     return (_unfold_stats(s[0], c_orig, k), _unfold_stats(q[0], c_orig, k))
@@ -290,8 +295,8 @@ def bn_bwd_stats_pallas(dy2d: jax.Array, x2d: jax.Array,
                    pl.BlockSpec((1, c), lambda mi: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(mean.reshape(1, c).astype(jnp.float32),
       invstd.reshape(1, c).astype(jnp.float32), dy2d, x2d)

@@ -47,27 +47,23 @@ from .ops.compression import Compression
 # SPMD path
 # ---------------------------------------------------------------------------
 
-def _is_varying(x, axis_name: str) -> bool:
-    """Whether ``x`` is varying over ``axis_name`` under shard_map's
-    varying-manual-axes (VMA) type system."""
-    try:
-        return axis_name in jax.typeof(x).vma
-    except (AttributeError, TypeError):
-        return True  # outside a manual region / older jax: assume local values
-
-
 def _vma_tracking_active(axis_name: str) -> bool:
-    """Whether the surrounding shard_map actually tracks varying axes
-    (check_vma=True). Under check_vma=False EVERY value reports an empty
-    vma set, so a pre-summed-gradient guard keyed on _is_varying would
-    misfire on perfectly good per-shard gradients; probe by pcasting a
-    fresh constant and seeing if the annotation sticks."""
-    try:
-        import jax.numpy as _jnp
-        probe = jax.lax.pcast(_jnp.zeros(()), (axis_name,), to="varying")
-        return axis_name in jax.typeof(probe).vma
-    except Exception:
-        return False
+    """Whether the surrounding shard_map tracks varying manual axes
+    (``check_vma=True``). Under ``check_vma=False`` — what every shard_map
+    holding a Pallas kernel must use — every value reports an empty vma
+    set and ``pcast`` is a no-op; probe by pcasting a fresh constant and
+    seeing if the annotation sticks."""
+    probe = jax.lax.pcast(jnp.zeros(()), (axis_name,), to="varying")
+    return axis_name in jax.typeof(probe).vma
+
+
+def _pre_summed(x, axis_name: str, tracking: bool) -> bool:
+    """Whether ``x`` is a gradient the shard_map transpose has ALREADY
+    psum'd over ``axis_name``: only decidable when the VMA system tracks
+    (``tracking`` = :func:`_vma_tracking_active`, probed once per tree)
+    and types ``x`` unvarying. An untracked value is treated as local,
+    which is what it is: without tracking ``jax.grad`` inserts no psum."""
+    return tracking and axis_name not in jax.typeof(x).vma
 
 
 def allreduce_gradients(grads, axis_name: str, op: ReduceOp = Average,
@@ -77,23 +73,20 @@ def allreduce_gradients(grads, axis_name: str, op: ReduceOp = Average,
     The functional analog of DistributedGradientTape.gradient
     (tensorflow/__init__.py:464-518).
 
-    VMA-aware: under shard_map, ``jax.grad`` w.r.t. *replicated* (unvarying)
-    params already psums gradient contributions in its transpose — such leaves
-    arrive pre-summed and must not be reduced again (only scaled for Average).
-    Leaves that are varying over ``axis_name`` (e.g. grads of explicitly
-    device-local params) get the explicit collective.
+    VMA-aware: under a ``check_vma=True`` shard_map, ``jax.grad`` w.r.t.
+    *replicated* (unvarying) params already psums gradient contributions in
+    its transpose — such leaves arrive pre-summed and must not be reduced
+    again (only scaled for Average). Every other leaf — varying over
+    ``axis_name``, or untracked under ``check_vma=False`` — is local and
+    gets the explicit collective.
     """
     wire = getattr(compression, "wire_codec", None)
+    tracking = _vma_tracking_active(axis_name)
 
     def reduce_leaf(g):
-        varying = _is_varying(g, axis_name)
+        pre_summed = _pre_summed(g, axis_name, tracking)
         if op == Adasum:
-            # Adasum callers compute local grads by construction; the
-            # pre-summed guard is only decidable when the surrounding
-            # shard_map tracks varying axes (check_vma=True) — under
-            # check_vma=False every value reports unvarying and the guard
-            # would misfire, so proceed with the collective there.
-            if not varying and _vma_tracking_active(axis_name):
+            if pre_summed:
                 raise ValueError(
                     "op=Adasum needs per-shard gradients; it cannot recover "
                     "local contributions from an implicitly pre-summed "
@@ -105,7 +98,7 @@ def allreduce_gradients(grads, axis_name: str, op: ReduceOp = Average,
             c, ctx = compression.compress(g)
             return compression.decompress(
                 adasum_p(c, axis_name, axis_size), ctx)
-        if varying:
+        if not pre_summed:
             if wire is not None:
                 if op not in (Average, Sum):
                     raise ValueError(
@@ -199,10 +192,10 @@ def distributed(inner: optax.GradientTransformation, axis_name: str = "world",
         unchanged."""
         g_leaves, treedef = jax.tree_util.tree_flatten(grads)
         r_leaves = jax.tree_util.tree_leaves(residuals)
+        tracking = _vma_tracking_active(axis_name)
         outs, new_rs = [], []
         for g, r in zip(g_leaves, r_leaves):
-            if not _is_varying(g, axis_name) \
-                    and _vma_tracking_active(axis_name):
+            if _pre_summed(g, axis_name, tracking):
                 out = g / jax.lax.psum(1, axis_name) if op == Average \
                     else g
                 outs.append(out)
@@ -415,7 +408,7 @@ def _distributed_zero1(inner: optax.GradientTransformation, axis_name: str,
         # the rs exact for them (n identical g/n contributions sum back to
         # g), so every leaf rides the same packed reduce-scatter.
         tracking = _vma_tracking_active(axis_name)
-        scale = [1.0 / n if (tracking and not _is_varying(g, axis_name))
+        scale = [1.0 / n if _pre_summed(g, axis_name, tracking)
                  else 1.0 for g in leaves]
         grad_shards = _shards_of(leaves, layout, scale=scale, reduce_op=op)
         param_shards = _shards_of(p_leaves, layout)
@@ -997,7 +990,7 @@ def distributed_delta_adasum(inner: optax.GradientTransformation,
         tracking = _vma_tracking_active(axis_name)
 
         def check(g):
-            if tracking and not _is_varying(g, axis_name):
+            if _pre_summed(g, axis_name, tracking):
                 raise ValueError(
                     "delta-Adasum needs per-shard gradients; an implicitly "
                     "pre-summed (unvarying) gradient has already mixed the "

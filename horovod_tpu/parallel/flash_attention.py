@@ -4,8 +4,10 @@ The sequence-parallel kernels (ring/Ulysses, parallel/{ring_attention,
 ulysses}.py) own the *distributed* attention surface; this module is the
 single-shard compute kernel: on TPU it calls the Pallas flash-attention
 kernel shipped with JAX (blockwise online-softmax — O(T) memory, causal
-blocks skipped), elsewhere it falls back to the materialized reference
-attention so CPU tests exercise the same call sites.
+blocks skipped); off TPU it computes the materialized reference attention
+so CPU tests exercise the same call sites. On TPU the stock kernel modules
+are imported unguarded: a jax that moved them is an ImportError at the
+first trace, never a silent change of kernel.
 
 Measured motivation (bench.py transformer mode, v5e): materialized
 attention at T=2048 spends ~0.5 GB/layer on the score matrix and the MFU
@@ -18,6 +20,7 @@ the TPU build owns (SURVEY §7 maps the reference's SIMD C++ to Pallas).
 from __future__ import annotations
 
 import functools
+import logging
 import math
 import os
 
@@ -49,13 +52,8 @@ def _splash_mode() -> str:
 
 
 def flash_available() -> bool:
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention  # noqa
-        return True
-    except Exception:
-        return False
+    """Whether attention takes the Pallas TPU kernels: on a TPU, always."""
+    return jax.default_backend() == "tpu"
 
 
 def splash_available() -> bool:
@@ -67,15 +65,7 @@ def splash_available() -> bool:
     """
     # default-on choice knob ("force" additionally overrides the
     # automatic under-remat degrade — see _select_kernel)
-    if _splash_mode() == "0":
-        return False
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        from jax.experimental.pallas.ops.tpu import splash_attention  # noqa
-        return True
-    except Exception:
-        return False
+    return _splash_mode() != "0" and jax.default_backend() == "tpu"
 
 
 def _scoped_vmem_bytes() -> int:
@@ -186,11 +176,20 @@ def _block_sizes(t: int):
                       block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
 
 
+@functools.lru_cache(maxsize=None)
+def _warn_unaligned_once(q_t: int, kv_t: int) -> None:
+    logging.getLogger("horovod_tpu").warning(
+        "flash_attention_local: sequence lengths q=%d kv=%d are not "
+        "multiples of 128; this shape runs the materialized O(T^2) "
+        "attention on TPU, not the Pallas flash kernel", q_t, kv_t)
+
+
 def flash_attention_local(q, k, v, causal: bool = True,
                           layout: str = "bthk",
                           under_remat: bool = False):
-    """Attention via the Pallas TPU flash kernel, with the materialized
-    fallback off-TPU (and for block-unaligned sequence lengths). ``layout``
+    """Attention via the Pallas TPU flash kernel; materialized attention
+    off-TPU and (with a one-time warning) for sequence lengths the kernel's
+    128-row blocks do not divide. ``layout``
     is the layout of q/k/v (and the result):
     "bthk" ([B, T, H, D], the framework's default) or "bhtk" ([B, H, T, D],
     the kernel's native layout — callers that can project straight into it
@@ -203,10 +202,13 @@ def flash_attention_local(q, k, v, causal: bool = True,
     # The Pallas flash kernel's _verify_block requires both sequence lengths
     # divisible by its block sizes (128 minimum); unaligned lengths
     # (ViT-B/16 at 224px -> 197 tokens, ViT_Tiny/32 -> 17) take the
-    # materialized fallback instead of crashing on TPU (ADVICE r3 medium).
+    # materialized attention instead of crashing on TPU (ADVICE r3 medium).
     kernel_t = q.shape[1] if layout == "bthk" else q.shape[2]
     kv_t = k.shape[1] if layout == "bthk" else k.shape[2]
-    if not flash_available() or kernel_t % 128 or kv_t % 128:
+    unaligned = kernel_t % 128 or kv_t % 128
+    if flash_available() and unaligned:
+        _warn_unaligned_once(kernel_t, kv_t)
+    if not flash_available() or unaligned:
         if layout == "bhtk":
             q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
         out = local_attention(q, k, v, causal=causal)

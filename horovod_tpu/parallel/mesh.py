@@ -34,19 +34,34 @@ LOCAL_AXIS = "local"   # intra-node / ICI axis
 
 logger = logging.getLogger("horovod_tpu")
 
-# Nominal per-participant link bandwidths in GB/s, by platform — the
-# roofline the bench sweep (bench.bench_busbw) reports achieved bus
-# bandwidth against. These are order-of-magnitude figures for the
-# *selection* layer (an ICI hop is ~10x a DCN hop on every TPU
-# generation), not calibrated hardware specs: the algorithm choice only
-# depends on the ratio and the bench reports both sides so the gap is
-# always visible.
+# Nominal per-chip link bandwidths in GB/s, keyed by ``device_kind`` as jax
+# reports it, each with its source — the roofline the bus-bandwidth sweep
+# reports achieved bandwidth against, and the ICI:DCN ratio the selection
+# layer reads. A TPU kind that is not here is an error (see
+# :func:`nominal_link_gbps`): another generation's figures would make every
+# roofline share computed from them wrong without a sign.
 _NOMINAL_LINK_GBPS = {
-    # (ici_gbps, dcn_gbps)
-    "tpu": (90.0, 12.5),   # v4/v5p-class ICI vs per-host DCN NIC
-    "gpu": (50.0, 12.5),   # NVLink-class vs host NIC
-    "cpu": (8.0, 1.0),     # test worlds: keep the 1:8 shape
+    # device_kind: (ici_gbps, dcn_gbps)
+    # ICI: Google Cloud "TPU v5e" — 1,600 Gbit/s chip-to-chip interconnect
+    # per chip. DCN: no per-chip figure is published; 12.5 GB/s is one
+    # 100 Gbit/s host NIC, an assumption nothing on a single host reads.
+    "TPU v5 lite": (200.0, 12.5),
+    # forced-host-device test worlds: not a device figure — keeps the 1:8
+    # fast:slow shape the selection tests are written against
+    "cpu": (8.0, 1.0),
 }
+
+
+def nominal_link_gbps(device_kind: str) -> Tuple[float, float]:
+    """``(ici_gbps, dcn_gbps)`` for a ``device_kind`` the table knows;
+    anything else raises."""
+    try:
+        return _NOMINAL_LINK_GBPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no nominal link rates for device_kind {device_kind!r}; known: "
+            f"{sorted(_NOMINAL_LINK_GBPS)}. Add the kind with its source to "
+            f"parallel/mesh.py _NOMINAL_LINK_GBPS") from None
 
 
 @dataclass(frozen=True)
@@ -308,11 +323,12 @@ def detect_topology(size: Optional[int] = None,
     """
     override = os.environ.get(env_mod.HOROVOD_TPU_LOCAL_SIZE)
     source = "flat"
-    platform = "cpu"
+    platform = kind = "cpu"
     devs: Sequence[jax.Device] = ()
     if devices is not None or size is None:
         devs = list(devices) if devices is not None else list(jax.devices())
-        platform = getattr(devs[0], "platform", "cpu") if devs else "cpu"
+        if devs:
+            platform, kind = devs[0].platform, devs[0].device_kind
         if size is None:
             size = len(devs)
     parsed_override = None
@@ -345,7 +361,7 @@ def detect_topology(size: Optional[int] = None,
             "demote to flat when no non-trivial divisor exists)",
             local_size, size, fallback)
         local_size = fallback
-    ici, dcn = _NOMINAL_LINK_GBPS.get(platform, _NOMINAL_LINK_GBPS["cpu"])
+    ici, dcn = nominal_link_gbps(kind)
     return Topology(size=int(size), local_size=int(local_size),
                     platform=platform, source=source,
                     ici_gbps=ici, dcn_gbps=dcn)

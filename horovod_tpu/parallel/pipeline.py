@@ -207,28 +207,21 @@ def pipeline_apply_p(stage_fn: Callable, stage_params, micro_inputs,
 
 def _vma_of(x):
     """The set of manual axes ``x`` is varying over (empty outside manual
-    regions / on older jax)."""
-    try:
-        return set(jax.typeof(x).vma)
-    except Exception:
-        return set()
+    regions and under ``check_vma=False``)."""
+    return set(jax.typeof(x).vma)
 
 
 def _vary(x, axes):
     """Mark ``x`` varying over ``axes`` (a name or tuple of names —
     shard_map VMA typing); only the axes it is not ALREADY varying over
-    are cast (pcast rejects re-varying an axis, and a blanket try/except
-    would then silently skip the whole cast). No-op outside manual
-    regions / on older jax."""
+    are cast (pcast rejects re-varying an axis). A no-op on the value
+    under ``check_vma=False``."""
     if isinstance(axes, str):
         axes = (axes,)
     need = tuple(a for a in axes if a not in _vma_of(x))
     if not need:
         return x
-    try:
-        return lax.pcast(x, need, to="varying")
-    except Exception:
-        return x
+    return lax.pcast(x, need, to="varying")
 
 
 def pipeline_train_1f1b(stage_fn: Callable, stage_params, micro_inputs,

@@ -74,11 +74,9 @@ KIND_EMPTY, KIND_DIAG, KIND_FULL = 0, 1, 2
 def _vary_like(x, ref):
     """Mark ``x`` varying over ``ref``'s manual axes (shard_map VMA typing)
     so scan carries / switch branches initialized from constants match the
-    data-derived branches' types; a no-op outside manual regions."""
-    try:
-        vma = tuple(jax.typeof(ref).vma)
-    except (AttributeError, TypeError):
-        return x
+    data-derived branches' types; a no-op outside manual regions and
+    under ``check_vma=False``, where nothing is tracked."""
+    vma = tuple(jax.typeof(ref).vma)
     return lax.pcast(x, vma, to="varying") if vma else x
 
 
@@ -103,37 +101,11 @@ def _chunk_len(tk: int) -> int:
 
 
 # The ring's per-block kernels reach into PRIVATE names of the stock Pallas
-# flash module (_flash_attention, _flash_attention_bwd_dkv/_dq, BlockSizes);
-# a jax bump can remove or rename them while the module itself still
-# imports, which would break only the TPU ring path — and only at trace
-# time. Probe once, warn once, and fall back to the bit-compatible chunked
-# pure-JAX kernels (_seg_fwd_jax/_seg_bwd_jax) so the bump fails loudly in
-# the log instead of silently breaking ring attention (ADVICE r5).
-_PALLAS_SEG_PROBE: dict = {}
-
-
-def _pallas_seg_importable() -> bool:
-    if "ok" not in _PALLAS_SEG_PROBE:
-        try:
-            from jax.experimental.pallas.ops.tpu import flash_attention as fa
-            for attr in ("_flash_attention", "_flash_attention_bwd_dkv",
-                         "_flash_attention_bwd_dq", "BlockSizes",
-                         "DEFAULT_MASK_VALUE"):
-                if not hasattr(fa, attr):
-                    raise ImportError(
-                        f"jax.experimental.pallas.ops.tpu.flash_attention."
-                        f"{attr} is gone")
-            _PALLAS_SEG_PROBE["ok"] = True
-        except Exception as e:
-            _PALLAS_SEG_PROBE["ok"] = False
-            import logging
-            logging.getLogger("horovod_tpu").warning(
-                "Pallas flash-attention internals unavailable (%s: %s); "
-                "ring attention falls back to the chunked pure-JAX segment "
-                "kernels — correct but slower on TPU. Pin jax or update "
-                "parallel/ring_attention.py for the new kernel API.",
-                type(e).__name__, e)
-    return _PALLAS_SEG_PROBE["ok"]
+# flash module (_flash_attention, _flash_attention_bwd_dkv/_dq, BlockSizes,
+# DEFAULT_MASK_VALUE). They are imported at the call sites below with no
+# guard: a jax bump that renames one is an ImportError/AttributeError at
+# the first TPU trace of the ring, never a silent switch to the chunked
+# pure-JAX kernels (which remain for CPU and for 128-unaligned blocks).
 
 
 def _pallas_seg_ok(s: int) -> bool:
@@ -141,8 +113,7 @@ def _pallas_seg_ok(s: int) -> bool:
             "1", "true", "yes", "on"):
         return False
     from .flash_attention import flash_available
-    return (flash_available() and _pallas_seg_importable()
-            and s >= 128 and s % 128 == 0)
+    return flash_available() and s >= 128 and s % 128 == 0
 
 
 # Preferred Pallas block size for the ring's per-segment kernels; 1024 is

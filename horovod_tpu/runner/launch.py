@@ -17,6 +17,7 @@ role of the reference's MPIController/rendezvous combo (SURVEY.md §2.9).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import socket
 import sys
@@ -36,18 +37,117 @@ LOCAL_HOSTNAMES = {"localhost", "127.0.0.1", "::1"}
 COORDINATOR_VIA_RENDEZVOUS = "@rendezvous"
 
 
+# non-HOROVOD variables a remote worker (ssh / task agent) is started with
+_FORWARDED_ENV = ("PATH", "PYTHONPATH", "XLA_FLAGS", "JAX_PLATFORMS",
+                  "JAX_COMPILATION_CACHE_DIR", "TPU_NAME", "LD_LIBRARY_PATH")
+
+
 def is_local_host(hostname: str) -> bool:
     return (hostname in LOCAL_HOSTNAMES
             or hostname == socket.gethostname()
             or hostname == socket.getfqdn())
 
 
+# -- one process per TPU chip ------------------------------------------------
+# libtpu gives every chip of a host to the first process that asks. Several
+# worker processes on one TPU host therefore each get ONE chip, named through
+# libtpu's own process-grid variables, and together form one ICI-connected
+# world. The launcher decides this from what it can observe without touching
+# a jax backend (it must not hold the chips its workers need): the usable
+# TPU chips of its own host, and the slot's local_size.
+_GOOGLE_PCI_VENDOR_ID = "0x1ae0"
+_TPU_PCI_DEVICE_IDS = {"0x0063"}            # TPU v5e
+_TPU_PROCESS_BASE_PORT = 8476
+# chips on the host -> the host's chip grid, one chip per process. Only what
+# has run is here: the four-chip v5e host (PR 21).
+_TPU_HOST_GRID = {4: "2,2,1"}
+
+
+def local_tpu_chips(sysfs: str = "/sys/bus/pci/devices",
+                    dev: str = "/dev") -> int:
+    """TPU chips THIS host may use: the TPU functions on its PCI bus that
+    have a device node (``/dev/vfio/<group>`` on v5e). A machine that was
+    given one chip of a four-chip board shows four on the bus and one
+    node."""
+    on_bus = 0
+    for vendor_path in glob.glob(os.path.join(sysfs, "*", "vendor")):
+        with open(vendor_path) as f:
+            if f.read().strip() != _GOOGLE_PCI_VENDOR_ID:
+                continue
+        with open(os.path.join(os.path.dirname(vendor_path),
+                               "device")) as f:
+            on_bus += f.read().strip() in _TPU_PCI_DEVICE_IDS
+    nodes = len(glob.glob(os.path.join(dev, "vfio", "[0-9]*")))
+    return min(on_bus, nodes)
+
+
+def keeps_off_tpu(env: Dict[str, str]) -> bool:
+    """Whether ``env`` pins jax to platforms other than the TPU (the CPU
+    test worlds set ``JAX_PLATFORMS=cpu``): such a world gets nothing from
+    the launcher that it did not get before there was a chip."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    return bool(platforms) and "tpu" not in platforms.split(",")
+
+
+def tpu_chip_binding(slot: SlotInfo,
+                     tpu_chips: Optional[int] = None) -> Dict[str, str]:
+    """The libtpu variables that give local rank ``i`` chip ``i`` only, or
+    ``{}`` where nothing is to be divided: a host without TPU chips
+    (``tpu_chips``; observed on this host when None), a remote slot (its
+    bus cannot be seen from here), or a single local process, which owns
+    every chip of its host as the SPMD path wants."""
+    if slot.local_size <= 1 or not is_local_host(slot.hostname):
+        return {}
+    if tpu_chips is None:
+        tpu_chips = local_tpu_chips()
+    if not tpu_chips:
+        return {}
+    if slot.cross_size > 1 or slot.local_size != tpu_chips \
+            or tpu_chips not in _TPU_HOST_GRID:
+        raise ValueError(
+            f"tpurun: cannot give each of {slot.local_size} local processes "
+            f"one chip of a {tpu_chips}-chip TPU host across "
+            f"{slot.cross_size} host(s): one process per chip is supported "
+            f"on a single host with -np equal to its chip count "
+            f"({sorted(_TPU_HOST_GRID)}); run one process per host (SPMD "
+            f"over its chips) otherwise")
+    ports = [_TPU_PROCESS_BASE_PORT + i for i in range(slot.local_size)]
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_HOST_GRID[tpu_chips],
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot.local_rank]),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+    }
+
+
+def accelerator_env(slot: SlotInfo, env: Dict[str, str],
+                    tpu_chips: Optional[int] = None) -> Dict[str, str]:
+    """What every worker-env builder adds for the accelerator: the one
+    compile-cache directory all workers of the job compile into and read
+    (jax reads the variable itself, so a worker script need not call
+    ``use_compile_cache``), and the slot's chip binding. Empty for a world
+    pinned off the TPU."""
+    if keeps_off_tpu(env):
+        return {}
+    out = {env_mod.JAX_COMPILATION_CACHE_DIR:
+           env.get(env_mod.JAX_COMPILATION_CACHE_DIR)
+           or env_mod.compile_cache_dir()}
+    out.update(tpu_chip_binding(slot, tpu_chips))
+    return out
+
+
 def make_worker_env(slot: SlotInfo, coordinator_addr: str,
                     rendezvous_addr: str, rendezvous_port: int,
                     base_env: Optional[Dict[str, str]] = None,
-                    elastic: bool = False) -> Dict[str, str]:
-    """Build the env block a worker boots from (gloo_run.py:77-97 parity)."""
+                    elastic: bool = False,
+                    tpu_chips: Optional[int] = None) -> Dict[str, str]:
+    """Build the env block a worker boots from (gloo_run.py:77-97 parity).
+    ``tpu_chips`` is the TPU chip count of the slot's host (None: observe
+    this host's); see :func:`tpu_chip_binding`."""
     env = dict(base_env if base_env is not None else os.environ)
+    env.update(accelerator_env(slot, env, tpu_chips))
     env.update({
         env_mod.HOROVOD_RANK: str(slot.rank),
         env_mod.HOROVOD_SIZE: str(slot.size),
@@ -87,9 +187,7 @@ def slot_command(command: List[str], env: Dict[str, str], slot: SlotInfo,
     if is_local_host(slot.hostname):
         return cmd
     exports = " ".join(f"{k}={shlex.quote(v)}" for k, v in sorted(env.items())
-                       if k.startswith("HOROVOD") or k in
-                       ("PATH", "PYTHONPATH", "XLA_FLAGS", "JAX_PLATFORMS",
-                        "TPU_NAME", "LD_LIBRARY_PATH"))
+                       if k.startswith("HOROVOD") or k in _FORWARDED_ENV)
     remote = f"cd {shlex.quote(os.getcwd())} > /dev/null 2>&1 ; {exports} {cmd}"
     return get_ssh_command(remote, slot.hostname, ssh_port, identity_file)
 
@@ -439,9 +537,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         # ship only what workers need, never the driver's whole environment
         # (it contains HOROVOD_TASK_SECRET; the RPC is signed, not encrypted)
         agent_env = {k: v for k, v in base_env.items()
-                     if k.startswith("HOROVOD") or k in
-                     ("PATH", "PYTHONPATH", "XLA_FLAGS", "JAX_PLATFORMS",
-                      "TPU_NAME", "LD_LIBRARY_PATH")}
+                     if k.startswith("HOROVOD") or k in _FORWARDED_ENV}
         agent_env.pop("HOROVOD_TASK_SECRET", None)
         launch_via_task_agents(args.task_agents.split(","),
                                bytes.fromhex(key_hex), args.num_proc,

@@ -46,9 +46,15 @@ def test_env_knob_switches_impl(monkeypatch):
 
 
 def test_pack_pallas_matches_concat():
+    from horovod_tpu.ops.pallas_kernels import pack_pallas_supported
     rng = np.random.RandomState(3)
-    ts = [jnp.asarray(rng.randn(*s), jnp.float32)
-          for s in [(5,), (3, 4), (2, 2, 2), (1,)]]
+    # whole 1-D tiles only: Mosaic refuses ragged tensors on the chip, and
+    # the engine does not offer them to the kernel
+    shapes = [(1024,), (8, 128), (2, 4, 128), (2048,)]
+    assert pack_pallas_supported(shapes, jnp.float32)
+    assert not pack_pallas_supported([(5,), (3, 4), (1024,)], jnp.float32)
+    assert not pack_pallas_supported([(1024,)], jnp.bfloat16)
+    ts = [jnp.asarray(rng.randn(*s), jnp.float32) for s in shapes]
     got = np.asarray(pack_pallas(ts))
     want = np.concatenate([np.asarray(t).ravel() for t in ts])
     np.testing.assert_array_equal(got, want)

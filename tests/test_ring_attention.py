@@ -17,21 +17,7 @@ def _mesh_seq(n=4):
     return jax.sharding.Mesh(_np.array(devs), ("seq",))
 
 
-# Known container-dependent failure (present since PR 6's seed audit):
-# the non-causal variant trips a jaxlib crash inside shard_map on the
-# jax 0.4.x line this image ships; it passes on jax >= 0.5. Gate it on
-# the version explicitly so tier-1 is green-or-skipped, never red, on
-# old jax (ISSUE 9 satellite).
-_JAX_PRE_05 = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-
-
-@pytest.mark.parametrize("causal", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        _JAX_PRE_05,
-        reason="non-causal ring attention crashes in jaxlib on the "
-               "container's jax 0.4.x (pre-existing; fixed by jax>=0.5)")),
-])
+@pytest.mark.parametrize("causal", [True, False])
 def test_ring_matches_local(causal):
     mesh = _mesh_seq(4)
     B, T, H, D = 2, 16, 4, 8  # T global; 4 per block... T_local = 4

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import horovod_tpu as hvd  # installs the jax compat shims first
+import horovod_tpu as hvd
 from jax import shard_map
 from horovod_tpu import optimizer as hopt
 from horovod_tpu.optimizer import (DistributedEagerOptimizer,
@@ -62,6 +62,34 @@ def _spmd_train(dist, params, x, y, mesh, state_specs, steps=4,
     for _ in range(steps):
         p, s = step(p, s, xb, yb)
     return p
+
+
+@pytest.mark.parametrize("shard_optimizer", [False, True],
+                         ids=["dense", "zero1"])
+def test_spmd_update_is_global_mean_gradient(shard_optimizer):
+    """jax.grad and hvd_opt.distributed inside ONE shard_map (the README's
+    SPMD quickstart): with sgd(1.0) the first update must be minus the
+    gradient of the whole batch. Under check_vma=False — what a shard_map
+    holding a Pallas kernel has to use on the chip — nothing is tracked,
+    jax.grad inserts no psum, and the gradients are local: taking them for
+    pre-summed skipped the allreduce (ISSUE 21; chip_smoke.py's quickstart
+    phase is the chip-side check)."""
+    mesh = Mesh(np.array(jax.devices()), ("world",))
+    params = _params()
+    x, y = _batch()
+    dist = hopt.distributed(optax.sgd(1.0), axis_name="world",
+                            op=hvd.Average, axis_size=8,
+                            shard_optimizer=shard_optimizer)
+    specs = (zero1_state_specs(jax.eval_shape(dist.init, params), "world")
+             if shard_optimizer else P())
+    got = _spmd_train(dist, params, x, y, mesh, specs, steps=1,
+                      init_inside=shard_optimizer)
+    want = jax.tree_util.tree_map(
+        lambda p, g: p - g, params, jax.grad(mlp_loss)(params, (x, y)))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("make_inner", [

@@ -103,6 +103,7 @@ class TestTopology:
 
         class FakeDev:
             platform = "tpu"
+            device_kind = "TPU v5 lite"
 
             def __init__(self, slice_index, process_index):
                 self.slice_index = slice_index
@@ -112,6 +113,19 @@ class TestTopology:
         t = detect_topology(devices=devs)
         assert t.local_size == 4 and t.source == "slice_attrs"
         assert t.platform == "tpu" and t.hierarchical_ok
+        # v5e: 1,600 Gbit/s chip-to-chip (Google Cloud "TPU v5e")
+        assert t.ici_gbps == 200.0
+
+    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        monkeypatch.delenv(HOROVOD_TPU_LOCAL_SIZE, raising=False)
+
+        class FakeDev:
+            platform = "tpu"
+            device_kind = "TPU v9"
+            process_index = 0
+
+        with pytest.raises(ValueError, match="TPU v9"):
+            detect_topology(devices=[FakeDev()])
 
 
 # ---------------------------------------------------------------------------

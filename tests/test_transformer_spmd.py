@@ -129,11 +129,6 @@ def test_ulysses_attention_variant_matches_ring():
     np.testing.assert_allclose(losses["ring"], losses["ulysses"], rtol=2e-5)
 
 
-@pytest.mark.skipif(
-    tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="shard_map raises _SpecError for the MoE train step's out_specs "
-           "on the container's jax 0.4.x (pre-existing since PR 6's seed "
-           "audit; passes on jax >= 0.5)")
 def test_moe_variant_trains_and_matches_across_meshes():
     """use_moe=True: the train step runs on a (data, seq, tensor=expert)
     mesh; the SPMD loss equals the single-device loss for the same params

@@ -75,5 +75,11 @@ def probe(batch, iters=10):
 
 
 if __name__ == "__main__":
+    from horovod_tpu.common.env import use_compile_cache
+    use_compile_cache()
+    platforms = sorted({d.platform for d in jax.devices()})
+    if platforms != ["tpu"]:
+        raise SystemExit(f"perf_probe.py measures the TPU step; visible "
+                         f"platforms are {platforms}")
     for b in [int(a) for a in sys.argv[1:]] or [128, 256]:
         probe(b)
