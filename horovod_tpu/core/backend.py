@@ -5,8 +5,9 @@ Plays the role of the reference's control-plane contexts
 gloo/gloo_context.cc:127-219 rendezvous) on top of the JAX distributed
 coordinator. Topology:
 
-- **rank/size** — process-level, like an MPI rank (``jax.process_index`` /
-  ``jax.process_count``).
+- **rank/size** — process-level, like an MPI rank: the id the launcher gave
+  the process (``HOROVOD_RANK``), ``jax.process_index`` in a world jax was
+  initialised for by someone else; ``jax.process_count``.
 - **local_rank/local_size** — position within the host (derived from
   HOROVOD_LOCAL_RANK env set by the launcher, or 0/1).
 - **cross_rank/cross_size** — position across hosts at the same local rank
@@ -38,6 +39,7 @@ class Backend:
         self._initialized = False
         self._removed = False
         self._rank = 0
+        self._proc_id = None
         self._size = 1
         self._local_rank = 0
         self._local_size = 1
@@ -79,6 +81,7 @@ class Backend:
             os.environ[env_mod.HOROVOD_RANK] = str(slot.rank)
         coord = os.environ.get(env_mod.HOROVOD_TPU_COORDINATOR)
         nprocs = os.environ.get(env_mod.HOROVOD_TPU_NUM_PROCESSES)
+        proc_id = None
         if coord and nprocs and int(nprocs) > 1:
             proc_id = int(os.environ.get(env_mod.HOROVOD_TPU_PROCESS_ID,
                                          os.environ.get(env_mod.HOROVOD_RANK, "0")))
@@ -106,7 +109,17 @@ class Backend:
                 heartbeat_timeout_seconds=heartbeat,
                 shutdown_timeout_seconds=shutdown_t)
             self._distributed = True
-        self._rank = jax.process_index()
+        # The rank is the one the launcher gave this process (HOROVOD_RANK,
+        # the reference's contract): the launcher's output prefix, the
+        # elastic driver and the failpoints number processes by it. jax's
+        # own process index is the PJRT client's; on a TPU host libtpu
+        # derives it from the chip's place in the grid, which need not
+        # follow the launcher's slots (found on the four-chip v5e host,
+        # PR 21). proc_id is the coordination service's numbering, kept for
+        # what concerns that service (who hosts it, who leaves it last).
+        self._proc_id = proc_id
+        self._rank = jax.process_index() if proc_id is None else \
+            int(os.environ.get(env_mod.HOROVOD_RANK, proc_id))
         self._size = jax.process_count()
         if slot is not None:
             self._local_rank = slot.local_rank
@@ -121,18 +134,41 @@ class Backend:
             self._cross_size = int(os.environ.get(env_mod.HOROVOD_CROSS_SIZE,
                                                   str(max(1, self._size // max(self._local_size, 1)))))
         # One device per process for the eager group mesh. Pick each process's
-        # first local device, ordered by process index.
+        # first local device, ordered by rank.
         per_proc = {}
         for d in jax.devices():
             per_proc.setdefault(d.process_index, d)
-        devs = [per_proc[i] for i in sorted(per_proc.keys())]
-        if len(devs) != self._size:
+        if len(per_proc) != self._size:
             raise HorovodInternalError(
-                f"expected one device per process ({self._size}), found {len(devs)}")
+                f"expected one device per process ({self._size}), found "
+                f"{len(per_proc)}")
+        order = self._process_index_of_each_rank() if self._distributed \
+            else sorted(per_proc)
+        devs = [per_proc[i] for i in order]
         self._group_mesh = Mesh(np.array(devs), (WORLD_AXIS,))
         self._group_sharding = NamedSharding(self._group_mesh, P(WORLD_AXIS))
         self._rep_sharding = NamedSharding(self._group_mesh, P())
         self._initialized = True
+
+    def _process_index_of_each_rank(self):
+        """jax's process index of rank 0, 1, ...: every process posts its
+        own under its rank in the coordination service's key-value store
+        and reads the others' (no device program; the service is new with
+        every ``jax.distributed.initialize``, so an elastic re-init posts
+        into an empty store)."""
+        from jax._src import distributed
+        client = distributed.global_state.client
+        timeout_ms = 1000 * int(float(os.environ.get(
+            env_mod.HOROVOD_GLOO_TIMEOUT_SECONDS, "120")))
+        client.key_value_set(f"hvd_tpu/process_index/{self._rank}",
+                             str(jax.process_index()))
+        order = [int(client.blocking_key_value_get(
+            f"hvd_tpu/process_index/{r}", timeout_ms))
+            for r in range(self._size)]
+        if sorted(order) != list(range(self._size)):
+            raise HorovodInternalError(
+                f"ranks do not map one-to-one onto jax processes: {order}")
+        return order
 
     def _fetch_elastic_slot(self):
         """Long-poll the elastic rendezvous for this worker's SlotInfo.
@@ -218,7 +254,8 @@ class Backend:
 
     def _ordered_distributed_shutdown(self):
         """Tear down the JAX distributed client with coordinator-last
-        ordering.
+        ordering. ("Rank" below is the coordination service's process id,
+        ``self._proc_id``: the service lives in process 0 of ITS numbering.)
 
         Recoverable mode (enabled for elastic worlds) removes the
         coordination service's shutdown barrier, so teardown order becomes a
@@ -250,13 +287,13 @@ class Backend:
         import time as _time
         version = os.environ.get("HOROVOD_TPU_WORLD_VERSION", "0")
         scope = f"shutdown.v{version}"
-        if self._rank != 0:
+        if self._proc_id != 0:
             try:
                 jax.distributed.shutdown()
             finally:
                 try:
                     put_data_into_kvstore(rdv_addr, int(rdv_port), scope,
-                                          str(self._rank), b"1", timeout=5)
+                                          str(self._proc_id), b"1", timeout=5)
                 except Exception:
                     pass
             return

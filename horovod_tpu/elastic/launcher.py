@@ -100,6 +100,11 @@ def launch_elastic_job(discovery, np: int, command: List[str],
                           network_interfaces)
 
     def _create_worker(slot: SlotInfo):
+        # on the driver's activating thread, before the slot's own thread
+        # exists: a slot that cannot be placed (tpu_chip_binding) fails
+        # driver.start() here, or stop(error)s the job on a resume. The
+        # refusal is the same for every slot of this host, so the first of
+        # them raises before any worker holds a chip.
         env = make_elastic_worker_env(slot, _rdv_addr_for(slot), server.port,
                                       base_env)
         cmd = slot_command(command, env, slot, ssh_port, identity_file)
@@ -154,7 +159,7 @@ def launch_elastic(args, command: List[str],
                            identity_file=args.ssh_identity_file,
                            network_interfaces=_parse_interfaces(args),
                            verbose=args.verbose)
-    except (RuntimeError, TimeoutError) as e:
+    except (RuntimeError, TimeoutError, ValueError) as e:
         print(str(e), file=sys.stderr)
         return 1
     return 0

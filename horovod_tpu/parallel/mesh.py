@@ -60,8 +60,10 @@ def nominal_link_gbps(device_kind: str) -> Tuple[float, float]:
     except KeyError:
         raise ValueError(
             f"no nominal link rates for device_kind {device_kind!r}; known: "
-            f"{sorted(_NOMINAL_LINK_GBPS)}. Add the kind with its source to "
-            f"parallel/mesh.py _NOMINAL_LINK_GBPS") from None
+            f"{sorted(_NOMINAL_LINK_GBPS)} (the TPU generations this program "
+            f"has run on, and the CPU test worlds; GPUs and other "
+            f"accelerators are not supported). Add a TPU kind with its "
+            f"source to parallel/mesh.py _NOMINAL_LINK_GBPS") from None
 
 
 @dataclass(frozen=True)
@@ -323,14 +325,15 @@ def detect_topology(size: Optional[int] = None,
     """
     override = os.environ.get(env_mod.HOROVOD_TPU_LOCAL_SIZE)
     source = "flat"
-    platform = kind = "cpu"
     devs: Sequence[jax.Device] = ()
     if devices is not None or size is None:
         devs = list(devices) if devices is not None else list(jax.devices())
-        if devs:
-            platform, kind = devs[0].platform, devs[0].device_kind
         if size is None:
             size = len(devs)
+    # the link rates are the device's own, also for a world given by size
+    # alone: never another platform's by default
+    first = devs[0] if devs else jax.devices()[0]
+    platform, kind = first.platform, first.device_kind
     parsed_override = None
     if override:
         try:

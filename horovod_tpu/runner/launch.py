@@ -222,19 +222,25 @@ def launch_static(hosts: List[HostInfo], np: int, command: List[str],
     failure = threading.Event()
     exit_codes: Dict[int, int] = {}
 
-    def _work(slot: SlotInfo):
-        env = make_worker_env(slot, coordinator_addr, driver_ip, server.port,
-                              base_env)
-        cmd = slot_command(command, env, slot, ssh_port, identity_file)
-        code = safe_shell_exec.execute(cmd, env=env, index=slot.rank,
-                                       events=[failure])
-        exit_codes[slot.rank] = code
-        if code != 0:
-            failure.set()
+    def _work(slot: SlotInfo, env: Dict[str, str]):
+        try:
+            cmd = slot_command(command, env, slot, ssh_port, identity_file)
+            exit_codes[slot.rank] = safe_shell_exec.execute(
+                cmd, env=env, index=slot.rank, events=[failure])
+        finally:
+            # a thread that died before its worker ran is a failed worker
+            if exit_codes.get(slot.rank) != 0:
+                failure.set()
 
-    threads = [threading.Thread(target=_work, args=(s,), daemon=True)
-               for s in assignments]
     try:
+        # Every slot's env is built here, before anything starts: a slot the
+        # launcher cannot place (tpu_chip_binding) fails the launch on this
+        # thread, not a worker thread nobody reads.
+        threads = [threading.Thread(
+            target=_work, daemon=True,
+            args=(s, make_worker_env(s, coordinator_addr, driver_ip,
+                                     server.port, base_env)))
+            for s in assignments]
         for t in threads:
             t.start()
         for t in threads:
@@ -242,7 +248,14 @@ def launch_static(hosts: List[HostInfo], np: int, command: List[str],
     finally:
         server.stop()
 
-    bad = {r: c for r, c in exit_codes.items() if c != 0}
+    _raise_unless_all_zero({s.rank: exit_codes.get(s.rank)
+                            for s in assignments})
+
+
+def _raise_unless_all_zero(codes: Dict[int, Optional[int]]) -> None:
+    """Fail the job unless every slot ran to exit code 0; a slot with no
+    recorded exit code (None) never ran."""
+    bad = {r: c for r, c in codes.items() if c != 0}
     if bad:
         raise RuntimeError(
             f"tpurun: {len(bad)} worker(s) exited non-zero: {bad}")
@@ -314,12 +327,14 @@ def launch_via_task_agents(agent_addrs: List[str], key: bytes, np: int,
                   f"{server.port}", file=sys.stderr)
         slot_clients = [(s, agent_of_slot[(s.hostname, s.local_rank)])
                         for s in assignments]
-        for slot, client in slot_clients:
-            # base_env is the caller's explicit worker env (the CLI path
-            # pre-filters os.environ); the job secret must never ride along
-            # — the RPC channel is authenticated, not encrypted.
-            env = make_worker_env(slot, COORDINATOR_VIA_RENDEZVOUS,
-                                  driver_ip, server.port, base_env or {})
+        # base_env is the caller's explicit worker env (the CLI path
+        # pre-filters os.environ); the job secret must never ride along
+        # — the RPC channel is authenticated, not encrypted. Every env is
+        # built before any agent starts a command (see launch_static).
+        envs = [make_worker_env(slot, COORDINATOR_VIA_RENDEZVOUS, driver_ip,
+                                server.port, base_env or {})
+                for slot, _ in slot_clients]
+        for (slot, client), env in zip(slot_clients, envs):
             env.pop("HOROVOD_TASK_SECRET", None)
             res = client.run_command(command, env=env)
             if not res.get("started"):
@@ -360,10 +375,8 @@ def launch_via_task_agents(agent_addrs: List[str], key: bytes, np: int,
                         timeout=15)
                 except Exception:
                     codes[rank] = -1
-        bad = {r: c for r, c in codes.items() if c != 0}
-        if bad:
-            raise RuntimeError(
-                f"tpurun: {len(bad)} worker(s) exited non-zero: {bad}")
+        _raise_unless_all_zero({s.rank: codes.get(s.rank)
+                                for s, _ in slot_clients})
     finally:
         server.stop()
 

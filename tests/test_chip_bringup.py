@@ -90,6 +90,66 @@ def test_binding_refuses_what_it_cannot_place():
         launch.tpu_chip_binding(slots[0], 4)
 
 
+@pytest.fixture
+def four_chip_host(monkeypatch, tmp_path):
+    """This host reports four TPU chips and the world is not pinned to the
+    CPU; returns (worker command, the file a started worker would leave)."""
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    marker = tmp_path / "a_worker_ran"
+    return [sys.executable, "-c",
+            f"open({str(marker)!r}, 'w').close()"], marker
+
+
+def test_launcher_fails_when_it_cannot_place_a_slot(four_chip_host):
+    """-np 2 on a four-chip host: the refusal must be the launcher's own
+    failure (it once died in the worker threads and the launcher exited 0
+    having started nothing)."""
+    command, marker = four_chip_host
+    with pytest.raises(ValueError, match="one process per chip"):
+        launch.main(["-np", "2"] + command)
+    assert not marker.exists()
+
+
+def test_elastic_launcher_fails_when_it_cannot_place_a_slot(four_chip_host):
+    command, marker = four_chip_host
+    assert launch.main(["-np", "2", "--min-np", "2", "-H", "localhost:2"]
+                       + command) == 1
+    assert not marker.exists()
+
+
+def test_task_agent_launch_fails_before_any_agent_starts(four_chip_host):
+    from horovod_tpu.runner.service import TaskService, make_secret_key
+    command, marker = four_chip_host
+    key = make_secret_key()
+    agents = [TaskService(key, addr=("127.0.0.1", 0)) for _ in range(2)]
+    for a in agents:
+        a.start()
+    try:
+        with pytest.raises(ValueError, match="one process per chip"):
+            launch.launch_via_task_agents(
+                [f"127.0.0.1:{a.port}" for a in agents], key, np=2,
+                command=command, base_env={}, timeout=30)
+    finally:
+        for a in agents:
+            a.stop()
+    assert not marker.exists()
+
+
+def test_slot_that_never_ran_fails_the_launch(monkeypatch):
+    """A worker thread that dies before its worker ran leaves no exit code;
+    that is a failed worker, not a success."""
+    def execute(cmd, env=None, index=None, events=None):
+        if index == 1:
+            raise OSError("could not start")
+        return 0
+    monkeypatch.setattr(launch.safe_shell_exec, "execute", execute)
+    monkeypatch.setattr(launch.threading, "excepthook", lambda args: None)
+    with pytest.raises(RuntimeError, match=r"\{1: None\}"):
+        launch.launch_static([HostInfo("localhost", 2)], 2, ["true"],
+                             {"JAX_PLATFORMS": "cpu"})
+
+
 def test_workers_share_one_cache_directory():
     slots = get_host_assignments([HostInfo("localhost", 2)], 2, 2)
     dirs = {_static(s, {}, 0)[env_mod.JAX_COMPILATION_CACHE_DIR]
@@ -184,7 +244,10 @@ def test_smoke_refuses_a_cpu():
 
 def test_smoke_rehearsal():
     """Every phase at toy widths on four forced CPU devices (about 30 s,
-    the phases after the first run side by side)."""
+    the phases after the first run side by side). Its four eager workers
+    imitate the four-chip host's process numbering: hvd.rank() must be the
+    launcher's slot and the world ordered by it where jax's process index
+    differs."""
     res = _smoke("--rehearse")
     assert res.returncode == 0, res.stdout[-4000:] + res.stderr[-4000:]
     last = json.loads(res.stdout.strip().splitlines()[-1])
@@ -218,7 +281,7 @@ def test_a_failed_phase_fails_the_smoke(monkeypatch, tmp_path, capsys):
         return [sys.executable, "-c", code]
 
     monkeypatch.setattr(chip_smoke.Runner, "child_cmd", child_cmd)
-    args = chip_smoke.argparse.Namespace(rehearse=False, seed=0)
+    args = chip_smoke.argparse.Namespace(rehearse=False)
     assert chip_smoke.parent(args) == 1
     out = capsys.readouterr().out
     assert "FAILED: ['kernels']" in out and '"ok"' not in out

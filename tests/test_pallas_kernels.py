@@ -194,3 +194,42 @@ class TestSplashRematSelection:
         monkeypatch.setenv("HOROVOD_SPLASH", "force")
         monkeypatch.delenv("HOROVOD_SPLASH_BLOCK_KV", raising=False)
         assert fa._select_kernel(2048, 128, under_remat=True) == "splash"
+
+
+def test_engine_offers_the_pack_kernel_aligned_buckets_only(monkeypatch):
+    """HOROVOD_PALLAS_PACK=1 in the engine: a bucket of whole tiles goes to
+    pack_pallas, a ragged one (which Mosaic refuses on the chip) to the
+    jitted concat, and both reduce to what the default path gives."""
+    import horovod_tpu as hvd
+    from horovod_tpu.ops import pallas_kernels as pk
+    monkeypatch.delenv("HOROVOD_PALLAS_PACK", raising=False)
+    hvd.init()
+    eng = hvd._engine()
+    rng = np.random.RandomState(4)
+    # two fusion buckets (one per dtype): whole f32 tiles, ragged bf16
+    tensors = [jnp.asarray(rng.randn(*s), jnp.float32)
+               for s in [(1024,), (8, 128)]]
+    tensors += [jnp.asarray(rng.randn(*s), jnp.bfloat16)
+                for s in [(5,), (3, 4)]]
+    kernel_buckets = []
+    real = pk.pack_pallas
+
+    def spy(bucket):
+        kernel_buckets.append([tuple(t.shape) for t in bucket])
+        return real(bucket)
+
+    monkeypatch.setattr(pk, "pack_pallas", spy)
+
+    def reduce(name):
+        return [np.asarray(h.synchronize(), np.float32)
+                for h in eng.grouped_allreduce(tensors, name=name)]
+
+    monkeypatch.setattr(eng, "_pack_pallas_base", False)
+    default = reduce("pack.default")
+    assert kernel_buckets == []
+    monkeypatch.setattr(eng, "_pack_pallas_base", True)
+    with_kernel = reduce("pack.kernel")
+    assert kernel_buckets == [[(1024,), (8, 128)]]
+    for got, want, t in zip(with_kernel, default, tensors):
+        assert got.shape == t.shape
+        np.testing.assert_array_equal(got, want)

@@ -127,6 +127,23 @@ class TestTopology:
         with pytest.raises(ValueError, match="TPU v9"):
             detect_topology(devices=[FakeDev()])
 
+    def test_world_given_by_size_takes_the_devices_own_rates(self,
+                                                             monkeypatch):
+        """No devices passed: the rates are those of the device jax has,
+        not the CPU row by default."""
+        monkeypatch.delenv(HOROVOD_TPU_LOCAL_SIZE, raising=False)
+
+        class FakeDev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+        monkeypatch.setattr(jax, "devices", lambda: [FakeDev()])
+        t = detect_topology(size=4, local_size=2)
+        assert (t.platform, t.ici_gbps, t.dcn_gbps) == ("tpu", 200.0, 12.5)
+        FakeDev.device_kind = "TPU v9"
+        with pytest.raises(ValueError, match="not supported"):
+            detect_topology(size=4)
+
 
 # ---------------------------------------------------------------------------
 # selection rules
