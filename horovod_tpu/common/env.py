@@ -114,7 +114,7 @@ HOROVOD_TPU_OVERLAP_STAGE_BYTES = "HOROVOD_TPU_OVERLAP_STAGE_BYTES"
 # stays inside the fused step program, the schedule replay sustains
 HOROVOD_TPU_ZERO1_PREFETCH = "HOROVOD_TPU_ZERO1_PREFETCH"
 # XLA latency-hiding scheduler as a supported knob (ISSUE 6 satellite,
-# folding tools/probe_resnet_overlap.py into the product): =1 appends
+# from the overlap experiment of docs/roofline.md section 3b): =1 appends
 # --xla_tpu_enable_latency_hiding_scheduler=true to XLA_FLAGS before the
 # first backend touch (loud WARNING + no-op if a jax backend already
 # exists — XLA parses XLA_FLAGS at backend init, not at import)
@@ -397,8 +397,8 @@ def apply_xla_lhs() -> bool:
     this must run before the first backend touch — it is called from
     ``horovod_tpu/__init__`` at import. If a jax backend already exists
     the append would be silently ignored; that case gets a loud WARNING
-    and a no-op instead (tools/probe_resnet_overlap.py passes the same
-    flag per compile through ``compiler_options``, which works at any
+    and a no-op instead (a program that must have the flag later can
+    pass it per compile through ``compiler_options``, which works at any
     time).
 
     Returns True when the flag is (already or newly) in effect."""

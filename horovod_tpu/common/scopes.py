@@ -1,0 +1,52 @@
+"""Names inside the compiled programs: the ``jax.named_scope`` names the
+product writes and the names of its jitted programs.
+
+A scope is metadata of the lowered program. It adds no operation and no
+host work, and there is no switch: every operation traced under it carries
+the name in its ``op_name``. jax writes the pass itself:
+``jit(train_step)/jvp()/layers/while/body/closed_call/attn/dot_general`` in
+the forward pass and ``jit(train_step)/transpose(jvp())/layers/...`` in the
+backward pass (through a ``shard_map``; without one the open scopes move
+inside the parentheses, ``jvp(head)``, ``transpose(jvp(loss))``). On the
+TPU the profiler keeps each executed operation's ``op_name`` as the
+``tf_op`` stat of the event's metadata; ``benchmark/scopes.py``,
+``benchmark/readers/trace_scopes.py`` and their metric files read these
+names, and ``docs/observability.md`` lists them ("Reading a device trace
+by scope"). A fusion has one ``op_name``, its root's.
+
+jax leaves metadata out of the persistent compilation cache's key, so a
+program cached before a scope was written is served without it. The
+program's name IS part of the key: a change to what the scopes cover that
+must show in traces takes a new program name with it.
+"""
+
+from __future__ import annotations
+
+import jax
+import optax
+
+# models/transformer.py, the step the LM cells run
+EMBED = "embed"         # _forward: the token lookup and its cast
+LAYERS = "layers"       # _forward: the lax.scan over the stacked layers
+ATTN = "attn"           # layer: rmsnorm, projections, attention, residual
+FFN = "ffn"             # layer: rmsnorm, dense or MoE branch, residual
+HEAD = "head"           # _forward: final rmsnorm, logits einsum, fp32 cast
+LOSS = "loss"           # _local_loss: log_softmax + gather; _lean_xent
+# optimizer.py and the step builders
+OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates
+DECOMPRESS = "decompress"       # eager apply program: what precedes them
+GRAD_REDUCE = "grad_reduce"     # distributed(): the cross-replica reduction
+
+# jitted programs, as the trace's ``XLA Modules`` line shows them (jit_<name>)
+TRAIN_STEP = "train_step"               # make_train_step
+APPLY_UPDATE = "hvd_apply_update"       # DistributedEagerOptimizer._apply_fn
+APPLY_DELTA = "hvd_apply_delta"         # the Adasum delta optimizer's twin
+
+
+def apply_update(optimizer, grads, opt_state, params):
+    """``optimizer.update`` + ``optax.apply_updates`` under the
+    ``optimizer`` scope; returns ``(params, opt_state)``."""
+    with jax.named_scope(OPTIMIZER):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+

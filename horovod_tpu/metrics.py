@@ -149,7 +149,7 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                   "line"),
     "hvd_tpu_hbm_bytes": (
         "gauge", "Device memory sampled off the hot path on the emitter "
-                 "thread, by kind (in_use/peak/limit) — the headroom "
+                 "thread, by kind (in_use/reserved/peak/limit) — the headroom "
                  "signal for admission control and memory-vs-MFU "
                  "tradeoffs"),
     "hvd_tpu_flight_dumps_total": (

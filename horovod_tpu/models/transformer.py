@@ -26,10 +26,10 @@ from typing import Any, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..common import scopes
 from ..parallel.moe import MoEParams, moe_layer_p
 from ..parallel.flash_attention import flash_attention_local
 from ..parallel.ring_attention import ring_attention_p, local_attention
@@ -171,7 +171,8 @@ def _forward(params, tokens, cfg: TransformerConfig,
     ``None`` outside shard_map (single-device path, no collectives).
     """
     dt = cfg.dtype
-    h = params["embed"][tokens].astype(dt)  # [B, T, D]
+    with jax.named_scope(scopes.EMBED):
+        h = params["embed"][tokens].astype(dt)  # [B, T, D]
 
     # flash wants [B, H, T, D]; projecting straight into that layout keeps
     # the transposes out of the hot path (they fold into the einsums)
@@ -219,51 +220,53 @@ def _forward(params, tokens, cfg: TransformerConfig,
 
     def layer(carry, lp):
         h, aux_sum = carry
-        # Attention
-        x = _rmsnorm(h, lp["ln1"])
-        h = h + attn_block(x, lp["wq"], lp["wk"], lp["wv"], lp["wo"])
-        # FFN: dense (TP over hidden dim) or MoE (EP over the same axis)
-        x = _rmsnorm(h, lp["ln2"])
-        if cfg.use_moe:
-            b, t, d = x.shape
-            mp = MoEParams(lp["router"], lp["w1"], lp["w2"])
-            tok = x.reshape(b * t, d)
-            if tensor_size is not None and tensor_size > 1:
-                # EP over the tensor axis: split this shard's tokens across
-                # the axis members (no duplicate expert compute), dispatch,
-                # and gather the processed tokens back
-                n = tensor_size
-                pad = (-tok.shape[0]) % n
-                n_tok = tok.shape[0]
-                if pad:
-                    tok = jnp.concatenate(
-                        [tok, jnp.zeros((pad, d), tok.dtype)])
-                per = tok.shape[0] // n
-                idx = lax.axis_index(TENSOR_AXIS)
-                mine = lax.dynamic_slice_in_dim(tok, idx * per, per)
-                # mask out pad rows: they must not route, take capacity,
-                # or skew the aux statistics
-                rows = idx * per + jnp.arange(per)
-                y_mine, aux = moe_layer_p(
-                    mine, mp, TENSOR_AXIS, n,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    valid_mask=rows < n_tok)
-                y2d = lax.all_gather(y_mine, TENSOR_AXIS, axis=0, tiled=True)
-                if pad:
-                    y2d = y2d[:-pad]
+        with jax.named_scope(scopes.ATTN):
+            x = _rmsnorm(h, lp["ln1"])
+            h = h + attn_block(x, lp["wq"], lp["wk"], lp["wv"], lp["wo"])
+        # dense (TP over hidden dim) or MoE (EP over the same axis)
+        with jax.named_scope(scopes.FFN):
+            x = _rmsnorm(h, lp["ln2"])
+            if cfg.use_moe:
+                b, t, d = x.shape
+                mp = MoEParams(lp["router"], lp["w1"], lp["w2"])
+                tok = x.reshape(b * t, d)
+                if tensor_size is not None and tensor_size > 1:
+                    # EP over the tensor axis: split this shard's tokens
+                    # across the axis members (no duplicate expert compute),
+                    # dispatch, and gather the processed tokens back
+                    n = tensor_size
+                    pad = (-tok.shape[0]) % n
+                    n_tok = tok.shape[0]
+                    if pad:
+                        tok = jnp.concatenate(
+                            [tok, jnp.zeros((pad, d), tok.dtype)])
+                    per = tok.shape[0] // n
+                    idx = lax.axis_index(TENSOR_AXIS)
+                    mine = lax.dynamic_slice_in_dim(tok, idx * per, per)
+                    # mask out pad rows: they must not route, take capacity,
+                    # or skew the aux statistics
+                    rows = idx * per + jnp.arange(per)
+                    y_mine, aux = moe_layer_p(
+                        mine, mp, TENSOR_AXIS, n,
+                        capacity_factor=cfg.moe_capacity_factor,
+                        valid_mask=rows < n_tok)
+                    y2d = lax.all_gather(y_mine, TENSOR_AXIS, axis=0,
+                                         tiled=True)
+                    if pad:
+                        y2d = y2d[:-pad]
+                else:
+                    y2d, aux = moe_layer_p(
+                        tok, mp, TENSOR_AXIS, 1,
+                        capacity_factor=cfg.moe_capacity_factor)
+                out = y2d.reshape(b, t, d)
+                aux_sum = aux_sum + aux
             else:
-                y2d, aux = moe_layer_p(
-                    tok, mp, TENSOR_AXIS, 1,
-                    capacity_factor=cfg.moe_capacity_factor)
-            out = y2d.reshape(b, t, d)
-            aux_sum = aux_sum + aux
-        else:
-            u = jax.nn.gelu(jnp.einsum("btd,df->btf", x,
-                                       lp["w1"].astype(dt)))
-            out = jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
-            if tensor_size is not None:
-                out = lax.psum(out, TENSOR_AXIS)
-        h = h + out
+                u = jax.nn.gelu(jnp.einsum("btd,df->btf", x,
+                                           lp["w1"].astype(dt)))
+                out = jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
+                if tensor_size is not None:
+                    out = lax.psum(out, TENSOR_AXIS)
+            h = h + out
         return (h, aux_sum), None
 
     if cfg.remat == "block":
@@ -276,11 +279,13 @@ def _forward(params, tokens, cfg: TransformerConfig,
                          f"expected 'none', 'block', or 'attention'")
 
     aux0 = jnp.zeros((), jnp.float32)
-    (h, aux_sum), _ = lax.scan(layer, (h, aux0), params["layers"])
-    h = _rmsnorm(h, params["ln_f"])
-    logits = jnp.einsum("btd,vd->btv", h, params["embed"].astype(dt))
-    if logits_f32:
-        logits = logits.astype(jnp.float32)
+    with jax.named_scope(scopes.LAYERS):
+        (h, aux_sum), _ = lax.scan(layer, (h, aux0), params["layers"])
+    with jax.named_scope(scopes.HEAD):
+        h = _rmsnorm(h, params["ln_f"])
+        logits = jnp.einsum("btd,vd->btv", h, params["embed"].astype(dt))
+        if logits_f32:
+            logits = logits.astype(jnp.float32)
     return logits, aux_sum / cfg.n_layers
 
 
@@ -295,9 +300,10 @@ def forward_block(params, tokens, cfg: TransformerConfig,
 
 def _local_loss(params, inputs, targets, cfg, seq_size=None, tensor_size=None):
     logits, aux = _forward(params, inputs, cfg, seq_size, tensor_size)
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.sum(nll), nll.size, aux
+    with jax.named_scope(scopes.LOSS):
+        logp = jax.nn.log_softmax(logits)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll), nll.size, aux
 
 
 def _lean_xent(logits, targets):
@@ -307,11 +313,12 @@ def _lean_xent(logits, targets):
     temps and ~8ms/step over log_softmax-on-fp32 at V=32768. Shared by the
     monolithic loss and the pipelined flagship so their numerics cannot
     drift."""
-    mx = jnp.max(logits, axis=-1).astype(jnp.float32)
-    lse = mx + jnp.log(jnp.sum(
-        jnp.exp(logits.astype(jnp.float32) - mx[..., None]), axis=-1))
-    hit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - hit.astype(jnp.float32))
+    with jax.named_scope(scopes.LOSS):
+        mx = jnp.max(logits, axis=-1).astype(jnp.float32)
+        lse = mx + jnp.log(jnp.sum(
+            jnp.exp(logits.astype(jnp.float32) - mx[..., None]), axis=-1))
+        hit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.mean(lse - hit.astype(jnp.float32))
 
 
 def lean_lm_loss(params, inputs, targets, cfg: TransformerConfig):
@@ -367,14 +374,14 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
     with dp/sp/tp shardings over ``mesh``."""
     loss_fn = make_spmd_loss(mesh, cfg)
 
-    def step(params, opt_state, inputs, targets):
+    def train_step(params, opt_state, inputs, targets):     # scopes.TRAIN_STEP
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(p, inputs, targets))(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = scopes.apply_update(optimizer, grads, opt_state,
+                                                params)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(train_step, donate_argnums=(0, 1))
 
 
 PIPE_AXIS = "pipe"
@@ -588,8 +595,8 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
 
     def step(params, opt_state, inputs, targets):
         loss, grads = grad_fn(params, inputs, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = scopes.apply_update(optimizer, grads, opt_state,
+                                                params)
         return params, opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1))
@@ -947,8 +954,8 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
 
         params = {"shared": shared, "expert": expert}
         grads = {"shared": g_shared, "expert": g_expert}
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = scopes.apply_update(optimizer, grads, opt_state,
+                                                params)
         return params["shared"], params["expert"], opt_state, loss
 
     return step

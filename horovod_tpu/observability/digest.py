@@ -39,7 +39,7 @@ class StepDigest:
     prefetch_hits: int               # ZeRO-1 prefetch legs used
     bucket_fill_pct: float           # last fusion-bucket fill efficiency
     compression_saved: float         # wire bytes removed by codecs
-    hbm_in_use: Optional[int] = None   # last sampled device bytes in use
+    hbm_in_use: Optional[int] = None   # last sampled bytes in use + reserved
     hbm_peak: Optional[int] = None     # last sampled peak bytes
 
     def as_dict(self) -> dict:

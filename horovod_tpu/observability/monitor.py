@@ -18,9 +18,12 @@ the trace ring into a disk firehose. Dumps are counted by trigger on
 
 :class:`HBMSampler` reads ``device.memory_stats()`` on the
 MetricsEmitter thread — never the step path — publishing
-``hvd_tpu_hbm_bytes{kind=in_use|peak|limit}`` and keeping the last
-watermark for the digest. Platforms without memory stats (CPU rigs,
-older runtimes) are detected once and sampling quietly stops.
+``hvd_tpu_hbm_bytes{kind=in_use|reserved|peak|limit}`` and keeping the
+last watermark for the digest: ``bytes_in_use + bytes_reserved``, since on
+the TPU runtime a loaded program's temporaries are ``bytes_reserved`` and
+``peak_bytes_in_use`` never sees them (PERF.md section 2). Platforms
+without memory stats (CPU rigs, older runtimes) are detected once and
+sampling quietly stops.
 """
 
 from __future__ import annotations
@@ -113,21 +116,22 @@ class HBMSampler:
                          "HBM telemetry disabled")
             return None
         self._supported = True
-        in_use = stats.get("bytes_in_use")
-        peak = stats.get("peak_bytes_in_use")
-        limit = stats.get("bytes_limit")
-        if in_use is not None:
-            self._g_hbm.set(float(in_use), kind="in_use")
-        if peak is not None:
-            self._g_hbm.set(float(peak), kind="peak")
-        if limit is not None:
-            self._g_hbm.set(float(limit), kind="limit")
+        for kind, key in (("in_use", "bytes_in_use"),
+                          ("reserved", "bytes_reserved"),
+                          ("peak", "peak_bytes_in_use"),
+                          ("limit", "bytes_limit")):
+            if stats.get(key) is not None:
+                self._g_hbm.set(float(stats[key]), kind=kind)
+        taken = stats.get("bytes_in_use")
+        if taken is not None:
+            taken += stats.get("bytes_reserved") or 0
         with self._lock:
-            self._last = (in_use, peak)
+            self._last = (taken, stats.get("peak_bytes_in_use"))
         return stats
 
     def last(self) -> Tuple[Optional[int], Optional[int]]:
-        """Last (bytes_in_use, peak_bytes_in_use) watermark."""
+        """Last (bytes_in_use + bytes_reserved, peak_bytes_in_use)
+        watermark."""
         with self._lock:
             return self._last
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import optax
 
 from .common import env as _env
+from .common import scopes
 from .common.lru import lru_get, lru_put
 from .common.reduce_ops import ReduceOp, Average, Sum, Adasum
 from .metrics import registry as _metrics_registry
@@ -212,6 +213,14 @@ def distributed(inner: optax.GradientTransformation, axis_name: str = "world",
         return (jax.tree_util.tree_unflatten(treedef, outs),
                 jax.tree_util.tree_unflatten(treedef, new_rs))
 
+    def _reduce(grads, residual):
+        """(reduced gradients, new residual), under ``grad_reduce``."""
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            if ef:
+                return _ef_reduce(grads, residual)
+            return allreduce_gradients(grads, axis_name, op, compression,
+                                       axis_size), residual
+
     def init_fn(params):
         accum = jax.tree_util.tree_map(jnp.zeros_like, params) \
             if backward_passes_per_step > 1 else None
@@ -222,13 +231,10 @@ def distributed(inner: optax.GradientTransformation, axis_name: str = "world",
 
     def update_fn(grads, state, params=None):
         if backward_passes_per_step == 1:
-            if ef:
-                reduced, new_res = _ef_reduce(grads, state.residual)
-            else:
-                reduced = allreduce_gradients(grads, axis_name, op,
-                                              compression, axis_size)
-                new_res = state.residual
-            updates, new_inner = inner.update(reduced, state.inner_state, params)
+            reduced, new_res = _reduce(grads, state.residual)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, new_inner = inner.update(reduced, state.inner_state,
+                                                  params)
             return updates, DistributedState(new_inner, state.accum,
                                              state.count, new_res)
 
@@ -240,13 +246,10 @@ def distributed(inner: optax.GradientTransformation, axis_name: str = "world",
             # Reference semantics (torch/optimizer.py:122-149): grads are
             # *summed* across the k local passes — only the cross-replica
             # reduction averages. No /k here.
-            if ef:
-                reduced, new_res = _ef_reduce(accum, state.residual)
-            else:
-                reduced = allreduce_gradients(accum, axis_name, op,
-                                              compression, axis_size)
-                new_res = state.residual
-            updates, new_inner = inner.update(reduced, state.inner_state, params)
+            reduced, new_res = _reduce(accum, state.residual)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, new_inner = inner.update(reduced, state.inner_state,
+                                                  params)
             zeroed = jax.tree_util.tree_map(jnp.zeros_like, accum)
             return (updates, new_inner, zeroed, jnp.zeros((), jnp.int32),
                     new_res)
@@ -864,25 +867,27 @@ class DistributedEagerOptimizer:
             comp, inner, op = self.compression, self.inner, self.op
 
             @jax.jit
-            def fn(reduced_c, opt_state, params):
+            def hvd_apply_update(reduced_c, opt_state, params):
                 p_leaves = jax.tree_util.tree_leaves(params)
                 out = []
-                for r, c, k, p in zip(reduced_c, ctxs, sparse_ks, p_leaves):
-                    if k is None:
-                        out.append(comp.decompress(r, c))
-                        continue
-                    # sparse leaf: duplicate rows combine in a jitted
-                    # scatter-add (the segment-sum the reference does in
-                    # DeduplicateIndexedSlices) — never on the host
-                    idx, vals = r
-                    d = jnp.zeros(p.shape, vals.dtype).at[idx].add(vals)
-                    if op == Average:
-                        d = d / world_size
-                    out.append(d)
+                with jax.named_scope(scopes.DECOMPRESS):
+                    for r, c, k, p in zip(reduced_c, ctxs, sparse_ks,
+                                          p_leaves):
+                        if k is None:
+                            out.append(comp.decompress(r, c))
+                            continue
+                        # sparse leaf: duplicate rows combine in a jitted
+                        # scatter-add (the segment-sum the reference does in
+                        # DeduplicateIndexedSlices) — never on the host
+                        idx, vals = r
+                        d = jnp.zeros(p.shape, vals.dtype).at[idx].add(vals)
+                        if op == Average:
+                            d = d / world_size
+                        out.append(d)
                 reduced = jax.tree_util.tree_unflatten(treedef, out)
-                updates, new_state = inner.update(reduced, opt_state, params)
-                return optax.apply_updates(params, updates), new_state
+                return scopes.apply_update(inner, reduced, opt_state, params)
 
+            fn = hvd_apply_update               # scopes.APPLY_UPDATE
             self._cache_put(self._apply_cache, key, fn)
         return fn
 
@@ -1073,17 +1078,20 @@ class DistributedDeltaAdasumOptimizer:
             comp = self.compression
 
             @jax.jit
-            def fn(reduced_c, params):
+            def hvd_apply_delta(reduced_c, params):     # scopes.APPLY_DELTA
                 # ctx None = never compressed (the world-size-1 path applies
                 # u_leaves directly; ADVICE r5): don't route through
                 # decompress(r, None), whose cast is a no-op at best and a
                 # dtype surprise at worst
-                deltas = [r if c is None else comp.decompress(r, c)
-                          for r, c in zip(reduced_c, ctxs)]
+                with jax.named_scope(scopes.DECOMPRESS):
+                    deltas = [r if c is None else comp.decompress(r, c)
+                              for r, c in zip(reduced_c, ctxs)]
                 updates = jax.tree_util.tree_unflatten(treedef, deltas)
-                return optax.apply_updates(params, updates)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    return optax.apply_updates(params, updates)
 
-            fn = lru_put(self._apply_cache, key, fn, self._cache_cap)
+            fn = lru_put(self._apply_cache, key, hvd_apply_delta,
+                         self._cache_cap)
         return fn
 
     def update_and_apply(self, grads, opt_state, params):

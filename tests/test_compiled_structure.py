@@ -770,3 +770,151 @@ def test_reducescatter_selection_stays_flat():
                               tree_threshold_bytes=0) == C.ALGO_FLAT
     assert C.validate_algorithm("reducescatter", C.ALGO_HIERARCHICAL,
                                 8, 4) == C.ALGO_FLAT
+
+
+# ---------------------------------------------------------------------------
+# Names inside the programs (common/scopes.py): what a device trace is read
+# by. The compiled text of a program built here, never a trace or a cached
+# executable: jax keeps metadata out of the persistent cache's key, and the
+# tests run without that cache.
+# ---------------------------------------------------------------------------
+
+import functools
+
+from horovod_tpu.common import scopes
+
+_LM_SCOPES = (scopes.EMBED, scopes.LAYERS, scopes.ATTN, scopes.FFN,
+              scopes.HEAD, scopes.LOSS)
+
+
+def _op_names(hlo):
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+def _under(op_name, scope):
+    """``scope`` is a component of the path, bare or inside the transforms
+    jax wraps around the names that were open when it was applied
+    (``jvp()/head`` through a shard_map, ``jvp(head)`` without)."""
+    return re.search(rf"(^|[/(]){scope}([/)]|$)", op_name) is not None
+
+
+def _tiny_lm():
+    from horovod_tpu.models.transformer import TransformerConfig, init_params
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
+                            d_ff=64, max_seq=16, attention="flash")
+    return (cfg, init_params(jax.random.PRNGKey(0), cfg),
+            jnp.zeros((2, 16), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def train_step_hlo():
+    import optax
+    from horovod_tpu.models.transformer import make_train_step
+    from horovod_tpu.parallel.mesh import training_mesh
+    cfg, params, tok = _tiny_lm()
+    mesh = training_mesh({"data": 1, "seq": 1, "tensor": 1},
+                         jax.devices()[:1])
+    opt = optax.adamw(1e-3)
+    return _hlo(make_train_step(mesh, cfg, opt), params, opt.init(params),
+                tok, tok)
+
+
+@pytest.mark.parametrize("scope", _LM_SCOPES)
+@pytest.mark.parametrize("phase", ["forward", "backward"])
+def test_train_step_scope_in_each_pass(train_step_hlo, scope, phase):
+    """Every scope of the LM step names operations of the forward pass
+    (``jvp(`` and no ``transpose(``: jax writes both) and of the backward
+    pass (``transpose(jvp(``)."""
+    found = [n for n in _op_names(train_step_hlo) if _under(n, scope)]
+    if phase == "forward":
+        found = [n for n in found if "jvp(" in n and "transpose(" not in n]
+    else:
+        found = [n for n in found if "transpose(jvp(" in n]
+    assert found, f"no {phase} operation under scope {scope!r}"
+
+
+def test_train_step_optimizer_scope_outside_both_passes(train_step_hlo):
+    found = [n for n in _op_names(train_step_hlo)
+             if _under(n, scopes.OPTIMIZER)]
+    assert found, "no operation under the optimizer scope"
+    assert not [n for n in found if "jvp(" in n or "transpose(" in n]
+    # and nothing of the model's scopes leaks into it
+    assert not [n for n in found if any(_under(n, s) for s in _LM_SCOPES)]
+
+
+@pytest.mark.parametrize("scope", [scopes.HEAD, scopes.LOSS])
+def test_lean_lm_loss_keeps_head_and_loss_scopes(scope):
+    from horovod_tpu.models.transformer import lean_lm_loss
+    cfg, params, tok = _tiny_lm()
+    hlo = _hlo(jax.jit(jax.grad(
+        lambda p: lean_lm_loss(p, tok, tok, cfg))), params)
+    assert [n for n in _op_names(hlo) if _under(n, scope)]
+
+
+@functools.lru_cache(maxsize=None)
+def _eager_apply_hlo(adasum):
+    """The eager optimizers' apply program over a bfloat16 wire, so that
+    ``decompress`` has a cast to name."""
+    import optax
+    from horovod_tpu.ops.compression import Compression
+    from horovod_tpu.optimizer import (DistributedDeltaAdasumOptimizer,
+                                       DistributedEagerOptimizer)
+    params = {"w": jnp.ones((8, 4)), "b": jnp.ones((4,))}
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    wire = [x.astype(jnp.bfloat16) for x in leaves]
+    ctxs = [jnp.float32] * len(leaves)
+    if adasum:
+        opt = DistributedDeltaAdasumOptimizer(optax.sgd(0.1),
+                                              compression=Compression.bf16)
+        return _hlo(opt._apply_fn(treedef, ctxs), wire, params)
+    opt = DistributedEagerOptimizer(optax.sgd(0.1, momentum=0.9),
+                                    compression=Compression.bf16)
+    return _hlo(opt._apply_fn(treedef, ctxs, [None] * len(leaves), 1),
+                wire, opt.init(params), params)
+
+
+@pytest.mark.parametrize("adasum,program", [
+    (False, scopes.APPLY_UPDATE), (True, scopes.APPLY_DELTA)])
+@pytest.mark.parametrize("scope", [scopes.DECOMPRESS, scopes.OPTIMIZER])
+def test_eager_apply_program_scopes(adasum, program, scope):
+    names = _op_names(_eager_apply_hlo(adasum))
+    found = [n for n in names if _under(n, scope)]
+    assert found and all(n.startswith(f"jit({program})/") for n in found)
+
+
+@pytest.mark.parametrize("scope", [scopes.GRAD_REDUCE, scopes.OPTIMIZER])
+def test_spmd_distributed_optimizer_scopes(scope):
+    """``hvd.distributed`` inside a shard_map, the README quickstart's
+    form: the reduction and the inner update each under its name."""
+    import optax
+    from horovod_tpu.optimizer import distributed
+    mesh = _world_mesh()
+    opt = distributed(optax.sgd(0.1, momentum=0.9), axis_name="world",
+                      axis_size=8)
+    params = {"w": jnp.ones((4, 4))}
+
+    def body(g, state, p):
+        updates, state = opt.update(g, state, p)
+        return optax.apply_updates(p, updates), state
+
+    step = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("world"), P(), P()),
+        out_specs=(P(), P()), check_vma=False))
+    grads = {"w": jnp.ones((32, 4))}
+    names = _op_names(_hlo(step, grads, opt.init(params), params))
+    found = [n for n in names if _under(n, scope)]
+    assert found, f"nothing under {scope!r}"
+    if scope == scopes.GRAD_REDUCE:
+        assert any(n.endswith("/psum") for n in found)
+        assert not [n for n in found if _under(n, scopes.OPTIMIZER)]
+
+
+@pytest.mark.parametrize("program", [scopes.TRAIN_STEP, scopes.APPLY_UPDATE,
+                                     scopes.APPLY_DELTA])
+def test_programs_say_what_they_are(train_step_hlo, program):
+    """The program's name is part of the persistent cache's key (metadata
+    is not), and what the trace's ``XLA Modules`` line shows."""
+    hlo = {scopes.TRAIN_STEP: lambda: train_step_hlo,
+           scopes.APPLY_UPDATE: lambda: _eager_apply_hlo(False),
+           scopes.APPLY_DELTA: lambda: _eager_apply_hlo(True)}[program]()
+    assert re.search(rf"^HloModule jit_{program}\b", hlo, re.M)

@@ -231,6 +231,22 @@ class TestHBMSampler:
         assert out["bytes_in_use"] == 1 << 30
         assert s.last() == (1 << 30, 2 << 30)
 
+    def test_reserved_bytes_count_as_taken(self, isolated_registry):
+        """A loaded program's temporaries are ``bytes_reserved`` on the TPU
+        runtime: the gauge publishes them and the digest's watermark adds
+        them, while ``peak_bytes_in_use`` is passed on as reported."""
+        s = HBMSampler(stats_fn=lambda: {
+            "bytes_in_use": 8 << 30, "bytes_reserved": 3 << 30,
+            "peak_bytes_in_use": 8 << 30, "bytes_limit": 16 << 30})
+        s.sample()
+        assert s.last() == (11 << 30, 8 << 30)
+        by_kind = {labels["kind"]: v for labels, v in
+                   hmetrics.registry().gauge("hvd_tpu_hbm_bytes")._snap()}
+        assert by_kind == {"in_use": float(8 << 30),
+                           "reserved": float(3 << 30),
+                           "peak": float(8 << 30),
+                           "limit": float(16 << 30)}
+
     def test_raising_stats_fn_degrades(self, isolated_registry):
         def boom():
             raise NotImplementedError("no memory_stats on this runtime")
@@ -329,8 +345,8 @@ def _rank_snap(rank, anomalies=0.0):
         },
         "gauges": {
             "hvd_tpu_hbm_bytes": {"help": "h", "values": [
-                [{"kind": "in_use"}, 4.0e9], [{"kind": "peak"}, 6.0e9],
-                [{"kind": "limit"}, 16.0e9]]},
+                [{"kind": "in_use"}, 4.0e9], [{"kind": "reserved"}, 3.0e9],
+                [{"kind": "peak"}, 6.0e9], [{"kind": "limit"}, 16.0e9]]},
         },
         "histograms": {
             "hvd_tpu_step_seconds": {"help": "st", "values": [
@@ -381,7 +397,8 @@ class TestHealthReportJSON:
         assert sh["ok"] is True
         assert sh["stats"]["steps_observed"] == 100
         assert sh["stats"]["step_time_p50_ms"] is not None
-        assert sh["stats"]["hbm_min_headroom_bytes"] == pytest.approx(12.0e9)
+        # limit less in_use less reserved (a loaded program's temporaries)
+        assert sh["stats"]["hbm_min_headroom_bytes"] == pytest.approx(9.0e9)
 
     def test_anomalies_turn_step_health_red(self, capsys, isolated_registry):
         health = _load_tool("health_report")

@@ -271,7 +271,8 @@ def step_health(series: Dict[str, list]) -> dict:
     for rank, kinds in hbm.items():
         limit, in_use = kinds.get("limit"), kinds.get("in_use")
         if limit and in_use is not None:
-            headroom[rank] = limit - in_use
+            # a loaded program's temporaries are "reserved", not "in_use"
+            headroom[rank] = limit - in_use - kinds.get("reserved", 0)
     return {
         "steps_observed": count,
         "step_time_mean_ms": (
@@ -535,7 +536,8 @@ def render(report: dict) -> str:
             kinds = sh.get("hbm_bytes", {}).get(rank, {})
             lines.append(
                 f"  rank {rank:<4} HBM headroom {hr / 2**30:.2f} GiB "
-                f"(in use {kinds.get('in_use', 0) / 2**30:.2f} / "
+                f"(in use {kinds.get('in_use', 0) / 2**30:.2f} + "
+                f"reserved {kinds.get('reserved', 0) / 2**30:.2f} / "
                 f"limit {kinds.get('limit', 0) / 2**30:.2f} GiB)")
     else:
         lines.append("  hbm: no device memory stats published "
