@@ -242,7 +242,7 @@ def spmd_run(args, cfg, mesh_axes: dict, rows: int, steps: int,
 def one_device_loss(args, cfg, rows: int) -> float:
     """Loss of the seed's parameters on the seed's batch, on ONE device:
     the one-chip reference for the same global batch. Taken eight rows at
-    a time (the fp32 logits of more do not fit one chip); the mean of
+    a time (a size one chip holds beside the parameters); the mean of
     equal chunks' means is the mean."""
     import jax
     import jax.numpy as jnp

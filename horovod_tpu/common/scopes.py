@@ -30,8 +30,8 @@ EMBED = "embed"         # _forward: the token lookup and its cast
 LAYERS = "layers"       # _forward: the lax.scan over the stacked layers
 ATTN = "attn"           # layer: rmsnorm, projections, attention, residual
 FFN = "ffn"             # layer: rmsnorm, dense or MoE branch, residual
-HEAD = "head"           # _forward: final rmsnorm, logits einsum, fp32 cast
-LOSS = "loss"           # _local_loss: log_softmax + gather; _lean_xent
+HEAD = "head"           # _forward: final rmsnorm, logits einsum
+LOSS = "loss"           # _lean_xent, both rules of its custom_vjp
 # optimizer.py and the step builders
 OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates
 DECOMPRESS = "decompress"       # eager apply program: what precedes them

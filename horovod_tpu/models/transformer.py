@@ -160,10 +160,9 @@ def _rmsnorm(x, scale):
 
 def _forward(params, tokens, cfg: TransformerConfig,
              seq_size: Optional[int] = None,
-             tensor_size: Optional[int] = None, causal: bool = True,
-             logits_f32: bool = True):
+             tensor_size: Optional[int] = None, causal: bool = True):
     """Forward over a *local* token block [B_local, T_local]; returns
-    (logits, moe_aux_loss) — aux is 0 for the dense FFN.
+    (logits in ``cfg.dtype``, moe_aux_loss) — aux is 0 for the dense FFN.
 
     ``seq_size``/``tensor_size`` are the mesh-axis sizes when running inside
     shard_map (collectives are emitted whenever the axis is manual, even at
@@ -284,47 +283,75 @@ def _forward(params, tokens, cfg: TransformerConfig,
     with jax.named_scope(scopes.HEAD):
         h = _rmsnorm(h, params["ln_f"])
         logits = jnp.einsum("btd,vd->btv", h, params["embed"].astype(dt))
-        if logits_f32:
-            logits = logits.astype(jnp.float32)
     return logits, aux_sum / cfg.n_layers
 
 
 def forward_block(params, tokens, cfg: TransformerConfig,
                   seq_size: Optional[int] = None,
                   tensor_size: Optional[int] = None, causal: bool = True):
-    """Logits-only wrapper (the driver's ``entry()`` compile-check target and
-    the dense-model public API)."""
+    """fp32 logits (the driver's ``entry()`` compile-check target and the
+    dense-model public API)."""
     logits, _ = _forward(params, tokens, cfg, seq_size, tensor_size, causal)
-    return logits
+    return logits.astype(jnp.float32)
 
 
-def _local_loss(params, inputs, targets, cfg, seq_size=None, tensor_size=None):
-    logits, aux = _forward(params, inputs, cfg, seq_size, tensor_size)
-    with jax.named_scope(scopes.LOSS):
-        logp = jax.nn.log_softmax(logits)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.sum(nll), nll.size, aux
-
-
+@jax.custom_vjp
 def _lean_xent(logits, targets):
-    """Mean token cross-entropy without fp32 [B, T, V] temporaries: the
-    logsumexp runs in fp32 *accumulation* over bf16 logits inside one
-    fusion. Measured (v5e, bench.py transformer mode): saves ~1 GB of HBM
-    temps and ~8ms/step over log_softmax-on-fp32 at V=32768. Shared by the
-    monolithic loss and the pipelined flagship so their numerics cannot
-    drift."""
+    """Per-token cross-entropy ``lse - logits[target]`` in fp32, from logits
+    in the model's dtype: the one cross-entropy of every step builder.
+
+    The log-sum-exp accumulates in fp32 over the logits as the head's matmul
+    wrote them; the backward is written out, ``g * (softmax - onehot)`` in
+    fp32 rounded once to the logits' dtype, with no gradient through the
+    max. What is saved for it is the logits themselves, the targets and
+    ``lse [B, T]``: no fp32 array of vocabulary width is returned or kept.
+
+    Measured against ``log_softmax`` on the logits cast to fp32, which
+    ``make_train_step`` used before (v5e, PERF.md PR 25: 4 x 2048 tokens,
+    V=50257, bf16): the loss's forward 5.03 -> 1.21 ms a step (the 1.65 GB
+    fp32 ``logp`` is no longer written; XLA fuses the max into the head
+    matmul's output and the cotangent into the two backward matmuls'
+    inputs), the step 138.59 -> 134.54 ms, peak HBM 11.69 -> 11.42 GB.
+    """
+    return _lean_xent_fwd(logits, targets)[0]
+
+
+def _lean_xent_fwd(logits, targets):
     with jax.named_scope(scopes.LOSS):
         mx = jnp.max(logits, axis=-1).astype(jnp.float32)
         lse = mx + jnp.log(jnp.sum(
             jnp.exp(logits.astype(jnp.float32) - mx[..., None]), axis=-1))
         hit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(lse - hit.astype(jnp.float32))
+        return lse - hit.astype(jnp.float32), (logits, targets, lse)
+
+
+def _lean_xent_bwd(res, g):
+    logits, targets, lse = res
+    with jax.named_scope(scopes.LOSS):
+        p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+        onehot = targets[..., None] == lax.broadcasted_iota(
+            targets.dtype, logits.shape, logits.ndim - 1)
+        return (g[..., None] * (p - onehot)).astype(logits.dtype), None
+
+
+_lean_xent.defvjp(_lean_xent_fwd, _lean_xent_bwd)
+
+
+def _mean_xent(logits, targets):
+    return jnp.mean(_lean_xent(logits, targets))
+
+
+def _local_loss(params, inputs, targets, cfg, seq_size=None, tensor_size=None):
+    logits, aux = _forward(params, inputs, cfg, seq_size, tensor_size)
+    nll = _lean_xent(logits, targets)
+    return jnp.sum(nll), nll.size, aux
 
 
 def lean_lm_loss(params, inputs, targets, cfg: TransformerConfig):
-    """Single-shard LM loss built on :func:`_lean_xent`."""
-    logits, aux = _forward(params, inputs, cfg, None, None, logits_f32=False)
-    loss = _lean_xent(logits, targets)
+    """Single-shard LM loss: the mean of :func:`_lean_xent`, the
+    cross-entropy that :func:`make_spmd_loss` sums over its shards."""
+    logits, aux = _forward(params, inputs, cfg)
+    loss = _mean_xent(logits, targets)
     if cfg.use_moe:
         # same load-balancing term the SPMD loss applies (make_spmd_loss);
         # silently dropping it would let the router collapse
@@ -549,8 +576,6 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
         h = _rmsnorm(y, lp["ln_f"])
         return jnp.einsum("btd,vd->btv", h, lp["embed"].astype(dt))
 
-    loss_fn = _lean_xent
-
     def body(params, inputs, targets):
         # inputs/targets arrive as this data-shard's slice of the global
         # batch; microbatching happens per replica
@@ -565,7 +590,7 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
                 lambda a: a.reshape((n_virtual, a.shape[0] // n_virtual)
                                     + a.shape[1:]), sp)
         loss, gs, gf, gl = pipeline_train_step(
-            stage_fn, sp, micro_in, micro_tgt, loss_fn,
+            stage_fn, sp, micro_in, micro_tgt, _mean_xent,
             PIPE_AXIS, n_stages, schedule=schedule, n_virtual=n_virtual,
             first_fn=first_fn, first_params={"embed": params["embed"]},
             last_fn=last_fn, last_params={"embed": params["embed"],
@@ -673,7 +698,7 @@ def make_pp_engine_train_step(mesh: Mesh, cfg: TransformerConfig, opt,
                 lambda a: a.reshape((n_virtual, rows // n_virtual)
                                     + a.shape[1:]), sp)
         loss, gs, gf, gl = pipeline_train_step(
-            stage_fn, sp, micro_in, micro_tgt, _lean_xent,
+            stage_fn, sp, micro_in, micro_tgt, _mean_xent,
             PIPE_AXIS, n_stages, schedule=schedule, n_virtual=n_virtual,
             first_fn=first_fn, first_params={"embed": params["embed"]},
             last_fn=last_fn, last_params={"embed": params["embed"],
@@ -861,7 +886,7 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
     def seg_loss(shared, h, targets):
         hf = _rmsnorm(h, shared["ln_f"])
         logits = jnp.einsum("btd,vd->btv", hf, shared["embed"].astype(dt))
-        return _lean_xent(logits, targets)
+        return _mean_xent(logits, targets)
 
     seg_route = [jax.jit(functools.partial(_route_pack, i=i), static_argnums=(2,))
                  for i in range(L)]

@@ -852,6 +852,35 @@ def test_lean_lm_loss_keeps_head_and_loss_scopes(scope):
 
 
 @functools.lru_cache(maxsize=None)
+def _xent_caller_hlo(builder):
+    import optax
+    from horovod_tpu.models import transformer as tfm
+    cfg, params, tok = _tiny_lm()
+    if builder == "lean_lm_loss":
+        return _hlo(jax.jit(jax.grad(
+            lambda p: tfm.lean_lm_loss(p, tok, tok, cfg))), params)
+    mesh = Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,))
+    opt = optax.sgd(0.1)
+    return _hlo(tfm.make_pp_train_step(mesh, cfg, opt, n_micro=2),
+                params, opt.init(params), tok, tok)
+
+
+@pytest.mark.parametrize("builder", ["lean_lm_loss", "make_pp_train_step"])
+@pytest.mark.parametrize("phase", ["forward", "backward"])
+def test_one_xent_names_loss_in_each_pass(builder, phase):
+    """Both rules of the cross-entropy's custom_vjp open scope ``loss``, so
+    every builder that calls it shows the name in both passes, the pipeline
+    tables (their own vjp inside a cond) too."""
+    found = [n for n in _op_names(_xent_caller_hlo(builder))
+             if _under(n, scopes.LOSS)]
+    if phase == "forward":
+        found = [n for n in found if "jvp(" in n and "transpose(" not in n]
+    else:
+        found = [n for n in found if "transpose(jvp(" in n]
+    assert found, f"no {phase} operation under scope 'loss' in {builder}"
+
+
+@functools.lru_cache(maxsize=None)
 def _eager_apply_hlo(adasum):
     """The eager optimizers' apply program over a bfloat16 wire, so that
     ``decompress`` has a cast to name."""

@@ -194,13 +194,14 @@ def test_moe_pad_tokens_do_not_skew_results():
 
 def test_flash_attention_fallback_and_lean_loss():
     """attention="flash" falls back to the materialized kernel off-TPU, and
-    lean_lm_loss matches the log_softmax formulation (fp32 config)."""
+    lean_lm_loss matches the log_softmax formulation written out here (fp32
+    config)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from horovod_tpu.models.transformer import (TransformerConfig,
-                                                init_params, _local_loss,
-                                                lean_lm_loss)
+                                                forward_block, init_params,
+                                                _local_loss, lean_lm_loss)
 
     cfg = TransformerConfig(vocab_size=128, d_model=32, n_heads=4, n_layers=2,
                             d_ff=64, max_seq=16, dtype=jnp.float32,
@@ -209,9 +210,11 @@ def test_flash_attention_fallback_and_lean_loss():
     tok = jnp.asarray(np.random.RandomState(0).randint(0, 128, (2, 16)))
     tgt = jnp.asarray(np.random.RandomState(1).randint(0, 128, (2, 16)))
     lean = float(lean_lm_loss(params, tok, tgt, cfg))
-    total, count, _ = _local_loss(params, tok, tgt, cfg)
-    ref = float(total) / count
+    ref = float(jnp.mean(_log_softmax_nll(forward_block(params, tok, cfg),
+                                          tgt)))
     assert abs(lean - ref) < 1e-4, (lean, ref)
+    total, count, _ = _local_loss(params, tok, tgt, cfg)
+    assert abs(float(total) / count - ref) < 1e-4
 
     # flash config == default config numerics on the fallback path
     cfg_ref = TransformerConfig(vocab_size=128, d_model=32, n_heads=4,
@@ -317,3 +320,123 @@ def test_remat_unknown_mode_raises():
     params = tfm.init_params(jax.random.PRNGKey(0), CFG)
     with pytest.raises(ValueError, match="remat"):
         tfm.forward_block(params, jnp.zeros((1, 8), jnp.int32), cfg)
+
+
+# ---------------------------------------------------------------------------
+# The one cross-entropy (_lean_xent: fp32 log-sum-exp over the logits as the
+# head wrote them, backward written by hand) against log_softmax written out
+# here on the same _forward logits. The benchmark's mesh_step check runs the
+# new code on both of its sides and cannot see a wrong backward: these do.
+# ---------------------------------------------------------------------------
+
+def _log_softmax_nll(logits, targets):
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def _written_out_spmd_loss(mesh, cfg):
+    """make_spmd_loss with the cross-entropy written out: the same _forward
+    on the same shards, so the two differ by the cross-entropy alone."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axes = (tfm.DATA_AXIS, tfm.SEQ_AXIS)
+
+    def body(params, inputs, targets):
+        logits, _ = tfm._forward(params, inputs, cfg, sizes[tfm.SEQ_AXIS],
+                                 sizes[tfm.TENSOR_AXIS])
+        nll = _log_softmax_nll(logits, targets)
+        n = nll.size * sizes[tfm.DATA_AXIS] * sizes[tfm.SEQ_AXIS]
+        return jax.lax.psum(jnp.sum(nll), axes) / n
+
+    tok_spec = P(*axes)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(tfm.param_specs(cfg), tok_spec, tok_spec),
+                         out_specs=P(), check_vma=False)
+
+
+def _xent_cfg(dtype):
+    # a vocabulary no other dimension equals, so a shape names the logits
+    return dataclasses.replace(CFG, vocab_size=80, dtype=dtype)
+
+
+def _xent_errors(dtype, n_data):
+    """(relative loss error, {leaf: gradient error over its band}) of
+    make_spmd_loss against the written-out form over ``data=n_data``.
+    Bands: float32, 1e-6 of the leaf's largest entry; bfloat16, one bf16
+    rounding of the cotangent, 2^-8 of the leaf's norm."""
+    cfg = _xent_cfg(dtype)
+    mesh = Mesh(np.array(jax.devices()[:n_data]).reshape(n_data, 1, 1),
+                (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
+    params = tfm.shard_params(tfm.init_params(jax.random.PRNGKey(5), cfg),
+                              mesh, cfg)
+    rng = np.random.RandomState(6)
+    tok_sh = NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS))
+    inputs, targets = (
+        jax.device_put(rng.randint(0, cfg.vocab_size, (4, 16), np.int32),
+                       tok_sh) for _ in range(2))
+    (loss, grads), (ref_loss, ref_grads) = (
+        jax.jit(jax.value_and_grad(f))(params, inputs, targets)
+        for f in (tfm.make_spmd_loss(mesh, cfg),
+                  _written_out_spmd_loss(mesh, cfg)))
+
+    def over_band(path, g, ref):
+        g, ref = np.asarray(g, np.float64), np.asarray(ref, np.float64)
+        assert np.abs(ref).max() > 0, path
+        if dtype == jnp.float32:
+            return np.abs(g - ref).max() / (1e-6 * np.abs(ref).max())
+        return np.linalg.norm(g - ref) / (2.0 ** -8 * np.linalg.norm(ref))
+
+    errors = jax.tree_util.tree_map_with_path(over_band, grads, ref_grads)
+    return (abs(float(loss) - float(ref_loss)) / abs(float(ref_loss)),
+            {jax.tree_util.keystr(k): v for k, v
+             in jax.tree_util.tree_leaves_with_path(errors)})
+
+
+@pytest.mark.parametrize("n_data", [1, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_xent_loss_and_every_gradient_match_written_out(dtype, n_data):
+    loss_err, grad_errs = _xent_errors(dtype, n_data)
+    assert loss_err < 1e-6, loss_err
+    assert len(grad_errs) == 10
+    assert max(grad_errs.values()) < 1.0, grad_errs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("left_out", ["onehot", "g"])
+def test_xent_wrong_backward_is_refused(monkeypatch, left_out, dtype):
+    """What the bands above can see: the hand-written backward without its
+    one-hot, or without the incoming cotangent, misses every leaf's band by
+    orders of magnitude while the loss stays right."""
+    def bwd(res, g):
+        logits, targets, lse = res
+        p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+        if left_out == "onehot":
+            d = g[..., None] * p
+        else:
+            d = p - jax.nn.one_hot(targets, logits.shape[-1])
+        return d.astype(logits.dtype), None
+
+    wrong = jax.custom_vjp(lambda logits, targets:
+                           tfm._lean_xent_fwd(logits, targets)[0])
+    wrong.defvjp(tfm._lean_xent_fwd, bwd)
+    monkeypatch.setattr(tfm, "_lean_xent", wrong)
+    loss_err, grad_errs = _xent_errors(dtype, 4)
+    assert loss_err < 1e-6, loss_err
+    assert min(grad_errs.values()) > 100.0, grad_errs
+
+
+def test_xent_saves_no_fp32_array_of_vocabulary_width():
+    """At bfloat16 what _local_loss keeps for its backward holds the logits
+    as the head's matmul wrote them and the fp32 log-sum-exp [B, T], and no
+    float32 array whose last dimension is the vocabulary."""
+    cfg = _xent_cfg(jnp.bfloat16)
+    params = tfm.init_params(jax.random.PRNGKey(5), cfg)
+    tok = jnp.zeros((2, 16), jnp.int32)
+    _, vjp = jax.vjp(
+        lambda p: tfm._local_loss(p, tok, tok, cfg)[0], params)
+    saved = {(x.dtype.name, x.shape) for x in jax.tree_util.tree_leaves(vjp)
+             if hasattr(x, "dtype")}
+    assert ("bfloat16", (2, 16, cfg.vocab_size)) in saved, saved
+    assert ("float32", (2, 16)) in saved, saved
+    wide = [s for s in saved
+            if s[0] == "float32" and s[1][-1:] == (cfg.vocab_size,)]
+    assert not wide, wide
