@@ -14,6 +14,12 @@ Three modes:
 - ``--mode pp``: the flagship through the memory-bounded 1F1B pipeline
   (``--stages``, ``--n-micro``).
 
+The block is told by flags (spmd and eager modes; the pipeline restates
+the block and refuses them): ``--positions rope``, ``--ffn swiglu``,
+``--norm sandwich``, ``--untied-head``, ``--n-loops 4`` make a looped
+decoder of the Ouro kind (benchmark/configs/ouro-2.6b.json), whose mean
+exit share of every pass goes to the gauge ``hvd_tpu_lm_exit_share``.
+
 Synthetic data; prints tokens/sec. Mirrors the reference's synthetic
 benchmark scripts (examples/*_synthetic_benchmark.py) for the LM workload.
 """
@@ -58,6 +64,19 @@ def main():
                          "causal ring work exactly across ranks (tokens/"
                          "targets are permuted with zigzag_indices here)")
     ap.add_argument("--moe", action="store_true")
+    ap.add_argument("--remat", default="none",
+                    choices=["none", "block", "attention"])
+    ap.add_argument("--positions", default="none", choices=["none", "rope"])
+    ap.add_argument("--rope-theta", type=float, default=10000.0)
+    ap.add_argument("--ffn", default="gelu", choices=["gelu", "swiglu"])
+    ap.add_argument("--norm", default="pre", choices=["pre", "sandwich"])
+    ap.add_argument("--norm-eps", type=float, default=1e-6)
+    ap.add_argument("--untied-head", action="store_true")
+    ap.add_argument("--n-loops", type=int, default=1,
+                    help="passes over the stack with the same weights; "
+                         "above 1 every pass ends in the head and a "
+                         "learned exit gate")
+    ap.add_argument("--exit-entropy-weight", type=float, default=0.1)
     ap.add_argument("--delta-adasum", action="store_true",
                     help="eager mode: delta-model Adasum (local optimizer "
                          "step first, Adasum on the parameter delta)")
@@ -80,7 +99,11 @@ def main():
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff, max_seq=args.seq,
         dtype=jnp.bfloat16, attention=args.attention,
-        sp_layout=args.sp_layout, use_moe=args.moe)
+        sp_layout=args.sp_layout, use_moe=args.moe, remat=args.remat,
+        positions=args.positions, rope_theta=args.rope_theta, ffn=args.ffn,
+        norm=args.norm, norm_eps=args.norm_eps,
+        tie_embeddings=not args.untied_head, n_loops=args.n_loops,
+        exit_entropy_weight=args.exit_entropy_weight)
     opt = optax.adamw(3e-4)
     rng = np.random.RandomState(0)
     # seq+1 raw tokens so the shifted input/target windows are exactly
@@ -98,11 +121,12 @@ def main():
                                                     pp_param_specs)
         n_stages = args.stages or len(jax.devices())
         mesh = Mesh(np.array(jax.devices()[:n_stages]), ("pipe",))
+        # first: the builder refuses by name what its block cannot run
+        step = make_pp_train_step(mesh, cfg, opt, n_micro=args.n_micro)
         specs = pp_param_specs(cfg)
         params = jax.tree_util.tree_map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
             init_params(jax.random.PRNGKey(0), cfg), specs)
-        step = make_pp_train_step(mesh, cfg, opt, n_micro=args.n_micro)
         opt_state = opt.init(params)
         params, opt_state, loss = step(params, opt_state, inputs, targets)
         t0 = time.perf_counter()
@@ -125,9 +149,10 @@ def main():
         opt_state = opt.init(params)
         tok_sh = NamedSharding(mesh, P("data", "seq"))
         if args.sp_layout == "zigzag":
-            # zigzag data layout: the model is layout-transparent (no
-            # positional encoding; per-token loss mean is permutation-
-            # invariant), only the tokens must be permuted to match
+            # zigzag data layout: only the tokens must be permuted to
+            # match (the per-token loss mean is permutation-invariant, and
+            # with --positions rope every shard rotates by the global
+            # positions of the stripes it holds)
             from horovod_tpu.parallel import zigzag_indices
             idx, _ = zigzag_indices(args.seq, mesh_spec.get("seq", 1))
             inputs = jnp.take(inputs, idx, axis=1)
@@ -170,9 +195,20 @@ def main():
         dt = (time.perf_counter() - t0) / args.steps
 
     toks = args.batch * args.seq
-    print({"mode": args.mode, "loss": round(loss, 4),
-           "step_ms": round(dt * 1e3, 2),
-           "tokens_per_sec": round(toks / dt, 1)})
+    report = {"mode": args.mode, "loss": round(loss, 4),
+              "step_ms": round(dt * 1e3, 2),
+              "tokens_per_sec": round(toks / dt, 1)}
+    if cfg.n_loops > 1:
+        # logged with the loss: whether the exit gate has collapsed
+        from horovod_tpu.metrics import registry
+        from horovod_tpu.models.transformer import exit_distribution
+        share = np.asarray(exit_distribution(
+            jax.device_get(params), tokens[:1, :-1], cfg))
+        gauge = registry().gauge("hvd_tpu_lm_exit_share")
+        for t, p_t in enumerate(share, 1):
+            gauge.set(float(p_t), **{"pass": str(t)})
+        report["exit_share"] = [round(float(p_t), 4) for p_t in share]
+    print(report)
 
 
 if __name__ == "__main__":

@@ -26,12 +26,15 @@ import jax
 import optax
 
 # models/transformer.py, the step the LM cells run
-EMBED = "embed"         # _forward: the token lookup and its cast
-LAYERS = "layers"       # _forward: the lax.scan over the stacked layers
-ATTN = "attn"           # layer: rmsnorm, projections, attention, residual
-FFN = "ffn"             # layer: rmsnorm, dense or MoE branch, residual
-HEAD = "head"           # _forward: final rmsnorm, logits einsum
+EMBED = "embed"         # _run_passes: the token lookup and its cast
+LOOP = "loop"           # _run_passes: the lax.scan over the passes (n_loops > 1)
+LAYERS = "layers"       # _run_passes: the lax.scan over the stacked layers
+ATTN = "attn"           # layer: rmsnorms, projections, attention, residual
+ROPE = "rope"           # the cos/sin tables; inside attn, q and k rotated
+FFN = "ffn"             # layer: rmsnorms, dense or MoE branch, residual
+HEAD = "head"           # every pass's final rmsnorm; _head: logits einsum
 LOSS = "loss"           # _lean_xent, both rules of its custom_vjp
+EXIT_GATE = "exit_gate"  # gate logit, exit distribution, entropy (n_loops > 1)
 # optimizer.py and the step builders
 OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates
 DECOMPRESS = "decompress"       # eager apply program: what precedes them

@@ -190,6 +190,12 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "max per-expert token count / mean (1.0 = perfectly "
                  "balanced), by layer — the per-expert face of the PR 5 "
                  "arrival-skew machinery"),
+    # models/transformer.py exit_distribution (ISSUE 28 looped decoder)
+    "hvd_tpu_lm_exit_share": (
+        "gauge", "Mean probability, over the tokens of the last logged "
+                 "batch, that a looped LM's learned exit gate leaves after "
+                 "each pass (label pass=1..n_loops; the shares sum to 1): "
+                 "a gate collapsed onto one pass reads 1 there"),
     # stall_inspector.py
     "hvd_tpu_stall_publish_failures_total": (
         "counter", "Stall-inspector KV liveness publishes that failed"),

@@ -118,7 +118,21 @@ def _select_kernel(t: int, d: int, under_remat: bool,
     an OOM a user can act on) — degrade to flash automatically unless
     HOROVOD_SPLASH=force insists (VERDICT r4 item 7: knobs are overrides,
     not the mechanism). ``itemsize`` is the q/k/v element size (fp32
-    inputs double the streamed-slab residency)."""
+    inputs double the streamed-slab residency).
+
+    What the estimate does with bf16 heads of 128 under remat: T = 1024
+    reads 12.6 MB and stays with splash; every T that 2048 divides (2048,
+    4096, 8192, ...) reads 17.8 MB against the 16 MiB scope and goes to the
+    stock flash kernel at 1024 blocks; odd multiples of 1024 (3072, ...)
+    take the 1024 kv block, read 12.6 MB and stay with splash. It is an
+    estimate anchored on two readings of an older backend, and whether
+    splash at T = 4096 would in fact overflow under recomputation has not
+    been tried on the chip. What the chip showed of the flash side
+    (PERF.md PR 28, v5e, 1 x 16 x 4096 x 128, remat="block", 24 layer
+    applications a step, by scope): forward 15.1 ms, the forward run again
+    15.8, dkv 27.6, dq 20.0; 77.7 ms of kernel time a step, 32.3% of the
+    compute roofline of the attention the objective needs (25.1 ms),
+    against 31.8% for splash at 4 x 2048 without remat."""
     if not under_remat:
         return "splash"
     if _splash_mode() == "force":

@@ -132,6 +132,32 @@ def test_transformer_lm_example_spmd():
     assert "tokens_per_sec" in r.stdout, r.stdout
 
 
+def test_transformer_lm_example_looped():
+    """The looped decoder from the example's flags: RoPE, SwiGLU, sandwich
+    norms, an untied head and four passes under remat, the sequence split
+    over the two devices _run gives; the exit gate's share of every pass
+    is reported with the loss."""
+    r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
+              "--mesh", "seq=2", "--d-model", "32",
+              "--n-layers", "2", "--n-heads", "4", "--d-ff", "48",
+              "--vocab", "128", "--seq", "32", "--batch", "4", "--steps",
+              "2", "--positions", "rope", "--rope-theta", "1e6", "--ffn",
+              "swiglu", "--norm", "sandwich", "--untied-head", "--n-loops",
+              "4", "--remat", "block"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "tokens_per_sec" in r.stdout and "exit_share" in r.stdout
+
+
+def test_transformer_lm_example_pp_refuses_the_loop_by_name():
+    r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
+              "--mode", "pp", "--stages", "2", "--d-model", "32",
+              "--n-layers", "2", "--n-heads", "4", "--d-ff", "64",
+              "--vocab", "128", "--seq", "32", "--batch", "4", "--steps",
+              "1", "--n-loops", "4"])
+    assert r.returncode != 0
+    assert "make_pp_train_step" in r.stderr and "n_loops=4" in r.stderr
+
+
 def test_transformer_lm_example_eager():
     r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
               "--mode", "eager", "--d-model", "32", "--n-layers", "1",

@@ -91,7 +91,8 @@ SECTIONS = [
     ("Estimator & store", "horovod_tpu", []),
     ("Models", "horovod_tpu.models.transformer", [
         "TransformerConfig", "init_params", "forward_block", "lean_lm_loss",
-        "make_train_step", "make_spmd_loss", "shard_params"]),
+        "make_train_step", "make_spmd_loss", "shard_params",
+        "forward_exits", "exit_distribution"]),
     ("", "horovod_tpu.models.vit", ["ViT", "ViT_B16", "ViT_S16"]),
     ("", "horovod_tpu.models.resnet", ["ResNet50", "ResNet101", "ResNet152"]),
     ("Parallelism kernels", "horovod_tpu.parallel.ring_attention", [
