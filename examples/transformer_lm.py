@@ -18,7 +18,9 @@ The block is told by flags (spmd and eager modes; the pipeline restates
 the block and refuses them): ``--positions rope``, ``--ffn swiglu``,
 ``--norm sandwich``, ``--untied-head``, ``--n-loops 4`` make a looped
 decoder of the Ouro kind (benchmark/configs/ouro-2.6b.json), whose mean
-exit share of every pass goes to the gauge ``hvd_tpu_lm_exit_share``.
+exit share of every pass goes to the gauge ``hvd_tpu_lm_exit_share``. In
+spmd mode the share of the gradient bytes whose all-reduce the step issues
+inside its backward scan goes to ``hvd_tpu_lm_grad_reduce_in_backward_share``.
 
 Synthetic data; prints tokens/sec. Mirrors the reference's synthetic
 benchmark scripts (examples/*_synthetic_benchmark.py) for the LM workload.
@@ -146,6 +148,15 @@ def main():
         params = shard_params(init_params(jax.random.PRNGKey(0), cfg),
                               mesh, cfg)
         step = make_train_step(mesh, cfg, opt)
+        # how much of the gradient exchange the step issues under its
+        # backward pass, by bytes: a property of the mesh and the model
+        from horovod_tpu.metrics import registry
+        from horovod_tpu.models.transformer import (
+            grad_reduce_in_backward_share)
+        in_backward = grad_reduce_in_backward_share(mesh, cfg)
+        registry().gauge("hvd_tpu_lm_grad_reduce_in_backward_share").set(
+            in_backward,
+            mesh=",".join(f"{a}={n}" for a, n in mesh.shape.items()))
         opt_state = opt.init(params)
         tok_sh = NamedSharding(mesh, P("data", "seq"))
         if args.sp_layout == "zigzag":
@@ -198,6 +209,8 @@ def main():
     report = {"mode": args.mode, "loss": round(loss, 4),
               "step_ms": round(dt * 1e3, 2),
               "tokens_per_sec": round(toks / dt, 1)}
+    if args.mode == "spmd":
+        report["grad_reduce_in_backward_share"] = round(in_backward, 4)
     if cfg.n_loops > 1:
         # logged with the loss: whether the exit gate has collapsed
         from horovod_tpu.metrics import registry

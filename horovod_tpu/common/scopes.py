@@ -4,10 +4,12 @@ product writes and the names of its jitted programs.
 A scope is metadata of the lowered program. It adds no operation and no
 host work, and there is no switch: every operation traced under it carries
 the name in its ``op_name``. jax writes the pass itself:
-``jit(train_step)/jvp()/layers/while/body/closed_call/attn/dot_general`` in
-the forward pass and ``jit(train_step)/transpose(jvp())/layers/...`` in the
-backward pass (through a ``shard_map``; without one the open scopes move
-inside the parentheses, ``jvp(head)``, ``transpose(jvp(loss))``). On the
+``jit(train_step)/shard_map/jvp(layers)/while/body/closed_call/attn/
+dot_general`` in the forward pass and ``jit(train_step)/shard_map/
+transpose(jvp(layers))/...`` in the backward pass: the scopes open where
+``value_and_grad`` is applied (inside the step's ``shard_map`` since PR 29)
+move inside the parentheses; differentiated through a ``shard_map`` from
+outside they stay behind it, ``transpose(jvp())/shard_map/layers/...``. On the
 TPU the profiler keeps each executed operation's ``op_name`` as the
 ``tf_op`` stat of the event's metadata; ``benchmark/scopes.py``,
 ``benchmark/readers/trace_scopes.py`` and their metric files read these
@@ -38,7 +40,9 @@ EXIT_GATE = "exit_gate"  # gate logit, exit distribution, entropy (n_loops > 1)
 # optimizer.py and the step builders
 OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates
 DECOMPRESS = "decompress"       # eager apply program: what precedes them
-GRAD_REDUCE = "grad_reduce"     # distributed(): the cross-replica reduction
+GRAD_REDUCE = "grad_reduce"     # the cross-replica gradient reduction:
+#   make_train_step's psums (inside the backward scan and after it) and
+#   distributed()'s
 
 # jitted programs, as the trace's ``XLA Modules`` line shows them (jit_<name>)
 TRAIN_STEP = "train_step"               # make_train_step

@@ -196,6 +196,16 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "batch, that a looped LM's learned exit gate leaves after "
                  "each pass (label pass=1..n_loops; the shares sum to 1): "
                  "a gate collapsed onto one pass reads 1 there"),
+    # models/transformer.py grad_reduce_in_backward_share (ISSUE 29)
+    "hvd_tpu_lm_grad_reduce_in_backward_share": (
+        "gauge", "Of the gradient bytes a chip puts through an all-reduce "
+                 "every SPMD step, the share whose sum is issued inside the "
+                 "backward scan, a layer at a time, where it can run beside "
+                 "the backward of the layers below (label mesh; "
+                 "examples/transformer_lm.py sets it when it has built the "
+                 "step; 0 on a mesh with nothing to sum over and for a "
+                 "looped model, whose shared layers are summed once after "
+                 "the pass loop)"),
     # stall_inspector.py
     "hvd_tpu_stall_publish_failures_total": (
         "counter", "Stall-inspector KV liveness publishes that failed"),

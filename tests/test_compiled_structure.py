@@ -947,3 +947,247 @@ def test_programs_say_what_they_are(train_step_hlo, program):
            scopes.APPLY_UPDATE: lambda: _eager_apply_hlo(False),
            scopes.APPLY_DELTA: lambda: _eager_apply_hlo(True)}[program]()
     assert re.search(rf"^HloModule jit_{program}\b", hlo, re.M)
+
+
+# ---------------------------------------------------------------------------
+# Where make_train_step sums its gradients (ISSUE 29): each stacked layer
+# leaf inside the backward scan, a layer at a time; everything else, and
+# every leaf of a looped model, once after the backward pass. Read from the
+# traced program, where XLA has moved nothing yet, and from the lowered text.
+
+def _psums(jaxpr, inside=()):
+    """(name stack, axes, operand avals, enclosing primitives) of every psum
+    in ``jaxpr`` and in the jaxprs its equations hold; a scan is written
+    ``scan[length,reverse]``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("psum"):
+            yield (str(eqn.source_info.name_stack), tuple(eqn.params["axes"]),
+                   [v.aval for v in eqn.invars], inside)
+        tag = eqn.primitive.name
+        if tag == "scan":
+            tag = f"scan[{eqn.params['length']},{eqn.params['reverse']}]"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _psums(sub, inside + (tag,))
+
+
+def _lm_step(axes, **fields):
+    """(cfg, traced jaxpr, lowered text with names) of the tiny LM's
+    make_train_step over a mesh of forced host devices."""
+    import dataclasses
+    import optax
+    from horovod_tpu.models import transformer as tfm
+    cfg = dataclasses.replace(_tiny_lm()[0], attention="ring",
+                              n_layers=3, **fields)
+    shape = tuple(axes.get(a, 1) for a in ("data", "seq", "tensor"))
+    mesh = Mesh(np.array(jax.devices()[:int(np.prod(shape))]).reshape(shape),
+                ("data", "seq", "tensor"))
+    params = tfm.shard_params(tfm.init_params(jax.random.PRNGKey(0), cfg),
+                              mesh, cfg)
+    tok = jax.device_put(jnp.zeros((4, 16), jnp.int32),
+                         NamedSharding(mesh, P("data", "seq")))
+    opt = optax.adamw(1e-3)
+    args = (params, opt.init(params), tok, tok)
+    step = tfm.make_train_step(mesh, cfg, opt)
+    return (cfg, jax.make_jaxpr(step)(*args).jaxpr,
+            step.lower(*args).as_text(debug_info=True))
+
+
+def _grad_psums(jaxpr):
+    return [p for p in _psums(jaxpr) if _under(p[0], scopes.GRAD_REDUCE)]
+
+
+def _leaf_shapes(cfg):
+    from horovod_tpu.models.transformer import init_params
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    layers = sorted(tuple(x.shape) for x in
+                    jax.tree_util.tree_leaves(shapes["layers"]))
+    rest = sorted(tuple(x.shape) for k, v in shapes.items() if k != "layers"
+                  for x in jax.tree_util.tree_leaves(v))
+    return layers, rest
+
+
+@pytest.mark.parametrize("remat", ["none", "block", "attention"])
+def test_train_step_sums_layer_gradients_inside_the_backward_scan(remat):
+    """``data=4``: one psum a layer leaf, of the leaf's per-layer shape,
+    inside the reversed scan over the layers and nowhere else (under remat
+    not inside the checkpointed function either: no collective is
+    recomputed); the embedding and the final norm each once after it; none
+    of the stacked ``[n_layers, ...]`` shape; every one float32 over
+    ``data`` alone."""
+    cfg, jaxpr, text = _lm_step({"data": 4}, remat=remat)
+    layers, rest = _leaf_shapes(cfg)
+    found = _grad_psums(jaxpr)
+    assert all(axes == ("data",) for _, axes, _, _ in found)
+    assert all(a.dtype == jnp.float32 for _, _, avals, _ in found
+               for a in avals)
+    in_scan = [p for p in found if any(t.startswith("scan") for t in p[3])]
+    after = [p for p in found if p not in in_scan]
+    backward = f"scan[{cfg.n_layers},True]"
+    assert all(p[3].count(backward) == 1
+               and not any(t.startswith("remat") or t == "checkpoint"
+                           for t in p[3]) for p in in_scan), in_scan
+    assert sorted(tuple(a.shape) for p in in_scan for a in p[2]) == sorted(
+        s[1:] for s in layers)
+    assert sorted(tuple(a.shape) for p in after for a in p[2]) == rest
+    # and in the lowered text: no all-reduce of a stacked layer leaf, none
+    # narrower than float32 among the gradients' shapes
+    stacked = {"x".join(map(str, s)) for s in layers}
+    per_layer = {"x".join(map(str, s[1:])) for s in layers if len(s) > 2}
+    reduced = re.findall(r'"stablehlo.all_reduce".*?\n\s*\}\) : '
+                         r'\(tensor<([^>]*)>\)', text, re.S)
+    assert not [r for r in reduced if r.rsplit("x", 1)[0] in stacked]
+    grads = [r for r in reduced if r.rsplit("x", 1)[0] in per_layer]
+    assert len(grads) >= len(per_layer)
+    assert all(r.endswith("xf32") for r in grads), grads
+
+
+def test_train_step_untied_head_is_summed_where_its_backward_ends():
+    """An untied ``lm_head``'s gradient is complete when the head's backward
+    ends: its psum comes before the backward scan over the layers, not
+    after the embedding's."""
+    cfg, jaxpr, _ = _lm_step({"data": 4}, tie_embeddings=False)
+    (body,) = [e for e in jaxpr.eqns if e.primitive.name == "jit"]
+    (smap,) = [e for e in body.params["jaxpr"].eqns
+               if e.primitive.name == "shard_map"]
+    order = []
+    for eqn in smap.params["jaxpr"].eqns:
+        if eqn.primitive.name == "scan" and eqn.params["reverse"]:
+            order.append("backward scan")
+        elif (eqn.primitive.name.startswith("psum")
+              and _under(str(eqn.source_info.name_stack), scopes.GRAD_REDUCE)):
+            order.append(tuple(eqn.invars[0].aval.shape))
+    head = (cfg.vocab_size, cfg.d_model)
+    assert order.index(head) < order.index("backward scan")
+    assert order.count(head) == 2       # lm_head before it, embed after
+    assert order.index("backward scan") < len(order) - 1 - order[::-1].index(
+        head)
+
+
+@pytest.mark.parametrize("remat", ["none", "block"])
+def test_train_step_looped_model_sums_layers_once_after_the_pass_loop(remat):
+    """``n_loops=2``: a shared layer's gradient is complete only after the
+    last backward pass over it, so every leaf, the stacked layers whole, is
+    summed exactly once and outside every scan."""
+    cfg, jaxpr, _ = _lm_step({"data": 4}, n_loops=2, remat=remat)
+    layers, rest = _leaf_shapes(cfg)
+    found = _grad_psums(jaxpr)
+    assert not [p for p in found if any(t.startswith("scan") for t in p[3])]
+    assert sorted(tuple(a.shape) for p in found for a in p[2]) == sorted(
+        layers + rest)
+    assert all(a.dtype == jnp.float32 for p in found for a in p[2])
+
+
+@pytest.mark.parametrize("axes", [{"data": 2, "seq": 2},
+                                  {"data": 2, "tensor": 2}])
+def test_train_step_sums_each_leaf_over_the_axes_its_spec_leaves_out(axes):
+    """Data and seq for every leaf; tensor too for what is replicated over
+    it (norms, embedding) and not for what is split over it."""
+    from horovod_tpu.models.transformer import init_params, param_specs
+    cfg, jaxpr, _ = _lm_step(axes)
+    specs = param_specs(cfg)["layers"]
+    stacked = jax.eval_shape(lambda k: init_params(k, cfg),
+                             jax.random.PRNGKey(0))["layers"]
+    split = sorted(stacked[k].ndim - 1 for k, s in specs.items()
+                   if "tensor" in s)
+    live = tuple(a for a in ("data", "seq", "tensor") if axes.get(a, 1) > 1)
+    found = _grad_psums(jaxpr)
+    over_data_seq = [p for p in found if "tensor" not in p[1]]
+    assert all(p[1] == tuple(a for a in live if a != "tensor")
+               for p in over_data_seq)
+    assert all(p[1] == live for p in found if p not in over_data_seq)
+    if axes.get("tensor", 1) > 1:
+        # exactly the leaves split over tensor are left unsummed over it
+        assert sorted(a.ndim for p in over_data_seq
+                      for a in p[2]) == split
+    else:
+        assert not [p for p in found if "tensor" in p[1]]
+
+
+@pytest.mark.parametrize("n_loops", [1, 2])
+def test_train_step_on_a_mesh_of_one_has_no_gradient_sum(n_loops):
+    """Nothing to sum over: no gradient psum, no wrapper (its custom_vjp
+    would show in the names), and no all-reduce among more than one chip."""
+    cfg, jaxpr, text = _lm_step({}, n_loops=n_loops)
+    assert not _grad_psums(jaxpr)
+    assert scopes.GRAD_REDUCE not in text
+    assert "_summed_cotangent" not in text and "custom_vjp" not in text
+    groups = re.findall(r"replica_groups = dense<([^>]*)>", text)
+    assert groups and all("," not in g for g in groups), groups
+
+
+def test_make_spmd_loss_and_forward_hold_no_wrapper():
+    """``_run_passes`` is also the body of the forward-only products: with no
+    ``layer_grad_axes`` their traces must not know the wrapper at all."""
+    from horovod_tpu.models import transformer as tfm
+    cfg, params, tok = _tiny_lm()
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1, 1),
+                ("data", "seq", "tensor"))
+    tok4 = jnp.zeros((4, 16), jnp.int32)
+    loss = jax.jit(jax.grad(
+        lambda p: tfm.make_spmd_loss(mesh, cfg)(p, tok4, tok4)))
+    for text in (loss.lower(params).as_text(debug_info=True),
+                 jax.jit(lambda p: tfm.forward_block(p, tok, cfg)).lower(
+                     params).as_text(debug_info=True)):
+        assert "_summed_cotangent" not in text and "custom_vjp" not in text
+        assert scopes.GRAD_REDUCE not in text
+
+
+class _Chip:     # what the builder asks of a device: its platform
+    platform = "tpu"
+
+
+@pytest.mark.parametrize("platform,shape,some", [
+    ("cpu", (4, 1, 1), False), ("cpu", (1, 1, 1), False),
+    ("tpu", (1, 1, 1), False),
+    ("tpu", (4, 1, 1), True), ("tpu", (2, 2, 1), True),
+    ("tpu", (1, 4, 1), True),
+    ("tpu", (1, 1, 4), False), ("tpu", (2, 1, 2), False),
+    ("tpu", (1, 2, 2), False)])
+def test_train_step_compiler_options_only_where_a_chip_run_showed_them(
+        platform, shape, some):
+    """The options that let the TPU compiler run the all-reduces beside
+    compute go to ``jax.jit`` for a TPU mesh over ``data`` and ``seq``
+    alone (what ran on the chip: the cell's ``data=4``, the smoke's
+    ``data=2,seq=2``), and nowhere else: empty off the TPU, on a mesh of
+    one, and wherever ``tensor > 1`` (the activations' psums would turn
+    asynchronous too, unseen on a chip; mixed with another axis the sums
+    run over subgroups of the chips, on which one of the options kills
+    libtpu 0.0.34's compiler)."""
+    from horovod_tpu.models import transformer as tfm
+    n = int(np.prod(shape))
+    devices = (jax.devices()[:n] if platform == "cpu"
+               else [_Chip() for _ in range(n)])
+    mesh = Mesh(np.array(devices).reshape(shape), ("data", "seq", "tensor"))
+    assert tfm._TPU_OVERLAP_OPTIONS     # the TPU form does pass some
+    assert tfm._overlap_compiler_options(mesh) == (
+        tfm._TPU_OVERLAP_OPTIONS if some else {})
+
+
+@pytest.mark.parametrize("axes,fields,want", [
+    ({"data": 4}, {}, "share"), ({}, {}, 0.0), ({"data": 4}, {"n_loops": 2},
+                                                0.0)])
+def test_grad_reduce_in_backward_share(axes, fields, want):
+    """Bytes of the leaves summed inside the backward scan over all the
+    gradient bytes a chip puts through an all-reduce: the layers' share on
+    ``data=4``, 0 on a mesh of one and for a looped model. The gauge that
+    carries it has its METRIC_SPECS entry (``examples/transformer_lm.py``
+    sets it; ``tests/test_examples.py``)."""
+    from horovod_tpu.metrics import METRIC_SPECS
+    import dataclasses
+    from horovod_tpu.models import transformer as tfm
+    cfg = dataclasses.replace(_tiny_lm()[0], n_layers=3, **fields)
+    layers, rest = _leaf_shapes(cfg)
+    if want == "share":
+        in_scan = sum(int(np.prod(s)) for s in layers)
+        want = in_scan / (in_scan + sum(int(np.prod(s)) for s in rest))
+        assert 0.3 < want < 1.0
+    mesh = Mesh(np.array(jax.devices()[:axes.get("data", 1)]).reshape(
+        -1, 1, 1), ("data", "seq", "tensor"))
+    assert tfm.grad_reduce_in_backward_share(mesh, cfg) == pytest.approx(
+        want, abs=1e-12)
+    assert METRIC_SPECS["hvd_tpu_lm_grad_reduce_in_backward_share"][0] == (
+        "gauge")

@@ -130,6 +130,9 @@ def test_transformer_lm_example_spmd():
               "--seq", "32", "--batch", "4", "--steps", "2"])
     assert r.returncode == 0, r.stderr[-2000:]
     assert "tokens_per_sec" in r.stdout, r.stdout
+    # the layer's 8,256 of the 12,384 gradient values are summed inside
+    # the backward scan (ln1, ln2, four d x d, two d x d_ff; embed, ln_f)
+    assert "'grad_reduce_in_backward_share': 0.6667" in r.stdout, r.stdout
 
 
 def test_transformer_lm_example_looped():
@@ -146,6 +149,8 @@ def test_transformer_lm_example_looped():
               "4", "--remat", "block"])
     assert r.returncode == 0, r.stderr[-2000:]
     assert "tokens_per_sec" in r.stdout and "exit_share" in r.stdout
+    # shared layers are summed once, after the pass loop
+    assert "'grad_reduce_in_backward_share': 0.0" in r.stdout, r.stdout
 
 
 def test_transformer_lm_example_pp_refuses_the_loop_by_name():

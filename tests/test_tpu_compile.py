@@ -1,0 +1,144 @@
+"""The SPMD step compiled HERE for a described TPU v5e:2x2 (no chip
+attached, nothing runs): what libtpu's own compiler makes of the step's
+gradient all-reduces with the options ``make_train_step`` hands it.
+
+The compiler is the installed libtpu's, so a wrong option name fails these
+tests loudly, and an upgrade that stops overlapping shows here before a
+chip is asked. Every call that loads libtpu sits in a fixture of this one
+file (one xdist worker loads it; see the on-chip-measurement guide): never
+at import, never in a second test file.
+"""
+
+import os
+import re
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+import optax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from horovod_tpu.models import transformer as tfm
+
+# lm-spmd-4chip-dp's own program but for its depth (2 of the cell's 4
+# layers): the widths of benchmark/configs/cerebras-gpt-1.3b.json, bf16
+# compute, the splash kernels, 4 rows of 2048 tokens a chip, adamw
+CFG = tfm.TransformerConfig(vocab_size=50257, d_model=2048, n_heads=16,
+                            n_layers=2, d_ff=8192, max_seq=2048,
+                            dtype=jnp.bfloat16, attention="flash")
+ROWS_PER_CHIP = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def pallas_branch(monkeypatch):
+    """Attention asks ``jax.default_backend()`` whether to take its Pallas
+    kernels; the process is held to the CPU, the compile is for the TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compiled_step(topo, shape, cfg):
+    """Scheduled HLO text of ``make_train_step`` over the described chips."""
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(shape),
+                (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
+    opt = optax.adamw(3e-4)
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            tree, tfm.param_specs(cfg))
+
+    params = on_mesh(shapes)
+    adam, *rest = jax.eval_shape(opt.init, shapes)
+    state = (adam._replace(
+        count=jax.ShapeDtypeStruct((), jnp.int32,
+                                   sharding=NamedSharding(mesh, P())),
+        mu=on_mesh(adam.mu), nu=on_mesh(adam.nu)), *rest)
+    tok = jax.ShapeDtypeStruct(
+        (ROWS_PER_CHIP * shape[0], cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS)))
+    step = tfm.make_train_step(mesh, cfg, opt)
+    return step.lower(params, state, tok, tok).compile().as_text()
+
+
+def _computations(text):
+    """name -> lines of every computation of an HLO module's text."""
+    found, name = {}, None
+    for line in text.split("\n"):
+        head = (re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+                if line.rstrip().endswith("{") else None)
+        if head:
+            name = head[1]
+            found[name] = []
+        elif line.rstrip() == "}":
+            name = None
+        elif name:
+            found[name].append(line)
+    return found
+
+
+def test_data4_layer_all_reduces_are_asynchronous_inside_the_backward_loop(
+        topo, pallas_branch):
+    """``data=4``: the options compile, and in the backward loop's body
+    every matrix leaf's all-reduce is an asynchronous start/done pair with
+    a matmul or the stacking between them; the embedding's runs beside the
+    layers' optimizer update."""
+    text = _compiled_step(topo, (4, 1, 1), CFG)
+    assert "splash_mha" in text         # the cell's kernels, not the ring
+    bodies = {name: lines for name, lines in _computations(text).items()
+              if any("transpose(jvp(layers))/while/body" in line
+                     and "%async-collective-done" in line for line in lines)}
+    # (where the compiler peels an iteration into the entry computation,
+    # the loop's body is the other one)
+    bodies = {name: lines for name, lines in bodies.items()
+              if not name.startswith("main")} or bodies
+    assert len(bodies) == 1, list(bodies)
+    (body,) = bodies.values()
+    started = [i for i, line in enumerate(body)
+               if re.search(r"%async-collective-start[.\d]* = ", line)]
+    done = [i for i, line in enumerate(body)
+            if re.search(r"%async-collective-done[.\d]* = ", line)]
+    # wq, wk, wv, wo, w1, w2 (the two norms' 8 kB may stay synchronous)
+    assert len(started) >= 6 and len(started) == len(done)
+    for a, b in zip(started, done):
+        between = [line for line in body[a + 1:b] if " fusion(" in line]
+        assert between, "a start/done pair with no compute between them"
+    # and no gradient leaf crosses chips in anything narrower than float32
+    grads = [line for line in text.split("\n")
+             if "grad_reduce/psum" in line and re.search(
+                 r"%(all-reduce|async-collective-start)[.\d]* = ", line)]
+    assert grads and not [g for g in grads if re.search(
+        r"= \(?(bf16|f16)\[", g)]
+    main = next(lines for name, lines in _computations(text).items()
+                if name.startswith("main"))
+    embed = [i for i, line in enumerate(main)
+             if re.search(r"%async-collective-(start|done)[.\d]* = ", line)
+             and f"f32[{CFG.vocab_size},{CFG.d_model}]" in line]
+    assert len(embed) >= 2
+    assert any(" fusion(" in line for line in main[embed[0] + 1:embed[-1]])
+
+
+def test_data4_without_the_options_overlaps_nothing(topo, pallas_branch,
+                                                    monkeypatch):
+    """What the options are for: libtpu's defaults leave every gradient
+    all-reduce synchronous (and combine a layer's into ops that wait for
+    the iteration's last gradient)."""
+    monkeypatch.setattr(tfm, "_TPU_OVERLAP_OPTIONS", {})
+    text = _compiled_step(topo, (4, 1, 1), CFG)
+    assert "async-collective-start" not in text
+    assert "all-reduce-start" not in text
+    assert " all-reduce(" in text
