@@ -1,6 +1,9 @@
-"""Scaling-efficiency harness (SURVEY §7 Slice 7; BASELINE.md's
-allreduce-scaling-efficiency 8→256-chip metric; reference
-docs/benchmarks.rst:7-13 measured 90% at 512 GPUs).
+"""Scaling-efficiency harness (SURVEY §7 Slice 7: allreduce scaling
+efficiency over sub-meshes; the reference's docs/benchmarks.rst:7-13
+published 90% at 512 GPUs). What this repo measures on the chip is
+``python3 benchmark/run.py --workload <cell>`` (PERF.md); a number this
+script prints on the forced-host CPU world is a harness check, not a
+rate.
 
 For each world size n (sub-meshes of the available devices — real chips on a
 pod, or the forced-host CPU world for harness validation):

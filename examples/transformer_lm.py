@@ -14,8 +14,9 @@ Three modes:
 - ``--mode pp``: the flagship through the memory-bounded 1F1B pipeline
   (``--stages``, ``--n-micro``).
 
-The block is told by flags (spmd and eager modes; the pipeline restates
-the block and refuses them): ``--positions rope``, ``--ffn swiglu``,
+The block is told by flags, in every mode (the pipeline runs the same
+block; its first and last stage refuse ``--untied-head`` and ``--n-loops``
+above 1 by name): ``--positions rope``, ``--ffn swiglu``,
 ``--norm sandwich``, ``--untied-head``, ``--n-loops 4`` make a looped
 decoder of the Ouro kind (benchmark/configs/ouro-2.6b.json), whose mean
 exit share of every pass goes to the gauge ``hvd_tpu_lm_exit_share``. In
@@ -123,7 +124,7 @@ def main():
                                                     pp_param_specs)
         n_stages = args.stages or len(jax.devices())
         mesh = Mesh(np.array(jax.devices()[:n_stages]), ("pipe",))
-        # first: the builder refuses by name what its block cannot run
+        # first: the builder refuses by name what its stages cannot run
         step = make_pp_train_step(mesh, cfg, opt, n_micro=args.n_micro)
         specs = pp_param_specs(cfg)
         params = jax.tree_util.tree_map(

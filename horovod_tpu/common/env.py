@@ -114,7 +114,7 @@ HOROVOD_TPU_OVERLAP_STAGE_BYTES = "HOROVOD_TPU_OVERLAP_STAGE_BYTES"
 # stays inside the fused step program, the schedule replay sustains
 HOROVOD_TPU_ZERO1_PREFETCH = "HOROVOD_TPU_ZERO1_PREFETCH"
 # XLA latency-hiding scheduler as a supported knob (ISSUE 6 satellite,
-# from the overlap experiment of docs/roofline.md section 3b): =1 appends
+# on the chip not measured, docs/roofline.md section 1): =1 appends
 # --xla_tpu_enable_latency_hiding_scheduler=true to XLA_FLAGS before the
 # first backend touch (loud WARNING + no-op if a jax backend already
 # exists — XLA parses XLA_FLAGS at backend init, not at import)
@@ -379,8 +379,8 @@ def use_compile_cache() -> str:
     """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
     and return that directory. With ``JAX_COMPILATION_CACHE_DIR`` set this
     sets nothing (jax reads the variable itself). Call before the first
-    compile; every entry script (chip_smoke.py, bench.py, bench_kernels.py,
-    the examples, the tools/ probes) calls it, and workers under the
+    compile; every entry script (chip_smoke.py, benchmark/worker.py, the
+    examples, the tools/ probes) calls it, and workers under the
     launcher inherit the directory through the variable."""
     path = compile_cache_dir()
     if not os.environ.get(JAX_COMPILATION_CACHE_DIR):

@@ -27,14 +27,16 @@ from __future__ import annotations
 import jax
 import optax
 
-# models/transformer.py, the step the LM cells run
-EMBED = "embed"         # _run_passes: the token lookup and its cast
+# models/transformer.py: the one block every step builder runs
+# (make_train_step, the pipeline's stages, MoE-EP's segments)
+EMBED = "embed"         # _embed: the token lookup and its cast
 LOOP = "loop"           # _run_passes: the lax.scan over the passes (n_loops > 1)
-LAYERS = "layers"       # _run_passes: the lax.scan over the stacked layers
-ATTN = "attn"           # layer: rmsnorms, projections, attention, residual
+LAYERS = "layers"       # the lax.scan over the stacked layers (_run_passes,
+#                         a pipeline stage)
+ATTN = "attn"           # _attn_sublayer: rmsnorms, projections, attention, residual
 ROPE = "rope"           # the cos/sin tables; inside attn, q and k rotated
-FFN = "ffn"             # layer: rmsnorms, dense or MoE branch, residual
-HEAD = "head"           # every pass's final rmsnorm; _head: logits einsum
+FFN = "ffn"             # _block: rmsnorms, dense or MoE branch, residual
+HEAD = "head"           # _final_norm: every pass's final rmsnorm; _head: logits einsum
 LOSS = "loss"           # _lean_xent, both rules of its custom_vjp
 EXIT_GATE = "exit_gate"  # gate logit, exit distribution, entropy (n_loops > 1)
 # optimizer.py and the step builders

@@ -764,8 +764,8 @@ def publish_snapshot(kv: Tuple[str, int], rank: int, snap: dict,
 
 
 def counter_total(snap: dict, name: str) -> float:
-    """Sum a snapshot counter across every label set (the helper bench.py
-    and the emitter's rate sampling share)."""
+    """Sum a snapshot counter across every label set (the emitter's rate
+    sampling reads it)."""
     ent = snap.get("counters", {}).get(name)
     if not ent:
         return 0.0

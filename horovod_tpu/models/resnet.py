@@ -49,12 +49,11 @@ class ResNet(nn.Module):
     num_classes: int = 1000
     num_filters: int = 64
     dtype: Any = jnp.bfloat16
-    # Opt-in Pallas fused-BN path. Measured on v5e: the standalone kernels
-    # run at full HBM bandwidth (~1 TB/s), but XLA already *fuses* the BN
-    # stat reductions into adjacent elementwise passes, so extracting them
-    # adds a memory pass and loses (~110ms -> ~184ms/step at batch 256).
-    # Kept for workloads where the stats are not fusion-adjacent (e.g.
-    # SyncBatchNorm local stats). Full analysis: docs/roofline.md.
+    # Opt-in Pallas fused-BN path. XLA already fuses the BN stat reductions
+    # into adjacent elementwise passes, so extracting them adds a memory
+    # pass; before PR 1 that lost, on today's chip it is not measured
+    # (docs/roofline.md section 1; ROADMAP queue 3: one traced run, then
+    # delete what does not win).
     fused_bn: bool = False
 
     @nn.compact

@@ -36,7 +36,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common import scopes
-from ..parallel.moe import MoEParams, moe_layer_p
+from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
+                            moe_dispatch, moe_experts, moe_layer_p)
 from ..parallel.flash_attention import flash_attention_local
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
@@ -243,21 +244,25 @@ def _rope_tables(cfg: TransformerConfig, t_local: int,
     positions of the tokens this shard holds: block ``r`` of the sequence
     under the contiguous layout, stripes ``(r, 2n-1-r)`` under zigzag
     (``parallel.ring_attention.zigzag_indices``), so ring and Ulysses
-    attention are handed q and k rotated as on a single shard."""
-    half = cfg.head_dim // 2
-    inv_freq = cfg.rope_theta ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
-    pos = jnp.arange(t_local)
-    if seq_size is not None and seq_size > 1:
-        r = lax.axis_index(SEQ_AXIS)
-        if cfg.sp_layout == "zigzag":
-            s = t_local // 2
-            pos = jnp.concatenate([
-                r * s + pos[:s], (2 * seq_size - 1 - r) * s + pos[:s]])
-        else:
-            pos = r * t_local + pos
-    angle = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
+    attention are handed q and k rotated as on a single shard. ``None``
+    without RoPE."""
+    if cfg.positions != "rope":
+        return None
+    with jax.named_scope(scopes.ROPE):
+        half = cfg.head_dim // 2
+        inv_freq = cfg.rope_theta ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+        pos = jnp.arange(t_local)
+        if seq_size is not None and seq_size > 1:
+            r = lax.axis_index(SEQ_AXIS)
+            if cfg.sp_layout == "zigzag":
+                s = t_local // 2
+                pos = jnp.concatenate([
+                    r * s + pos[:s], (2 * seq_size - 1 - r) * s + pos[:s]])
+            else:
+                pos = r * t_local + pos
+        angle = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
+        return jnp.cos(angle), jnp.sin(angle)
 
 
 def _rope(x, cos, sin):
@@ -294,6 +299,176 @@ def _sum_in_backward(x, axes):
     return _summed_cotangent(x, axes) if axes else x
 
 
+def _attn_mix(x, wq, wk, wv, wo, *, cfg: TransformerConfig, rope=None,
+              seq_size: Optional[int] = None,
+              tensor_size: Optional[int] = None, causal: bool = True,
+              under_remat: bool = False):
+    """Attention over the normed ``x [B, T, D]``: the q/k/v projections,
+    RoPE by ``rope`` (:func:`_rope_tables`), the attention itself (ring or
+    Ulysses under sequence parallelism, the flash kernel, or materialized),
+    the output projection, and its psum over ``tensor`` inside a shard_map.
+    ``under_remat``: a backward pass runs this again (the kernels then take
+    their smaller-VMEM variant). What ``remat="attention"`` checkpoints."""
+    dt = cfg.dtype
+    # flash wants [B, H, T, K]; projecting straight into that layout keeps
+    # the transposes out of the hot path (they fold into the einsums).
+    # Under sequence parallelism ring/ulysses own the kernel
+    flash = cfg.attention == "flash" and (seq_size is None or seq_size <= 1)
+    qkv_eq = "btd,dhk->bhtk" if flash else "btd,dhk->bthk"
+    q = jnp.einsum(qkv_eq, x, wq.astype(dt))
+    k = jnp.einsum(qkv_eq, x, wk.astype(dt))
+    v = jnp.einsum(qkv_eq, x, wv.astype(dt))
+    if rope is not None:
+        with jax.named_scope(scopes.ROPE):
+            cos, sin = rope
+            if not flash:       # q, k are [B, T, H, K]
+                cos, sin = cos[:, None, :], sin[:, None, :]
+            q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+    if seq_size is not None and seq_size > 1:
+        if cfg.attention == "ulysses":
+            if cfg.sp_layout == "zigzag" and causal:
+                raise ValueError(
+                    "sp_layout='zigzag' needs ring attention: Ulysses "
+                    "re-gathers the sequence in axis order, which under "
+                    "a zigzag permutation breaks the causal mask")
+            att = ulysses_attention_p(q, k, v, SEQ_AXIS, seq_size,
+                                      causal=causal, under_remat=under_remat)
+        else:
+            att = ring_attention_p(q, k, v, SEQ_AXIS, seq_size,
+                                   causal=causal, layout=cfg.sp_layout,
+                                   under_remat=under_remat)
+    elif flash:
+        att = flash_attention_local(q, k, v, causal=causal, layout="bhtk",
+                                    under_remat=under_remat)
+    else:
+        att = local_attention(q, k, v, causal=causal)
+    out = jnp.einsum("bhtk,hkd->btd" if flash else "bthk,hkd->btd",
+                     att, wo.astype(dt))
+    if tensor_size is not None:
+        out = lax.psum(out, TENSOR_AXIS)
+    return out
+
+
+def _residual(h, out, lp, post: str, cfg: TransformerConfig):
+    """How a sublayer's output joins the stream: ``h + out``, under
+    sandwich norms ``h + N'(out)`` with the scale ``lp[post]``."""
+    if cfg.norm == "sandwich":
+        out = _rmsnorm(out, lp[post], cfg.norm_eps)
+    return h + out
+
+
+def _attn_sublayer(h, lp, cfg: TransformerConfig, mix):
+    """``h + [N'] mix(N(h), wq, wk, wv, wo)`` of one layer's leaves ``lp``;
+    ``mix`` is :func:`_attn_mix` with its caller's arguments bound."""
+    with jax.named_scope(scopes.ATTN):
+        out = mix(_rmsnorm(h, lp["ln1"], cfg.norm_eps),
+                  lp["wq"], lp["wk"], lp["wv"], lp["wo"])
+        return _residual(h, out, lp, "ln1_post", cfg)
+
+
+def _dense_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
+    """``gelu(x w1) w2`` or ``(silu(x wg) * (x wu)) wd``, the hidden dim
+    split over ``tensor`` inside a shard_map."""
+    dt = cfg.dtype
+    if cfg.ffn == "swiglu":
+        u = jax.nn.silu(jnp.einsum(
+            "btd,df->btf", x, lp["wg"].astype(dt))) * jnp.einsum(
+            "btd,df->btf", x, lp["wu"].astype(dt))
+        out = jnp.einsum("btf,fd->btd", u, lp["wd"].astype(dt))
+    else:
+        u = jax.nn.gelu(jnp.einsum("btd,df->btf", x, lp["w1"].astype(dt)))
+        out = jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
+    if tensor_size is not None:
+        out = lax.psum(out, TENSOR_AXIS)
+    return out
+
+
+def _moe_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
+    """The MoE FFN with its experts split over ``tensor`` (EP over the axis
+    the dense FFN gives to TP): ``(out [B, T, D], aux)``."""
+    b, t, d = x.shape
+    mp = MoEParams(lp["router"], lp["w1"], lp["w2"])
+    tok = x.reshape(b * t, d)
+    if tensor_size is not None and tensor_size > 1:
+        # EP over the tensor axis: split this shard's tokens across the
+        # axis members (no duplicate expert compute), dispatch, and gather
+        # the processed tokens back
+        n = tensor_size
+        pad = (-tok.shape[0]) % n
+        n_tok = tok.shape[0]
+        if pad:
+            tok = jnp.concatenate([tok, jnp.zeros((pad, d), tok.dtype)])
+        per = tok.shape[0] // n
+        idx = lax.axis_index(TENSOR_AXIS)
+        mine = lax.dynamic_slice_in_dim(tok, idx * per, per)
+        # mask out pad rows: they must not route, take capacity, or skew
+        # the aux statistics
+        rows = idx * per + jnp.arange(per)
+        y_mine, aux = moe_layer_p(
+            mine, mp, TENSOR_AXIS, n,
+            capacity_factor=cfg.moe_capacity_factor, valid_mask=rows < n_tok)
+        y2d = lax.all_gather(y_mine, TENSOR_AXIS, axis=0, tiled=True)
+        if pad:
+            y2d = y2d[:-pad]
+    else:
+        y2d, aux = moe_layer_p(tok, mp, TENSOR_AXIS, 1,
+                               capacity_factor=cfg.moe_capacity_factor)
+    return y2d.reshape(b, t, d), aux
+
+
+def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
+           tensor_size: Optional[int] = None, causal: bool = True,
+           under_remat: bool = False):
+    """One transformer layer as a scan body over the stacked layer leaves,
+    ``layer((h, aux_sum), lp) -> ((h, aux_sum), None)``, for every step
+    builder: the attention sublayer, then the dense (TP over the hidden dim)
+    or MoE (EP over the same axis) FFN sublayer, under ``cfg.remat``'s
+    checkpoints. The arguments are :func:`_attn_mix`'s."""
+    mix = functools.partial(_attn_mix, cfg=cfg, rope=rope, seq_size=seq_size,
+                            tensor_size=tensor_size, causal=causal,
+                            under_remat=under_remat)
+    if cfg.remat == "attention":
+        # backward recomputes q/k/v projections + attention from the normed
+        # input instead of saving them (prevent_cse is unnecessary inside
+        # scan, and disabling it lets XLA fuse the recompute cleanly)
+        mix = jax.checkpoint(mix, prevent_cse=False)
+    elif cfg.remat not in ("none", "block"):
+        raise ValueError(f"unknown remat mode {cfg.remat!r}; "
+                         f"expected 'none', 'block', or 'attention'")
+
+    def layer(carry, lp):
+        h, aux_sum = carry
+        h = _attn_sublayer(h, lp, cfg, mix)
+        with jax.named_scope(scopes.FFN):
+            x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
+            if cfg.use_moe:
+                out, aux = _moe_ffn(x, lp, cfg, tensor_size)
+                aux_sum = aux_sum + aux
+            else:
+                out = _dense_ffn(x, lp, cfg, tensor_size)
+            h = _residual(h, out, lp, "ln2_post", cfg)
+        return (h, aux_sum), None
+
+    if cfg.remat == "block":
+        # each scanned layer recomputes from its carry in backward: live
+        # activations shrink from every layer's intermediates to one
+        # layer's input per step (VERDICT r3 item 4 — the B>4 OOM lever);
+        # under n_loops > 1 in every pass alike
+        layer = jax.checkpoint(layer, prevent_cse=False)
+    return layer
+
+
+def _embed(params, tokens, cfg: TransformerConfig):
+    with jax.named_scope(scopes.EMBED):
+        return params["embed"][tokens].astype(cfg.dtype)  # [B, T, D]
+
+
+def _final_norm(params, h, cfg: TransformerConfig):
+    """What a pass over the stack ends in, and what the head takes."""
+    with jax.named_scope(scopes.HEAD):
+        return _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+
+
 def _run_passes(params, tokens, cfg: TransformerConfig,
                 seq_size: Optional[int], tensor_size: Optional[int],
                 causal: bool, exit_fn, layer_grad_axes=None):
@@ -314,135 +489,10 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     leaf of ``params["layers"]``, the mesh axes its gradient is summed over
     inside the backward scan, as each layer's backward produces it.
     """
-    dt = cfg.dtype
-    eps = cfg.norm_eps
-    sandwich = cfg.norm == "sandwich"
-    with jax.named_scope(scopes.EMBED):
-        h = params["embed"][tokens].astype(dt)  # [B, T, D]
-
-    # flash wants [B, H, T, D]; projecting straight into that layout keeps
-    # the transposes out of the hot path (they fold into the einsums)
-    flash = (cfg.attention == "flash"
-             and (seq_size is None or seq_size <= 1))
-    if cfg.positions == "rope":
-        with jax.named_scope(scopes.ROPE):
-            cos, sin = _rope_tables(cfg, tokens.shape[1], seq_size)
-            if not flash:       # q, k are [B, T, H, K]
-                cos, sin = cos[:, None, :], sin[:, None, :]
-
-    def attn_block(x, wq, wk, wv, wo):
-        qkv_eq = "btd,dhk->bhtk" if flash else "btd,dhk->bthk"
-        q = jnp.einsum(qkv_eq, x, wq.astype(dt))
-        k = jnp.einsum(qkv_eq, x, wk.astype(dt))
-        v = jnp.einsum(qkv_eq, x, wv.astype(dt))
-        if cfg.positions == "rope":
-            with jax.named_scope(scopes.ROPE):
-                q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-        if seq_size is not None and seq_size > 1:
-            remat_hint = cfg.remat != "none"
-            if cfg.attention == "ulysses":
-                if cfg.sp_layout == "zigzag" and causal:
-                    raise ValueError(
-                        "sp_layout='zigzag' needs ring attention: Ulysses "
-                        "re-gathers the sequence in axis order, which under "
-                        "a zigzag permutation breaks the causal mask")
-                att = ulysses_attention_p(q, k, v, SEQ_AXIS, seq_size,
-                                          causal=causal,
-                                          under_remat=remat_hint)
-            else:
-                att = ring_attention_p(q, k, v, SEQ_AXIS, seq_size,
-                                       causal=causal,
-                                       layout=cfg.sp_layout,
-                                       under_remat=remat_hint)
-        elif flash:
-            att = flash_attention_local(q, k, v, causal=causal,
-                                        layout="bhtk",
-                                        under_remat=cfg.remat != "none")
-        else:
-            att = local_attention(q, k, v, causal=causal)
-        out = jnp.einsum("bhtk,hkd->btd" if flash else "bthk,hkd->btd",
-                         att, wo.astype(dt))
-        if tensor_size is not None:
-            out = lax.psum(out, TENSOR_AXIS)
-        return out
-
-    if cfg.remat == "attention":
-        # backward recomputes q/k/v projections + attention from the normed
-        # input instead of saving them (prevent_cse is unnecessary inside
-        # scan, and disabling it lets XLA fuse the recompute cleanly)
-        attn_block = jax.checkpoint(attn_block, prevent_cse=False)
-
-    def layer(carry, lp):
-        h, aux_sum = carry
-        with jax.named_scope(scopes.ATTN):
-            x = _rmsnorm(h, lp["ln1"], eps)
-            out = attn_block(x, lp["wq"], lp["wk"], lp["wv"], lp["wo"])
-            if sandwich:
-                out = _rmsnorm(out, lp["ln1_post"], eps)
-            h = h + out
-        # dense (TP over hidden dim) or MoE (EP over the same axis)
-        with jax.named_scope(scopes.FFN):
-            x = _rmsnorm(h, lp["ln2"], eps)
-            if cfg.use_moe:
-                b, t, d = x.shape
-                mp = MoEParams(lp["router"], lp["w1"], lp["w2"])
-                tok = x.reshape(b * t, d)
-                if tensor_size is not None and tensor_size > 1:
-                    # EP over the tensor axis: split this shard's tokens
-                    # across the axis members (no duplicate expert compute),
-                    # dispatch, and gather the processed tokens back
-                    n = tensor_size
-                    pad = (-tok.shape[0]) % n
-                    n_tok = tok.shape[0]
-                    if pad:
-                        tok = jnp.concatenate(
-                            [tok, jnp.zeros((pad, d), tok.dtype)])
-                    per = tok.shape[0] // n
-                    idx = lax.axis_index(TENSOR_AXIS)
-                    mine = lax.dynamic_slice_in_dim(tok, idx * per, per)
-                    # mask out pad rows: they must not route, take capacity,
-                    # or skew the aux statistics
-                    rows = idx * per + jnp.arange(per)
-                    y_mine, aux = moe_layer_p(
-                        mine, mp, TENSOR_AXIS, n,
-                        capacity_factor=cfg.moe_capacity_factor,
-                        valid_mask=rows < n_tok)
-                    y2d = lax.all_gather(y_mine, TENSOR_AXIS, axis=0,
-                                         tiled=True)
-                    if pad:
-                        y2d = y2d[:-pad]
-                else:
-                    y2d, aux = moe_layer_p(
-                        tok, mp, TENSOR_AXIS, 1,
-                        capacity_factor=cfg.moe_capacity_factor)
-                out = y2d.reshape(b, t, d)
-                aux_sum = aux_sum + aux
-            else:
-                if cfg.ffn == "swiglu":
-                    u = jax.nn.silu(jnp.einsum(
-                        "btd,df->btf", x, lp["wg"].astype(dt))) * jnp.einsum(
-                        "btd,df->btf", x, lp["wu"].astype(dt))
-                    out = jnp.einsum("btf,fd->btd", u, lp["wd"].astype(dt))
-                else:
-                    u = jax.nn.gelu(jnp.einsum("btd,df->btf", x,
-                                               lp["w1"].astype(dt)))
-                    out = jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
-                if tensor_size is not None:
-                    out = lax.psum(out, TENSOR_AXIS)
-            if sandwich:
-                out = _rmsnorm(out, lp["ln2_post"], eps)
-            h = h + out
-        return (h, aux_sum), None
-
-    if cfg.remat == "block":
-        # each scanned layer recomputes from its carry in backward: live
-        # activations shrink from every layer's intermediates to one
-        # layer's input per step (VERDICT r3 item 4 — the B>4 OOM lever);
-        # under n_loops > 1 in every pass alike
-        layer = jax.checkpoint(layer, prevent_cse=False)
-    elif cfg.remat not in ("none", "attention"):
-        raise ValueError(f"unknown remat mode {cfg.remat!r}; "
-                         f"expected 'none', 'block', or 'attention'")
+    h = _embed(params, tokens, cfg)
+    layer = _block(cfg, _rope_tables(cfg, tokens.shape[1], seq_size),
+                   seq_size, tensor_size, causal,
+                   under_remat=cfg.remat != "none")
 
     if layer_grad_axes:
         # on the fp32 leaves, ahead of every cast, and outside the
@@ -455,8 +505,7 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     def one_pass(h, aux_sum):
         with jax.named_scope(scopes.LAYERS):
             (h, aux_sum), _ = lax.scan(layer, (h, aux_sum), params["layers"])
-        with jax.named_scope(scopes.HEAD):
-            return _rmsnorm(h, params["ln_f"], eps), aux_sum
+        return _final_norm(params, h, cfg), aux_sum
 
     aux0 = jnp.zeros((), jnp.float32)
     if cfg.n_loops == 1:
@@ -825,72 +874,34 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
 
 PIPE_AXIS = "pipe"
 
-# the fields of TransformerConfig that change the block of _run_passes
-_BLOCK_FIELDS = ("positions", "ffn", "norm", "norm_eps", "tie_embeddings",
-                 "n_loops")
-
-
-def _refuse_block_fields(cfg: TransformerConfig, builder: str) -> None:
-    """The pipeline and MoE-EP builders restate the block as it was before
-    ``_BLOCK_FIELDS`` existed: a configuration that sets one of them would
-    train another model there, so it is refused by name."""
-    default = TransformerConfig()
-    changed = [f"{f}={getattr(cfg, f)!r}" for f in _BLOCK_FIELDS
-               if getattr(cfg, f) != getattr(default, f)]
-    if changed:
+def _refuse_loop_and_untied_head(cfg: TransformerConfig, builder: str) -> None:
+    """The pipeline's first and last stage and MoE-EP's loss segment run one
+    pass over the stack into a head tied to the embedding: the exits after
+    every pass, the exit gate and an ``lm_head`` leaf have no stage or
+    segment there yet."""
+    if cfg.n_loops > 1:
         raise ValueError(
-            f"{builder} runs its own statement of the transformer block, "
-            f"which knows none of {', '.join(_BLOCK_FIELDS)}; got "
-            f"{', '.join(changed)}. make_train_step (dp/sp/tp) runs them.")
-
-
-def _pp_layer(lp, h, cfg: TransformerConfig, under_remat: bool = False):
-    """One transformer layer on a local activation block — the same
-    math as ``_forward``'s layer closure restricted to its PP-relevant
-    case (no seq/tensor collectives); kept in lockstep with it
-    so the pipelined flagship reproduces the monolithic numerics,
-    including the under-remat splash→flash VMEM degrade. With
-    ``cfg.use_moe`` the FFN is the capacity-routed MoE with every expert
-    resident on the stage (EP degree 1 inside the pipeline body — the
-    cross-rank EP transport is the ENGINE's alltoall, which cannot run
-    inside this jitted program; the load-balance aux term is omitted
-    from the pipeline objective, see docs/parallelism.md)."""
-    dt = cfg.dtype
-    flash = cfg.attention == "flash"
-    x = _rmsnorm(h, lp["ln1"])
-    qkv_eq = "btd,dhk->bhtk" if flash else "btd,dhk->bthk"
-    q = jnp.einsum(qkv_eq, x, lp["wq"].astype(dt))
-    k = jnp.einsum(qkv_eq, x, lp["wk"].astype(dt))
-    v = jnp.einsum(qkv_eq, x, lp["wv"].astype(dt))
-    if flash:
-        att = flash_attention_local(q, k, v, causal=True, layout="bhtk",
-                                    under_remat=under_remat)
-    else:
-        att = local_attention(q, k, v, causal=True)
-    h = h + jnp.einsum("bhtk,hkd->btd" if flash else "bthk,hkd->btd",
-                       att, lp["wo"].astype(dt))
-    x = _rmsnorm(h, lp["ln2"])
-    if cfg.use_moe:
-        b, t, d = x.shape
-        mp = MoEParams(lp["router"], lp["w1"], lp["w2"])
-        y2d, _ = moe_layer_p(x.reshape(b * t, d), mp, None, 1,
-                             capacity_factor=cfg.moe_capacity_factor)
-        return h + y2d.reshape(b, t, d)
-    u = jax.nn.gelu(jnp.einsum("btd,df->btf", x, lp["w1"].astype(dt)))
-    return h + jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
+            f"{builder} runs one pass over the stack: got "
+            f"n_loops={cfg.n_loops}, and the exit after every pass and the "
+            f"exit gate have no stage here. make_train_step (dp/sp/tp) "
+            f"runs them.")
+    if not cfg.tie_embeddings:
+        raise ValueError(
+            f"{builder} ties the head to the embedding (their two "
+            f"gradients are summed into one leaf): got "
+            f"tie_embeddings={cfg.tie_embeddings}. make_train_step "
+            f"(dp/sp/tp) runs an lm_head of its own.")
 
 
 def pp_param_specs(cfg: TransformerConfig):
     """Param shardings for the pipeline-parallel flagship: the stacked
-    [n_layers, ...] layer params split over the pipe axis; the (tied)
-    embedding and final norm replicated on every stage. MoE layers add
-    the router to the per-stage split (every expert is resident on its
-    stage — EP degree 1 inside the pipeline body)."""
-    keys = ("ln1", "wq", "wk", "wv", "wo", "ln2", "w1", "w2")
-    if cfg.use_moe:
-        keys = keys + ("router",)
-    layers = {k: P(PIPE_AXIS) for k in keys}
-    return {"embed": P(), "layers": layers, "ln_f": P()}
+    [n_layers, ...] layer params (whatever leaves ``param_specs`` gives the
+    configuration) split over the pipe axis; the (tied) embedding and final
+    norm replicated on every stage. Under MoE every expert is resident on
+    its stage — EP degree 1 inside the pipeline body."""
+    return {"embed": P(),
+            "layers": {k: P(PIPE_AXIS) for k in param_specs(cfg)["layers"]},
+            "ln_f": P()}
 
 
 def pp_layer_order(n_layers: int, n_stages: int, n_virtual: int,
@@ -930,6 +941,81 @@ def pp_permute_layers(params, order):
     return out
 
 
+def _pp_loss_and_grads(mesh: Mesh, cfg: TransformerConfig, n_micro: int,
+                       schedule: str, n_virtual: int, boundary_codec,
+                       topology, builder: str):
+    """The gradient body both pipeline builders run inside their shard_map
+    over ``mesh``'s pipe axis: ``body(params, inputs, targets) -> (loss,
+    grads)`` on one replica's batch. Embedding on stage 0, this stage's
+    rows of the stacked layers scanned through :func:`_block`, final norm,
+    tied head and :func:`_lean_xent` on the last stage. ``grads`` has
+    ``params``' layout: the layers' rows stay on their stage, the tied
+    embedding's is the sum of its stage-0 (lookup) and last-stage (head)
+    contributions, replicated over pipe like ``ln_f``'s.
+
+    The MoE FFN runs with every expert resident on its stage (EP degree 1:
+    the cross-rank EP transport is the ENGINE's alltoall, which cannot run
+    inside this jitted program), and its load-balance aux term is left out
+    of the pipeline objective (docs/parallelism.md)."""
+    from ..parallel.pipeline import (pipeline_train_step,
+                                     resolve_pipeline_schedule,
+                                     split_microbatches)
+    _refuse_loop_and_untied_head(cfg, builder)
+    n_stages = mesh.shape[PIPE_AXIS]
+    # resolve ONCE at build time (divcheck: never on the dispatch path) so
+    # the parameter placement matches what the executor will run
+    schedule, n_virtual = resolve_pipeline_schedule(
+        schedule, n_stages, n_micro, n_virtual, topology)
+    if cfg.n_layers % (n_stages * n_virtual):
+        raise ValueError(f"n_layers {cfg.n_layers} must divide into "
+                         f"{n_stages} pipeline stages x {n_virtual} "
+                         f"virtual chunks")
+
+    def stage_fn(sp, x):
+        # every schedule's backward recomputes a stage from its stashed
+        # input, so the attention kernels run under recompute whatever
+        # cfg.remat says (the splash->flash VMEM degrade applies);
+        # remat="block" checkpoints each layer inside that recompute too,
+        # and a deep stage's vjp keeps one layer's activations live
+        layer = _block(cfg, _rope_tables(cfg, x.shape[1], None),
+                       under_remat=True)
+        with jax.named_scope(scopes.LAYERS):
+            (h, _), _ = lax.scan(layer, (x, jnp.zeros((), jnp.float32)), sp)
+        return h
+
+    def first_fn(fp, micro_tok):
+        return _embed(fp, micro_tok, cfg)
+
+    def last_fn(lp, y):
+        return _head(lp, _final_norm(lp, y, cfg), cfg)
+
+    def body(params, inputs, targets):
+        sp = params["layers"]
+        if n_virtual > 1:
+            # this stage's contiguous row block holds its n_virtual chunks
+            # back to back (pp_layer_order placed them); view as
+            # [v, layers_per_chunk, ...] for the table executor
+            sp = jax.tree_util.tree_map(
+                lambda a: a.reshape((n_virtual, a.shape[0] // n_virtual)
+                                    + a.shape[1:]), sp)
+        loss, gs, gf, gl = pipeline_train_step(
+            stage_fn, sp, split_microbatches(inputs, n_micro),
+            split_microbatches(targets, n_micro), _mean_xent,
+            PIPE_AXIS, n_stages, schedule=schedule, n_virtual=n_virtual,
+            first_fn=first_fn, first_params={"embed": params["embed"]},
+            last_fn=last_fn, last_params={"embed": params["embed"],
+                                          "ln_f": params["ln_f"]},
+            boundary_codec=boundary_codec, topology=topology)
+        if n_virtual > 1:
+            gs = jax.tree_util.tree_map(
+                lambda a: a.reshape((a.shape[0] * a.shape[1],)
+                                    + a.shape[2:]), gs)
+        return loss, {"embed": gf["embed"] + gl["embed"],
+                      "layers": gs, "ln_f": gl["ln_f"]}
+
+    return body
+
+
 def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
                        n_micro: int, schedule: str = "1f1b",
                        n_virtual: int = 1, boundary_codec=None,
@@ -938,12 +1024,10 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
     or a 2-D ``("data", "pipe")`` mesh for DP×PP composition — using the
     memory-bounded 1F1B schedule (parallel/pipeline.py): embedding on
     stage 0, ``n_layers/n_stages`` transformer layers per stage, final
-    norm + tied-embedding head + lean logsumexp loss on the last stage.
-    Gradients: per-stage layer grads stay sharded over the pipe axis; the
-    tied embedding's gradient is the psum'd sum of its stage-0 (lookup)
-    and last-stage (head) contributions; under DP every gradient is
-    additionally pmean'd over the data axis (the reference's allreduce,
-    realized as the pipeline replica reduction). Returns a jitted
+    norm + tied-embedding head + lean logsumexp loss on the last stage
+    (:func:`_pp_loss_and_grads`). Under DP every gradient is additionally
+    pmean'd over the data axis (the reference's allreduce, realized as the
+    pipeline replica reduction). Returns a jitted
     ``(params, opt_state, inputs, targets) -> (params, opt_state, loss)``
     where inputs/targets carry the GLOBAL batch (split over data).
 
@@ -960,79 +1044,20 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
     pp_layer_order(...))`` — grads return in the same layout.
     ``boundary_codec`` is a ``(codec, coded_edges)`` pair (see
     ``parallel.mesh.pipeline_boundary_edges``) enabling PR 13 wire codecs
-    on DCN-crossing stage boundaries."""
-    from ..parallel.pipeline import (pipeline_train_step,
-                                     resolve_pipeline_schedule,
-                                     split_microbatches)
+    on DCN-crossing stage boundaries (the table schedules apply it; under
+    ``1f1b`` a coded edge is refused)."""
     if cfg.use_moe:
         raise NotImplementedError("PP flagship: dense FFN only (compose "
                                   "MoE with dp/sp/tp via make_train_step)")
-    _refuse_block_fields(cfg, "make_pp_train_step")
     d_size = mesh.shape.get(DATA_AXIS, 1)
-    n_stages = mesh.shape[PIPE_AXIS]
-    # resolve ONCE at build time (divcheck: never on the dispatch path) so
-    # the parameter placement below matches what the executor will run
-    schedule, n_virtual = resolve_pipeline_schedule(
-        schedule, n_stages, n_micro, n_virtual, topology)
-    if cfg.n_layers % (n_stages * n_virtual):
-        raise ValueError(f"n_layers {cfg.n_layers} must divide into "
-                         f"{n_stages} pipeline stages x {n_virtual} "
-                         f"virtual chunks")
-    if cfg.remat not in ("none", "block"):
-        raise NotImplementedError(
-            f"PP flagship supports remat='none'|'block', got {cfg.remat!r}")
-    dt = cfg.dtype
-    specs = pp_param_specs(cfg)
-
-    # the 1F1B backward ALWAYS recomputes each stage from its stashed
-    # input, so the attention kernels run under recompute regardless of
-    # cfg.remat — the splash→flash VMEM degrade must apply here just as
-    # in _forward
-    layer_fn = functools.partial(_pp_layer, cfg=cfg, under_remat=True)
-    if cfg.remat == "block":
-        # remat='block' additionally checkpoints each layer inside the
-        # stage recompute, so a deep stage's vjp keeps one layer's
-        # activations live instead of all of them — the same lever the
-        # monolithic path uses past the B=4 memory cliff
-        layer_fn = jax.checkpoint(layer_fn, prevent_cse=False)
-
-    def stage_fn(sp, x):
-        h, _ = lax.scan(lambda h, lp: (layer_fn(lp, h), None), x, sp)
-        return h
-
-    def first_fn(fp, micro_tok):
-        return fp["embed"][micro_tok].astype(dt)
-
-    def last_fn(lp, y):
-        h = _rmsnorm(y, lp["ln_f"])
-        return jnp.einsum("btd,vd->btv", h, lp["embed"].astype(dt))
+    pipe_body = _pp_loss_and_grads(mesh, cfg, n_micro, schedule, n_virtual,
+                                   boundary_codec, topology,
+                                   "make_pp_train_step")
 
     def body(params, inputs, targets):
         # inputs/targets arrive as this data-shard's slice of the global
         # batch; microbatching happens per replica
-        micro_in = split_microbatches(inputs, n_micro)
-        micro_tgt = split_microbatches(targets, n_micro)
-        sp = params["layers"]
-        if n_virtual > 1:
-            # this stage's contiguous row block holds its n_virtual chunks
-            # back to back (pp_layer_order placed them); view as
-            # [v, layers_per_chunk, ...] for the table executor
-            sp = jax.tree_util.tree_map(
-                lambda a: a.reshape((n_virtual, a.shape[0] // n_virtual)
-                                    + a.shape[1:]), sp)
-        loss, gs, gf, gl = pipeline_train_step(
-            stage_fn, sp, micro_in, micro_tgt, _mean_xent,
-            PIPE_AXIS, n_stages, schedule=schedule, n_virtual=n_virtual,
-            first_fn=first_fn, first_params={"embed": params["embed"]},
-            last_fn=last_fn, last_params={"embed": params["embed"],
-                                          "ln_f": params["ln_f"]},
-            boundary_codec=boundary_codec, topology=topology)
-        if n_virtual > 1:
-            gs = jax.tree_util.tree_map(
-                lambda a: a.reshape((a.shape[0] * a.shape[1],)
-                                    + a.shape[2:]), gs)
-        grads = {"embed": gf["embed"] + gl["embed"],
-                 "layers": gs, "ln_f": gl["ln_f"]}
+        loss, grads = pipe_body(params, inputs, targets)
         if d_size > 1:
             # DP x PP: average replicas' grads + loss over the data axis
             # (the reference's gradient allreduce)
@@ -1041,13 +1066,12 @@ def make_pp_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer,
             loss = lax.pmean(loss, DATA_AXIS)
         return loss, grads
 
+    specs = pp_param_specs(cfg)
     tok_spec = P(DATA_AXIS) if d_size > 1 else P()
     # check_vma=False for the reason given in make_spmd_loss
     grad_fn = jax.shard_map(
         body, mesh=mesh, in_specs=(specs, tok_spec, tok_spec),
-        out_specs=(P(), {"embed": P(), "layers": specs["layers"],
-                         "ln_f": P()}),
-        check_vma=False)
+        out_specs=(P(), specs), check_vma=False)
 
     def step(params, opt_state, inputs, targets):
         loss, grads = grad_fn(params, inputs, targets)
@@ -1084,69 +1108,24 @@ def make_pp_engine_train_step(mesh: Mesh, cfg: TransformerConfig, opt,
     opt_state, loss)`` (the engine legs must stay outside jit so replay
     can bracket them)."""
     from ..common.env import Config
-    from ..parallel.pipeline import (pipeline_train_step,
-                                     resolve_pipeline_schedule,
-                                     split_microbatches)
-    _refuse_block_fields(cfg, "make_pp_engine_train_step")
     if schedule is None:
         ecfg = Config.from_env()
         schedule = ecfg.pipeline_schedule
         n_virtual = n_virtual or ecfg.pipeline_virtual_stages
-    n_virtual = max(1, int(n_virtual))
-    n_stages = mesh.shape[PIPE_AXIS]
-    schedule, n_virtual = resolve_pipeline_schedule(
-        schedule, n_stages, n_micro, n_virtual, topology)
-    if cfg.n_layers % (n_stages * n_virtual):
-        raise ValueError(f"n_layers {cfg.n_layers} must divide into "
-                         f"{n_stages} pipeline stages x {n_virtual} "
-                         f"virtual chunks")
-    if cfg.remat not in ("none", "block"):
-        raise NotImplementedError(
-            f"PP flagship supports remat='none'|'block', got {cfg.remat!r}")
-    dt = cfg.dtype
-    layer_fn = functools.partial(_pp_layer, cfg=cfg, under_remat=True)
-    if cfg.remat == "block":
-        layer_fn = jax.checkpoint(layer_fn, prevent_cse=False)
-
-    def stage_fn(sp, x):
-        h, _ = lax.scan(lambda h, lp: (layer_fn(lp, h), None), x, sp)
-        return h
-
-    def first_fn(fp, micro_tok):
-        return fp["embed"][micro_tok].astype(dt)
-
-    def last_fn(lp, y):
-        h = _rmsnorm(y, lp["ln_f"])
-        return jnp.einsum("btd,vd->btv", h, lp["embed"].astype(dt))
-
-    rows = cfg.n_layers // n_stages
+    pipe_body = _pp_loss_and_grads(mesh, cfg, n_micro, schedule,
+                                   max(1, int(n_virtual)), boundary_codec,
+                                   topology, "make_pp_engine_train_step")
 
     def body(params, inputs, targets):
-        micro_in = split_microbatches(inputs, n_micro)
-        micro_tgt = split_microbatches(targets, n_micro)
-        sp = params["layers"]
-        if n_virtual > 1:
-            sp = jax.tree_util.tree_map(
-                lambda a: a.reshape((n_virtual, rows // n_virtual)
-                                    + a.shape[1:]), sp)
-        loss, gs, gf, gl = pipeline_train_step(
-            stage_fn, sp, micro_in, micro_tgt, _mean_xent,
-            PIPE_AXIS, n_stages, schedule=schedule, n_virtual=n_virtual,
-            first_fn=first_fn, first_params={"embed": params["embed"]},
-            last_fn=last_fn, last_params={"embed": params["embed"],
-                                          "ln_f": params["ln_f"]},
-            boundary_codec=boundary_codec, topology=topology)
-        if n_virtual > 1:
-            gs = jax.tree_util.tree_map(
-                lambda a: a.reshape((rows,) + a.shape[2:]), gs)
+        loss, grads = pipe_body(params, inputs, targets)
         # replicate the per-stage layer grads over pipe: the engine's DP
         # reduction needs every rank of this replica to contribute the
         # SAME full-model tensor (the world mean then equals the
         # data-axis mean)
-        gs = jax.tree_util.tree_map(
-            lambda a: lax.all_gather(a, PIPE_AXIS, axis=0, tiled=True), gs)
-        return loss, {"embed": gf["embed"] + gl["embed"],
-                      "layers": gs, "ln_f": gl["ln_f"]}
+        grads["layers"] = jax.tree_util.tree_map(
+            lambda a: lax.all_gather(a, PIPE_AXIS, axis=0, tiled=True),
+            grads["layers"])
+        return loss, grads
 
     specs = pp_param_specs(cfg)
     # check_vma=False for the reason given in make_spmd_loss; besides, the
@@ -1195,12 +1174,13 @@ def moe_ep_partition(params, rank: int, size: int, cfg: TransformerConfig):
 def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
     """Expert-parallel MoE train step riding the ENGINE alltoall (ISSUE 17
     tentpole): experts sharded over the engine world (one device per
-    process — the DP axis), capacity-based top-1 routing in lockstep with
-    :func:`~horovod_tpu.parallel.moe.moe_layer_p`'s math, but the dispatch
-    and combine exchanges go through ``engine.grouped_alltoall`` — so they
-    ride the full engine stack: per-(bytes, topology) flat-vs-hierarchical
-    selection, link-split wire accounting, the DCN-leg codec, replay
-    capture, and Join metadata.
+    process — the DP axis), the three pieces of
+    :func:`~horovod_tpu.parallel.moe.moe_layer_p` (capacity-based top-1
+    routing, the local experts' FFN, the combine), but the dispatch and
+    combine exchanges between them go through ``engine.grouped_alltoall``
+    — so they ride the full engine stack: per-(bytes, topology)
+    flat-vs-hierarchical selection, link-split wire accounting, the
+    DCN-leg codec, replay capture, and Join metadata.
 
     Structure: the per-rank compute (embedding, attention, routing/pack,
     expert FFN, combine, loss head) runs as jitted segments chained with
@@ -1226,41 +1206,25 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
     (shared, expert, opt_state, loss)`` over the placement
     :func:`moe_ep_partition` produces; ``opt_state`` is
     ``optimizer.init({"shared": shared, "expert": expert})``."""
-    import math as _math
     from ..metrics import registry as _registry
     from ..common.reduce_ops import ReduceOp
 
-    _refuse_block_fields(cfg, "make_moe_ep_train_step")
+    _refuse_loop_and_untied_head(cfg, "make_moe_ep_train_step")
     n = engine.backend.size()
     E = cfg.n_experts
     if E % max(n, 1):
         raise ValueError(f"n_experts {E} must divide over {n} "
                          f"expert-parallel ranks")
-    el = E // max(n, 1)
     capf = engine.config.moe_capacity_factor or cfg.moe_capacity_factor
-    dt = cfg.dtype
     L = cfg.n_layers
     aux_w = cfg.moe_aux_weight
-    flash = cfg.attention == "flash"
     reg = _registry()
     m_tokens = reg.counter("hvd_tpu_moe_expert_tokens_total")
     m_skew = reg.gauge("hvd_tpu_moe_dispatch_skew")
 
     @jax.jit
     def seg_embed(shared, tokens):
-        return shared["embed"][tokens].astype(dt)
-
-    def _attn(lp, x):
-        qkv_eq = "btd,dhk->bhtk" if flash else "btd,dhk->bthk"
-        q = jnp.einsum(qkv_eq, x, lp["wq"].astype(dt))
-        k = jnp.einsum(qkv_eq, x, lp["wk"].astype(dt))
-        v = jnp.einsum(qkv_eq, x, lp["wv"].astype(dt))
-        if flash:
-            att = flash_attention_local(q, k, v, causal=True, layout="bhtk")
-        else:
-            att = local_attention(q, k, v, causal=True)
-        return jnp.einsum("bhtk,hkd->btd" if flash else "bthk,hkd->btd",
-                          att, lp["wo"].astype(dt))
+        return _embed(shared, tokens, cfg)
 
     def _route_pack(shared, h, capacity, i):
         """Attention + capacity routing + dispatch-buffer pack for layer
@@ -1269,63 +1233,43 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
         post-attention residual). Aux outputs (non-diff): expert/slot
         indices for the combine and the per-expert routing counts."""
         lp = {k: v[i] for k, v in shared["layers"].items()}
-        x = _rmsnorm(h, lp["ln1"])
-        h = h + _attn(lp, x)
-        x = _rmsnorm(h, lp["ln2"])
-        b, t, d = x.shape
-        tok = x.reshape(b * t, d)
-        logits = (tok @ lp["router"].astype(tok.dtype)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert = jnp.argmax(probs, axis=-1)
-        gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
-        onehot = jax.nn.one_hot(expert, E, dtype=jnp.float32)
-        counts = jnp.sum(onehot, axis=0)
-        aux = E * jnp.sum((counts / (b * t)) *
-                          (jnp.sum(probs, axis=0) / (b * t)))
-        pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot,
-                      axis=-1).astype(jnp.int32) - 1
-        keep = jnp.logical_and(pos < capacity, pos >= 0)
-        slot = jnp.where(keep, pos, capacity - 1)
-        gatek = gate * keep.astype(jnp.float32)
-        disp = jnp.zeros((E, capacity, d), tok.dtype)
-        disp = disp.at[expert, slot].add(
-            tok * keep[:, None].astype(tok.dtype))
+        h = _attn_sublayer(h, lp, cfg, functools.partial(
+            _attn_mix, cfg=cfg, rope=_rope_tables(cfg, h.shape[1], None)))
+        with jax.named_scope(scopes.FFN):
+            x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
+            disp, aux, route = moe_dispatch(
+                x.reshape(-1, x.shape[-1]), lp["router"], capacity)
         # [E, C, D] is already the exchange layout: dim0 chunk k (global
         # experts [k·el, (k+1)·el)) goes to the rank that owns them
-        return (disp.reshape(E * capacity, d), aux, gatek, h), \
-            (expert, slot, counts)
+        return (disp.reshape(E * capacity, -1), aux, route.weight, h), \
+            (route.expert, route.slot, route.counts)
 
-    def _expert_ffn(exp, r_flat, capacity, i):
+    def _expert_ffn(exp, r_flat, i):
         """Local-expert FFN on the received tokens; returns the combine
-        buffer back in exchange layout. relu matches moe_layer_p so the
-        two transports are numerically interchangeable."""
-        d = r_flat.shape[-1]
-        e_in = r_flat.reshape(n, el, capacity, d).transpose(1, 0, 2, 3) \
-            .reshape(el, n * capacity, d)
-        hfe = jax.nn.relu(jnp.einsum("ecd,edf->ecf", e_in,
-                                     exp["w1"][i].astype(r_flat.dtype)))
-        y = jnp.einsum("ecf,efd->ecd", hfe,
-                       exp["w2"][i].astype(r_flat.dtype))
-        return y.reshape(el, n, capacity, d).transpose(1, 0, 2, 3) \
-            .reshape(n * el * capacity, d)
+        buffer back in exchange layout."""
+        with jax.named_scope(scopes.FFN):
+            return moe_experts(r_flat.reshape(n, -1, r_flat.shape[-1]),
+                               exp["w1"][i], exp["w2"][i]
+                               ).reshape(r_flat.shape)
 
-    def _combine(h, c_flat, gatek, expert, slot, capacity):
-        b, t, d = h.shape
-        comb = c_flat.reshape(E, capacity, d)
-        out = comb[expert, slot] * gatek.astype(comb.dtype)[:, None]
-        return h + out.reshape(b, t, d)
+    def _combine(post, h, c_flat, gatek, expert, slot):
+        """``post``: the layer's ``ln2_post`` scale under sandwich norms,
+        nothing otherwise."""
+        with jax.named_scope(scopes.FFN):
+            out = moe_combine(c_flat.reshape(E, -1, c_flat.shape[-1]),
+                              expert, slot, gatek)
+            return _residual(h, out.reshape(h.shape), post, "ln2_post", cfg)
 
     @jax.jit
     def seg_loss(shared, h, targets):
-        hf = _rmsnorm(h, shared["ln_f"])
-        logits = jnp.einsum("btd,vd->btv", hf, shared["embed"].astype(dt))
-        return _mean_xent(logits, targets)
+        return _mean_xent(_head(shared, _final_norm(shared, h, cfg), cfg),
+                          targets)
 
     seg_route = [jax.jit(functools.partial(_route_pack, i=i), static_argnums=(2,))
                  for i in range(L)]
-    seg_ffn = [jax.jit(functools.partial(_expert_ffn, i=i), static_argnums=(2,))
+    seg_ffn = [jax.jit(functools.partial(_expert_ffn, i=i))
                for i in range(L)]
-    seg_comb = jax.jit(_combine, static_argnums=(5,))
+    seg_comb = jax.jit(_combine)
 
     def _exchange(buf, name):
         """One engine alltoall round in its own replay-step bracket: the
@@ -1344,10 +1288,13 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
 
     def step(shared, expert, opt_state, tokens, targets):
         b, t = tokens.shape
-        capacity = max(int(_math.ceil(b * t * capf / E)), 1)
+        capacity = moe_capacity(b * t, capf, E)
 
         # -- forward: jitted segments chained through engine exchanges ----
         h, vjp0 = jax.vjp(lambda s: seg_embed(s, tokens), shared)
+        # the leaves of the layers the combine segment reads
+        post = ({"ln2_post": shared["layers"]["ln2_post"]}
+                if cfg.norm == "sandwich" else {})
         layer_bwd = []
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(L):
@@ -1362,13 +1309,13 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
                 m_skew.set(float(cs.max() / max(cs.mean(), 1e-9)),
                            layer=str(i))
             r_flat = _exchange(d_flat, f"moe.dispatch.l{i}")
-            e_flat, vjp_b = jax.vjp(
-                lambda ex, rr: seg_ffn[i](ex, rr, capacity), expert, r_flat)
+            e_flat, vjp_b = jax.vjp(seg_ffn[i], expert, r_flat)
             c_flat = _exchange(e_flat, f"moe.combine.l{i}")
             h, vjp_c = jax.vjp(
-                lambda hh, cc, gg: seg_comb(hh, cc, gg, eidx, slot,
-                                            capacity),
-                h_attn, c_flat, gatek)
+                lambda pp, hh, cc, gg: seg_comb(
+                    {k: v[i] for k, v in pp.items()}, hh, cc, gg, eidx,
+                    slot),
+                post, h_attn, c_flat, gatek)
             aux_total = aux_total + aux
             layer_bwd.append((vjp_a, vjp_b, vjp_c))
         loss, vjp_l = jax.vjp(lambda s, hh: seg_loss(s, hh, targets),
@@ -1383,7 +1330,9 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
         g_shared = _tree_add(g_shared, gs_l)
         for i in reversed(range(L)):
             vjp_a, vjp_b, vjp_c = layer_bwd[i]
-            g_hattn, g_c, g_gatek = vjp_c(g_h)
+            g_post, g_hattn, g_c, g_gatek = vjp_c(g_h)
+            for k, g in g_post.items():
+                g_shared["layers"][k] = g_shared["layers"][k] + g
             # the uniform block exchange is an involution: the vjp of
             # alltoall is the same alltoall on the cotangents
             g_e = _exchange(g_c, f"moe.combine.bwd.l{i}")
@@ -1416,6 +1365,10 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
                                                 params)
         return params["shared"], params["expert"], opt_state, loss
 
+    # the jitted programs a step chains, for whoever lowers or traces them
+    step.segments = {"embed": seg_embed, "route": seg_route,
+                     "expert_ffn": seg_ffn, "combine": seg_comb,
+                     "loss": seg_loss}
     return step
 
 

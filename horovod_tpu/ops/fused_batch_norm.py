@@ -1,11 +1,11 @@
 """Fused BatchNorm for TPU: Pallas one-pass statistics + custom_vjp backward.
 
-Why this exists: profiling the ResNet-50 train step on a v5e chip shows
-BatchNorm statistics reductions (XLA ``convert_reduce_fusion`` ops) take ~48%
-of the step — more than the convolutions (see docs/roofline.md). XLA lowers
-each stat pass at well under HBM bandwidth; the Pallas kernels in
-:mod:`horovod_tpu.ops.pallas_kernels` read the activation once in bf16 and
-accumulate in fp32 VMEM.
+Why this exists: the largest operations of the ResNet-50 train step on a
+v5e chip are BatchNorm statistics reductions (XLA ``convert_reduce_fusion``
+ops; docs/roofline.md section 1), and the step is bound by HBM there. The
+Pallas kernels in :mod:`horovod_tpu.ops.pallas_kernels` read the activation
+once in bf16 and accumulate in fp32 VMEM. Whether they beat XLA's own
+fusions on today's chip: not measured (default off; ROADMAP queue 3).
 
 Reference parity: the reference has SyncBatchNorm frontends
 (torch/sync_batch_norm.py:17-199, tensorflow/sync_batch_norm.py) whose math

@@ -140,9 +140,9 @@ def pack_pallas_supported(shapes, dtype) -> bool:
 
 def pack_pallas(tensors):
     """Pallas fusion packer: one kernel, one DMA-style copy per tensor into
-    the flat buffer (evaluated against the jitted-concat pack; see
-    bench_kernels.py — XLA's fused concat has been faster in practice, so
-    this stays opt-in via HOROVOD_PALLAS_PACK). Tensors are flattened
+    the flat buffer. Against XLA's fused concat pack on the chip: not
+    measured (no benchmark cell makes the engine pack), so this stays
+    opt-in via HOROVOD_PALLAS_PACK. Tensors are flattened
     before the kernel (free in XLA; Mosaic has no general in-kernel shape
     cast). Shapes must satisfy :func:`pack_pallas_supported`."""
     from jax.experimental import pallas as pl
@@ -170,9 +170,9 @@ def pack_pallas_enabled() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Fused BatchNorm statistics (the ResNet hot op: profiler-measured 48% of the
-# train step is BN stat reductions — see docs/roofline.md). One bf16 read of
-# the activation per pass, fp32 accumulation in VMEM.
+# Fused BatchNorm statistics (the ResNet hot op: the BN stat reductions are
+# the largest operations of the traced step — docs/roofline.md section 1).
+# One bf16 read of the activation per pass, fp32 accumulation in VMEM.
 # ---------------------------------------------------------------------------
 
 _BN_BLOCK_BYTES = 512 * 1024  # per-operand VMEM budget per grid step

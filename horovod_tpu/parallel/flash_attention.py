@@ -9,12 +9,14 @@ so CPU tests exercise the same call sites. On TPU the stock kernel modules
 are imported unguarded: a jax that moved them is an ImportError at the
 first trace, never a silent change of kernel.
 
-Measured motivation (bench.py transformer mode, v5e): materialized
-attention at T=2048 spends ~0.5 GB/layer on the score matrix and the MFU
-bench OOMs above 4 layers; flash attention removes the T² buffer and lifts
-the flagship LM step to >40% MFU. The reference has no attention kernels at
-all (it is model-agnostic); this is part of the beyond-parity compute layer
-the TPU build owns (SURVEY §7 maps the reference's SIMD C++ to Pallas).
+Motivation: materialized attention keeps a [B, H, T, T] score matrix a
+layer (0.5 GB at B4 H16 T2048 in bf16); the kernels never write it. What
+they take on the chip is ``attn_kernel_ms_per_step`` of the LM cells
+(``python3 benchmark/run.py --workload lm-spmd-1chip --trace 1``; PERF.md
+section 5); materialized attention at those shapes: not measured. The
+reference has no attention kernels at all (it is model-agnostic); this is
+part of the beyond-parity compute layer the TPU build owns (SURVEY §7 maps
+the reference's SIMD C++ to Pallas).
 """
 
 from __future__ import annotations
@@ -57,12 +59,13 @@ def flash_available() -> bool:
 
 
 def splash_available() -> bool:
-    """The newer splash-attention TPU kernel. Repeated paired measurements
-    at the flagship shape (B4 H16 T2048 D128 causal, v5e, kv-block 2048)
-    put its fwd+bwd ahead of the tuned flash kernel (isolated-layer ~6.3
-    vs ~11.5 ms); the whole-step difference is a few percent and inside
-    the shared-chip run-to-run noise — bench_kernels.py re-measures live.
-    """
+    """The newer splash-attention TPU kernel, the default wherever
+    :func:`_select_kernel` does not degrade to flash. On the v5e it is what
+    ``lm-spmd-1chip`` runs (B4 H16 T2048 D128 causal: ``attn_kernel_ms_per_step``
+    13.14 ms over 4 layers, 31.9% of roofline; ledger, PR 22) and flash is
+    what ``ouro-spmd-1chip-loop4`` runs under remat (ledger, PR 28). The two
+    kernels at ONE shape against each other: not measured (the one-chip
+    sweep of ROADMAP queue 1)."""
     # default-on choice knob ("force" additionally overrides the
     # automatic under-remat degrade — see _select_kernel)
     return _splash_mode() != "0" and jax.default_backend() == "tpu"

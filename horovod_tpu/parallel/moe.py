@@ -46,10 +46,91 @@ def init_moe(key, d_model: int, d_ff: int, n_experts: int,
         * math.sqrt(2.0 / d_ff))
 
 
+class MoERoute(NamedTuple):
+    """Where :func:`moe_dispatch` put every token; :func:`moe_combine`
+    takes the first three."""
+    expert: jax.Array   # [T] int: the expert a token is routed to
+    slot: jax.Array     # [T] int: its place in that expert's queue
+    weight: jax.Array   # [T] fp32: gate probability, 0 for a dropped token
+    counts: jax.Array   # [E] fp32: valid tokens routed to each expert,
+    #                     before the capacity cut
+
+
+def moe_capacity(n_tokens: int, capacity_factor: float,
+                 n_experts: int) -> int:
+    """Slots an expert keeps for one source's ``n_tokens``."""
+    return max(int(math.ceil(n_tokens * capacity_factor / n_experts)), 1)
+
+
+def moe_dispatch(x, router, capacity: int, valid_mask=None):
+    """Top-1 routing of the local tokens ``x [T, d]`` and their packing into
+    the dispatch buffer: ``(disp [E, C, d], aux_loss, MoERoute)``. Dim 0 of
+    ``disp`` in ``n`` equal blocks is the exchange layout: block ``i`` holds
+    the tokens for the experts of shard ``i``."""
+    t, d = x.shape
+    e_total = router.shape[1]
+    logits = (x @ router.astype(x.dtype)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
+    expert = jnp.argmax(probs, axis=-1)                  # [T]
+    gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
+
+    if valid_mask is None:
+        valid = jnp.ones((t,), jnp.float32)
+    else:
+        valid = valid_mask.astype(jnp.float32)
+    n_valid = jnp.maximum(jnp.sum(valid), 1.0)
+
+    # Switch aux loss: E · Σ_e (fraction of tokens on e)·(mean prob of e),
+    # over VALID tokens only (pad rows would otherwise skew both factors)
+    onehot = jax.nn.one_hot(expert, e_total, dtype=jnp.float32) * valid[:, None]
+    counts = jnp.sum(onehot, axis=0)
+    aux = e_total * jnp.sum(
+        (counts / n_valid) *
+        (jnp.sum(probs * valid[:, None], axis=0) / n_valid))
+
+    # capacity slotting: position of each token in its expert's queue
+    # (invalid tokens take no slot)
+    pos_in_expert = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot,
+                            axis=-1).astype(jnp.int32) - 1     # [T]
+    keep = jnp.logical_and(pos_in_expert < capacity,
+                           pos_in_expert >= 0)
+    slot = jnp.where(keep, pos_in_expert, capacity - 1)
+
+    # dispatch buffer [E, C, d]; dropped tokens masked to zero contributions
+    disp = jnp.zeros((e_total, capacity, d), x.dtype)
+    disp = disp.at[expert, slot].add(x * keep[:, None].astype(x.dtype))
+    return disp, aux, MoERoute(expert, slot, gate * keep, counts)
+
+
+def moe_experts(recv, w_in, w_out):
+    """The local experts' FFN over what the dispatch exchange delivered:
+    ``recv [n, E_local·C, d]``, block ``i`` from source ``i``. Returns the
+    same layout, block ``i`` going back to source ``i``."""
+    n, _, d = recv.shape
+    e_local = w_in.shape[0]
+    expert_in = recv.reshape(n, e_local, -1, d).transpose(1, 0, 2, 3) \
+        .reshape(e_local, -1, d)
+    # batched expert FFN on the MXU: [E_local, nC, d]·[E_local, d, f]
+    h = jax.nn.relu(jnp.einsum("ecd,edf->ecf", expert_in,
+                               w_in.astype(recv.dtype)))
+    y = jnp.einsum("ecf,efd->ecd", h, w_out.astype(recv.dtype))
+    return y.reshape(e_local, n, -1, d).transpose(1, 0, 2, 3) \
+        .reshape(recv.shape)
+
+
+def moe_combine(combined, expert, slot, weight):
+    """Every token's expert output from ``combined [E, C, d]`` (the combine
+    exchange's result) by its :class:`MoERoute`, scaled by its gate:
+    ``[T, d]``, zeros for dropped tokens."""
+    return combined[expert, slot] * weight.astype(combined.dtype)[:, None]
+
+
 def moe_layer_p(x, params: MoEParams, axis_name: str, axis_size: int,
                 capacity_factor: float = 1.25,
                 valid_mask=None) -> Tuple[jax.Array, jax.Array]:
-    """Top-1 MoE over ``axis_name`` (size may be 1 = no EP).
+    """Top-1 MoE over ``axis_name`` (size may be 1 = no EP):
+    :func:`moe_dispatch`, :func:`moe_experts` and :func:`moe_combine` with
+    a ``lax.all_to_all`` between them.
 
     Capacity and the aux loss are **per dispatch group** (this call's ``x``
     plus its axis peers) — the standard Switch/GShard semantics; global-batch
@@ -67,64 +148,15 @@ def moe_layer_p(x, params: MoEParams, axis_name: str, axis_size: int,
     tokens — add the residual outside), and the scalar load-balance loss.
     """
     n = axis_size
-    t, d = x.shape
-    e_local = params.w_in.shape[0]
-    e_total = e_local * n
-    capacity = max(int(math.ceil(t * capacity_factor / e_total)), 1)
+    e_total = params.w_in.shape[0] * n
+    capacity = moe_capacity(x.shape[0], capacity_factor, e_total)
 
-    logits = (x @ params.router.astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
-    expert = jnp.argmax(probs, axis=-1)                  # [T]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
+    def exchange(buf):      # [n, E_local·C, d]; slice i goes to shard i
+        return lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0,
+                              tiled=False) if n > 1 else buf
 
-    if valid_mask is None:
-        valid = jnp.ones((t,), jnp.float32)
-    else:
-        valid = valid_mask.astype(jnp.float32)
-    n_valid = jnp.maximum(jnp.sum(valid), 1.0)
-
-    # Switch aux loss: E · Σ_e (fraction of tokens on e)·(mean prob of e),
-    # over VALID tokens only (pad rows would otherwise skew both factors)
-    onehot = jax.nn.one_hot(expert, e_total, dtype=jnp.float32) * valid[:, None]
-    aux = e_total * jnp.sum(
-        (jnp.sum(onehot, axis=0) / n_valid) *
-        (jnp.sum(probs * valid[:, None], axis=0) / n_valid))
-
-    # capacity slotting: position of each token in its expert's queue
-    # (invalid tokens take no slot)
-    pos_in_expert = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot,
-                            axis=-1).astype(jnp.int32) - 1     # [T]
-    keep = jnp.logical_and(pos_in_expert < capacity,
-                           pos_in_expert >= 0)
-    slot = jnp.where(keep, pos_in_expert, capacity - 1)
-
-    # dispatch buffer [E, C, d]; dropped tokens masked to zero contributions
-    disp = jnp.zeros((e_total, capacity, d), x.dtype)
-    disp = disp.at[expert, slot].add(x * keep[:, None].astype(x.dtype))
-
-    if n > 1:
-        # [E, C, d] -> [n, E_local·C, d]; slice i goes to expert shard i
-        send = disp.reshape(n, e_local * capacity, d)
-        recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
-                              tiled=False)                # [n, E_local·C, d]
-        expert_in = recv.reshape(n, e_local, capacity, d) \
-            .transpose(1, 0, 2, 3).reshape(e_local, n * capacity, d)
-    else:
-        expert_in = disp  # [E_local(=E), C, d]
-
-    # batched expert FFN on the MXU: [E_local, nC, d]·[E_local, d, f]
-    h = jax.nn.relu(jnp.einsum("ecd,edf->ecf", expert_in,
-                               params.w_in.astype(x.dtype)))
-    y = jnp.einsum("ecf,efd->ecd", h, params.w_out.astype(x.dtype))
-
-    if n > 1:
-        back = y.reshape(e_local, n, capacity, d).transpose(1, 0, 2, 3) \
-            .reshape(n, e_local * capacity, d)
-        combined = lax.all_to_all(back, axis_name, split_axis=0,
-                                  concat_axis=0, tiled=False) \
-            .reshape(e_total, capacity, d)
-    else:
-        combined = y
-
-    out = combined[expert, slot] * (gate * keep).astype(x.dtype)[:, None]
-    return out, aux
+    disp, aux, route = moe_dispatch(x, params.router, capacity, valid_mask)
+    y = moe_experts(exchange(disp.reshape(n, -1, x.shape[1])),
+                    params.w_in, params.w_out)
+    return moe_combine(exchange(y).reshape(disp.shape), route.expert,
+                       route.slot, route.weight), aux

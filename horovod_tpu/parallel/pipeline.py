@@ -1194,7 +1194,9 @@ def pipeline_train_step(stage_fn: Callable, stage_params, micro_inputs,
 
     ``boundary_codec``: optional ``(codec, coded_edges)`` applying the
     PR 13 wire codecs to stage-boundary hops that cross DCN (see
-    :func:`horovod_tpu.parallel.mesh.pipeline_boundary_edges`).
+    :func:`horovod_tpu.parallel.mesh.pipeline_boundary_edges`). The table
+    executor alone applies it: with a coded edge and a schedule that
+    resolves to ``1f1b`` the call raises instead of dropping it.
     ``topology``: optional MeasuredTopology pricing the ``auto`` mode.
 
     Returns ``(loss, stage_grads, first_grads, last_grads)`` with the
@@ -1205,6 +1207,13 @@ def pipeline_train_step(stage_fn: Callable, stage_params, micro_inputs,
     schedule, v = resolve_pipeline_schedule(schedule, n_stages, n_micro,
                                             n_virtual, topology)
     if schedule == "1f1b":
+        if (boundary_codec and boundary_codec[0] != "none"
+                and any(boundary_codec[1])):
+            raise ValueError(
+                f"boundary_codec {boundary_codec[0]!r} on a coded stage "
+                f"boundary, but the schedule resolved to '1f1b', whose "
+                f"executor moves every boundary uncoded; the schedules that "
+                f"apply it are 'interleaved' and 'zb'")
         if v == 1:
             return pipeline_train_1f1b(
                 stage_fn, stage_params, micro_inputs, micro_targets,

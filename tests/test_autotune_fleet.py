@@ -851,7 +851,7 @@ class TestGapAttribution:
 
 
 # ---------------------------------------------------------------------------
-# bench provenance (ISSUE 14 satellite)
+# knob provenance (ISSUE 14 satellite)
 # ---------------------------------------------------------------------------
 
 class TestKnobProvenance:
@@ -861,18 +861,6 @@ class TestKnobProvenance:
         cfg = Config.from_env()
         assert cfg.provenance["tree_threshold_bytes"] == "env-forced"
         assert cfg.provenance["fusion_threshold_bytes"] == "default"
-
-    def test_bench_report_shape(self):
-        sys.path.insert(0, os.path.dirname(TOOLS))
-        try:
-            import bench
-            rep = bench.knob_provenance_report()
-        finally:
-            sys.path.remove(os.path.dirname(TOOLS))
-        prov = rep["knob_provenance"]
-        assert "tree_threshold_bytes" in prov
-        assert set(prov["tree_threshold_bytes"]) == {"value", "source"}
-        assert "link_table" in rep or "autotune_state" in rep or True
 
     def test_calibration_sets_provenance(self, tmp_path):
         """engine._apply_calibration flips tree_threshold provenance to

@@ -806,31 +806,77 @@ def _tiny_lm():
             jnp.zeros((2, 16), jnp.int32))
 
 
-@pytest.fixture(scope="module")
-def train_step_hlo():
+@functools.lru_cache(maxsize=None)
+def _lm_step_hlo(builder):
+    """The compiled step of the tiny LM through ``make_train_step`` (a mesh
+    of one) or ``make_pp_train_step`` (two stages): both run the one block."""
     import optax
-    from horovod_tpu.models.transformer import make_train_step
+    from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel.mesh import training_mesh
     cfg, params, tok = _tiny_lm()
-    mesh = training_mesh({"data": 1, "seq": 1, "tensor": 1},
-                         jax.devices()[:1])
-    opt = optax.adamw(1e-3)
-    return _hlo(make_train_step(mesh, cfg, opt), params, opt.init(params),
-                tok, tok)
+    if builder == "make_train_step":
+        opt = optax.adamw(1e-3)
+        step = tfm.make_train_step(training_mesh(
+            {"data": 1, "seq": 1, "tensor": 1}, jax.devices()[:1]), cfg, opt)
+    else:
+        opt = optax.sgd(0.1)
+        step = tfm.make_pp_train_step(
+            Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,)), cfg, opt,
+            n_micro=2)
+    return _hlo(step, params, opt.init(params), tok, tok)
+
+
+@pytest.fixture(scope="module")
+def train_step_hlo():
+    return _lm_step_hlo("make_train_step")
 
 
 @pytest.mark.parametrize("scope", _LM_SCOPES)
 @pytest.mark.parametrize("phase", ["forward", "backward"])
-def test_train_step_scope_in_each_pass(train_step_hlo, scope, phase):
+@pytest.mark.parametrize("builder", ["make_train_step", "make_pp_train_step"])
+def test_train_step_scope_in_each_pass(builder, scope, phase):
     """Every scope of the LM step names operations of the forward pass
     (``jvp(`` and no ``transpose(``: jax writes both) and of the backward
-    pass (``transpose(jvp(``)."""
-    found = [n for n in _op_names(train_step_hlo) if _under(n, scope)]
+    pass (``transpose(jvp(``), in the pipeline's step (its own vjp inside
+    the schedule's scan) as in the SPMD step."""
+    found = [n for n in _op_names(_lm_step_hlo(builder)) if _under(n, scope)]
     if phase == "forward":
         found = [n for n in found if "jvp(" in n and "transpose(" not in n]
     else:
         found = [n for n in found if "transpose(jvp(" in n]
     assert found, f"no {phase} operation under scope {scope!r}"
+
+
+@pytest.mark.parametrize("segment,scope", [
+    ("embed", scopes.EMBED), ("route", scopes.ATTN), ("route", scopes.FFN),
+    ("expert_ffn", scopes.FFN), ("combine", scopes.FFN),
+    ("loss", scopes.HEAD), ("loss", scopes.LOSS)])
+def test_moe_ep_segments_carry_the_blocks_names(segment, scope):
+    """``make_moe_ep_train_step`` chains jitted segments of the same block
+    through the engine's exchanges: each program names what it holds of
+    it."""
+    import optax
+    import horovod_tpu as hvd
+    from horovod_tpu.models import transformer as tfm
+    hvd.init()
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=1, d_ff=64, max_seq=16,
+                                attention="flash", use_moe=True, n_experts=4)
+    shared, expert = tfm.moe_ep_partition(
+        tfm.init_params(jax.random.PRNGKey(0), cfg), 0, 1, cfg)
+    seg = tfm.make_moe_ep_train_step(hvd._engine(), cfg,
+                                     optax.sgd(0.1)).segments
+    tok = jnp.zeros((2, 16), jnp.int32)
+    h = jnp.zeros((2, 16, 32), cfg.dtype)
+    buf = jnp.zeros((4 * 16, 32), cfg.dtype)        # [E * capacity, D]
+    route = jnp.zeros((32,), jnp.int32)
+    hlo = {"embed": lambda: _hlo(seg["embed"], shared, tok),
+           "route": lambda: _hlo(seg["route"][0], shared, h, 16),
+           "expert_ffn": lambda: _hlo(seg["expert_ffn"][0], expert, buf),
+           "combine": lambda: _hlo(seg["combine"], {}, h, buf,
+                                   jnp.ones((32,)), route, route),
+           "loss": lambda: _hlo(seg["loss"], shared, h, tok)}[segment]()
+    assert [n for n in _op_names(hlo) if _under(n, scope)]
 
 
 def test_train_step_optimizer_scope_outside_both_passes(train_step_hlo):
@@ -853,16 +899,12 @@ def test_lean_lm_loss_keeps_head_and_loss_scopes(scope):
 
 @functools.lru_cache(maxsize=None)
 def _xent_caller_hlo(builder):
-    import optax
+    if builder != "lean_lm_loss":
+        return _lm_step_hlo(builder)
     from horovod_tpu.models import transformer as tfm
     cfg, params, tok = _tiny_lm()
-    if builder == "lean_lm_loss":
-        return _hlo(jax.jit(jax.grad(
-            lambda p: tfm.lean_lm_loss(p, tok, tok, cfg))), params)
-    mesh = Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,))
-    opt = optax.sgd(0.1)
-    return _hlo(tfm.make_pp_train_step(mesh, cfg, opt, n_micro=2),
-                params, opt.init(params), tok, tok)
+    return _hlo(jax.jit(jax.grad(
+        lambda p: tfm.lean_lm_loss(p, tok, tok, cfg))), params)
 
 
 @pytest.mark.parametrize("builder", ["lean_lm_loss", "make_pp_train_step"])

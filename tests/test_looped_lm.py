@@ -294,21 +294,85 @@ def test_moe_refuses_the_gated_ffn_by_name():
 
 NEW_FIELDS = {"positions": "rope", "ffn": "swiglu", "norm": "sandwich",
               "norm_eps": 1e-5, "tie_embeddings": False, "n_loops": 4}
+STILL_REFUSED = ("tie_embeddings", "n_loops")
+BAND = dict(rtol=1e-4, atol=1e-5)   # test_pp_flagship_matches_single_device's
+
+
+def _pp_step(builder, cfg, params, x, y):
+    """(loss, gradients) of one pipeline step over two stages: the builder's
+    own sgd(1.0) update read back as a difference."""
+    mesh = Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,))
+    placed = jax.tree_util.tree_map(
+        lambda a, s: jax.device_put(np.asarray(a), NamedSharding(mesh, s)),
+        params, tfm.pp_param_specs(cfg))
+    if builder == "make_pp_train_step":
+        opt = optax.sgd(1.0)
+        step = tfm.make_pp_train_step(mesh, cfg, opt, n_micro=2)
+    else:
+        import horovod_tpu as hvd
+        from horovod_tpu.optimizer import DistributedEagerOptimizer
+        hvd.init()
+        hvd._engine().replay.invalidate_all("test isolation")
+        opt = DistributedEagerOptimizer(optax.sgd(1.0), op=hvd.Sum)
+        step = tfm.make_pp_engine_train_step(mesh, cfg, opt, n_micro=2,
+                                             schedule="1f1b")
+    after, _, loss = step(placed, opt.init(placed), x, y)
+    return loss, jax.tree_util.tree_map(
+        lambda a, b: np.asarray(a) - np.asarray(b), params, after)
+
+
+def _moe_ep_step(cfg, params, x, y):
+    """(loss, gradients) of one MoE-EP step in the engine's world of one,
+    in the layout of ``params``."""
+    import horovod_tpu as hvd
+    hvd.init()
+    eng = hvd._engine()
+    eng.replay.invalidate_all("test isolation")
+    shared, expert = tfm.moe_ep_partition(params, 0, 1, cfg)
+    opt = optax.sgd(1.0)
+    step = tfm.make_moe_ep_train_step(eng, cfg, opt)
+    shared2, expert2, _, loss = step(
+        shared, expert, opt.init({"shared": shared, "expert": expert}), x, y)
+    after = {**shared2, "layers": {**shared2["layers"], **expert2}}
+    return loss, jax.tree_util.tree_map(
+        lambda a, b: np.asarray(a) - np.asarray(b), params, after)
 
 
 @pytest.mark.parametrize("field", list(NEW_FIELDS))
 @pytest.mark.parametrize("builder", ["make_pp_train_step",
                                      "make_pp_engine_train_step",
                                      "make_moe_ep_train_step"])
-def test_a_builder_with_its_own_block_refuses_the_new_fields(builder, field):
-    """The pipeline and MoE-EP builders restate the block: they refuse by
-    name what they would otherwise silently not run."""
+def test_every_builder_runs_the_one_block(builder, field):
+    """The pipeline and MoE-EP builders run the block ``make_train_step``
+    runs: with one of its fields set they give the single-device loss and
+    gradients of that configuration. What their first and last stage (and
+    MoE-EP's loss segment) cannot run, more than one pass and an untied
+    head, they refuse by name."""
+    moe = builder == "make_moe_ep_train_step"
+    if moe and field == "ffn":
+        # the configuration's own refusal: use_moe replaces the dense FFN
+        with pytest.raises(ValueError, match="ffn"):
+            dataclasses.replace(PLAIN, use_moe=True, ffn="swiglu")
+        return
     cfg = dataclasses.replace(PLAIN, **{field: NEW_FIELDS[field]})
-    args = {"make_moe_ep_train_step": (None, cfg, optax.sgd(0.1))}.get(
-        builder, (Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,)), cfg,
-                  optax.sgd(0.1), 2))
-    with pytest.raises(ValueError, match=f"{builder}.*{field}="):
-        getattr(tfm, builder)(*args)
+    if moe:
+        cfg = dataclasses.replace(cfg, use_moe=True, n_experts=4)
+    if field in STILL_REFUSED:
+        args = (None, cfg, optax.sgd(0.1)) if moe else (
+            Mesh(np.array(jax.devices()[:2]), (tfm.PIPE_AXIS,)), cfg,
+            optax.sgd(0.1), 2)
+        with pytest.raises(ValueError, match=f"{builder}.*{field}="):
+            getattr(tfm, builder)(*args)
+        return
+    params, (x, y) = _params(cfg), _tokens()
+    want_loss, want = jax.value_and_grad(
+        lambda p: tfm.lean_lm_loss(p, x, y, cfg))(params)
+    loss, got = (_moe_ep_step(cfg, params, x, y) if moe
+                 else _pp_step(builder, cfg, params, x, y))
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    assert set(got) == set(want) and set(got["layers"]) == set(want["layers"])
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, **BAND), got, want)
 
 
 # -- the names inside the program ------------------------------------------

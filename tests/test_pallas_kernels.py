@@ -1,6 +1,6 @@
 """Pallas kernel correctness vs the lax reference implementations (interpret
-mode on the CPU world; the same code compiles via Mosaic on real TPU — see
-bench_kernels.py for the measured numbers that set the defaults)."""
+mode on the CPU world; the same code compiles via Mosaic on real TPU, where
+``chip_smoke.py``'s kernels phase holds each against its lax twin)."""
 
 import numpy as np
 import pytest

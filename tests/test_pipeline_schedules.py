@@ -52,7 +52,7 @@ def _loss(y, tgt):
     return jnp.mean((y - tgt) ** 2)
 
 
-def _run(schedule, n_virtual, n_micro, steps=2, seed=0):
+def _run(schedule, n_virtual, n_micro, steps=2, seed=0, boundary_codec=None):
     """Run `steps` SGD steps of the 8-cell pipeline under `schedule`;
     return (losses, final params in MODEL order) for bitwise comparison."""
     mesh = Mesh(np.array(jax.devices()[:S]), ("pipe",))
@@ -75,7 +75,7 @@ def _run(schedule, n_virtual, n_micro, steps=2, seed=0):
                 lambda a: a.reshape((v, lpc) + a.shape[1:]), params)
         loss, gs, _, _ = pipeline_train_step(
             _stage_fn, sp, micro_in, micro_tgt, _loss, "pipe", S,
-            schedule=sched, n_virtual=v)
+            schedule=sched, n_virtual=v, boundary_codec=boundary_codec)
         if v > 1:
             gs = jax.tree_util.tree_map(
                 lambda a: a.reshape((v * lpc,) + a.shape[2:]), gs)
@@ -130,6 +130,28 @@ def test_schedule_parity_non_divisible_micro(schedule, n_virtual):
     l, p = _run(schedule, n_virtual, n_micro=5)
     assert l == base_l
     _assert_bitwise(p, base_p)
+
+
+@pytest.mark.parametrize("schedule", ["1f1b", "zb"])
+def test_a_coded_stage_boundary_is_applied_or_refused(schedule):
+    """A wire codec on a stage boundary (stage 1 -> 2 crossing DCN): the
+    table executor applies it, the 1F1B executor cannot, and
+    ``pipeline_train_step`` says so instead of moving the edge uncoded."""
+    coded = ("int8", (False, True, False, False))
+    if schedule == "1f1b":
+        with pytest.raises(ValueError, match="'interleaved' and 'zb'"):
+            _run("1f1b", 1, n_micro=8, steps=1, boundary_codec=coded)
+        # no edge coded, or no codec: nothing is asked for, nothing refused
+        _run("1f1b", 1, n_micro=8, steps=1,
+             boundary_codec=("int8", (False,) * S))
+        _run("1f1b", 1, n_micro=8, steps=1,
+             boundary_codec=("none", coded[1]))
+        return
+    (plain,), _ = _run(schedule, 1, n_micro=8, steps=1)
+    (quantized,), _ = _run(schedule, 1, n_micro=8, steps=1,
+                           boundary_codec=coded)
+    assert quantized != plain
+    assert abs(quantized - plain) < 0.02 * plain
 
 
 def test_m_less_than_stages_demotes_once_with_warning():

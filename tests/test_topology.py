@@ -5,8 +5,7 @@ descriptor (detection, the HOROVOD_TPU_LOCAL_SIZE override,
 non-divisible fallback), the pure selection rules
 (``ops.collectives.choose_algorithm`` / ``validate_algorithm``), the
 per-link wire attribution (``link_split`` + the engine's link-labeled
-accounting), the trace/report link breakdown, and the bench sweep's
-perf smoke. Compiled-program structure per selected algorithm lives in
+accounting) and the trace/report link breakdown. Compiled-program structure per selected algorithm lives in
 tests/test_compiled_structure.py; real np=2 forced-algorithm parity in
 tests/test_multiprocess.py.
 """
@@ -302,36 +301,6 @@ class TestTraceLinkBreakdown:
         assert rep["wire_by_link"]["GROUPED_ALLREDUCE"]["dcn"] == 500
         assert rep["skew_by_kind"]["GROUPED_ALLREDUCE"][
             "wire_bytes_by_link"] == {"ici": 1500, "dcn": 500}
-
-
-# ---------------------------------------------------------------------------
-# bench sweep smoke (tier-1-safe, perf marker)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.perf
-def test_perf_smoke_busbw_sweep_one_band():
-    """Build + run the bus-bandwidth sweep for one small band on the CPU
-    world — no timing assertions, just that the sweep emits the
-    busbw/roofline/selected-algorithm fields the acceptance names."""
-    from bench import bench_busbw
-    r = bench_busbw(sizes_bytes=[64 * 1024], iters=1)
-    assert "busbw_allreduce_64KB" in r and r["busbw_allreduce_64KB"] > 0
-    assert r["busbw_roofline_allreduce_64KB"] > 0
-    assert r["collective_algo_selected"]["allreduce_64KB"] in C.ALGORITHMS
-    assert r["collective_algo_selected"]["allgather_64KB"] in C.ALGORITHMS
-    assert r["busbw_topology"]["size"] == 8
-
-
-@pytest.mark.perf
-def test_perf_smoke_alltoall_busbw_one_band():
-    """ISSUE 17: the sweep's alltoall kind — (n-1)/n busbw convention,
-    measured-vs-roofline pair, and the per-band selected algorithm
-    resolved through the alltoall-specific knobs."""
-    from bench import bench_busbw
-    r = bench_busbw(sizes_bytes=[64 * 1024], iters=1)
-    assert "busbw_alltoall_64KB" in r and r["busbw_alltoall_64KB"] > 0
-    assert r["busbw_roofline_alltoall_64KB"] > 0
-    assert r["collective_algo_selected"]["alltoall_64KB"] in C.ALGORITHMS
 
 
 # ---------------------------------------------------------------------------

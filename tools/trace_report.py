@@ -323,9 +323,10 @@ def overlap_report(events: List[dict]) -> dict:
       blocks the step for 1 ms was 90% hidden. None when the trace has no
       closed collective spans.
 
-    Driven by ``bench.py`` over the PR 5 trace ring (overlap on vs off,
-    same world, same model) so overlap wins land in the BENCH_r*
-    trajectory and regressions are visible."""
+    Fed a merged PR 5 trace-ring segment of the same world and model with
+    overlap on and with it off, the two ratios are what a change of the
+    overlap schedule is judged by. On the chip: not measured (no benchmark
+    cell makes the engine reduce; ROADMAP queue 3)."""
     wg = wire_vs_gap(events)
     total_us = sum(r["total_us"] for r in wg.values())
     wire_us = sum(r["wire_us"] for r in wg.values())
