@@ -574,7 +574,7 @@ def child_kernels(args) -> None:
     from jax.sharding import Mesh, PartitionSpec as P
     from horovod_tpu.ops import pallas_kernels as pk
     from horovod_tpu.ops.adasum import adasum_combine
-    from horovod_tpu.parallel.flash_attention import (_select_kernel,
+    from horovod_tpu.parallel.flash_attention import (attention_kernel,
                                                       flash_attention_local)
     from horovod_tpu.parallel.ring_attention import (local_attention,
                                                      ring_attention_p)
@@ -681,6 +681,8 @@ def child_kernels(args) -> None:
                 return flash_attention_local(q, k, v, causal=True,
                                              layout="bhtk",
                                              under_remat=under_remat)
+            if under_remat:     # the backward runs the forward again
+                fn = jax.checkpoint(fn)
 
         def loss(f):
             return lambda q, k, v: jnp.sum(
@@ -696,14 +698,22 @@ def child_kernels(args) -> None:
         return max(errs)
 
     t = 128 if args.rehearse else 2048
-    if not args.rehearse and (_select_kernel(t, d, False) != "splash"
-                              or _select_kernel(t, d, True) != "flash"):
-        raise AssertionError("kernel selection no longer degrades splash "
-                             "to flash under remat at the flagship shape")
+    if not args.rehearse:
+        for under_remat in (False, True):
+            took = attention_kernel((1, 2, t, d), (1, 2, t, d), True,
+                                    under_remat)
+            if (took["kernel"], took["fused_bwd"]) != ("splash", "1"):
+                raise AssertionError(
+                    "the flagship shape no longer takes splash with the "
+                    f"fused backward (under_remat={under_remat}): {took}")
     check(f"splash attention fwd+bwd T{t}",
           lambda: attention(t, False, False))
-    check(f"flash attention (under remat) fwd+bwd T{t}",
+    check(f"splash attention under remat fwd+bwd T{t}",
           lambda: attention(t, True, False))
+    # a length splash does not take (not a multiple of 1024): the stock
+    # flash kernel at its own 128 blocks
+    check(f"flash attention fwd+bwd T{3 * t // 4}",
+          lambda: attention(3 * t // 4, False, False))
     check(f"ring segment kernels fwd+bwd T{t // 2}",
           lambda: attention(t // 2, False, True))
     if failed:

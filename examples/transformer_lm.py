@@ -21,7 +21,9 @@ above 1 by name): ``--positions rope``, ``--ffn swiglu``,
 decoder of the Ouro kind (benchmark/configs/ouro-2.6b.json), whose mean
 exit share of every pass goes to the gauge ``hvd_tpu_lm_exit_share``. In
 spmd mode the share of the gradient bytes whose all-reduce the step issues
-inside its backward scan goes to ``hvd_tpu_lm_grad_reduce_in_backward_share``.
+inside its backward scan goes to ``hvd_tpu_lm_grad_reduce_in_backward_share``,
+and with ``--attention flash`` which kernel the local attention call takes,
+at which blocks, to ``hvd_tpu_attn_kernel``.
 
 Synthetic data; prints tokens/sec. Mirrors the reference's synthetic
 benchmark scripts (examples/*_synthetic_benchmark.py) for the LM workload.
@@ -158,6 +160,17 @@ def main():
         registry().gauge("hvd_tpu_lm_grad_reduce_in_backward_share").set(
             in_backward,
             mesh=",".join(f"{a}={n}" for a, n in mesh.shape.items()))
+        attn = None
+        if cfg.attention == "flash" and mesh.shape["seq"] == 1:
+            # what the step's local attention call runs on this backend, and
+            # at which blocks: a property of the shape a chip holds
+            from horovod_tpu.parallel.flash_attention import attention_kernel
+            local = (args.batch // mesh.shape["data"],
+                     cfg.n_heads // mesh.shape["tensor"], args.seq,
+                     cfg.d_model // cfg.n_heads)
+            attn = attention_kernel(local, local, causal=True,
+                                    under_remat=cfg.remat != "none")
+            registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
         opt_state = opt.init(params)
         tok_sh = NamedSharding(mesh, P("data", "seq"))
         if args.sp_layout == "zigzag":
@@ -212,6 +225,8 @@ def main():
               "tokens_per_sec": round(toks / dt, 1)}
     if args.mode == "spmd":
         report["grad_reduce_in_backward_share"] = round(in_backward, 4)
+        if attn:
+            report["attn_kernel"] = attn
     if cfg.n_loops > 1:
         # logged with the loss: whether the exit gate has collapsed
         from horovod_tpu.metrics import registry

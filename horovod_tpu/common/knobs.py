@@ -510,18 +510,12 @@ KNOB_SPECS: Dict[str, dict] = {
         "type": "choice", "default": "1",
         "choices": ("0", "1", "force", "true", "false", "yes", "no",
                     "on", "off"),
-        "help": "Splash-attention kernel for local attention: 0 off, 1 "
-                "auto (falls back off-TPU), force (raise when "
-                "unavailable); boolean aliases accepted in both "
-                "directions, unknown tokens warn and take the "
-                "default."},
-    "HOROVOD_SPLASH_VMEM_LIMIT": {
-        "type": "int", "default": str(16 * 1024 * 1024),
-        "help": "Scoped VMEM budget (bytes) the splash kernel compiles "
-                "against."},
-    "HOROVOD_SPLASH_BLOCK_KV": {
-        "type": "int", "default": "2048",
-        "help": "Preferred KV block size for the splash kernel."},
+        "help": "Splash-attention kernel for local attention: 0 off "
+                "(the stock flash kernel instead), 1 on wherever the "
+                "shape allows (on a TPU); force reads as 1 since the "
+                "under-remat degrade it overrode is gone; boolean "
+                "aliases accepted in both directions, unknown tokens "
+                "warn and take the default."},
     "HOROVOD_RING_PALLAS": {
         "type": "bool", "default": "1",
         "help": "Pallas blockwise kernel inside ring attention; =0 "

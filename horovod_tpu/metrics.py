@@ -206,6 +206,16 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "step; 0 on a mesh with nothing to sum over and for a "
                  "looped model, whose shared layers are summed once after "
                  "the pass loop)"),
+    # parallel/flash_attention.py attention_kernel (ISSUE 31)
+    "hvd_tpu_attn_kernel": (
+        "gauge", "1 on the one label set that says what the model's local "
+                 "attention call runs on this backend: kernel (splash, "
+                 "flash: the two stock Pallas kernels; materialized), the "
+                 "forward's block_q and block_kv, and fused_bwd (1: dq "
+                 "comes out of the dkv kernel). A function of the call's "
+                 "shape, causal and under_remat alone; "
+                 "examples/transformer_lm.py sets it when it has built its "
+                 "step"),
     # stall_inspector.py
     "hvd_tpu_stall_publish_failures_total": (
         "counter", "Stall-inspector KV liveness publishes that failed"),

@@ -2,12 +2,15 @@
 
 The sequence-parallel kernels (ring/Ulysses, parallel/{ring_attention,
 ulysses}.py) own the *distributed* attention surface; this module is the
-single-shard compute kernel: on TPU it calls the Pallas flash-attention
-kernel shipped with JAX (blockwise online-softmax — O(T) memory, causal
-blocks skipped); off TPU it computes the materialized reference attention
-so CPU tests exercise the same call sites. On TPU the stock kernel modules
-are imported unguarded: a jax that moved them is an ImportError at the
-first trace, never a silent change of kernel.
+single-shard compute kernel: on TPU it calls the Pallas attention kernels
+shipped with JAX (blockwise online-softmax: O(T) memory, wholly masked
+blocks skipped): splash at the blocks :func:`splash_geometry` chose for
+square attention over a multiple of 1024 positions with heads a multiple of
+128, the older flash kernel for the other aligned shapes; off TPU it computes
+the materialized reference attention so CPU tests exercise the same call
+sites. :func:`attention_kernel` says which of them a shape reaches. On TPU
+the stock kernel modules are imported unguarded: a jax that moved them is an
+ImportError at the first trace, never a silent change of kernel.
 
 Motivation: materialized attention keeps a [B, H, T, T] score matrix a
 layer (0.5 GB at B4 H16 T2048 in bf16); the kernels never write it. What
@@ -24,7 +27,7 @@ from __future__ import annotations
 import functools
 import logging
 import math
-import os
+from typing import NamedTuple, Optional
 
 import jax
 
@@ -59,95 +62,109 @@ def flash_available() -> bool:
 
 
 def splash_available() -> bool:
-    """The newer splash-attention TPU kernel, the default wherever
-    :func:`_select_kernel` does not degrade to flash. On the v5e it is what
-    ``lm-spmd-1chip`` runs (B4 H16 T2048 D128 causal: ``attn_kernel_ms_per_step``
-    13.14 ms over 4 layers, 31.9% of roofline; ledger, PR 22) and flash is
-    what ``ouro-spmd-1chip-loop4`` runs under remat (ledger, PR 28). The two
-    kernels at ONE shape against each other: not measured (the one-chip
-    sweep of ROADMAP queue 1)."""
-    # default-on choice knob ("force" additionally overrides the
-    # automatic under-remat degrade — see _select_kernel)
-    return _splash_mode() != "0" and jax.default_backend() == "tpu"
+    """The stock splash-attention kernel: on a TPU unless ``HOROVOD_SPLASH``
+    is off. Since PR 31 it takes every shape :func:`_splash_ok` admits,
+    under recomputation too (``force`` has nothing left to override and
+    reads as on)."""
+    return _splash_mode() != "0" and flash_available()
 
 
-def _scoped_vmem_bytes() -> int:
-    """v5e scoped VMEM budget the splash kernel compiles against;
-    overridable per chip generation (read per call, like the sibling
-    HOROVOD_SPLASH* knobs)."""
-    return int(os.environ.get("HOROVOD_SPLASH_VMEM_LIMIT",
-                              str(16 * 1024 * 1024)))
+class SplashBlocks(NamedTuple):
+    """The geometry the stock splash kernel is built with: the forward's and
+    the backward's blocks as its ``BlockSizes`` names them. The backward is
+    always the ONE fused kernel (``use_fused_bwd_kernel``): dq comes out of
+    the dkv kernel, a partial per kv block summed outside, so there are no
+    dq blocks."""
+    block_q: int
+    block_kv: int
+    block_kv_compute: int
+    block_q_dkv: int
+    block_kv_dkv: int
+    block_kv_dkv_compute: int
 
 
-def _splash_bkv(t: int) -> int:
-    """The kv block size the splash kernel will actually be built with
-    (single source of truth for _build_splash_kernel and the VMEM
-    estimator): 2048 is the measured winner but must divide t; odd
-    multiples of 1024 take the 1024 block. HOROVOD_SPLASH_BLOCK_KV
-    overrides (e.g. to fit under remat recompute)."""
-    bkv_pref = int(os.environ.get("HOROVOD_SPLASH_BLOCK_KV", "2048"))
-    return bkv_pref if t % bkv_pref == 0 else 1024
+def splash_geometry(t: int, d: int, causal: bool,
+                    under_remat: bool) -> SplashBlocks:
+    """The blocks for square attention over ``t`` positions (a multiple of
+    1024: :func:`_splash_ok`) with heads of ``d``. Chosen on the v5e by
+    ``tools/attn_sweep.py`` (PERF.md section 6, PR 31: every block in 512,
+    1024, 2048 at 4 x 16 x 2048 x 128 and at 1 x 16 x 4096 x 128 under
+    ``jax.checkpoint``, ms a call forward / forward + backward):
+
+    - the kernel skips a (block_q, block_kv) block of the mask that is
+      wholly masked and runs every ``block_kv_compute`` slice of one that is
+      not, so a causal call wants a kv block below ``t``: 1024, in compute
+      slices of 512 (0.93 / 0.70 ms against 1.24 / 0.89 for one kv block of
+      2048; q 512 or kv 512 skip more and lose it again to the extra grid
+      steps: 0.96-1.05 / 0.71-0.78);
+    - the backward is one fused kernel at 1024 blocks (5 matmuls for the
+      7 of dkv and dq apart): 2.65 / 2.01 ms forward + backward against
+      3.26 / 2.48 for the best split backward and 3.57 / 2.65 for the
+      blocks before PR 31. It writes dq a kv block at a time in q's dtype
+      and sums the partials outside; against float32 its dq reads 3.79e-3 /
+      3.83e-3 (relative L2, worst of 8 seeds) where the split backward
+      reads 3.72e-3 / 3.73e-3, dk and dv the same;
+    - 2048 anywhere in the backward, or q 2048 with kv 2048 forward, does
+      not fit the 16 MiB of scoped VMEM; what is chosen here compiles under
+      recomputation too (``tests/test_tpu_compile.py`` holds that), so
+      ``under_remat`` changes nothing on this backend: both shapes chose
+      the same blocks;
+    - heads of 256 (in no model of this repo; fit by the same compile test,
+      times not measured): the fused backward at q 1024 needs 17.4 MB and a
+      forward kv block of 2048 does not fit either, so the backward's q
+      block is 512 and every kv block 1024.
+
+    Not causal (in no cell: ViT's lengths never reach the kernel; 4 x 16 x
+    2048 x 128 in the same sweep): no block is masked, a smaller kv block
+    skips nothing, so the kv block is 2048 where it divides ``t``, in
+    compute slices of 1024, and the backward is fused there too: 1.06 ms
+    forward, 3.12 forward + backward, against 1.14 and 4.03 for the blocks
+    before PR 31 and 1.18 and 3.32 for the causal blocks."""
+    del under_remat     # one answer on this backend: see above
+    wide = d > 128
+    kv = 1024 if causal or wide or t % 2048 else 2048
+    return SplashBlocks(block_q=1024, block_kv=kv,
+                        block_kv_compute=512 if causal else 1024,
+                        block_q_dkv=512 if wide else 1024, block_kv_dkv=kv,
+                        block_kv_dkv_compute=1024)
 
 
-def _splash_remat_vmem_bytes(t: int, d: int, bkv: int,
-                             itemsize: int = 2) -> int:
-    """Engineering estimate of splash's peak scoped-VMEM residency when a
-    remat'd block RECOMPUTES the residual-saving forward inside the
-    backward pass (so forward slabs co-reside with the dq/dkv kernel's).
-    Counted: the f32 score slab (block_q x block_kv), double-buffered
-    streamed K/V and q blocks, and the f32 output accumulator — for both
-    the recomputed forward (at block_kv = ``bkv``) and the backward
-    kernels (at their 1024 blocks). Anchored on the two v5e measurements
-    (VERDICT r4 weak #4): bkv=2048 at the flagship shape overflows the
-    16 MiB scope (estimate 17.0 MiB), bkv=1024 fits (12.0 MiB)."""
-    bq = min(1024, t)
-    bkv = min(bkv, t)
-
-    def slab(block_q, block_k):
-        return (block_q * block_k * 4            # f32 scores
-                + 2 * (2 * block_k * d * itemsize)  # double-buffered K,V
-                + 2 * (block_q * d * itemsize)      # double-buffered q
-                + block_q * d * 4)                  # f32 out accumulator
-
-    bd = min(1024, t)
-    return slab(bq, bkv) + slab(bd, bd)
-
-
-def _select_kernel(t: int, d: int, under_remat: bool,
-                   itemsize: int = 2) -> str:
-    """'splash' or 'flash' for a splash-eligible shape. Under remat the
-    residual-saving splash forward is recomputed inside the backward and
-    its VMEM residency can overflow the scope (an XLA compile error, not
-    an OOM a user can act on) — degrade to flash automatically unless
-    HOROVOD_SPLASH=force insists (VERDICT r4 item 7: knobs are overrides,
-    not the mechanism). ``itemsize`` is the q/k/v element size (fp32
-    inputs double the streamed-slab residency).
-
-    What the estimate does with bf16 heads of 128 under remat: T = 1024
-    reads 12.6 MB and stays with splash; every T that 2048 divides (2048,
-    4096, 8192, ...) reads 17.8 MB against the 16 MiB scope and goes to the
-    stock flash kernel at 1024 blocks; odd multiples of 1024 (3072, ...)
-    take the 1024 kv block, read 12.6 MB and stay with splash. It is an
-    estimate anchored on two readings of an older backend, and whether
-    splash at T = 4096 would in fact overflow under recomputation has not
-    been tried on the chip. What the chip showed of the flash side
-    (PERF.md PR 28, v5e, 1 x 16 x 4096 x 128, remat="block", 24 layer
-    applications a step, by scope): forward 15.1 ms, the forward run again
-    15.8, dkv 27.6, dq 20.0; 77.7 ms of kernel time a step, 32.3% of the
-    compute roofline of the attention the objective needs (25.1 ms),
-    against 31.8% for splash at 4 x 2048 without remat."""
-    if not under_remat:
+def _select_kernel(q_shape, kv_shape) -> str:
+    """"splash", "flash" or "materialized" for q and k/v of [B, H, T, D] on
+    this backend. Materialized attention off the TPU and for sequence
+    lengths the kernels' 128-row blocks do not divide (ViT's 197 and 17
+    tokens); splash for what :func:`_splash_ok` admits; the stock flash
+    kernel for the rest (rectangular q/kv, T not a multiple of 1024, heads
+    not a multiple of 128) and with ``HOROVOD_SPLASH`` off."""
+    if not flash_available() or q_shape[2] % 128 or kv_shape[2] % 128:
+        return "materialized"
+    if splash_available() and _splash_ok(q_shape, kv_shape):
         return "splash"
-    if _splash_mode() == "force":
-        return "splash"
-    if _splash_remat_vmem_bytes(t, d, _splash_bkv(t),
-                                itemsize) > _scoped_vmem_bytes():
-        return "flash"
-    return "splash"
+    return "flash"
+
+
+def attention_kernel(q_shape, kv_shape, causal: bool = True,
+                     under_remat: bool = False) -> dict:
+    """What :func:`flash_attention_local` runs for q and k/v of [B, H, T, D]
+    on this backend, as the labels of the gauge ``hvd_tpu_attn_kernel``:
+    ``kernel`` ("splash", "flash", "materialized"), the forward's
+    ``block_q`` and ``block_kv`` and whether the backward is one fused
+    kernel. A function of the shapes, ``causal`` and ``under_remat`` alone;
+    the call itself dispatches on it."""
+    kernel = _select_kernel(q_shape, kv_shape)
+    if kernel == "splash":
+        g = splash_geometry(q_shape[2], q_shape[3], causal, under_remat)
+        blocks = (g.block_q, g.block_kv, True)
+    elif kernel == "flash":
+        blocks = (_flash_block(q_shape[2], kv_shape[2]),) * 2 + (False,)
+    else:
+        blocks = (0, 0, False)
+    return {"kernel": kernel, "block_q": str(blocks[0]),
+            "block_kv": str(blocks[1]), "fused_bwd": str(int(blocks[2]))}
 
 
 @functools.lru_cache(maxsize=32)
-def _splash_kernel(h: int, t: int, causal: bool):
+def _splash_kernel(h: int, t: int, d: int, causal: bool, under_remat: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     # Kernel construction may run inside a jit trace (shapes are only known
@@ -155,21 +172,13 @@ def _splash_kernel(h: int, t: int, causal: bool):
     # tracers — the lru_cache would otherwise leak a tracer into later
     # traces (observed as UnexpectedTracerError on the second trace).
     with jax.ensure_compile_time_eval():
-        return _build_splash_kernel(sk, sm, h, t, causal)
-
-
-def _build_splash_kernel(sk, sm, h: int, t: int, causal: bool):
-    mk = sm.CausalMask if causal else (lambda s: sm.FullMask(s))
-    mask = sm.MultiHeadMask([mk((t, t)) for _ in range(h)])
-    bq = min(1024, t)
-    bkv = _splash_bkv(t)  # shared with the remat VMEM estimator
-    bd = min(1024, t)
-    bs = sk.BlockSizes(block_q=bq, block_kv=bkv, block_kv_compute=bkv,
-                       block_q_dkv=bd, block_kv_dkv=bd,
-                       block_kv_dkv_compute=bd, block_q_dq=bd,
-                       block_kv_dq=bd)
-    return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                              block_sizes=bs)
+        mk = sm.CausalMask if causal else sm.FullMask
+        mask = sm.MultiHeadMask([mk((t, t)) for _ in range(h)])
+        bs = sk.BlockSizes(
+            use_fused_bwd_kernel=True,
+            **splash_geometry(t, d, causal, under_remat)._asdict())
+        return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
+                                  block_sizes=bs)
 
 
 def _splash_ok(q_shape, kv_shape) -> bool:
@@ -180,13 +189,16 @@ def _splash_ok(q_shape, kv_shape) -> bool:
             and kv_shape[2] == t and kv_shape[3] == d)
 
 
-def _block_sizes(t: int):
-    """Measured on v5e (T=2048, D=128): 1024/1024 blocks beat the kernel's
-    512-default by ~20% fwd; fall back to defaults for short sequences."""
+def _flash_block(q_t: int, kv_t: int) -> int:
+    """Every block of the stock flash kernel: 1024 where it divides both
+    sequence lengths, else the kernel's own default of 128. 1024 against
+    512 on this backend: PERF.md section 6 (PR 31's sweep, T = 4096)."""
+    return 128 if q_t % 1024 or kv_t % 1024 else 1024
+
+
+def _block_sizes(q_t: int, kv_t: int):
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-    if t < 1024 or t % 1024:
-        return None
-    b = 1024
+    b = _flash_block(q_t, kv_t)
     return BlockSizes(block_q=b, block_k_major=b, block_k=b, block_b=1,
                       block_q_major_dkv=b, block_k_major_dkv=b,
                       block_k_dkv=b, block_q_dkv=b,
@@ -204,44 +216,47 @@ def _warn_unaligned_once(q_t: int, kv_t: int) -> None:
 def flash_attention_local(q, k, v, causal: bool = True,
                           layout: str = "bthk",
                           under_remat: bool = False):
-    """Attention via the Pallas TPU flash kernel; materialized attention
-    off-TPU and (with a one-time warning) for sequence lengths the kernel's
-    128-row blocks do not divide. ``layout``
-    is the layout of q/k/v (and the result):
-    "bthk" ([B, T, H, D], the framework's default) or "bhtk" ([B, H, T, D],
-    the kernel's native layout — callers that can project straight into it
-    skip the transposes). ``under_remat=True`` tells the kernel selector
-    this call sits inside a jax.checkpoint region whose backward recomputes
-    it — splash auto-degrades to flash when its recompute VMEM bound
-    exceeds the chip scope (see :func:`_select_kernel`)."""
+    """Attention via the stock Pallas TPU kernels (:func:`attention_kernel`
+    says which); materialized attention off-TPU and (with a one-time
+    warning) for sequence lengths the kernels' 128-row blocks do not divide.
+    ``layout`` is the layout of q/k/v (and the result): "bthk" ([B, T, H,
+    D], the framework's default) or "bhtk" ([B, H, T, D], the kernels'
+    native layout — callers that can project straight into it skip the
+    transposes). ``under_remat=True`` says this call sits inside a
+    jax.checkpoint region whose backward runs it again; the geometry may
+    depend on it (:func:`splash_geometry`: on this backend it does not)."""
     if layout not in ("bthk", "bhtk"):
         raise ValueError(f"unknown attention layout {layout!r}")
-    # The Pallas flash kernel's _verify_block requires both sequence lengths
-    # divisible by its block sizes (128 minimum); unaligned lengths
-    # (ViT-B/16 at 224px -> 197 tokens, ViT_Tiny/32 -> 17) take the
-    # materialized attention instead of crashing on TPU (ADVICE r3 medium).
-    kernel_t = q.shape[1] if layout == "bthk" else q.shape[2]
-    kv_t = k.shape[1] if layout == "bthk" else k.shape[2]
-    unaligned = kernel_t % 128 or kv_t % 128
-    if flash_available() and unaligned:
-        _warn_unaligned_once(kernel_t, kv_t)
-    if not flash_available() or unaligned:
-        if layout == "bhtk":
-            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        out = local_attention(q, k, v, causal=causal)
-        return out.transpose(0, 2, 1, 3) if layout == "bhtk" else out
+    bhtk = layout == "bhtk"
+
+    def swap(x):    # [B, T, H, D] <-> [B, H, T, D]
+        return x.transpose(0, 2, 1, 3)
+
+    def as_bhtk(shape):
+        return shape if bhtk else (shape[0], shape[2], shape[1], shape[3])
+
+    kernel = _select_kernel(as_bhtk(q.shape), as_bhtk(k.shape))
+    if kernel == "materialized":
+        # The Pallas kernels want both sequence lengths divisible by their
+        # blocks (128 at least); unaligned lengths (ViT-B/16 at 224px -> 197
+        # tokens, ViT_Tiny/32 -> 17) take the materialized attention
+        # instead of crashing on TPU.
+        if flash_available():
+            _warn_unaligned_once(as_bhtk(q.shape)[2], as_bhtk(k.shape)[2])
+        if bhtk:
+            return swap(local_attention(swap(q), swap(k), swap(v),
+                                        causal=causal))
+        return local_attention(q, k, v, causal=causal)
+    if not bhtk:
+        q, k, v = swap(q), swap(k), swap(v)
     scale = 1.0 / math.sqrt(q.shape[-1])
-    if layout == "bthk":
-        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    if (splash_available() and _splash_ok(q.shape, k.shape)
-            and _select_kernel(q.shape[2], q.shape[3], under_remat,
-                               q.dtype.itemsize) == "splash"):
-        kernel = _splash_kernel(q.shape[1], q.shape[2], causal)
-        out = jax.vmap(kernel)((q * scale).astype(q.dtype), k, v)
+    if kernel == "splash":
+        splash = _splash_kernel(q.shape[1], q.shape[2], q.shape[3], causal,
+                                under_remat)
+        out = jax.vmap(splash)((q * scale).astype(q.dtype), k, v)
     else:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as _fa)
-        bs = _block_sizes(q.shape[2])
         out = _fa(q, k, v, causal=causal, sm_scale=scale,
-                  **({"block_sizes": bs} if bs is not None else {}))
-    return out.transpose(0, 2, 1, 3) if layout == "bthk" else out
+                  block_sizes=_block_sizes(q.shape[2], k.shape[2]))
+    return out if bhtk else swap(out)

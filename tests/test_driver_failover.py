@@ -106,6 +106,11 @@ class TestJournalReplay:
             driver.record_worker_exit("h1", 1, exit_code=1)
             driver.record_worker_exit("h1", 1, exit_code=1)  # strike 2
             _mid_resize(driver, disc, b, {"h1": 2, "h2": 2})
+            # the resize's notify entry is journalled after its hosts
+            # entry: a shadow taken between the two reads notify None
+            assert wait_until(
+                lambda: _shadow(b).notify == driver._last_notify and
+                _shadow(b).head == driver._journal.head(), timeout=5)
 
             # replay from the STANDBY's locally-replicated store
             shadow = _shadow(b)

@@ -123,16 +123,22 @@ def test_api_docs_in_sync(tmp_path):
         "docs/api.md is stale — run python tools/gen_api_docs.py"
 
 
-def test_transformer_lm_example_spmd():
+@pytest.mark.parametrize("attention", ["ring", "flash"])
+def test_transformer_lm_example_spmd(attention):
     r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
               "--mesh", "data=2", "--d-model", "32", "--n-layers", "1",
               "--n-heads", "4", "--d-ff", "64", "--vocab", "128",
-              "--seq", "32", "--batch", "4", "--steps", "2"])
+              "--seq", "32", "--batch", "4", "--steps", "2",
+              "--attention", attention])
     assert r.returncode == 0, r.stderr[-2000:]
     assert "tokens_per_sec" in r.stdout, r.stdout
     # the layer's 8,256 of the 12,384 gradient values are summed inside
     # the backward scan (ln1, ln2, four d x d, two d x d_ff; embed, ln_f)
     assert "'grad_reduce_in_backward_share': 0.6667" in r.stdout, r.stdout
+    # which kernel the local attention call takes is said where that call
+    # is flash_attention_local's: off the TPU, none
+    assert ("'attn_kernel': {'kernel': 'materialized'" in r.stdout) == (
+        attention == "flash"), r.stdout
 
 
 def test_transformer_lm_example_looped():

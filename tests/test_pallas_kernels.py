@@ -164,36 +164,126 @@ def test_resnet_fused_bn_variant_trains():
                for leaf in jax.tree_util.tree_leaves(g))
 
 
-class TestSplashRematSelection:
-    """VERDICT r4 item 7: splash must auto-degrade to flash when a remat'd
-    block would recompute its residual-saving forward with a VMEM
-    residency above the chip scope — the env knobs are overrides, not the
-    mechanism. The selection arithmetic is backend-independent."""
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The selection asks ``jax.default_backend()``; its arithmetic is the
+    same on any backend."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("HOROVOD_SPLASH", raising=False)
 
-    def test_flagship_remat_shape_degrades_to_flash(self, monkeypatch):
-        from horovod_tpu.parallel import flash_attention as fa
-        monkeypatch.delenv("HOROVOD_SPLASH", raising=False)
-        monkeypatch.delenv("HOROVOD_SPLASH_BLOCK_KV", raising=False)
-        # T=2048 D=128 (flagship): bkv=2048 recompute bound > 16 MiB scope
-        assert fa._splash_remat_vmem_bytes(2048, 128, 2048) > \
-            fa._scoped_vmem_bytes()
-        assert fa._select_kernel(2048, 128, under_remat=True) == "flash"
-        # ...but without remat splash stays
-        assert fa._select_kernel(2048, 128, under_remat=False) == "splash"
 
-    def test_small_block_fits_and_keeps_splash(self, monkeypatch):
-        from horovod_tpu.parallel import flash_attention as fa
-        # the other empirical anchor: bkv=1024 fits under the scope
-        assert fa._splash_remat_vmem_bytes(2048, 128, 1024) < \
-            fa._scoped_vmem_bytes()
-        monkeypatch.setenv("HOROVOD_SPLASH_BLOCK_KV", "1024")
-        assert fa._select_kernel(2048, 128, under_remat=True) == "splash"
+@pytest.mark.parametrize("under_remat", [False, True])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("t", [1024, 2048, 3072, 4096, 8192])
+def test_splash_geometry_is_buildable_and_skips_what_the_mask_drops(
+        t, d, causal, under_remat):
+    """The blocks ``splash_geometry`` answers for every shape ``_splash_ok``
+    admits: the stock kernel can be built with them, and a causal call of
+    2048 positions or more never gets one kv block as long as the sequence
+    (the kernel skips by block: one block is the whole square)."""
+    from horovod_tpu.parallel import flash_attention as fa
+    g = fa.splash_geometry(t, d, causal, under_remat)
+    assert all(b % 128 == 0 and t % b == 0 for b in g), g
+    assert g.block_kv % g.block_kv_compute == 0
+    assert g.block_kv_dkv % g.block_kv_dkv_compute == 0
+    if causal and t >= 2048:
+        assert g.block_kv < t and g.block_kv_dkv < t
+    # the stock BlockSizes refuses dq blocks beside the fused backward
+    # kernel: the geometry has none, and the kernel is built with it
+    kernel = fa._splash_kernel(2, t, d, causal, under_remat)
+    sizes = kernel.kwargs["block_sizes"]
+    assert sizes.use_fused_bwd_kernel
+    assert (sizes.block_q_dq, sizes.block_kv_dq) == (None, None)
+    assert kernel.dq_mask_info is None
+    fa._splash_kernel.cache_clear()
 
-    def test_force_overrides_degrade(self, monkeypatch):
-        from horovod_tpu.parallel import flash_attention as fa
-        monkeypatch.setenv("HOROVOD_SPLASH", "force")
-        monkeypatch.delenv("HOROVOD_SPLASH_BLOCK_KV", raising=False)
-        assert fa._select_kernel(2048, 128, under_remat=True) == "splash"
+
+@pytest.mark.parametrize("q, kv, mode, want", [
+    ((4, 16, 2048, 128), None, None, "splash"),
+    ((1, 16, 4096, 128), None, "force", "splash"),
+    ((1, 16, 4096, 128), None, "off", "flash"),
+    ((4, 16, 1536, 128), None, None, "flash"),          # T not / 1024
+    ((4, 16, 512, 128), None, None, "flash"),           # too short
+    ((4, 16, 2048, 64), None, None, "flash"),           # head not / 128
+    ((4, 16, 1024, 128), (4, 16, 2048, 128), None, "flash"),   # rectangular
+    ((8, 12, 197, 64), None, None, "materialized"),     # ViT-B/16
+    ((8, 12, 256, 64), (8, 12, 17, 64), None, "materialized"),
+])
+def test_which_kernel_a_shape_reaches(on_tpu, monkeypatch, q, kv, mode, want):
+    """Splash for what ``_splash_ok`` admits, with and without
+    recomputation; the stock flash kernel for the rest and with
+    ``HOROVOD_SPLASH`` off; materialized attention for unaligned lengths."""
+    from horovod_tpu.parallel import flash_attention as fa
+    if mode:
+        monkeypatch.setenv("HOROVOD_SPLASH", mode)
+    kv = kv or q
+    assert fa._select_kernel(q, kv) == want
+    for under_remat in (False, True):
+        labels = fa.attention_kernel(q, kv, True, under_remat)
+        assert labels["kernel"] == want
+        assert set(labels) == {"kernel", "block_q", "block_kv", "fused_bwd"}
+        if want == "flash":
+            block = "1024" if q[2] % 1024 == 0 == kv[2] % 1024 else "128"
+            assert (labels["block_q"], labels["fused_bwd"]) == (block, "0")
+
+
+def test_off_the_tpu_attention_is_materialized_and_the_gauge_is_declared():
+    from horovod_tpu.metrics import METRIC_SPECS
+    from horovod_tpu.parallel import flash_attention as fa
+    sq = (4, 16, 2048, 128)
+    assert fa.attention_kernel(sq, sq) == {
+        "kernel": "materialized", "block_q": "0", "block_kv": "0",
+        "fused_bwd": "0"}
+    assert METRIC_SPECS["hvd_tpu_attn_kernel"][0] == "gauge"
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_chosen_splash_geometry_against_float32(on_tpu, monkeypatch, causal):
+    """dq, dk, dv of ``flash_attention_local`` with the stock splash kernel
+    at the chosen blocks (interpreted here) against a float32 materialized
+    attention: relative L2. On the v5e at the cells' shapes the causal
+    geometry reads dq 3.8e-3, dk 3.7e-3, dv 3.1e-3 at worst over 8 seeds
+    (``tools/attn_sweep.py errors``, PERF.md section 4); the band is 1.5
+    times that; a backward through scores rounded to 8 bits reads 3e-2."""
+    import functools
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    from horovod_tpu.parallel import flash_attention as fa
+    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
+        sk.make_splash_mha, interpret=True))
+    fa._splash_kernel.cache_clear()
+    t, d = 2048, 128
+    q, k, v, w = (jax.random.normal(key, (1, 2, t, d), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for key in jax.random.split(jax.random.PRNGKey(5), 4))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    def kernel(q, k, v):
+        return fa.flash_attention_local(q, k, v, causal=causal,
+                                        layout="bhtk")
+
+    def reference(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    assert fa.attention_kernel(q.shape, k.shape, causal)["kernel"] == "splash"
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+    fa._splash_kernel.cache_clear()
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        err = np.linalg.norm(g - r) / np.linalg.norm(r)
+        assert err < 6e-3, (name, err)
 
 
 def test_engine_offers_the_pack_kernel_aligned_buckets_only(monkeypatch):

@@ -1,6 +1,9 @@
 """The SPMD step compiled HERE for a described TPU v5e:2x2 (no chip
 attached, nothing runs): what libtpu's own compiler makes of the step's
-gradient all-reduces with the options ``make_train_step`` hands it.
+gradient all-reduces with the options ``make_train_step`` hands it, and
+whether the attention kernels fit as ``parallel/flash_attention.py`` builds
+them (an over-large block fails the compile: ``RESOURCE_EXHAUSTED ...
+memory space vmem``).
 
 The compiler is the installed libtpu's, so a wrong option name fails these
 tests loudly, and an upgrade that stops overlapping shows here before a
@@ -28,6 +31,14 @@ CFG = tfm.TransformerConfig(vocab_size=50257, d_model=2048, n_heads=16,
                             n_layers=2, d_ff=8192, max_seq=2048,
                             dtype=jnp.bfloat16, attention="flash")
 ROWS_PER_CHIP = 4
+# ouro-spmd-1chip-loop4's own program but for its depth (2 of the cell's 6
+# layers): the widths of benchmark/configs/ouro-2.6b.json, four passes, one
+# row of 4096 tokens under remat="block"
+LOOPED = tfm.TransformerConfig(
+    vocab_size=49152, d_model=2048, n_heads=16, n_layers=2, d_ff=5632,
+    max_seq=4096, dtype=jnp.bfloat16, attention="flash", remat="block",
+    positions="rope", rope_theta=1e6, ffn="swiglu", norm="sandwich",
+    norm_eps=1e-6, tie_embeddings=False, n_loops=4)
 
 
 @pytest.fixture(scope="module")
@@ -48,9 +59,9 @@ def pallas_branch(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compiled_step(topo, shape, cfg):
+def _compiled_step(topo, shape, cfg, rows_per_chip=ROWS_PER_CHIP):
     """Scheduled HLO text of ``make_train_step`` over the described chips."""
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(shape),
+    mesh = Mesh(np.array(topo.devices[:int(np.prod(shape))]).reshape(shape),
                 (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
     opt = optax.adamw(3e-4)
     shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
@@ -69,7 +80,7 @@ def _compiled_step(topo, shape, cfg):
                                    sharding=NamedSharding(mesh, P())),
         mu=on_mesh(adam.mu), nu=on_mesh(adam.nu)), *rest)
     tok = jax.ShapeDtypeStruct(
-        (ROWS_PER_CHIP * shape[0], cfg.max_seq), jnp.int32,
+        (rows_per_chip * shape[0], cfg.max_seq), jnp.int32,
         sharding=NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS)))
     step = tfm.make_train_step(mesh, cfg, opt)
     return step.lower(params, state, tok, tok).compile().as_text()
@@ -142,3 +153,49 @@ def test_data4_without_the_options_overlaps_nothing(topo, pallas_branch,
     assert "async-collective-start" not in text
     assert "all-reduce-start" not in text
     assert " all-reduce(" in text
+
+
+@pytest.mark.parametrize("cfg, rows", [(CFG, ROWS_PER_CHIP), (LOOPED, 1)],
+                         ids=["plain-4x2048", "looped-1x4096-remat"])
+def test_the_attention_kernels_fit_and_the_backward_is_one_kernel(
+        topo, pallas_branch, cfg, rows):
+    """Whether a geometry fits the chip's VMEM is the compiler's word, not
+    an estimate: both LM cells' steps (depth 2) compile for the v5e with the
+    splash kernels at the blocks ``splash_geometry`` chose, under
+    recomputation too; the backward is the fused dkv kernel alone, and the
+    stock flash kernel is in neither program."""
+    text = _compiled_step(topo, (1, 1, 1), cfg, rows)
+    calls = set(re.findall(r"%((?:splash|flash)\w*?)(?:\.\d+)? = ", text))
+    assert {"splash_mha_fwd_residuals",
+            "splash_mha_dkv_no_residuals"} <= calls, calls
+    assert not [c for c in calls
+                if c.startswith("splash_mha_dq") or "flash" in c], calls
+
+
+@pytest.mark.parametrize("rows, t, d, causal, remat", [
+    (4, 2048, 128, False, False),       # the geometry no cell runs
+    (1, 4096, 128, False, True),
+    (4, 2048, 256, True, False),        # ... and a head no cell has
+    (1, 4096, 256, True, True),
+], ids=["full-4x2048", "full-1x4096-remat", "head256-4x2048",
+        "head256-1x4096-remat"])
+def test_the_attention_call_alone_fits(topo, pallas_branch, rows, t, d,
+                                       causal, remat):
+    """Forward and backward of ``flash_attention_local`` by itself, for
+    what ``splash_geometry`` answers where no cell's step would show a
+    block too large for the chip's VMEM."""
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.parallel import flash_attention as fa
+
+    def attn(q, k, v):
+        return fa.flash_attention_local(q, k, v, causal=causal,
+                                        layout="bhtk", under_remat=remat)
+
+    body = jax.checkpoint(attn) if remat else attn
+    x = jax.ShapeDtypeStruct((rows, 16, t, d), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    text = jax.jit(jax.grad(
+        lambda q, k, v: body(q, k, v).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(x, x, x).compile().as_text()
+    assert "splash_mha_dkv_no_residuals" in text
+    assert "splash_mha_dq" not in text

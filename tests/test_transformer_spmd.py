@@ -272,8 +272,9 @@ def test_splash_gating_and_kernel_construction():
     assert not _splash_ok(sq, (1, 4, 2048, 128))  # rectangular q/kv
     for t in (1024, 2048, 3072):
         for causal in (True, False):
-            k = _splash_kernel(2, t, causal)   # construction validates blocks
-            assert k is not None
+            for under_remat in (True, False):
+                # construction validates the blocks against T
+                assert _splash_kernel(2, t, 128, causal, under_remat)
     _splash_kernel.cache_clear()
 
 

@@ -98,7 +98,8 @@ SECTIONS = [
     ("Parallelism kernels", "horovod_tpu.parallel.ring_attention", [
         "ring_attention_p", "local_attention"]),
     ("", "horovod_tpu.parallel.ulysses", ["ulysses_attention_p"]),
-    ("", "horovod_tpu.parallel.flash_attention", ["flash_attention_local"]),
+    ("", "horovod_tpu.parallel.flash_attention", ["flash_attention_local",
+                                                   "attention_kernel"]),
     ("", "horovod_tpu.parallel.moe", ["moe_layer_p", "MoEParams"]),
     ("", "horovod_tpu.parallel.pipeline", []),
     ("Ops", "horovod_tpu.ops.sync_batch_norm", []),
