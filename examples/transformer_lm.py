@@ -25,6 +25,19 @@ inside its backward scan goes to ``hvd_tpu_lm_grad_reduce_in_backward_share``,
 and with ``--attention flash`` which kernel the local attention call takes,
 at which blocks, to ``hvd_tpu_attn_kernel``.
 
+``--kv-heads``, ``--head-size``, ``--qk-norm``, ``--attn-gate`` and
+``--embed-scale`` are the block's too. ``--pattern sssf`` makes the stack a
+per-layer pattern (spmd mode; the pipeline refuses it by name): ``s`` a
+sliding-window layer (``--window``), ``f`` a full-attention layer that does
+not rotate, repeated over ``--n-layers``; the first ``--dense-layers`` keep
+the dense FFN, the others route ``--top-k`` of ``--experts`` sigmoid-scored
+SwiGLU experts of ``--expert-ff`` beside ``--shared-experts``, of which
+this program holds ``--experts-held`` from ``--first-expert`` on
+(benchmark/configs/trinity-mini.json is such a model). The step then
+returns the experts' counts beside the loss: the held share, the fullest
+expert's load and the dropped assignments (0) go to the gauges
+``hvd_tpu_moe_*``.
+
 Synthetic data; prints tokens/sec. Mirrors the reference's synthetic
 benchmark scripts (examples/*_synthetic_benchmark.py) for the LM workload.
 """
@@ -82,6 +95,26 @@ def main():
                          "above 1 every pass ends in the head and a "
                          "learned exit gate")
     ap.add_argument("--exit-entropy-weight", type=float, default=0.1)
+    ap.add_argument("--kv-heads", type=int, default=0,
+                    help="K and V heads, a divisor of --n-heads (0: as many)")
+    ap.add_argument("--head-size", type=int, default=0,
+                    help="a head's width where it is not d_model / n_heads")
+    ap.add_argument("--qk-norm", action="store_true")
+    ap.add_argument("--attn-gate", action="store_true")
+    ap.add_argument("--embed-scale", type=float, default=1.0)
+    ap.add_argument("--pattern", default="",
+                    help="one letter a layer of a period, s (window) or f "
+                         "(full, no rotation), e.g. sssf")
+    ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--dense-layers", type=int, default=1)
+    ap.add_argument("--experts", type=int, default=8)
+    ap.add_argument("--top-k", type=int, default=2)
+    ap.add_argument("--expert-ff", type=int, default=0)
+    ap.add_argument("--shared-experts", type=int, default=0)
+    ap.add_argument("--route-scale", type=float, default=1.0)
+    ap.add_argument("--experts-held", type=int, default=0)
+    ap.add_argument("--first-expert", type=int, default=0)
+    ap.add_argument("--router-bias-rate", type=float, default=0.0)
     ap.add_argument("--delta-adasum", action="store_true",
                     help="eager mode: delta-model Adasum (local optimizer "
                          "step first, Adasum on the parameter delta)")
@@ -95,12 +128,29 @@ def main():
 
     from horovod_tpu.common.env import use_compile_cache
     use_compile_cache()
-    from horovod_tpu.models.transformer import (TransformerConfig,
+    from horovod_tpu.models.transformer import (LayerKind,
+                                                TransformerConfig,
                                                 init_params, lean_lm_loss,
                                                 make_train_step,
                                                 shard_params)
 
+    pattern = {}
+    if args.pattern:
+        kinds = {"s": (args.window, True), "f": (0, False)}
+        pattern = dict(
+            layers=tuple(
+                LayerKind(*kinds[args.pattern[i % len(args.pattern)]],
+                          experts=i >= args.dense_layers)
+                for i in range(args.n_layers)),
+            moe_top_k=args.top_k, d_ff_expert=args.expert_ff or args.d_ff,
+            n_shared_experts=args.shared_experts,
+            route_scale=args.route_scale, experts_held=args.experts_held,
+            first_expert=args.first_expert,
+            router_bias_rate=args.router_bias_rate)
     cfg = TransformerConfig(
+        n_kv_heads=args.kv_heads, head_size=args.head_size,
+        qk_norm=args.qk_norm, attn_gate=args.attn_gate,
+        embed_scale=args.embed_scale, n_experts=args.experts, **pattern,
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff, max_seq=args.seq,
         dtype=jnp.bfloat16, attention=args.attention,
@@ -165,12 +215,16 @@ def main():
             # what the step's local attention call runs on this backend, and
             # at which blocks: a property of the shape a chip holds
             from horovod_tpu.parallel.flash_attention import attention_kernel
-            local = (args.batch // mesh.shape["data"],
-                     cfg.n_heads // mesh.shape["tensor"], args.seq,
-                     cfg.d_model // cfg.n_heads)
-            attn = attention_kernel(local, local, causal=True,
-                                    under_remat=cfg.remat != "none")
-            registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
+            rows = args.batch // mesh.shape["data"]
+            local, local_kv = ((rows, h // mesh.shape["tensor"], args.seq,
+                                cfg.head_dim)
+                               for h in (cfg.n_heads, cfg.kv_heads))
+            # one label set a kind of layer; the report keeps the last
+            for window in sorted({k.window for k in cfg.layers} or {0}):
+                attn = attention_kernel(local, local_kv, causal=True,
+                                        under_remat=cfg.remat != "none",
+                                        window=window)
+                registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
         opt_state = opt.init(params)
         tok_sh = NamedSharding(mesh, P("data", "seq"))
         if args.sp_layout == "zigzag":
@@ -184,13 +238,28 @@ def main():
             targets = jnp.take(targets, idx, axis=1)
         inputs = jax.device_put(inputs, tok_sh)
         targets = jax.device_put(targets, tok_sh)
-        params, opt_state, loss = step(params, opt_state, inputs, targets)
+        # (with routed-expert layers the step returns their counts too)
+        params, opt_state, loss, *stats = step(params, opt_state, inputs,
+                                               targets)
         t0 = time.perf_counter()
         for _ in range(args.steps):
-            params, opt_state, loss = step(params, opt_state, inputs,
-                                           targets)
+            params, opt_state, loss, *stats = step(params, opt_state,
+                                                   inputs, targets)
         loss = float(loss)
         dt = (time.perf_counter() - t0) / args.steps
+        if stats:
+            # logged with the loss: where the router sent the last step
+            from horovod_tpu.models.transformer import routing_stats
+            routing = routing_stats(stats[0]["expert_counts"], cfg,
+                                    args.batch * args.seq)
+            for name, gauge in (
+                    ("held_share", "hvd_tpu_moe_held_assignment_share"),
+                    ("load_max_over_mean",
+                     "hvd_tpu_moe_expert_load_max_over_mean")):
+                for layer, value in enumerate(routing[name]):
+                    registry().gauge(gauge).set(value, layer=str(layer))
+            registry().gauge("hvd_tpu_moe_dropped_assignments").set(
+                routing["dropped"])
     else:
         import horovod_tpu as hvd
         hvd.init()
@@ -227,6 +296,9 @@ def main():
         report["grad_reduce_in_backward_share"] = round(in_backward, 4)
         if attn:
             report["attn_kernel"] = attn
+        if stats:
+            report["routing"] = {k: np.round(v, 4).tolist()
+                                 for k, v in routing.items()}
     if cfg.n_loops > 1:
         # logged with the loss: whether the exit gate has collapsed
         from horovod_tpu.metrics import registry

@@ -39,6 +39,18 @@ FFN = "ffn"             # _block: rmsnorms, dense or MoE branch, residual
 HEAD = "head"           # _final_norm: every pass's final rmsnorm; _head: logits einsum
 LOSS = "loss"           # _lean_xent, both rules of its custom_vjp
 EXIT_GATE = "exit_gate"  # gate logit, exit distribution, entropy (n_loops > 1)
+# inside attn, where the configuration has them (cfg.layers: a per-layer
+# pattern; cfg.qk_norm; cfg.attn_gate)
+ATTN_WINDOW = "attn_window"     # the attention call of a sliding-window layer
+ATTN_FULL = "attn_full"         # ... of a causal full-attention layer
+QK_NORM = "qk_norm"             # q and k RMSNormed over the head
+ATTN_GATE = "attn_gate"         # the gate's projection, sigmoid and product
+# inside ffn, the routed-expert layer (parallel/moe.py topk_*)
+ROUTER = "router"               # fp32 scores, top-k, weights, counts
+MOE_DISPATCH = "moe_dispatch"   # the sort by expert and the gather
+EXPERTS = "experts"             # the held experts' three grouped matmuls
+SHARED_EXPERT = "shared_expert"  # the SwiGLU every token takes
+MOE_COMBINE = "moe_combine"     # weighted scatter-add back to the tokens
 # optimizer.py and the step builders
 OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates
 DECOMPRESS = "decompress"       # eager apply program: what precedes them

@@ -206,16 +206,31 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "step; 0 on a mesh with nothing to sum over and for a "
                  "looped model, whose shared layers are summed once after "
                  "the pass loop)"),
-    # parallel/flash_attention.py attention_kernel (ISSUE 31)
+    # parallel/flash_attention.py attention_kernel (ISSUE 31; window: 32)
     "hvd_tpu_attn_kernel": (
-        "gauge", "1 on the one label set that says what the model's local "
+        "gauge", "1 on the label set that says what the model's local "
                  "attention call runs on this backend: kernel (splash, "
                  "flash: the two stock Pallas kernels; materialized), the "
-                 "forward's block_q and block_kv, and fused_bwd (1: dq "
-                 "comes out of the dkv kernel). A function of the call's "
-                 "shape, causal and under_remat alone; "
-                 "examples/transformer_lm.py sets it when it has built its "
-                 "step"),
+                 "forward's block_q and block_kv, fused_bwd (1: dq "
+                 "comes out of the dkv kernel) and window (0: none). A "
+                 "function of the call's shape, causal, under_remat and "
+                 "window alone; examples/transformer_lm.py sets it when it "
+                 "has built its step, once for each kind of layer of a "
+                 "per-layer pattern"),
+    # models/transformer.py routing_stats (ISSUE 32 routed-expert layers)
+    "hvd_tpu_moe_held_assignment_share": (
+        "gauge", "Of the tokens x top-k assignments of the last logged "
+                 "step, the share that landed on the experts this program "
+                 "holds, by expert layer (held / experts under an even "
+                 "router; what the absent experts would do is left out)"),
+    "hvd_tpu_moe_expert_load_max_over_mean": (
+        "gauge", "The fullest expert's assignments over the mean over all "
+                 "experts, last logged step, by expert layer (1.0: even; "
+                 "the selection bias moves against it every step)"),
+    "hvd_tpu_moe_dropped_assignments": (
+        "gauge", "Assignments of the last logged step that no expert was "
+                 "counted for, over all expert layers: 0, the routed-expert "
+                 "layer has no capacity and drops nothing"),
     # stall_inspector.py
     "hvd_tpu_stall_publish_failures_total": (
         "counter", "Stall-inspector KV liveness publishes that failed"),

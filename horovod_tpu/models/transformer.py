@@ -24,10 +24,11 @@ scan-over-layers for compile-time scaling.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
@@ -37,7 +38,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common import scopes
 from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
-                            moe_dispatch, moe_experts, moe_layer_p)
+                            moe_dispatch, moe_experts, moe_layer_p,
+                            router_bias_update, topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
@@ -45,6 +47,19 @@ from ..parallel.ulysses import ulysses_attention_p
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"
 TENSOR_AXIS = "tensor"
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer of a per-layer pattern (``TransformerConfig.layers``)
+    is. ``window`` > 0: key ``j`` is visible from query ``i`` iff ``0 <= i -
+    j < window``; 0: every ``j <= i``. ``rope``: under ``positions="rope"``,
+    whether this layer rotates q and k (False: no position signal of its
+    own). ``experts``: the routed-expert FFN (``moe_top_k`` of
+    ``n_experts`` and the shared experts) in place of the dense one."""
+    window: int = 0
+    rope: bool = True
+    experts: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,6 +126,46 @@ class TransformerConfig:
     # pass takes what is left): ``_exit_loss``.
     n_loops: int = 1
     exit_entropy_weight: float = 0.1
+    # Attention, field by field; 0 / False / 1.0 is the block as it was.
+    # ``n_kv_heads``: K and V heads, a divisor of ``n_heads`` (query head n
+    # reads KV head ``n // (n_heads / n_kv_heads)``); ``head_size``: the
+    # width of a head where it is not ``d_model / n_heads``; ``qk_norm``: q
+    # and k RMSNormed over the head with a learned scale, before RoPE;
+    # ``attn_gate``: the attention's output times ``sigmoid(x wgate)``
+    # before the output projection; ``embed_scale`` multiplies the
+    # embedding's rows.
+    n_kv_heads: int = 0
+    head_size: int = 0
+    qk_norm: bool = False
+    attn_gate: bool = False
+    embed_scale: float = 1.0
+    # A per-layer pattern: one :class:`LayerKind` a layer (then ``n_layers``
+    # is their number). The stack is its leading dense layers (leaves under
+    # ``params["dense_layers"]``), then its expert layers
+    # (``params["layers"]``), each stack ONE scan of the one block over its
+    # stacked leaves: what differs from layer to layer, the window and the
+    # rotation, is a ``lax.switch`` around the attention call on the
+    # layer's kind (:func:`_run_pattern`). Empty: ``n_layers`` of the one
+    # block, as before.
+    layers: Tuple[LayerKind, ...] = ()
+    # The routed-expert FFN of a ``LayerKind.experts`` layer
+    # (parallel/moe.py ``topk_*``): ``moe_top_k`` of ``n_experts`` by
+    # sigmoid scores, weights normalised over the chosen (``route_norm``)
+    # times ``route_scale``, SwiGLU experts of width ``d_ff_expert``,
+    # ``n_shared_experts`` of the same width that every token takes, no
+    # capacity and no drop. This program holds experts ``first_expert ..
+    # first_expert + experts_held - 1`` (0 held: all) and leaves the others'
+    # part out. ``router_bias_rate``: after a step ``b_e += rate *
+    # sign(mean(c) - c_e)`` on the selection bias, by
+    # :func:`make_train_step`, outside the optimizer.
+    moe_top_k: int = 0
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    route_scale: float = 1.0
+    route_norm: bool = True
+    experts_held: int = 0
+    first_expert: int = 0
+    router_bias_rate: float = 0.0
 
     def __post_init__(self):
         for name, known in (("positions", ("none", "rope")),
@@ -127,32 +182,185 @@ class TransformerConfig:
                              f"ffn={self.ffn!r} cannot be combined with it")
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError("positions='rope' needs an even head size")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_kv_heads {self.n_kv_heads} must divide "
+                             f"n_heads {self.n_heads}")
+        if self.layers:
+            if len(self.layers) != self.n_layers:
+                raise ValueError(f"layers names {len(self.layers)} layers, "
+                                 f"n_layers {self.n_layers}")
+            if self.n_loops > 1 or self.use_moe:
+                raise ValueError("a per-layer pattern (layers) runs one "
+                                 "pass and brings its own expert layers: "
+                                 "not with n_loops > 1 or use_moe")
+            _n_lead(self)
+        if self.has_experts and not (
+                0 < self.moe_top_k <= self.n_experts and self.d_ff_expert
+                and self.first_expert + self.held <= self.n_experts):
+            raise ValueError(
+                "an expert layer needs 0 < moe_top_k <= n_experts, "
+                "d_ff_expert, and its held experts among the n_experts")
 
     @property
     def head_dim(self) -> int:
+        if self.head_size:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def has_experts(self) -> bool:
+        return any(kind.experts for kind in self.layers)
+
+    @property
+    def held(self) -> int:
+        """Routed experts this program holds."""
+        return self.experts_held or self.n_experts
+
+
+class ExpertRoutes(NamedTuple):
+    """Where the routed-expert layers sent this shard's tokens; a leading
+    [expert layers] axis once the stack has run."""
+    expert: jax.Array   # [..., B, T, moe_top_k] int32: every token's choices
+    counts: jax.Array   # [..., n_experts] int32: assignments to each expert
+
+
+def _n_lead(cfg: TransformerConfig) -> int:
+    """The leading layers of a per-layer pattern that have the dense FFN;
+    every layer after them routes experts."""
+    kinds = cfg.layers
+    n_lead = next((i for i, kind in enumerate(kinds) if kind.experts),
+                  len(kinds))
+    if not all(kind.experts for kind in kinds[n_lead:]):
+        raise ValueError("layers: a dense-FFN layer after an expert layer "
+                         "has no stack to live in (dense layers lead)")
+    return n_lead
+
+
+def _norm_init(k, shape, fan_in):
+    return jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)
+
+
+def _new_attn_leaves(key, cfg: TransformerConfig, n: int) -> dict:
+    """The attention leaves the newer fields bring, for ``n`` stacked
+    layers, from keys no other leaf draws from."""
+    D, H, Dh = cfg.d_model, cfg.n_heads, cfg.head_dim
+    out = {}
+    if cfg.qk_norm:
+        out.update({"q_norm": jnp.ones((n, Dh), jnp.float32),
+                    "k_norm": jnp.ones((n, Dh), jnp.float32)})
+    if cfg.attn_gate:
+        out["wgate"] = _norm_init(jax.random.fold_in(key, 7), (n, D, H, Dh),
+                                  D)
+    return out
+
+
+def _init_patterned(key, cfg: TransformerConfig) -> dict:
+    """``{"dense_layers": ..., "layers": ...}`` of a per-layer pattern: the
+    leading dense layers' leaves and the expert layers', each stacked on a
+    leading axis (either may be missing). A held expert's weights are drawn
+    from its global number, so every share of the experts draws the same
+    expert the same."""
+    D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    n_lead = _n_lead(cfg)
+    sandwich = cfg.norm == "sandwich"
+
+    def common(k, n):
+        ks = jax.random.split(k, 4)
+        leaves = {
+            "ln1": jnp.ones((n, D), jnp.float32),
+            "wq": _norm_init(ks[0], (n, D, H, Dh), D),
+            "wk": _norm_init(ks[1], (n, D, Hkv, Dh), D),
+            "wv": _norm_init(ks[2], (n, D, Hkv, Dh), D),
+            "wo": _norm_init(ks[3], (n, H, Dh, D), H * Dh),
+            "ln2": jnp.ones((n, D), jnp.float32),
+            **_new_attn_leaves(k, cfg, n)}
+        if sandwich:
+            leaves.update({"ln1_post": jnp.ones((n, D), jnp.float32),
+                           "ln2_post": jnp.ones((n, D), jnp.float32)})
+        return leaves
+
+    def swiglu(k, lead, f, prefix=""):
+        ks = jax.random.split(k, 3)
+        return {prefix + "wg": _norm_init(ks[0], lead + (D, f), D),
+                prefix + "wu": _norm_init(ks[1], lead + (D, f), D),
+                prefix + "wd": _norm_init(ks[2], lead + (f, D), f)}
+
+    k_dense, k_moe = jax.random.split(key)
+    out = {}
+    if n_lead:
+        if cfg.ffn != "swiglu":
+            raise ValueError("a per-layer pattern's dense layers are SwiGLU")
+        out["dense_layers"] = {
+            **common(k_dense, n_lead),
+            **swiglu(jax.random.fold_in(k_dense, 1), (n_lead,), cfg.d_ff)}
+    n_moe = cfg.n_layers - n_lead
+    if n_moe:
+        E, Fe = cfg.n_experts, cfg.d_ff_expert
+        k_route, k_exp, k_shared = jax.random.split(
+            jax.random.fold_in(k_moe, 1), 3)
+
+        def expert(e):      # [n_moe, ...] leaves of global expert e
+            return swiglu(jax.random.fold_in(k_exp, e), (n_moe,), Fe, "e")
+
+        held = jax.vmap(expert, out_axes=1)(
+            cfg.first_expert + jnp.arange(cfg.held))
+        out["layers"] = {
+            **common(k_moe, n_moe),
+            "router": _norm_init(k_route, (n_moe, D, E), D),
+            "router_bias": jnp.zeros((n_moe, E), jnp.float32), **held}
+        if cfg.n_shared_experts:
+            out["layers"].update(swiglu(
+                k_shared, (n_moe,), cfg.n_shared_experts * Fe, "shared_"))
+    return out
 
 
 def init_params(key, cfg: TransformerConfig):
     """fp32 master params as a flat dict pytree. Layer params are stacked on a
-    leading n_layers axis so the forward can lax.scan over layers."""
+    leading n_layers axis so the forward can lax.scan over layers (under a
+    per-layer pattern two stacks: :func:`_init_patterned`)."""
     k_embed, k_layers, k_out = jax.random.split(key, 3)
-    D, H, Dh, F, L = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
-                      cfg.n_layers)
+    D, norm_init = cfg.d_model, _norm_init
+    stacks = (_init_patterned(k_layers, cfg) if cfg.layers
+              else {"layers": _init_layers(k_layers, cfg)})
+    params = {
+        "embed": norm_init(k_embed, (cfg.vocab_size, D), D) * (D ** 0.5) * 0.02,
+        **stacks,
+        "ln_f": jnp.ones((D,), jnp.float32),
+    }
+    # the fields below draw from keys the defaults never used, so a default
+    # configuration keeps its weights seed for seed
+    if not cfg.tie_embeddings:
+        params["lm_head"] = norm_init(k_out, (cfg.vocab_size, D), D)
+    if cfg.n_loops > 1:
+        params["exit_gate"] = {
+            "w": norm_init(jax.random.fold_in(k_out, 1), (D,), D),
+            "b": jnp.zeros((), jnp.float32)}
+    return params
 
-    def norm_init(k, shape, fan_in):
-        return jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)
 
+def _init_layers(k_layers, cfg: TransformerConfig) -> dict:
+    """The homogeneous stack's leaves, [n_layers, ...] each."""
+    D, H, Hkv, Dh, F, L = (cfg.d_model, cfg.n_heads, cfg.kv_heads,
+                           cfg.head_dim, cfg.d_ff, cfg.n_layers)
+    norm_init = _norm_init
     n_keys = 7 if cfg.use_moe else 6   # dense init stays seed-compatible
     ks = jax.random.split(k_layers, n_keys * L).reshape(L, n_keys, 2)
     layers = {
         "ln1": jnp.ones((L, D), jnp.float32),
         "wq": jnp.stack([norm_init(ks[i, 0], (D, H, Dh), D) for i in range(L)]),
-        "wk": jnp.stack([norm_init(ks[i, 1], (D, H, Dh), D) for i in range(L)]),
-        "wv": jnp.stack([norm_init(ks[i, 2], (D, H, Dh), D) for i in range(L)]),
-        "wo": jnp.stack([norm_init(ks[i, 3], (H, Dh, D), D) for i in range(L)]),
+        "wk": jnp.stack([norm_init(ks[i, 1], (D, Hkv, Dh), D)
+                         for i in range(L)]),
+        "wv": jnp.stack([norm_init(ks[i, 2], (D, Hkv, Dh), D)
+                         for i in range(L)]),
+        "wo": jnp.stack([norm_init(ks[i, 3], (H, Dh, D), H * Dh)
+                         for i in range(L)]),
         "ln2": jnp.ones((L, D), jnp.float32),
+        **_new_attn_leaves(k_layers, cfg, L),
     }
     if cfg.use_moe:
         E = cfg.n_experts
@@ -185,31 +393,33 @@ def init_params(key, cfg: TransformerConfig):
     if cfg.norm == "sandwich":
         layers.update({"ln1_post": jnp.ones((L, D), jnp.float32),
                        "ln2_post": jnp.ones((L, D), jnp.float32)})
-    params = {
-        "embed": norm_init(k_embed, (cfg.vocab_size, D), D) * (D ** 0.5) * 0.02,
-        "layers": layers,
-        "ln_f": jnp.ones((D,), jnp.float32),
-    }
-    # the fields below draw from keys the defaults never used, so a default
-    # configuration keeps its weights seed for seed
-    if not cfg.tie_embeddings:
-        params["lm_head"] = norm_init(k_out, (cfg.vocab_size, D), D)
-    if cfg.n_loops > 1:
-        params["exit_gate"] = {
-            "w": norm_init(jax.random.fold_in(k_out, 1), (D,), D),
-            "b": jnp.zeros((), jnp.float32)}
-    return params
+    return layers
 
 
-def param_specs(cfg: TransformerConfig):
-    """PartitionSpecs over (data, seq, tensor): heads/hidden sharded on tensor,
-    everything replicated over data+seq (their reduction happens in backward)."""
+def _layer_specs(cfg: TransformerConfig, experts: bool) -> dict:
+    """PartitionSpecs of one stack's leaves: the homogeneous stack's, a
+    pattern's leading dense layers' or its expert layers' (``experts``)."""
     layers = {
         "ln1": P(), "ln2": P(),
         "wq": P(None, None, TENSOR_AXIS), "wk": P(None, None, TENSOR_AXIS),
         "wv": P(None, None, TENSOR_AXIS), "wo": P(None, TENSOR_AXIS),
     }
-    if cfg.use_moe:
+    if cfg.qk_norm:
+        layers.update({"q_norm": P(), "k_norm": P()})
+    if cfg.attn_gate:
+        layers["wgate"] = P(None, None, TENSOR_AXIS)
+    if experts:
+        # every held expert's hidden dim split over tensor, like the dense
+        # FFN's; the router and its bias replicated
+        layers.update({"router": P(), "router_bias": P(),
+                       "ewg": P(None, None, None, TENSOR_AXIS),
+                       "ewu": P(None, None, None, TENSOR_AXIS),
+                       "ewd": P(None, None, TENSOR_AXIS)})
+        if cfg.n_shared_experts:
+            layers.update({"shared_wg": P(None, None, TENSOR_AXIS),
+                           "shared_wu": P(None, None, TENSOR_AXIS),
+                           "shared_wd": P(None, TENSOR_AXIS)})
+    elif cfg.use_moe:
         # experts sharded over the tensor axis (EP replaces TP for the FFN);
         # the router stays replicated
         layers.update({"router": P(),
@@ -224,7 +434,21 @@ def param_specs(cfg: TransformerConfig):
                        "w2": P(None, TENSOR_AXIS)})
     if cfg.norm == "sandwich":
         layers.update({"ln1_post": P(), "ln2_post": P()})
-    specs = {"embed": P(), "layers": layers, "ln_f": P()}
+    return layers
+
+
+def param_specs(cfg: TransformerConfig):
+    """PartitionSpecs over (data, seq, tensor): heads/hidden sharded on tensor,
+    everything replicated over data+seq (their reduction happens in backward)."""
+    specs = {"embed": P(), "ln_f": P()}
+    if cfg.layers:
+        n_lead = _n_lead(cfg)
+        if n_lead:
+            specs["dense_layers"] = _layer_specs(cfg, experts=False)
+        if cfg.n_layers > n_lead:
+            specs["layers"] = _layer_specs(cfg, experts=True)
+    else:
+        specs["layers"] = _layer_specs(cfg, experts=False)
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
     if cfg.n_loops > 1:
@@ -299,32 +523,47 @@ def _sum_in_backward(x, axes):
     return _summed_cotangent(x, axes) if axes else x
 
 
-def _attn_mix(x, wq, wk, wv, wo, *, cfg: TransformerConfig, rope=None,
+def _attn_mix(x, lp, *, cfg: TransformerConfig, rope=None,
               seq_size: Optional[int] = None,
               tensor_size: Optional[int] = None, causal: bool = True,
-              under_remat: bool = False):
-    """Attention over the normed ``x [B, T, D]``: the q/k/v projections,
-    RoPE by ``rope`` (:func:`_rope_tables`), the attention itself (ring or
-    Ulysses under sequence parallelism, the flash kernel, or materialized),
-    the output projection, and its psum over ``tensor`` inside a shard_map.
+              under_remat: bool = False, window: int = 0,
+              scope: Optional[str] = None):
+    """Attention over the normed ``x [B, T, D]`` with one layer's leaves
+    ``lp``: the q/k/v projections (``cfg.kv_heads`` K and V heads), q and k
+    normed over the head (``cfg.qk_norm``), RoPE by ``rope``
+    (:func:`_rope_tables`), the attention itself (ring or Ulysses under
+    sequence parallelism, the flash kernel, or materialized; ``window``:
+    :class:`LayerKind`), the output gate (``cfg.attn_gate``), the output
+    projection, and its psum over ``tensor`` inside a shard_map.
     ``under_remat``: a backward pass runs this again (the kernels then take
-    their smaller-VMEM variant). What ``remat="attention"`` checkpoints."""
+    their smaller-VMEM variant). ``scope`` names the attention call inside
+    ``attn``. What ``remat="attention"`` checkpoints."""
     dt = cfg.dtype
     # flash wants [B, H, T, K]; projecting straight into that layout keeps
     # the transposes out of the hot path (they fold into the einsums).
     # Under sequence parallelism ring/ulysses own the kernel
-    flash = cfg.attention == "flash" and (seq_size is None or seq_size <= 1)
+    sharded_seq = seq_size is not None and seq_size > 1
+    flash = cfg.attention == "flash" and not sharded_seq
     qkv_eq = "btd,dhk->bhtk" if flash else "btd,dhk->bthk"
-    q = jnp.einsum(qkv_eq, x, wq.astype(dt))
-    k = jnp.einsum(qkv_eq, x, wk.astype(dt))
-    v = jnp.einsum(qkv_eq, x, wv.astype(dt))
+    q = jnp.einsum(qkv_eq, x, lp["wq"].astype(dt))
+    k = jnp.einsum(qkv_eq, x, lp["wk"].astype(dt))
+    v = jnp.einsum(qkv_eq, x, lp["wv"].astype(dt))
+    if cfg.qk_norm:
+        with jax.named_scope(scopes.QK_NORM):
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     if rope is not None:
         with jax.named_scope(scopes.ROPE):
             cos, sin = rope
             if not flash:       # q, k are [B, T, H, K]
                 cos, sin = cos[:, None, :], sin[:, None, :]
             q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-    if seq_size is not None and seq_size > 1:
+    if sharded_seq:
+        if window or cfg.kv_heads != cfg.n_heads:
+            raise ValueError(
+                "ring and Ulysses attention (seq > 1) know no window and no "
+                f"grouped KV heads: got window={window}, n_kv_heads="
+                f"{cfg.kv_heads} of n_heads={cfg.n_heads}")
         if cfg.attention == "ulysses":
             if cfg.sp_layout == "zigzag" and causal:
                 raise ValueError(
@@ -337,13 +576,20 @@ def _attn_mix(x, wq, wk, wv, wo, *, cfg: TransformerConfig, rope=None,
             att = ring_attention_p(q, k, v, SEQ_AXIS, seq_size,
                                    causal=causal, layout=cfg.sp_layout,
                                    under_remat=under_remat)
-    elif flash:
-        att = flash_attention_local(q, k, v, causal=causal, layout="bhtk",
-                                    under_remat=under_remat)
     else:
-        att = local_attention(q, k, v, causal=causal)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            if flash:
+                att = flash_attention_local(
+                    q, k, v, causal=causal, layout="bhtk",
+                    under_remat=under_remat, window=window)
+            else:
+                att = local_attention(q, k, v, causal=causal, window=window)
+    if cfg.attn_gate:
+        with jax.named_scope(scopes.ATTN_GATE):
+            att = att * jax.nn.sigmoid(
+                jnp.einsum(qkv_eq, x, lp["wgate"].astype(dt)))
     out = jnp.einsum("bhtk,hkd->btd" if flash else "bthk,hkd->btd",
-                     att, wo.astype(dt))
+                     att, lp["wo"].astype(dt))
     if tensor_size is not None:
         out = lax.psum(out, TENSOR_AXIS)
     return out
@@ -358,27 +604,29 @@ def _residual(h, out, lp, post: str, cfg: TransformerConfig):
 
 
 def _attn_sublayer(h, lp, cfg: TransformerConfig, mix):
-    """``h + [N'] mix(N(h), wq, wk, wv, wo)`` of one layer's leaves ``lp``;
-    ``mix`` is :func:`_attn_mix` with its caller's arguments bound."""
+    """``h + [N'] mix(N(h), lp)`` of one layer's leaves ``lp``; ``mix`` is
+    :func:`_attn_mix` with its caller's arguments bound."""
     with jax.named_scope(scopes.ATTN):
-        out = mix(_rmsnorm(h, lp["ln1"], cfg.norm_eps),
-                  lp["wq"], lp["wk"], lp["wv"], lp["wo"])
+        out = mix(_rmsnorm(h, lp["ln1"], cfg.norm_eps), lp)
         return _residual(h, out, lp, "ln1_post", cfg)
 
 
-def _dense_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
+def _dense_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int],
+               prefix: str = "", reduce: bool = True):
     """``gelu(x w1) w2`` or ``(silu(x wg) * (x wu)) wd``, the hidden dim
-    split over ``tensor`` inside a shard_map."""
+    split over ``tensor`` inside a shard_map (``reduce``: summed over it
+    here). ``prefix``: the leaves' names begin with it (``"shared_"``: an
+    expert layer's shared experts, one SwiGLU as wide as all of them)."""
     dt = cfg.dtype
-    if cfg.ffn == "swiglu":
+    if cfg.ffn == "swiglu" or prefix:
         u = jax.nn.silu(jnp.einsum(
-            "btd,df->btf", x, lp["wg"].astype(dt))) * jnp.einsum(
-            "btd,df->btf", x, lp["wu"].astype(dt))
-        out = jnp.einsum("btf,fd->btd", u, lp["wd"].astype(dt))
+            "btd,df->btf", x, lp[prefix + "wg"].astype(dt))) * jnp.einsum(
+            "btd,df->btf", x, lp[prefix + "wu"].astype(dt))
+        out = jnp.einsum("btf,fd->btd", u, lp[prefix + "wd"].astype(dt))
     else:
         u = jax.nn.gelu(jnp.einsum("btd,df->btf", x, lp["w1"].astype(dt)))
         out = jnp.einsum("btf,fd->btd", u, lp["w2"].astype(dt))
-    if tensor_size is not None:
+    if tensor_size is not None and reduce:
         out = lax.psum(out, TENSOR_AXIS)
     return out
 
@@ -416,38 +664,99 @@ def _moe_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
     return y2d.reshape(b, t, d), aux
 
 
+def _expert_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
+    """The routed-expert FFN of a ``LayerKind.experts`` layer over the
+    normed ``x [B, T, D]``: ``(Shared(x) + sum over the chosen AND held
+    experts of w_e Expert_e(x), this layer's ExpertRoutes)``. The layer is told
+    which experts it holds (``cfg.first_expert``, the leading axis of
+    ``lp["ewg"]``); the chosen experts it does not hold add nothing here.
+    Under ``tensor`` every expert's hidden dim is split like the dense
+    FFN's, and the shared and the routed part are summed over it once.
+
+    On a SHARE of the experts (fewer held than the router has outputs, no
+    exchange) the routing weights are constants of the backward pass. What
+    a share could know of the gradient through them is one chip's term of a
+    sum over every chip that holds experts: it lacks the chosen experts
+    that are absent, each of which would pull its own score up against the
+    held ones', and a deployment applies the sum, never a term. Applied
+    alone, to the routers or (through ``x``) to the layers below, the term
+    moves the tokens onto the held experts step after step (v5e, PERF.md
+    section 6, PR 32: twice their even share within forty steps). The
+    selection bias still moves: its rule needs the counts only, and those
+    are whole."""
+    b, t, d = x.shape
+    tok = x.reshape(b * t, d)
+    route = topk_route(tok, lp["router"], lp["router_bias"], cfg.moe_top_k,
+                       cfg.route_scale, cfg.route_norm)
+    if cfg.held < cfg.n_experts:
+        route = route._replace(weight=lax.stop_gradient(route.weight))
+    out = topk_moe_held(tok, route, lp["ewg"], lp["ewu"], lp["ewd"],
+                        cfg.first_expert).reshape(b, t, d)
+    if cfg.n_shared_experts:
+        with jax.named_scope(scopes.SHARED_EXPERT):
+            out = out + _dense_ffn(x, lp, cfg, tensor_size, "shared_",
+                                   reduce=False)
+    if tensor_size is not None:
+        out = lax.psum(out, TENSOR_AXIS)
+    return out, ExpertRoutes(route.expert.reshape(b, t, -1), route.counts)
+
+
 def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
            tensor_size: Optional[int] = None, causal: bool = True,
-           under_remat: bool = False):
+           under_remat: bool = False, kinds: Tuple[LayerKind, ...] = ()):
     """One transformer layer as a scan body over the stacked layer leaves,
-    ``layer((h, aux_sum), lp) -> ((h, aux_sum), None)``, for every step
-    builder: the attention sublayer, then the dense (TP over the hidden dim)
-    or MoE (EP over the same axis) FFN sublayer, under ``cfg.remat``'s
-    checkpoints. The arguments are :func:`_attn_mix`'s."""
-    mix = functools.partial(_attn_mix, cfg=cfg, rope=rope, seq_size=seq_size,
-                            tensor_size=tensor_size, causal=causal,
-                            under_remat=under_remat)
-    if cfg.remat == "attention":
-        # backward recomputes q/k/v projections + attention from the normed
-        # input instead of saving them (prevent_cse is unnecessary inside
-        # scan, and disabling it lets XLA fuse the recompute cleanly)
-        mix = jax.checkpoint(mix, prevent_cse=False)
-    elif cfg.remat not in ("none", "block"):
+    ``layer((h, aux_sum), lp) -> ((h, aux_sum), routes)``, for every step
+    builder: the attention sublayer, then the dense (TP over the hidden dim),
+    MoE (EP over the same axis) or routed-expert FFN sublayer, under
+    ``cfg.remat``'s checkpoints. ``kinds``: under a per-layer pattern
+    (``cfg.layers``), the distinct kinds of the layers this body runs, all
+    with the same FFN; the body then takes ``(lp, which)``, ``which`` the
+    index into ``kinds`` of the layer at hand, and the attention call (its
+    window, its rotation) is a ``lax.switch`` on it. ``routes``: an expert
+    layer's :class:`ExpertRoutes`, else None. The other arguments are
+    :func:`_attn_mix`'s."""
+    experts = bool(kinds) and kinds[0].experts
+
+    def mix_of(kind: Optional[LayerKind]):
+        window, rotate, scope = (0, True, None) if kind is None else (
+            kind.window, kind.rope,
+            scopes.ATTN_WINDOW if kind.window else scopes.ATTN_FULL)
+        mix = functools.partial(
+            _attn_mix, cfg=cfg, rope=rope if rotate else None,
+            seq_size=seq_size, tensor_size=tensor_size, causal=causal,
+            under_remat=under_remat, window=window, scope=scope)
+        if cfg.remat == "attention":
+            # backward recomputes q/k/v projections + attention from the
+            # normed input instead of saving them (prevent_cse is unnecessary
+            # inside scan, and disabling it lets XLA fuse the recompute
+            # cleanly)
+            mix = jax.checkpoint(mix, prevent_cse=False)
+        return mix
+
+    if cfg.remat not in ("none", "block", "attention"):
         raise ValueError(f"unknown remat mode {cfg.remat!r}; "
                          f"expected 'none', 'block', or 'attention'")
+    mixes = [mix_of(kind) for kind in kinds] or [mix_of(None)]
 
     def layer(carry, lp):
         h, aux_sum = carry
+        routes, mix = None, mixes[0]
+        if kinds:       # a pattern's scan: (leaves, the layer's kind)
+            lp, which = lp
+            if len(mixes) > 1:
+                mix = lambda x, lp: lax.switch(which, mixes, x, lp)  # noqa: E731
         h = _attn_sublayer(h, lp, cfg, mix)
         with jax.named_scope(scopes.FFN):
             x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
-            if cfg.use_moe:
+            if experts:
+                out, routes = _expert_ffn(x, lp, cfg, tensor_size)
+            elif cfg.use_moe:
                 out, aux = _moe_ffn(x, lp, cfg, tensor_size)
                 aux_sum = aux_sum + aux
             else:
                 out = _dense_ffn(x, lp, cfg, tensor_size)
             h = _residual(h, out, lp, "ln2_post", cfg)
-        return (h, aux_sum), None
+        return (h, aux_sum), routes
 
     if cfg.remat == "block":
         # each scanned layer recomputes from its carry in backward: live
@@ -460,13 +769,46 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
 
 def _embed(params, tokens, cfg: TransformerConfig):
     with jax.named_scope(scopes.EMBED):
-        return params["embed"][tokens].astype(cfg.dtype)  # [B, T, D]
+        h = params["embed"][tokens]
+        if cfg.embed_scale != 1.0:
+            h = h * cfg.embed_scale
+        return h.astype(cfg.dtype)  # [B, T, D]
 
 
 def _final_norm(params, h, cfg: TransformerConfig):
     """What a pass over the stack ends in, and what the head takes."""
     with jax.named_scope(scopes.HEAD):
         return _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+
+
+def _run_pattern(params, h, cfg: TransformerConfig, block,
+                 layer_grad_axes):
+    """``cfg.layers`` over ``h``: the leading dense layers, then the expert
+    layers, each stack one ``lax.scan`` of the one block over its stacked
+    leaves and, beside them, every layer's index into the stack's distinct
+    kinds (``block(kinds)`` is :func:`_block`'s body for them). Returns
+    ``(h, routes)``: :class:`ExpertRoutes` over the expert layers, None
+    without any."""
+    n_lead = _n_lead(cfg)
+    carry, routes = (h, jnp.zeros((), jnp.float32)), None
+    with jax.named_scope(scopes.LAYERS):
+        for stack, kinds, grad_axes in (
+                ("dense_layers", cfg.layers[:n_lead], None),
+                ("layers", cfg.layers[n_lead:], layer_grad_axes)):
+            if not kinds:
+                continue
+            distinct = tuple(dict.fromkeys(kinds))
+            layer = block(distinct)
+            if grad_axes:   # as in _run_passes: fp32 leaves, outside the
+                #             checkpointed function
+                def layer(carry, xs, layer=layer, axes=grad_axes):
+                    return layer(carry, ({
+                        k: _sum_in_backward(v, axes[k])
+                        for k, v in xs[0].items()}, xs[1]))
+            which = jnp.array([distinct.index(kind) for kind in kinds],
+                              jnp.int32)
+            carry, routes = lax.scan(layer, carry, (params[stack], which))
+    return carry[0], routes
 
 
 def _run_passes(params, tokens, cfg: TransformerConfig,
@@ -476,9 +818,10 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     [B_local, T_local]: the embedding, then ``cfg.n_loops`` passes over the
     scanned stack with the same weights, each ended by the final norm.
     ``exit_fn(h)`` is what is kept of a pass's normed state ``h``. Returns
-    ``(exits, moe_aux_loss)``: ``exit_fn``'s result when there is one pass,
-    its results stacked on a leading [n_loops] axis otherwise; aux is 0 for
-    the dense FFN.
+    ``(exits, moe_aux_loss, routes)``: ``exit_fn``'s result when there is
+    one pass, its results stacked on a leading [n_loops] axis otherwise; aux
+    is 0 for the dense FFN; ``routes`` the :class:`ExpertRoutes` of this
+    shard's tokens, None without routed-expert layers.
 
     ``seq_size``/``tensor_size`` are the mesh-axis sizes when running inside
     shard_map (collectives are emitted whenever the axis is manual, even at
@@ -490,8 +833,20 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     inside the backward scan, as each layer's backward produces it.
     """
     h = _embed(params, tokens, cfg)
-    layer = _block(cfg, _rope_tables(cfg, tokens.shape[1], seq_size),
-                   seq_size, tensor_size, causal,
+    rope = _rope_tables(cfg, tokens.shape[1], seq_size)
+    if cfg.layers:
+        h, routes = _run_pattern(
+            params, h, cfg, functools.partial(
+                _block, cfg, rope, seq_size, tensor_size, causal,
+                cfg.remat != "none"), layer_grad_axes)
+        if cfg.remat == "block":
+            # the exit recomputes from the normed state, as under n_loops >
+            # 1 below: the logits and their cotangent are not kept while
+            # the layers' backward runs
+            exit_fn = jax.checkpoint(exit_fn, prevent_cse=False)
+        return (exit_fn(_final_norm(params, h, cfg)),
+                jnp.zeros((), jnp.float32), routes)
+    layer = _block(cfg, rope, seq_size, tensor_size, causal,
                    under_remat=cfg.remat != "none")
 
     if layer_grad_axes:
@@ -510,7 +865,7 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     aux0 = jnp.zeros((), jnp.float32)
     if cfg.n_loops == 1:
         h, aux_sum = one_pass(h, aux0)
-        return exit_fn(h), aux_sum / cfg.n_layers
+        return exit_fn(h), aux_sum / cfg.n_layers, None
 
     if cfg.remat == "block":
         # the exit recomputes from the pass's normed state as the layers do
@@ -526,7 +881,7 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     with jax.named_scope(scopes.LOOP):
         (_, aux_sum), exits = lax.scan(loop_body, (h, aux0), None,
                                        length=cfg.n_loops)
-    return exits, aux_sum / (cfg.n_layers * cfg.n_loops)
+    return exits, aux_sum / (cfg.n_layers * cfg.n_loops), None
 
 
 def _head(params, h, cfg: TransformerConfig):
@@ -569,8 +924,8 @@ def _forward(params, tokens, cfg: TransformerConfig,
              tensor_size: Optional[int] = None, causal: bool = True):
     """Forward over a *local* token block [B_local, T_local]; returns
     (the last pass's logits in ``cfg.dtype``, moe_aux_loss)."""
-    h, aux = _run_passes(params, tokens, cfg, seq_size, tensor_size, causal,
-                         lambda h: h)
+    h, aux, _ = _run_passes(params, tokens, cfg, seq_size, tensor_size,
+                            causal, lambda h: h)
     return _head(params, h if cfg.n_loops == 1 else h[-1], cfg), aux
 
 
@@ -588,18 +943,44 @@ def forward_exits(params, tokens, cfg: TransformerConfig):
     exit kept: ``(logits [n_loops, B, T, V] in cfg.dtype, p [n_loops, B,
     T])``, the head after each pass and the exit distribution the loss
     weights them by."""
-    (logits, z), _ = _run_passes(
+    (logits, z), _, _ = _run_passes(
         params, tokens, cfg, None, None, True,
         lambda h: (_head(params, h, cfg), _gate_logit(params, h)))
     return logits, jnp.exp(_exit_log_probs(z))
+
+
+def forward_routes(params, tokens, cfg: TransformerConfig):
+    """Single-shard forward of a model with routed-expert layers: ``(logits
+    [B, T, V] in cfg.dtype, ExpertRoutes)``, every expert layer's choices
+    and counts beside what they led to."""
+    h, _, routes = _run_passes(params, tokens, cfg, None, None, True,
+                               lambda h: h)
+    return _head(params, h, cfg), routes
+
+
+def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
+    """What the step's ``expert_counts`` [expert layers, n_experts] say of
+    ``n_tokens`` tokens, as ``examples/transformer_lm.py`` logs them on the
+    gauges ``hvd_tpu_moe_*``: by layer the share of the ``n_tokens x
+    moe_top_k`` assignments that land on the held experts (``held /
+    n_experts`` under an even router) and the fullest expert's load over
+    the mean load; and the assignments no expert was counted for (0: the
+    layer drops nothing)."""
+    counts = np.asarray(counts, np.float64)
+    total = n_tokens * cfg.moe_top_k
+    held = counts[:, cfg.first_expert:cfg.first_expert + cfg.held]
+    return {"held_share": (held.sum(axis=1) / total).tolist(),
+            "load_max_over_mean": (counts.max(axis=1)
+                                   / counts.mean(axis=1)).tolist(),
+            "dropped": float(np.abs(total - counts.sum(axis=1)).sum())}
 
 
 @functools.partial(jax.jit, static_argnames="cfg")
 def exit_distribution(params, tokens, cfg: TransformerConfig):
     """Mean exit probability of every pass over the tokens, [n_loops]: the
     one number that says whether the exit gate has collapsed onto a pass."""
-    z, _ = _run_passes(params, tokens, cfg, None, None, True,
-                       lambda h: _gate_logit(params, h))
+    z, _, _ = _run_passes(params, tokens, cfg, None, None, True,
+                          lambda h: _gate_logit(params, h))
     return jnp.mean(jnp.exp(_exit_log_probs(z)), axis=(1, 2))
 
 
@@ -649,13 +1030,14 @@ def _mean_xent(logits, targets):
     return jnp.mean(_lean_xent(logits, targets))
 
 
-def _local_loss(params, inputs, targets, cfg, seq_size=None, tensor_size=None,
-                grad_axes=None):
-    """(sum over the local tokens of what each pays, their number, aux): the
-    cross-entropy of the one exit, or under ``n_loops > 1`` every exit's,
-    weighted by the gate (:func:`_exit_loss`). ``grad_axes``: the leaves
-    whose gradients :func:`make_train_step` sums inside the backward pass,
-    each with its mesh axes."""
+def _local_loss_and_routes(params, inputs, targets, cfg, seq_size=None,
+                           tensor_size=None, grad_axes=None):
+    """(sum over the local tokens of what each pays, their number, aux,
+    routes): the cross-entropy of the one exit, or under ``n_loops > 1``
+    every exit's, weighted by the gate (:func:`_exit_loss`); ``routes`` is
+    :func:`_run_passes`'s. ``grad_axes``: the leaves whose gradients
+    :func:`make_train_step` sums inside the backward pass, each with its
+    mesh axes."""
     grad_axes = grad_axes or {}
 
     def exit_fn(h):
@@ -667,11 +1049,17 @@ def _local_loss(params, inputs, targets, cfg, seq_size=None, tensor_size=None,
         nll = _lean_xent(_head(head, h, cfg), targets)
         return nll if cfg.n_loops == 1 else (nll, _gate_logit(params, h))
 
-    exits, aux = _run_passes(params, inputs, cfg, seq_size, tensor_size, True,
-                             exit_fn, grad_axes.get("layers"))
+    exits, aux, routes = _run_passes(params, inputs, cfg, seq_size,
+                                     tensor_size, True, exit_fn,
+                                     grad_axes.get("layers"))
     per_token = exits if cfg.n_loops == 1 else _exit_loss(
         *exits, cfg.exit_entropy_weight)
-    return jnp.sum(per_token), per_token.size, aux
+    return jnp.sum(per_token), per_token.size, aux, routes
+
+
+def _local_loss(*args, **kwargs):
+    """:func:`_local_loss_and_routes` without the routes."""
+    return _local_loss_and_routes(*args, **kwargs)[:3]
 
 
 def lean_lm_loss(params, inputs, targets, cfg: TransformerConfig):
@@ -828,13 +1216,28 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
     the final norm and (``n_loops > 1``) everything after the backward, each
     leaf its own psum. The state stays replicated and the optimizer is
     called on whole gradients. On a mesh with no axis to sum over no psum
-    and no wrapper is traced."""
+    and no wrapper is traced.
+
+    With routed-expert layers (``cfg.has_experts``) the step returns a
+    fourth value, ``{"expert_counts": [expert layers, n_experts] int32}``:
+    the assignments of the step's tokens, over the whole mesh, to every
+    expert. From them it moves each layer's selection bias
+    (``params["layers"]["router_bias"]``) by
+    :func:`~horovod_tpu.parallel.moe.router_bias_update` at
+    ``cfg.router_bias_rate``: the bias is state the step carries in the
+    parameter tree and outside the optimizer. Its gradient is zero, and
+    whatever ``optimizer`` makes of the leaf (adamw: weight decay) is
+    replaced by the bias before the step plus that update.
+    On a SHARE of the experts (``0 < cfg.experts_held < cfg.n_experts``, no
+    exchange) the routers take no gradient, and nothing flows back through
+    the routing weights: see :func:`_expert_ffn`."""
     d_size, s_size, t_size = _mesh_sizes(mesh)
     specs = param_specs(cfg)
     tok_spec = P(DATA_AXIS, SEQ_AXIS)
     axes = grad_reduce_axes(mesh, cfg)
     early = _in_backward(axes, cfg)
     late = {k: a for k, a in axes.items() if k not in early}
+    routed = cfg.has_experts
 
     def body(params, inputs, targets):
         def local(p):
@@ -842,31 +1245,43 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
             # check_vma=False a psum transposes to a psum, so the mesh-wide
             # sums stay out of the differentiated function (the 1/t_size is
             # what the transpose of its pmean over tensor leaves here)
-            total, count, aux = _local_loss(p, inputs, targets, cfg,
-                                            s_size, t_size, early)
+            total, count, aux, routes = _local_loss_and_routes(
+                p, inputs, targets, cfg, s_size, t_size, early)
             n = count * d_size * s_size
             objective = total / n
             if cfg.use_moe:
                 objective = objective + cfg.moe_aux_weight * aux / (
                     d_size * s_size)
-            return objective / t_size, (total, n, aux)
+            return objective / t_size, (total, n, aux,
+                                        routes.counts if routed else None)
 
-        (_, (total, n, aux)), grads = jax.value_and_grad(
+        (_, (total, n, aux, counts)), grads = jax.value_and_grad(
             local, has_aux=True)(params)
         grads = {**grads, **jax.tree_util.tree_map(
             _sum_grad, {k: grads[k] for k in late}, late)}
-        return _mesh_loss(total, n, aux, cfg), grads
+        loss = _mesh_loss(total, n, aux, cfg)
+        if not routed:
+            return loss, grads
+        if d_size * s_size > 1:     # every shard's tokens
+            counts = lax.psum(counts, (DATA_AXIS, SEQ_AXIS))
+        return loss, grads, counts
 
     # check_vma=False for the reason given in make_spmd_loss
-    grad_fn = jax.shard_map(body, mesh=mesh,
-                            in_specs=(specs, tok_spec, tok_spec),
-                            out_specs=(P(), specs), check_vma=False)
+    grad_fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(specs, tok_spec, tok_spec),
+        out_specs=(P(), specs) + ((P(),) if routed else ()), check_vma=False)
 
     def train_step(params, opt_state, inputs, targets):     # scopes.TRAIN_STEP
-        loss, grads = grad_fn(params, inputs, targets)
+        loss, grads, *counts = grad_fn(params, inputs, targets)
+        bias = params["layers"]["router_bias"] if routed else None
         params, opt_state = scopes.apply_update(optimizer, grads, opt_state,
                                                 params)
-        return params, opt_state, loss
+        if not routed:
+            return params, opt_state, loss
+        params = {**params, "layers": {
+            **params["layers"], "router_bias": router_bias_update(
+                bias, counts[0], cfg.router_bias_rate)}}
+        return params, opt_state, loss, {"expert_counts": counts[0]}
 
     return jax.jit(train_step, donate_argnums=(0, 1),
                    compiler_options=_overlap_compiler_options(mesh))
@@ -876,9 +1291,19 @@ PIPE_AXIS = "pipe"
 
 def _refuse_loop_and_untied_head(cfg: TransformerConfig, builder: str) -> None:
     """The pipeline's first and last stage and MoE-EP's loss segment run one
-    pass over the stack into a head tied to the embedding: the exits after
-    every pass, the exit gate and an ``lm_head`` leaf have no stage or
-    segment there yet."""
+    pass over ONE homogeneous stack into a head tied to the embedding: the
+    exits after every pass, the exit gate, an ``lm_head`` leaf and a
+    per-layer pattern (its window layers, its routed-expert layers and
+    their selection bias, its two stacks of leaves) have no stage or
+    segment there yet. The block's other fields (KV heads, head size, q/k
+    norm, the output gate, the embedding's multiplier) run there as in
+    :func:`make_train_step`: they are the one ``_block``'s."""
+    if cfg.layers:
+        raise ValueError(
+            f"{builder} runs one homogeneous stack: got a per-layer pattern "
+            f"(layers, {len(cfg.layers)} kinds), whose dense and expert "
+            f"stacks, windows and selection bias have no stage here. "
+            f"make_train_step (dp/tp) runs them.")
     if cfg.n_loops > 1:
         raise ValueError(
             f"{builder} runs one pass over the stack: got "

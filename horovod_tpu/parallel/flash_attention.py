@@ -6,9 +6,12 @@ single-shard compute kernel: on TPU it calls the Pallas attention kernels
 shipped with JAX (blockwise online-softmax: O(T) memory, wholly masked
 blocks skipped): splash at the blocks :func:`splash_geometry` chose for
 square attention over a multiple of 1024 positions with heads a multiple of
-128, the older flash kernel for the other aligned shapes; off TPU it computes
-the materialized reference attention so CPU tests exercise the same call
-sites. :func:`attention_kernel` says which of them a shape reaches. On TPU
+128, causal or banded (a sliding ``window``), with as many KV heads as query
+heads or fewer (then the kernel's MQA form over each KV head's group of
+query heads: K and V are never repeated in HBM); the older flash kernel for
+the other aligned shapes; off TPU it computes the materialized reference
+attention so CPU tests exercise the same call sites.
+:func:`attention_kernel` says which of them a shape reaches. On TPU
 the stock kernel modules are imported unguarded: a jax that moved them is an
 ImportError at the first trace, never a silent change of kernel.
 
@@ -83,8 +86,8 @@ class SplashBlocks(NamedTuple):
     block_kv_dkv_compute: int
 
 
-def splash_geometry(t: int, d: int, causal: bool,
-                    under_remat: bool) -> SplashBlocks:
+def splash_geometry(t: int, d: int, causal: bool, under_remat: bool,
+                    window: int = 0) -> SplashBlocks:
     """The blocks for square attention over ``t`` positions (a multiple of
     1024: :func:`_splash_ok`) with heads of ``d``. Chosen on the v5e by
     ``tools/attn_sweep.py`` (PERF.md section 6, PR 31: every block in 512,
@@ -114,13 +117,26 @@ def splash_geometry(t: int, d: int, causal: bool,
       forward kv block of 2048 does not fit either, so the backward's q
       block is 512 and every kv block 1024.
 
+    Under a band (``window``: key ``j`` visible from query ``i`` iff ``0 <=
+    i - j < window``; PR 32, same tool, 1 x 32/4 x 8192 x 128 under
+    ``jax.checkpoint``, 32 query heads over 4 KV heads through the kernel's
+    MQA form, window 2048 / causal; ms forward, forward + forward +
+    backward): the causal blocks again, 2.61, 9.51 / 4.45, 14.55, against
+    2.60, 11.11 / 5.38, 17.59 for 512 blocks everywhere (a band of 2048
+    runs 5 kv blocks of 512 a q block where 3 of 1024 do, and skips no more
+    by it than the grid steps cost), 2.94, 9.85 / 4.91, 15.02 for compute
+    slices of 1024, 3.20, 10.85 / 4.54, 14.82 for kv blocks of 2048, and
+    10.48 / 17.76 with dq in a kernel of its own: the fused backward's dq
+    partials (8 copies of dq at T = 8192) still cost less than a second pass
+    over the scores. So ``window`` changes nothing here either.
+
     Not causal (in no cell: ViT's lengths never reach the kernel; 4 x 16 x
     2048 x 128 in the same sweep): no block is masked, a smaller kv block
     skips nothing, so the kv block is 2048 where it divides ``t``, in
     compute slices of 1024, and the backward is fused there too: 1.06 ms
     forward, 3.12 forward + backward, against 1.14 and 4.03 for the blocks
     before PR 31 and 1.18 and 3.32 for the causal blocks."""
-    del under_remat     # one answer on this backend: see above
+    del under_remat, window     # one answer on this backend: see above
     wide = d > 128
     kv = 1024 if causal or wide or t % 2048 else 2048
     return SplashBlocks(block_q=1024, block_kv=kv,
@@ -129,42 +145,51 @@ def splash_geometry(t: int, d: int, causal: bool,
                         block_kv_dkv_compute=1024)
 
 
-def _select_kernel(q_shape, kv_shape) -> str:
+def _select_kernel(q_shape, kv_shape, window: int = 0) -> str:
     """"splash", "flash" or "materialized" for q and k/v of [B, H, T, D] on
     this backend. Materialized attention off the TPU and for sequence
     lengths the kernels' 128-row blocks do not divide (ViT's 197 and 17
     tokens); splash for what :func:`_splash_ok` admits; the stock flash
     kernel for the rest (rectangular q/kv, T not a multiple of 1024, heads
-    not a multiple of 128) and with ``HOROVOD_SPLASH`` off."""
+    not a multiple of 128) and with ``HOROVOD_SPLASH`` off. The stock flash
+    kernel knows no window and no grouped KV heads: what splash refuses of
+    those is materialized."""
     if not flash_available() or q_shape[2] % 128 or kv_shape[2] % 128:
         return "materialized"
     if splash_available() and _splash_ok(q_shape, kv_shape):
         return "splash"
-    return "flash"
+    return "materialized" if window or q_shape[1] != kv_shape[1] else "flash"
 
 
 def attention_kernel(q_shape, kv_shape, causal: bool = True,
-                     under_remat: bool = False) -> dict:
+                     under_remat: bool = False, window: int = 0) -> dict:
     """What :func:`flash_attention_local` runs for q and k/v of [B, H, T, D]
     on this backend, as the labels of the gauge ``hvd_tpu_attn_kernel``:
     ``kernel`` ("splash", "flash", "materialized"), the forward's
-    ``block_q`` and ``block_kv`` and whether the backward is one fused
-    kernel. A function of the shapes, ``causal`` and ``under_remat`` alone;
-    the call itself dispatches on it."""
-    kernel = _select_kernel(q_shape, kv_shape)
+    ``block_q`` and ``block_kv``, whether the backward is one fused kernel
+    and the ``window`` (0: none). A function of the shapes, ``causal``,
+    ``under_remat`` and ``window`` alone; the call itself dispatches on
+    it."""
+    kernel = _select_kernel(q_shape, kv_shape, window)
     if kernel == "splash":
-        g = splash_geometry(q_shape[2], q_shape[3], causal, under_remat)
+        g = splash_geometry(q_shape[2], q_shape[3], causal, under_remat,
+                            window)
         blocks = (g.block_q, g.block_kv, True)
     elif kernel == "flash":
         blocks = (_flash_block(q_shape[2], kv_shape[2]),) * 2 + (False,)
     else:
         blocks = (0, 0, False)
     return {"kernel": kernel, "block_q": str(blocks[0]),
-            "block_kv": str(blocks[1]), "fused_bwd": str(int(blocks[2]))}
+            "block_kv": str(blocks[1]), "fused_bwd": str(int(blocks[2])),
+            "window": str(window)}
 
 
 @functools.lru_cache(maxsize=32)
-def _splash_kernel(h: int, t: int, d: int, causal: bool, under_remat: bool):
+def _splash_kernel(h: int, t: int, d: int, causal: bool, under_remat: bool,
+                   window: int = 0, grouped: bool = False):
+    """The stock splash kernel over ``h`` query heads: one K and V a head
+    (``make_splash_mha``), or with ``grouped`` ONE K and V for all ``h`` of
+    them (``make_splash_mqa``: a KV head and its group of query heads)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     # Kernel construction may run inside a jit trace (shapes are only known
@@ -172,21 +197,28 @@ def _splash_kernel(h: int, t: int, d: int, causal: bool, under_remat: bool):
     # tracers — the lru_cache would otherwise leak a tracer into later
     # traces (observed as UnexpectedTracerError on the second trace).
     with jax.ensure_compile_time_eval():
-        mk = sm.CausalMask if causal else sm.FullMask
-        mask = sm.MultiHeadMask([mk((t, t)) for _ in range(h)])
+        if window:
+            if not causal:
+                raise ValueError("a window is causal: key j is visible "
+                                 "from query i iff 0 <= i - j < window")
+            one = sm.LocalMask((t, t), (window - 1, 0), 0)
+        else:
+            one = (sm.CausalMask if causal else sm.FullMask)((t, t))
+        mask = sm.MultiHeadMask([one for _ in range(h)])
         bs = sk.BlockSizes(
             use_fused_bwd_kernel=True,
-            **splash_geometry(t, d, causal, under_remat)._asdict())
-        return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                                  block_sizes=bs)
+            **splash_geometry(t, d, causal, under_remat, window)._asdict())
+        make = sk.make_splash_mqa if grouped else sk.make_splash_mha
+        return make(mask, head_shards=1, q_seq_shards=1, block_sizes=bs)
 
 
 def _splash_ok(q_shape, kv_shape) -> bool:
-    _, _, t, d = q_shape
+    _, h, t, d = q_shape
     # square attention only: the mask is built (t, t); rectangular q/kv
     # (cross-attention, chunked decode) falls back to the flash kernel
     return (t >= 1024 and t % 1024 == 0 and d % 128 == 0
-            and kv_shape[2] == t and kv_shape[3] == d)
+            and kv_shape[2] == t and kv_shape[3] == d
+            and h % kv_shape[1] == 0)
 
 
 def _flash_block(q_t: int, kv_t: int) -> int:
@@ -215,7 +247,7 @@ def _warn_unaligned_once(q_t: int, kv_t: int) -> None:
 
 def flash_attention_local(q, k, v, causal: bool = True,
                           layout: str = "bthk",
-                          under_remat: bool = False):
+                          under_remat: bool = False, window: int = 0):
     """Attention via the stock Pallas TPU kernels (:func:`attention_kernel`
     says which); materialized attention off-TPU and (with a one-time
     warning) for sequence lengths the kernels' 128-row blocks do not divide.
@@ -224,7 +256,10 @@ def flash_attention_local(q, k, v, causal: bool = True,
     native layout — callers that can project straight into it skip the
     transposes). ``under_remat=True`` says this call sits inside a
     jax.checkpoint region whose backward runs it again; the geometry may
-    depend on it (:func:`splash_geometry`: on this backend it does not)."""
+    depend on it (:func:`splash_geometry`: on this backend it does not).
+    ``window`` > 0: key ``j`` is visible from query ``i`` iff ``0 <= i - j
+    < window``. ``k`` and ``v`` may have fewer heads than ``q``, a divisor
+    of its number: query head ``n`` reads KV head ``n // (H / H_kv)``."""
     if layout not in ("bthk", "bhtk"):
         raise ValueError(f"unknown attention layout {layout!r}")
     bhtk = layout == "bhtk"
@@ -235,7 +270,7 @@ def flash_attention_local(q, k, v, causal: bool = True,
     def as_bhtk(shape):
         return shape if bhtk else (shape[0], shape[2], shape[1], shape[3])
 
-    kernel = _select_kernel(as_bhtk(q.shape), as_bhtk(k.shape))
+    kernel = _select_kernel(as_bhtk(q.shape), as_bhtk(k.shape), window)
     if kernel == "materialized":
         # The Pallas kernels want both sequence lengths divisible by their
         # blocks (128 at least); unaligned lengths (ViT-B/16 at 224px -> 197
@@ -245,15 +280,26 @@ def flash_attention_local(q, k, v, causal: bool = True,
             _warn_unaligned_once(as_bhtk(q.shape)[2], as_bhtk(k.shape)[2])
         if bhtk:
             return swap(local_attention(swap(q), swap(k), swap(v),
-                                        causal=causal))
-        return local_attention(q, k, v, causal=causal)
+                                        causal=causal, window=window))
+        return local_attention(q, k, v, causal=causal, window=window)
     if not bhtk:
         q, k, v = swap(q), swap(k), swap(v)
     scale = 1.0 / math.sqrt(q.shape[-1])
     if kernel == "splash":
-        splash = _splash_kernel(q.shape[1], q.shape[2], q.shape[3], causal,
-                                under_remat)
-        out = jax.vmap(splash)((q * scale).astype(q.dtype), k, v)
+        b, h, t, d = q.shape
+        q = (q * scale).astype(q.dtype)
+        if k.shape[1] == h:
+            splash = _splash_kernel(h, t, d, causal, under_remat, window)
+            out = jax.vmap(splash)(q, k, v)
+        else:
+            # a KV head and its group of query heads a call: [B, H_kv, G, T,
+            # D] against [B, H_kv, T, D]
+            group = h // k.shape[1]
+            splash = _splash_kernel(group, t, d, causal, under_remat, window,
+                                    grouped=True)
+            out = jax.vmap(jax.vmap(splash))(
+                q.reshape(b, k.shape[1], group, t, d), k, v
+            ).reshape(b, h, t, d)
     else:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as _fa)

@@ -1,4 +1,17 @@
-"""Expert parallelism: Switch-style top-1 MoE with all-to-all dispatch.
+"""Expert parallelism: two MoE layers, each as three functions (route and
+pack, the held experts' FFN, combine) with the exchange between them left to
+the caller.
+
+1. Switch-style top-1 with capacity slots and all-to-all dispatch
+   (:func:`moe_dispatch`, :func:`moe_experts`, :func:`moe_combine`;
+   :func:`moe_layer_p` puts ``lax.all_to_all`` between them), below.
+2. Sigmoid top-k with no capacity and no drop, SwiGLU experts and a selection
+   bias that balances the load without an auxiliary loss
+   (:func:`topk_route`, :func:`topk_dispatch`, :func:`grouped_swiglu`,
+   :func:`topk_combine`; :func:`topk_moe_held` runs them on ONE chip's
+   share of the experts with no exchange), at the end of this file.
+
+The first, in this module's first words:
 
 SURVEY §2.8: the reference has no EP, "but **alltoall** — EP's transport
 primitive — is first-class" (operations.cc:951, NCCLAlltoall). This module
@@ -16,12 +29,16 @@ The auxiliary load-balancing loss is the standard fraction·probability dot.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..common import scopes
+from .flash_attention import flash_available
 
 
 class MoEParams(NamedTuple):
@@ -160,3 +177,225 @@ def moe_layer_p(x, params: MoEParams, axis_name: str, axis_size: int,
                     params.w_in, params.w_out)
     return moe_combine(exchange(y).reshape(disp.shape), route.expert,
                        route.slot, route.weight), aux
+
+
+# --------------------------------------------------------------------------
+# Sigmoid top-k routing without drops (DeepSeek-V3-style: arXiv:2412.19437
+# section 2.1.2; the bias update: arXiv:2408.15664).
+
+
+class TopKRoute(NamedTuple):
+    """Where :func:`topk_route` sent every token."""
+    expert: jax.Array   # [T, k] int32: the experts a token chose
+    weight: jax.Array   # [T, k] fp32: what each choice's output is scaled by
+    counts: jax.Array   # [E] int32: assignments to each expert, all E
+
+
+def topk_route(x, router, bias, k: int, route_scale: float = 1.0,
+               route_norm: bool = True) -> TopKRoute:
+    """``s = sigmoid(float32(x) router)`` over all ``E`` experts; the choice
+    is the ``k`` largest of ``s + bias``; the weights are ``s`` of the chosen
+    (``bias`` enters the choice only, and takes no gradient), divided by
+    their sum under ``route_norm``, times ``route_scale``. fp32 at the
+    highest matmul precision: a near-tie should fall as it does in exact
+    arithmetic."""
+    with jax.named_scope(scopes.ROUTER):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, expert = lax.top_k(s + lax.stop_gradient(bias), k)
+        w = jnp.take_along_axis(s, expert, axis=-1)
+        if route_norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        counts = jnp.zeros((router.shape[1],), jnp.int32).at[
+            expert.reshape(-1)].add(1)
+        return TopKRoute(expert.astype(jnp.int32), w * route_scale, counts)
+
+
+class TopKPacked(NamedTuple):
+    """:func:`topk_dispatch`'s buffer and how to undo it."""
+    rows: jax.Array         # [R, d]: tokens in order of their expert
+    group_sizes: jax.Array  # [held] int32: rows of each held expert
+    token: jax.Array        # [R] int32: the token a row came from
+    weight: jax.Array       # [R] fp32: its choice's weight, 0 past the last
+    #                         held assignment
+
+
+def topk_order(route: TopKRoute, first_expert: int, experts_held: int):
+    """``(order [T x k], group_sizes [held])``: the ``T x k`` assignments
+    (token-major, ``t * k + j``) sorted by expert, those of the held experts
+    ``first_expert .. first_expert + experts_held - 1`` first, and how many
+    each held expert got."""
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        local = route.expert.reshape(-1) - first_expert
+        held = jnp.logical_and(local >= 0, local < experts_held)
+        order = jnp.argsort(jnp.where(held, local, experts_held),
+                            stable=True)
+        return order, lax.dynamic_slice_in_dim(route.counts, first_expert,
+                                               experts_held)
+
+
+def topk_dispatch(x, weight, order, group_sizes, start,
+                  n_rows: int) -> TopKPacked:
+    """Rows ``start .. start + n_rows - 1`` of the sorted assignments
+    (:func:`topk_order`) gathered from ``x [T, d]``, each with its
+    ``weight [T, k]``, and the part of every held expert's group that falls
+    among them. This is the dispatch
+    exchange's send side; with one chip's share and no exchange, its
+    whole."""
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        k = weight.shape[1]
+        ends = jnp.cumsum(group_sizes)
+        cut = lambda edge: jnp.clip(edge - start, 0, n_rows)  # noqa: E731
+        picked = lax.dynamic_slice_in_dim(order, start, n_rows)
+        token = (picked // k).astype(jnp.int32)
+        live = start + jnp.arange(n_rows) < ends[-1]
+        weight = jnp.where(live, weight.reshape(-1)[picked], 0.0)
+        # (the where cuts a dead row's cotangent too: the grouped kernels
+        # compute nothing there, in either pass)
+        return TopKPacked(jnp.where(live[:, None], x[token], 0),
+                          cut(ends) - cut(ends - group_sizes), token, weight)
+
+
+# (m, k, n) tiles of the megablox kernel: tools/grouped_matmul_sweep.py on the
+# v5e (PERF.md section 6, PR 32), the three products of grouped_swiglu
+# forward + backward over 5,120 rows of 8 experts / 10,240 of 16: 1.70 / 3.50
+# ms against 1.69 / 3.84 at (128, 1024, 1024), 1.93 / 3.92 at (512, 1024,
+# 1024), and 2.27 / 5.01 for lax.ragged_dot
+GMM_TILING = (256, 1024, 1024)
+
+
+def grouped_matmul(rows, w, group_sizes):
+    """``rows[g's rows] @ w[g]`` for every group ``g``, rows in order of
+    their group. On the TPU the installed megablox ``gmm`` (custom calls
+    ``gmm`` forward and for the rows' gradient, ``tgmm`` for the weights'),
+    where its row tile divides the buffer; ``lax.ragged_dot`` elsewhere
+    (libtpu's own ``ragged-dot`` kernel on the TPU, a quarter slower there:
+    see ``GMM_TILING``). What either leaves in the rows past the last group
+    is not to be read: :func:`topk_dispatch` and :func:`topk_combine` cut
+    them on both passes."""
+    w = w.astype(rows.dtype)
+    if flash_available() and rows.shape[0] % GMM_TILING[0] == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        return megablox.gmm(rows, w, group_sizes, rows.dtype, tuple(
+            min(tile, size) for tile, size in zip(
+                GMM_TILING, (rows.shape[0],) + w.shape[1:])))
+    return lax.ragged_dot(rows, w, group_sizes)
+
+
+def grouped_swiglu(rows, group_sizes, wg, wu, wd):
+    """The held experts' FFN over the dispatch buffer, ``(silu(x wg_e) *
+    (x wu_e)) wd_e`` for the rows of each expert ``e``: three grouped
+    matrix products. ``wg``, ``wu`` [held, d, f]; ``wd`` [held, f, d]."""
+    with jax.named_scope(scopes.EXPERTS):
+        u = jax.nn.silu(grouped_matmul(rows, wg, group_sizes)) \
+            * grouped_matmul(rows, wu, group_sizes)
+        return grouped_matmul(u, wd, group_sizes)
+
+
+def topk_combine(y, packed: TopKPacked, n_tokens: int):
+    """Every token's weighted sum of its held experts' outputs ``y [R, d]``,
+    ``[T, d]`` accumulated in fp32; nothing for a row past the last held
+    assignment, zero for a token none of whose rows is here."""
+    with jax.named_scope(scopes.MOE_COMBINE):
+        # (0 * junk is not 0: such rows are cut by where, not by weight)
+        scaled = jnp.where(packed.weight[:, None] != 0.0,
+                           y.astype(jnp.float32) * packed.weight[:, None],
+                           0.0)
+        return jnp.zeros((n_tokens, y.shape[1]), jnp.float32).at[
+            packed.token].add(scaled)
+
+
+# the dispatch buffer's rows over an even router's share of the assignments:
+# of 48 readings of the benchmark cell's layers (3 seeds, 4 batches, 4
+# layers; v5e, PR 32) the held experts drew 0.51 to 2.10 times their even
+# share
+BUFFER_FACTOR = 2.5
+
+
+def topk_buffer_rows(n_tokens: int, k: int, n_experts: int,
+                     experts_held: int) -> int:
+    """Rows of the dispatch buffer: ``BUFFER_FACTOR`` times an even router's
+    share of the ``T x k`` assignments, in multiples of 512 and at most all
+    of them."""
+    even = n_tokens * k * experts_held / n_experts
+    return min(n_tokens * k,
+               -(-int(math.ceil(even * BUFFER_FACTOR)) // 512) * 512)
+
+
+def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
+    """One chip's share of the routed experts, no exchange: the sum over the
+    held experts ``first_expert .. first_expert + wg.shape[0] - 1`` of
+    ``weight_e Expert_e(x)`` for every token that chose them, ``[T, d]`` in
+    ``x``'s dtype; what the absent experts would add is left out.
+
+    No assignment is ever dropped, and the buffer is never ``T x k`` rows:
+    it holds ``BUFFER_FACTOR`` times an even router's share
+    (:func:`topk_buffer_rows`), and the sorted assignments go through it a
+    buffer at a time, as many buffers as they fill: one wherever the held
+    experts draw less than ``BUFFER_FACTOR`` times their even share; the
+    further buffers are the no-drop guarantee under any routing, and what
+    the tests force.
+    That is a ``lax.while_loop`` on each pass, under a ``custom_vjp`` whose
+    backward pass runs a buffer again and pulls the cotangent back through
+    it, buffer by buffer: the layer keeps its inputs and nothing else, and
+    a buffer that is not needed costs nothing. (Differentiated through, a
+    scan with a ``lax.cond`` a buffer handed out every weight's zero
+    cotangent and a [T, d] of zeros for each buffer it did not run: 80 ms of
+    a 350 ms step on the v5e, PERF.md PR 32.)"""
+    t, k = route.expert.shape
+    held = wg.shape[0]
+    # cast once, ahead of every buffer: the loop then carries the weights'
+    # cotangents in the compute dtype, not three fp32 copies
+    wg, wu, wd = (w.astype(x.dtype) for w in (wg, wu, wd))
+    n_rows = topk_buffer_rows(t, k, route.counts.shape[0], held)
+    order, group_sizes = topk_order(route, first_expert, held)
+    # whole buffers: a slice past the end would be moved back over rows
+    # already taken (the padding is past the last held assignment: dead)
+    order = jnp.pad(order, (0, -(t * k) % n_rows))
+
+    def buffer(start, order, group_sizes, x, weight, wg, wu, wd):
+        packed = topk_dispatch(x, weight, order, group_sizes, start, n_rows)
+        y = grouped_swiglu(packed.rows, packed.group_sizes, wg, wu, wd)
+        return topk_combine(y, packed, t)
+
+    def over_the_buffers(group_sizes, body, init):
+        """``body(start, carry)`` for the start of every buffer the held
+        assignments reach: at least the first."""
+        n_held = jnp.sum(group_sizes)
+        return lax.while_loop(
+            lambda c: jnp.logical_or(c[0] == 0, c[0] < n_held),
+            lambda c: (c[0] + n_rows, body(c[0], c[1])),
+            (jnp.zeros((), n_held.dtype), init))[1]
+
+    @jax.custom_vjp
+    def run(order, group_sizes, *args):
+        return over_the_buffers(
+            group_sizes,
+            lambda start, out: out + buffer(start, order, group_sizes,
+                                            *args),
+            jnp.zeros((t, x.shape[1]), jnp.float32))
+
+    def run_bwd(kept, g):
+        order, group_sizes, *args = kept
+
+        def pull_back(start, sums):
+            got = jax.vjp(functools.partial(buffer, start, order,
+                                            group_sizes), *args)[1](g)
+            return jax.tree_util.tree_map(jnp.add, sums, got)
+
+        return (None, None) + over_the_buffers(
+            group_sizes, pull_back,
+            tuple(jnp.zeros_like(a) for a in args))
+
+    run.defvjp(lambda *kept: (run(*kept), kept), run_bwd)
+    return run(order, group_sizes, x, route.weight, wg, wu, wd).astype(
+        x.dtype)
+
+
+def router_bias_update(bias, counts, rate: float):
+    """The auxiliary-loss-free balance step (arXiv:2408.15664): ``b_e +=
+    rate * sign(mean(c) - c_e)`` from the step's assignments ``c`` to every
+    expert. ``bias``, ``counts`` [..., E]."""
+    c = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)

@@ -529,16 +529,29 @@ def zigzag_pair_kinds(rank: int, owner: int, n: int):
             ("hi", "lo"): k3(a_hi, b_lo), ("hi", "hi"): k3(a_hi, b_hi)}
 
 
-def local_attention(q, k, v, causal: bool = True):
+def local_attention(q, k, v, causal: bool = True, window: int = 0):
     """Single-device reference attention (same layout), for tests and the
-    non-SP path: [B, T, H, D] -> [B, T, H, D]."""
+    non-SP path: [B, T, H, D] -> [B, T, H, D]. ``window`` > 0: key ``j`` is
+    visible from query ``i`` iff ``0 <= i - j < window``. ``k`` and ``v``
+    may have fewer heads than ``q``: query head ``n`` reads KV head ``n //
+    (H / H_kv)``, and K and V are not repeated."""
     B, T, H, D = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(D)
+    grouped = k.shape[2] != H
+    if grouped:     # [B, T, H_kv, G, D]: a KV head's group of query heads
+        q = q.reshape(B, T, k.shape[2], H // k.shape[2], D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk" if grouped else "bqhd,bkhd->bhqk",
+                   q, k, preferred_element_type=jnp.float32) / math.sqrt(D)
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window:
+            mask = jnp.logical_and(mask, ~jnp.tril(mask, -window))
         s = jnp.where(mask[None, None], s, _NEG_INF)
+    elif window:
+        raise ValueError("a window is causal")
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+    out = jnp.einsum("bhgqk,bkhd->bqhgd" if grouped else "bhqk,bkhd->bqhd",
+                     p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    if grouped:
+        out = out.reshape(B, T, H, D)
     return out.astype(q.dtype)

@@ -223,7 +223,8 @@ def test_which_kernel_a_shape_reaches(on_tpu, monkeypatch, q, kv, mode, want):
     for under_remat in (False, True):
         labels = fa.attention_kernel(q, kv, True, under_remat)
         assert labels["kernel"] == want
-        assert set(labels) == {"kernel", "block_q", "block_kv", "fused_bwd"}
+        assert set(labels) == {"kernel", "block_q", "block_kv", "fused_bwd",
+                               "window"}
         if want == "flash":
             block = "1024" if q[2] % 1024 == 0 == kv[2] % 1024 else "128"
             assert (labels["block_q"], labels["fused_bwd"]) == (block, "0")
@@ -235,7 +236,7 @@ def test_off_the_tpu_attention_is_materialized_and_the_gauge_is_declared():
     sq = (4, 16, 2048, 128)
     assert fa.attention_kernel(sq, sq) == {
         "kernel": "materialized", "block_q": "0", "block_kv": "0",
-        "fused_bwd": "0"}
+        "fused_bwd": "0", "window": "0"}
     assert METRIC_SPECS["hvd_tpu_attn_kernel"][0] == "gauge"
 
 

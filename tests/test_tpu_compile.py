@@ -39,6 +39,22 @@ LOOPED = tfm.TransformerConfig(
     max_seq=4096, dtype=jnp.bfloat16, attention="flash", remat="block",
     positions="rope", rope_theta=1e6, ffn="swiglu", norm="sandwich",
     norm_eps=1e-6, tie_embeddings=False, n_loops=4)
+# trinity-spmd-1chip-ep8share-8k's own program but for its depth (1 dense
+# + 2 of the cell's 4 expert layers, a window layer and the full one): the
+# widths of benchmark/configs/trinity-mini.json, 32 query heads over 4 KV
+# heads of 128, window 2048, 8 of 128 experts held, top 8, one row of 8192
+# tokens under remat="block"
+_KIND = tfm.LayerKind
+SPARSE = tfm.TransformerConfig(
+    vocab_size=25024, d_model=2048, n_heads=32, n_kv_heads=4, head_size=128,
+    n_layers=3, d_ff=6144, max_seq=8192, dtype=jnp.bfloat16,
+    attention="flash", remat="block", positions="rope", rope_theta=1e4,
+    ffn="swiglu", norm="sandwich", norm_eps=1e-5, tie_embeddings=False,
+    qk_norm=True, attn_gate=True, embed_scale=2048 ** 0.5,
+    layers=(_KIND(2048, True, False), _KIND(2048, True, True),
+            _KIND(0, False, True)),
+    n_experts=128, moe_top_k=8, d_ff_expert=1024, n_shared_experts=1,
+    route_scale=2.826, experts_held=8, router_bias_rate=1e-3)
 
 
 @pytest.fixture(scope="module")
@@ -170,6 +186,49 @@ def test_the_attention_kernels_fit_and_the_backward_is_one_kernel(
             "splash_mha_dkv_no_residuals"} <= calls, calls
     assert not [c for c in calls
                 if c.startswith("splash_mha_dq") or "flash" in c], calls
+
+
+def test_the_sparse_expert_step_compiles_with_its_kernels(topo,
+                                                         pallas_branch):
+    """The new cell's step (depth 1 + 2) for the v5e: the window layers and
+    the full layer both through splash's MQA form (a KV head and its group
+    of 8 query heads a call: K and V are not repeated), forward and the ONE
+    fused backward kernel under recomputation, inside scoped VMEM; the held
+    experts' products through the megablox kernels; no stock flash kernel,
+    no dq kernel, no ragged-dot fallback."""
+    text = _compiled_step(topo, (1, 1, 1), SPARSE, 1)
+    calls = set(re.findall(r"%((?:splash|flash|gmm|tgmm|ragged)[\w\-]*?)"
+                           r"(?:\.\d+)? = ", text))
+    assert {"splash_mqa_fwd_residuals", "splash_mqa_dkv_no_residuals",
+            "gmm", "tgmm"} <= calls, calls
+    assert not [c for c in calls if "_dq" in c or "flash" in c
+                or "mha" in c or "ragged" in c], calls
+    # K and V reach the kernels with their 4 heads, never as 32
+    kv = re.findall(r"%splash_mqa_fwd_residuals[.\d]* = .*", text)
+    assert kv and all("bf16[4,8192,128]" in line or
+                      "bf16[1,4,8192,128]" in line for line in kv), kv[0][:400]
+
+
+@pytest.mark.parametrize("window", [2048, 0], ids=["band", "causal"])
+def test_the_grouped_attention_call_alone_fits(topo, pallas_branch, window):
+    """1 x 32/4 x 8192 x 128 under ``jax.checkpoint``, the banded and the
+    causal call of the sparse-expert cell by themselves."""
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.parallel import flash_attention as fa
+
+    @jax.checkpoint
+    def attn(q, k, v):
+        return fa.flash_attention_local(q, k, v, layout="bhtk",
+                                        under_remat=True, window=window)
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=chip)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert "splash_mqa_dkv_no_residuals" in text
+    assert "splash_mqa_dq" not in text and "splash_mha" not in text
 
 
 @pytest.mark.parametrize("rows, t, d, causal, remat", [

@@ -18,9 +18,12 @@ Three stages, the first without a chip:
 
 Shapes: ``lm`` is 4 x 16 x 2048 x 128, differentiated plainly; ``loop`` is
 1 x 16 x 4096 x 128 under ``jax.checkpoint``, so its backward runs the
-forward again, as the looped cell's does; ``short`` (8 rows of 1024) and
-``full`` (``lm`` without the causal mask) are in no cell and are measured
-on ``--geometry``'s alone. ``--rehearse`` runs the same code interpreted on
+forward again, as the looped cell's does; ``band`` and ``gqa`` (ISSUE 32)
+are 1 x 32/4 x 8192 x 128 under ``jax.checkpoint``, 32 query heads over 4
+KV heads through the kernel's MQA form a KV head, ``band`` under a window
+of 2048 and ``gqa`` causal: the two kinds of layer of the sparse-expert
+cell; ``short`` (8 rows of 1024) and ``full`` (``lm`` without the causal
+mask) are in no cell and are measured on ``--geometry``'s alone. ``--rehearse`` runs the same code interpreted on
 the CPU at T = 256: a test of the script, never a time.
 
     python tools/attn_sweep.py fit --out .bench_tree/attn_fit.json
@@ -49,13 +52,22 @@ import jax.numpy as jnp
 import numpy as np
 
 HEADS, HEAD = 16, 128
+# "heads", "kv_heads" (default HEADS of each) and "window" (default none)
 SHAPES = {"lm": {"rows": 4, "t": 2048, "remat": False, "causal": True},
           "loop": {"rows": 1, "t": 4096, "remat": True, "causal": True},
+          "band": {"rows": 1, "t": 8192, "remat": True, "causal": True,
+                   "heads": 32, "kv_heads": 4, "window": 2048},
+          "gqa": {"rows": 1, "t": 8192, "remat": True, "causal": True,
+                  "heads": 32, "kv_heads": 4},
           # in no cell; measured on --geometry's alone
           "short": {"rows": 8, "t": 1024, "remat": False, "causal": True},
           "full": {"rows": 4, "t": 2048, "remat": False, "causal": False}}
 REHEARSAL = {"lm": {"rows": 2, "t": 256, "remat": False, "causal": True},
              "loop": {"rows": 1, "t": 256, "remat": True, "causal": True},
+             "band": {"rows": 1, "t": 256, "remat": True, "causal": True,
+                      "heads": 4, "kv_heads": 2, "window": 64},
+             "gqa": {"rows": 1, "t": 256, "remat": True, "causal": True,
+                     "heads": 4, "kv_heads": 2},
              "short": {"rows": 2, "t": 128, "remat": False, "causal": True},
              "full": {"rows": 2, "t": 256, "remat": False, "causal": False}}
 BLOCKS = (512, 1024, 2048)
@@ -92,9 +104,13 @@ def triples(blocks, t):
             if c <= kv and kv % c == 0 and max(q, kv) <= t]
 
 
-def attention(geo: Geometry, t: int, causal: bool, interpret: bool):
-    """q, k, v [rows, HEADS, t, HEAD] -> the attention, built as
-    ``flash_attention_local`` builds it but for the geometry."""
+def attention(geo: Geometry, shape: dict, interpret: bool):
+    """q [rows, heads, t, HEAD], k, v [rows, kv_heads, t, HEAD] -> the
+    attention, built as ``flash_attention_local`` builds it but for the
+    geometry."""
+    t, causal = shape["t"], shape["causal"]
+    heads = shape.get("heads", HEADS)
+    group = heads // shape.get("kv_heads", heads)
     scale = 1.0 / math.sqrt(HEAD)
     if geo.kernel == "flash":
         from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -115,18 +131,34 @@ def attention(geo: Geometry, t: int, causal: bool, interpret: bool):
         block_q_dkv=geo.dkv[0], block_kv_dkv=geo.dkv[1],
         block_kv_dkv_compute=geo.dkv[2], use_fused_bwd_kernel=geo.fused, **dq)
     with jax.ensure_compile_time_eval():
-        mk = sm.CausalMask if causal else sm.FullMask
-        mask = sm.MultiHeadMask([mk((t, t))] * HEADS)
-        kernel = sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                                    block_sizes=bs, interpret=interpret)
-    return lambda q, k, v: jax.vmap(kernel)(
-        (q * scale).astype(q.dtype), k, v)
+        if shape.get("window"):
+            one = sm.LocalMask((t, t), (shape["window"] - 1, 0), 0)
+        else:
+            one = (sm.CausalMask if causal else sm.FullMask)((t, t))
+        if group == 1:
+            kernel = sk.make_splash_mha(
+                sm.MultiHeadMask([one] * heads), head_shards=1,
+                q_seq_shards=1, block_sizes=bs, interpret=interpret)
+            return lambda q, k, v: jax.vmap(kernel)(
+                (q * scale).astype(q.dtype), k, v)
+        kernel = sk.make_splash_mqa(
+            sm.MultiHeadMask([one] * group), head_shards=1, q_seq_shards=1,
+            block_sizes=bs, interpret=interpret)
+
+    def grouped(q, k, v):   # a KV head and its group of query heads a call
+        b, h = q.shape[:2]
+        q = (q * scale).astype(q.dtype).reshape(
+            (b, h // group, group) + q.shape[2:])
+        return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(
+            (b, h) + q.shape[3:])
+
+    return grouped
 
 
 def programs(geo: Geometry, shape: dict, interpret: bool):
     """(forward alone, gradient of a weighted sum of the output in q, k, v):
     both jitted, both of (q, k, v, w)."""
-    attn = attention(geo, shape["t"], shape["causal"], interpret)
+    attn = attention(geo, shape, interpret)
     body = jax.checkpoint(attn) if shape["remat"] else attn
 
     def loss(q, k, v, w):
@@ -140,12 +172,17 @@ def programs(geo: Geometry, shape: dict, interpret: bool):
 def reference_grads(shape: dict):
     """The same gradient from a float32 materialized attention."""
     t = shape["t"]
+    group = shape.get("heads", HEADS) // shape.get("kv_heads", HEADS)
 
     def loss(q, k, v, w):
         q, k, v, w = (x.astype(jnp.float32) for x in (q, k, v, w))
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(HEAD)
         if shape["causal"]:
-            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+            seen = jnp.tril(jnp.ones((t, t), bool))
+            if shape.get("window"):
+                seen = seen & ~jnp.tril(seen, -shape["window"])
+            s = jnp.where(seen, s, -jnp.inf)
         out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
         return jnp.sum(out * w)
 
@@ -157,12 +194,16 @@ def reference_grads(shape: dict):
 
 
 def inputs(shape: dict, seed: int, sharding=None):
-    dims = (shape["rows"], HEADS, shape["t"], HEAD)
+    heads = shape.get("heads", HEADS)
+    dims = [(shape["rows"], h, shape["t"], HEAD)    # q, k, v, w
+            for h in (heads,) + (shape.get("kv_heads", heads),) * 2
+            + (heads,)]
     if sharding is not None:    # a described chip holds no array
-        return [jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=sharding)
-                ] * 4
-    return [jax.random.normal(k, dims, jnp.float32).astype(jnp.bfloat16)
-            for k in jax.random.split(jax.random.PRNGKey(seed), 4)]
+        return [jax.ShapeDtypeStruct(d, jnp.bfloat16, sharding=sharding)
+                for d in dims]
+    return [jax.random.normal(k, d, jnp.float32).astype(jnp.bfloat16)
+            for k, d in zip(jax.random.split(jax.random.PRNGKey(seed), 4),
+                            dims)]
 
 
 def stage_candidates(shape: dict, blocks) -> dict:
