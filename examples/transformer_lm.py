@@ -254,6 +254,7 @@ def main():
                                     args.batch * args.seq)
             for name, gauge in (
                     ("held_share", "hvd_tpu_moe_held_assignment_share"),
+                    ("buffer_fill", "hvd_tpu_moe_buffer_fill"),
                     ("load_max_over_mean",
                      "hvd_tpu_moe_expert_load_max_over_mean")):
                 for layer, value in enumerate(routing[name]):

@@ -223,6 +223,11 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "step, the share that landed on the experts this program "
                  "holds, by expert layer (held / experts under an even "
                  "router; what the absent experts would do is left out)"),
+    "hvd_tpu_moe_buffer_fill": (
+        "gauge", "The held experts' assignments of the last logged step "
+                 "over the rows of the dispatch buffer, by expert layer "
+                 "(0.4 under an even router; the row sums' work follows "
+                 "it; past 1 a second buffer ran)"),
     "hvd_tpu_moe_expert_load_max_over_mean": (
         "gauge", "The fullest expert's assignments over the mean over all "
                  "experts, last logged step, by expert layer (1.0: even; "

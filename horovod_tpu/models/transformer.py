@@ -39,7 +39,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..common import scopes
 from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
                             moe_dispatch, moe_experts, moe_layer_p,
-                            router_bias_update, topk_moe_held, topk_route)
+                            router_bias_update, topk_buffer_rows,
+                            topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
@@ -964,12 +965,16 @@ def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
     gauges ``hvd_tpu_moe_*``: by layer the share of the ``n_tokens x
     moe_top_k`` assignments that land on the held experts (``held /
     n_experts`` under an even router) and the fullest expert's load over
-    the mean load; and the assignments no expert was counted for (0: the
-    layer drops nothing)."""
+    the mean load; the held assignments over the rows of the dispatch
+    buffer for ``n_tokens`` tokens (what the row sums' work follows; past
+    1 a second buffer ran); and the assignments no expert was counted for
+    (0: the layer drops nothing)."""
     counts = np.asarray(counts, np.float64)
     total = n_tokens * cfg.moe_top_k
     held = counts[:, cfg.first_expert:cfg.first_expert + cfg.held]
     return {"held_share": (held.sum(axis=1) / total).tolist(),
+            "buffer_fill": (held.sum(axis=1) / topk_buffer_rows(
+                n_tokens, cfg.moe_top_k, cfg.n_experts, cfg.held)).tolist(),
             "load_max_over_mean": (counts.max(axis=1)
                                    / counts.mean(axis=1)).tolist(),
             "dropped": float(np.abs(total - counts.sum(axis=1)).sum())}

@@ -204,12 +204,20 @@ def topk_route(x, router, bias, k: int, route_scale: float = 1.0,
             x.astype(jnp.float32), router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         _, expert = lax.top_k(s + lax.stop_gradient(bias), k)
-        w = jnp.take_along_axis(s, expert, axis=-1)
+        # the chosen scores and the counts by comparison, not by index: one
+        # non-zero term a sum, so take_along_axis and a scatter-add of ones
+        # to the bit, in two fused passes over [T, k, E]; by index the v5e
+        # takes 8-9 ns an element (PERF.md section 6, PR 33)
+        chosen = expert[..., None] == jnp.arange(router.shape[1])
+        w = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
         if route_norm:
+            # (behind a barrier the sum over the chosen stays the reduction
+            # over [T, k] it was: merged into the one over E above it adds
+            # in another order, and the weights' last bit moves)
+            w = lax.optimization_barrier(w)
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        counts = jnp.zeros((router.shape[1],), jnp.int32).at[
-            expert.reshape(-1)].add(1)
-        return TopKRoute(expert.astype(jnp.int32), w * route_scale, counts)
+        return TopKRoute(expert.astype(jnp.int32), w * route_scale,
+                         jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32))
 
 
 class TopKPacked(NamedTuple):
@@ -222,39 +230,89 @@ class TopKPacked(NamedTuple):
 
 
 def topk_order(route: TopKRoute, first_expert: int, experts_held: int):
-    """``(order [T x k], group_sizes [held])``: the ``T x k`` assignments
-    (token-major, ``t * k + j``) sorted by expert, those of the held experts
-    ``first_expert .. first_expert + experts_held - 1`` first, and how many
-    each held expert got."""
+    """``(token [T x k], weight [T x k], group_sizes [held])``: the token
+    and the weight of each of the ``T x k`` assignments, sorted by expert
+    (and by token within one), those of the held experts ``first_expert ..
+    first_expert + experts_held - 1`` first, and how many each held expert
+    got. The sort carries what would be gathered after it."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        local = route.expert.reshape(-1) - first_expert
+        local = route.expert - first_expert
         held = jnp.logical_and(local >= 0, local < experts_held)
-        order = jnp.argsort(jnp.where(held, local, experts_held),
-                            stable=True)
-        return order, lax.dynamic_slice_in_dim(route.counts, first_expert,
-                                               experts_held)
+        _, token, weight = lax.sort(
+            (jnp.where(held, local, experts_held).reshape(-1),
+             lax.broadcasted_iota(jnp.int32, local.shape, 0).reshape(-1),
+             route.weight.reshape(-1)), num_keys=1, is_stable=True)
+        return token, weight, lax.dynamic_slice_in_dim(
+            route.counts, first_expert, experts_held)
 
 
-def topk_dispatch(x, weight, order, group_sizes, start,
+@jax.custom_vjp
+def rows_from_tokens(x, token, n_live):
+    """``x[token]``: the buffer's rows ``[R, d]`` from the tokens ``x [T,
+    d]``. Its transpose is :func:`tokens_from_rows`, and that one's is this:
+    the form of the sum is chosen there, for the combine and for the
+    dispatch's backward pass alike. ``n_live``: see there."""
+    return x[token]
+
+
+# rows a scatter-add of tokens_from_rows: the v5e adds a row of 2,048 in
+# 105-140 ns, dead or live, dropped or not, its indices sorted or not
+# (tools/grouped_matmul_sweep.py --mode rows; PERF.md section 6, PR 33), so
+# what is left to choose is how many rows; at 2,048 a call the carried sum is
+# copied every time
+ROW_CHUNK = 1024
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def tokens_from_rows(rows, token, n_live, n_tokens: int):
+    """``rows [R, d]`` summed into the tokens they came from, ``[n_tokens,
+    d]`` in ``rows``' dtype: the first ``n_live`` rows, ``ROW_CHUNK`` at a
+    time (the rest are past the last held assignment and hold zeros: a
+    chunk of them costs nothing, as a buffer that is not needed does)."""
+    chunk = math.gcd(rows.shape[0], ROW_CHUNK)
+
+    def add(c):
+        return c[0] + chunk, c[1].at[
+            lax.dynamic_slice_in_dim(token, c[0], chunk)].add(
+                lax.dynamic_slice_in_dim(rows, c[0], chunk))
+
+    return lax.while_loop(
+        lambda c: c[0] < n_live, add,
+        (jnp.zeros((), n_live.dtype),
+         jnp.zeros((n_tokens, rows.shape[1]), rows.dtype)))[1]
+
+
+rows_from_tokens.defvjp(
+    # (x for its shape alone)
+    lambda x, token, n_live: (x[token], (x, token, n_live)),
+    lambda kept, g: (tokens_from_rows(g, kept[1], kept[2], kept[0].shape[0]),
+                     None, None))
+tokens_from_rows.defvjp(
+    lambda rows, token, n_live, n_tokens: (
+        tokens_from_rows(rows, token, n_live, n_tokens), (token, n_live)),
+    lambda n_tokens, kept, g: (rows_from_tokens(g, *kept), None, None))
+
+
+def topk_dispatch(x, token, weight, group_sizes, start,
                   n_rows: int) -> TopKPacked:
     """Rows ``start .. start + n_rows - 1`` of the sorted assignments
-    (:func:`topk_order`) gathered from ``x [T, d]``, each with its
-    ``weight [T, k]``, and the part of every held expert's group that falls
-    among them. This is the dispatch
-    exchange's send side; with one chip's share and no exchange, its
-    whole."""
+    (:func:`topk_order`'s ``token`` and ``weight``) gathered from ``x [T,
+    d]``, and the part of every held expert's group that falls among them.
+    This is the dispatch exchange's send side; with one chip's share and no
+    exchange, its whole."""
     with jax.named_scope(scopes.MOE_DISPATCH):
-        k = weight.shape[1]
         ends = jnp.cumsum(group_sizes)
         cut = lambda edge: jnp.clip(edge - start, 0, n_rows)  # noqa: E731
-        picked = lax.dynamic_slice_in_dim(order, start, n_rows)
-        token = (picked // k).astype(jnp.int32)
-        live = start + jnp.arange(n_rows) < ends[-1]
-        weight = jnp.where(live, weight.reshape(-1)[picked], 0.0)
+        token = lax.dynamic_slice_in_dim(token, start, n_rows)
+        live = jnp.arange(n_rows) < cut(ends[-1])
+        weight = jnp.where(
+            live, lax.dynamic_slice_in_dim(weight, start, n_rows), 0.0)
         # (the where cuts a dead row's cotangent too: the grouped kernels
         # compute nothing there, in either pass)
-        return TopKPacked(jnp.where(live[:, None], x[token], 0),
-                          cut(ends) - cut(ends - group_sizes), token, weight)
+        return TopKPacked(
+            jnp.where(live[:, None],
+                      rows_from_tokens(x, token, cut(ends[-1])), 0),
+            cut(ends) - cut(ends - group_sizes), token, weight)
 
 
 # (m, k, n) tiles of the megablox kernel: tools/grouped_matmul_sweep.py on the
@@ -302,8 +360,8 @@ def topk_combine(y, packed: TopKPacked, n_tokens: int):
         scaled = jnp.where(packed.weight[:, None] != 0.0,
                            y.astype(jnp.float32) * packed.weight[:, None],
                            0.0)
-        return jnp.zeros((n_tokens, y.shape[1]), jnp.float32).at[
-            packed.token].add(scaled)
+        return tokens_from_rows(scaled, packed.token,
+                                jnp.sum(packed.group_sizes), n_tokens)
 
 
 # the dispatch buffer's rows over an even router's share of the assignments:
@@ -349,13 +407,14 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
     # cotangents in the compute dtype, not three fp32 copies
     wg, wu, wd = (w.astype(x.dtype) for w in (wg, wu, wd))
     n_rows = topk_buffer_rows(t, k, route.counts.shape[0], held)
-    order, group_sizes = topk_order(route, first_expert, held)
+    token, weight, group_sizes = topk_order(route, first_expert, held)
     # whole buffers: a slice past the end would be moved back over rows
     # already taken (the padding is past the last held assignment: dead)
-    order = jnp.pad(order, (0, -(t * k) % n_rows))
+    token, weight = (jnp.pad(a, (0, -(t * k) % n_rows))
+                     for a in (token, weight))
 
-    def buffer(start, order, group_sizes, x, weight, wg, wu, wd):
-        packed = topk_dispatch(x, weight, order, group_sizes, start, n_rows)
+    def buffer(start, token, group_sizes, x, weight, wg, wu, wd):
+        packed = topk_dispatch(x, token, weight, group_sizes, start, n_rows)
         y = grouped_swiglu(packed.rows, packed.group_sizes, wg, wu, wd)
         return topk_combine(y, packed, t)
 
@@ -369,18 +428,18 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
             (jnp.zeros((), n_held.dtype), init))[1]
 
     @jax.custom_vjp
-    def run(order, group_sizes, *args):
+    def run(token, group_sizes, *args):
         return over_the_buffers(
             group_sizes,
-            lambda start, out: out + buffer(start, order, group_sizes,
+            lambda start, out: out + buffer(start, token, group_sizes,
                                             *args),
             jnp.zeros((t, x.shape[1]), jnp.float32))
 
     def run_bwd(kept, g):
-        order, group_sizes, *args = kept
+        token, group_sizes, *args = kept
 
         def pull_back(start, sums):
-            got = jax.vjp(functools.partial(buffer, start, order,
+            got = jax.vjp(functools.partial(buffer, start, token,
                                             group_sizes), *args)[1](g)
             return jax.tree_util.tree_map(jnp.add, sums, got)
 
@@ -389,8 +448,7 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
             tuple(jnp.zeros_like(a) for a in args))
 
     run.defvjp(lambda *kept: (run(*kept), kept), run_bwd)
-    return run(order, group_sizes, x, route.weight, wg, wu, wd).astype(
-        x.dtype)
+    return run(token, group_sizes, x, weight, wg, wu, wd).astype(x.dtype)
 
 
 def router_bias_update(bias, counts, rate: float):

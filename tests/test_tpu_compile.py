@@ -258,3 +258,29 @@ def test_the_attention_call_alone_fits(topo, pallas_branch, rows, t, d,
         (0, 1, 2))).lower(x, x, x).compile().as_text()
     assert "splash_mha_dkv_no_residuals" in text
     assert "splash_mha_dq" not in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_row_sum_as_a_one_hot_product_fits(topo, dtype):
+    """Form (f) of ``tools/grouped_matmul_sweep.py --mode rows`` at the
+    sparse-expert cell's shape (10,240 rows of 2,048 into 8,192 tokens):
+    measured on the chip (PERF.md section 6, PR 33) and not shipped; the
+    megablox ``tgmm`` over tiles of 256 tokens at (512, 256, 1024), float32
+    rows as two bfloat16 parts side by side."""
+    import importlib.util
+    from jax.sharding import SingleDeviceSharding
+    spec = importlib.util.spec_from_file_location(
+        "grouped_matmul_sweep", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "grouped_matmul_sweep.py"))
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    chip = SingleDeviceSharding(topo.devices[0])
+    text = jax.jit(lambda rows, token, n_live: sweep.onehot_tgmm(False)(
+        rows, token, n_live, 8192)).lower(
+            jax.ShapeDtypeStruct((10240, 2048), dtype, sharding=chip),
+            jax.ShapeDtypeStruct((10240,), jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    ).compile().as_text()
+    assert re.search(r"%tgmm[.\d]* = ", text)

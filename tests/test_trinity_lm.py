@@ -16,6 +16,7 @@ check (``benchmark/configs/trinity-mini*.py``), read on the chip.
 import dataclasses
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -201,6 +202,18 @@ def test_the_step_returns_the_counts_and_moves_the_bias_by_them(stepped):
     want = old + np.float32(1e-3) * np.sign(
         counts.mean(axis=1, keepdims=True) - counts).astype(np.float32)
     assert np.array_equal(np.asarray(new["layers"]["router_bias"]), want)
+
+
+def test_the_gauges_of_where_the_router_sent_the_step():
+    """``routing_stats`` of hand-made counts: a share of 2 of 8 experts,
+    256 tokens, top 2, a buffer of 512 rows; layer 0 even, layer 1 with
+    every assignment on the held two (the buffer exactly full)."""
+    cfg = dataclasses.replace(SMALL, experts_held=2, first_expert=4)
+    assert moe.topk_buffer_rows(256, 2, 8, 2) == 512
+    got = tfm.routing_stats(np.array([[64] * 8, [0] * 4 + [256] * 2 + [0] * 2]),
+                            cfg, 256)
+    assert got == {"held_share": [0.25, 1.0], "buffer_fill": [0.25, 1.0],
+                   "load_max_over_mean": [1.0, 4.0], "dropped": 0.0}
 
 
 def test_the_optimizer_never_touches_the_bias():
@@ -403,6 +416,62 @@ def test_the_bias_enters_the_choice_and_not_the_weight_nor_the_gradient():
     assert not np.asarray(g_bias).any() and np.asarray(g_router).any()
 
 
+def _indexed(fn, *args):
+    """The ``scatter`` and ``gather`` operations of ``fn``'s lowered text,
+    ``(rows, elements)``: those that move whole rows of a matrix and those
+    that move single elements."""
+    text = jax.jit(fn).lower(*args).as_text()
+    rows = elements = 0
+    for line in text.split("\n"):
+        if '"stablehlo.gather"' in line:
+            width = re.search(r"slice_sizes = array<i64: ([\d, ]+)>",
+                              line).group(1).split(", ")[-1]
+            rows, elements = (rows + (width != "1"),
+                              elements + (width == "1"))
+        elif '"stablehlo.scatter"' in line:
+            whole = "update_window_dims = [1]" in line
+            rows, elements = rows + whole, elements + (not whole)
+    return rows, elements
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("norm, scale", [(True, 2.826), (True, 1.0),
+                                         (False, 2.826)])
+def test_the_router_by_comparison_is_the_router_by_index(k, norm, scale):
+    """The chosen scores and the counts as ``take_along_axis`` and a
+    scatter-add of ones give them (``topk_route`` until ISSUE 33), to the
+    bit; and neither the route nor its gradient with respect to the router
+    indexes anything."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, 16))
+    router = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(2), (8,))
+
+    def by_index(x, router):
+        s = jax.nn.sigmoid(jnp.dot(x, router,
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, expert = jax.lax.top_k(s + bias, k)
+        w = jnp.take_along_axis(s, expert, axis=-1)
+        if norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return expert, w * scale, jnp.zeros((8,), jnp.int32).at[
+            expert.reshape(-1)].add(1)
+
+    def route(x, router):
+        return moe.topk_route(x, router, bias, k, scale, norm)
+
+    def total(fn):
+        return lambda router: jnp.sum(fn(x, router)[1] ** 2)
+
+    for got, want in zip(jax.jit(route)(x, router),
+                         jax.jit(by_index)(x, router)):
+        assert got.dtype == want.dtype and jnp.array_equal(got, want)
+    assert jnp.array_equal(jax.grad(total(route))(router),
+                           jax.grad(total(by_index))(router))
+    assert _indexed(by_index, x, router) == (0, 2)
+    assert _indexed(route, x, router) == (0, 0)
+    assert _indexed(jax.grad(total(route)), router) == (0, 0)
+
+
 @pytest.mark.parametrize("counts, want", [
     ([4, 4, 4, 4], [0, 0, 0, 0]), ([8, 4, 2, 2], [-1, 0, 1, 1]),
     ([0, 0, 0, 16], [1, 1, 1, -1])])
@@ -476,6 +545,113 @@ def test_no_assignment_is_dropped(t, forced, first, held, buffers):
         for fn in (held_fn, dense_fn)]
     for g, w in zip(*grads):
         _close(g, w, 1e-4)
+
+
+def _routed_by_hand(t):
+    """A route of 2 choices a token over 8 experts, 0-2 held, in which ONE
+    token's two rows are the last of the first buffer and the first of the
+    second: expert 0 takes the first ``n_rows - t`` tokens, expert 1 every
+    token, expert 2 the last token alone (the others' second choice is the
+    absent expert 5)."""
+    n_rows = moe.topk_buffer_rows(t, 2, 8, 3)
+    assert t < n_rows < 2 * t
+    at = np.arange(t)
+    expert = np.stack([np.where(at < n_rows - t, 0, 1),
+                       np.where(at < n_rows - t, 1, 5)], axis=1)
+    expert[-1] = (1, 2)
+    weight = jax.random.uniform(jax.random.PRNGKey(3), (t, 2), jnp.float32,
+                                0.2, 1.0)
+    return moe.TopKRoute(jnp.asarray(expert, jnp.int32), weight, jnp.asarray(
+        np.bincount(expert.ravel(), minlength=8), jnp.int32)), n_rows
+
+
+@pytest.mark.parametrize("case", ["eight-rows-a-token",
+                                  "a-token-on-the-boundary"])
+def test_no_assignment_is_dropped_at_the_edges(case):
+    """Every token with 8 live rows (top 8 of 8 experts, all held); and a
+    token whose rows are the last of one buffer and the first of the next,
+    so that its sum is made of two buffers' (and two chunks') parts: the
+    dense loop's output and gradient, the routing weights' too."""
+    if case == "eight-rows-a-token":
+        x = jax.random.normal(jax.random.PRNGKey(0), (256, 16))
+        route = moe.topk_route(
+            x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)),
+            jnp.zeros(8), 8, 2.826, True)
+        held = 8
+        assert moe.topk_buffer_rows(256, 8, 8, held) == 256 * 8
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(0), (4096, 16))
+        route, n_rows = _routed_by_hand(4096)
+        held = 3
+        token, _, sizes = moe.topk_order(route, 0, held)
+        assert int(sizes.sum()) == n_rows + 1
+        assert token[n_rows - 1] == token[n_rows] == 4095
+    wg, wu, wd = _expert_weights(held)
+
+    def total(layer):
+        return lambda x, weight, *w: jnp.sum(layer(
+            x, route._replace(weight=weight), *w, 0) ** 2)
+
+    args = (x, route.weight, wg, wu, wd)
+    _close(jax.jit(lambda *a: moe.topk_moe_held(a[0], route, *a[1:], 0))(
+        x, wg, wu, wd), _dense_experts(x, route, wg, wu, wd, 0), 1e-5)
+    for g, w in zip(*(jax.jit(jax.grad(total(layer), (0, 1, 2, 3, 4)))(*args)
+                      for layer in (moe.topk_moe_held, _dense_experts))):
+        _close(g, w, 1e-4)
+
+
+def test_the_combine_accumulates_in_float32_and_rounds_once():
+    """bfloat16 tokens and experts, every token with 8 live rows: what the
+    dense loop gives when it adds its 8 terms in float32 and rounds once,
+    to one bfloat16 ulp; added in bfloat16, a third of the sums differ."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (256, 16))
+    route = moe.topk_route(
+        x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)), jnp.zeros(8),
+        8, 2.826, True)
+    x, wg, wu, wd = (a.astype(jnp.bfloat16)
+                     for a in (x,) + _expert_weights(8))
+    got = jax.jit(lambda *a: moe.topk_moe_held(a[0], route, *a[1:], 0))(
+        x, wg, wu, wd)
+    in_float32 = _dense_experts(x, route, wg, wu, wd, 0)
+    assert got.dtype == jnp.bfloat16 and in_float32.dtype == jnp.float32
+    want = np.asarray(in_float32.astype(jnp.bfloat16), np.float32)
+    assert np.all(np.abs(np.asarray(got, np.float32) - want)
+                  <= np.abs(want) * 2.0 ** -7)
+    in_bfloat16 = jnp.zeros_like(x)
+    for e in range(8):
+        one = _dense_experts(x, route._replace(expert=jnp.where(
+            route.expert == e, e, 8)), wg, wu, wd, 0)
+        in_bfloat16 = in_bfloat16 + one.astype(jnp.bfloat16)
+    assert np.mean(np.asarray(in_bfloat16, np.float32) != want) > 0.3
+
+
+def test_the_held_experts_index_rows_and_nothing_else():
+    """The ``(row, element)`` scatters and gathers of ``topk_moe_held``'s
+    lowered text. Forward 2: the dispatch's row gather and the combine's
+    row sum. Its gradient as a share takes it (the route a constant) 6: the
+    forward's two, the buffer run again in the backward pass (its sum is
+    dead code there), and the two transposes, a gather for the sum and a
+    sum for the gather. No single element is gathered or scattered: the
+    sort carries the tokens and the weights. With a gradient through the
+    routing weights (every expert held) ONE scatter of elements more, the
+    sort's own transpose."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (256, 16))
+    route = moe.topk_route(
+        x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)), jnp.zeros(8),
+        2, 2.826, True)
+    wg, wu, wd = _expert_weights(8)
+
+    def held(x, weight, wg, wu, wd):
+        return moe.topk_moe_held(x, route._replace(weight=weight), wg, wu,
+                                 wd, 0)
+
+    def total(*args):
+        return jnp.sum(held(*args) ** 2)
+
+    args = (x, route.weight, wg, wu, wd)
+    assert _indexed(held, *args) == (2, 0)
+    assert _indexed(jax.grad(total, (0, 2, 3, 4)), *args) == (6, 0)
+    assert _indexed(jax.grad(total, (0, 1, 2, 3, 4)), *args) == (6, 1)
 
 
 # -- the share test (model-configs guide, section 4) -----------------------

@@ -1,22 +1,34 @@
 #!/usr/bin/env python3
-"""Which grouped matrix product the routed experts run (ISSUE 32): the three
-products of ``parallel/moe.py grouped_swiglu`` over a dispatch buffer, timed
-on the chip as ``lax.ragged_dot`` (libtpu's own ``ragged-dot`` kernel) and as
-the installed megablox ``gmm`` at a few tilings. Nothing a cell runs imports
-this file; what it led to is ``parallel/moe.py grouped_matmul``.
+"""The routed experts' pieces alone on the chip. Nothing a cell runs imports
+this file; what it led to is in ``parallel/moe.py``.
 
-    chiprun --chips 1 -- python tools/grouped_matmul_sweep.py \\
-        --out chiprun_out/grouped_matmul.json
+``--mode products`` (ISSUE 32): the three products of ``grouped_swiglu`` over
+a dispatch buffer, as ``lax.ragged_dot`` (libtpu's own ``ragged-dot``
+kernel) and as the installed megablox ``gmm`` at a few tilings; it led to
+``grouped_matmul``. The shape is the sparse-expert cell's: ``--rows`` buffer
+rows of 2048, ``--experts`` held experts of width 1024, group sizes drawn
+like an even router's (multinomial), the rest of the buffer past the last
+group.
 
-The shape is the sparse-expert cell's: ``--rows`` buffer rows of 2048,
-``--experts`` held experts of width 1024, group sizes drawn like an even
-router's (multinomial), the rest of the buffer past the last group.
+``--mode rows`` (ISSUE 33): the buffer's rows summed into the tokens they
+came from, ``tokens_from_rows`` (the combine, fp32 rows; the backward of the
+dispatch's gather, bf16 rows), in the forms of ``row_sums`` below, and its
+transpose the row gather. ``--rows`` rows of 2048 into 8,192 tokens, the
+tokens as ``topk_order`` leaves them for a router that sends 2,048 / 4,096 /
+8,192 of the 65,536 assignments to the 8 held experts of 128; the rows past
+the last held assignment hold zeros. Every form is held to the first one's
+result.
+
+    chiprun --chips 1 -- python tools/grouped_matmul_sweep.py --mode rows \\
+        --rows 10240 --out chiprun_out/row_sums.json
+
 ``--rehearse``: tiny sizes, interpreted on the CPU: a test of the script.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -62,17 +74,8 @@ def ms_per_call(fn, xs, calls: int) -> float:
     return best
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", required=True)
-    ap.add_argument("--rows", type=int, default=5120)
-    ap.add_argument("--experts", type=int, default=8)
-    ap.add_argument("--rehearse", action="store_true")
-    args = ap.parse_args()
-    if not args.rehearse and jax.devices()[0].platform != "tpu":
-        sys.exit("grouped_matmul_sweep: no TPU here; times come from the "
-                 "chip only")
-    rows, d, f = (256, 128, 128) if args.rehearse else (args.rows, 2048, 1024)
+def products(args) -> dict:
+    rows, d, f = (512, 128, 128) if args.rehearse else (args.rows, 2048, 1024)
     rng = np.random.RandomState(0)
     sizes = rng.multinomial(rows * 4 // 5, [1 / args.experts] * args.experts)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -101,6 +104,198 @@ def main() -> None:
             rec = {"failed": str(e).replace("\n", " ")[:300]}
         out["ms"][name] = rec
         print(name, rec, flush=True)
+    return out
+
+
+# -- the row sums (ISSUE 33) ------------------------------------------------
+# each form: (rows [R, d], token [R], n_live, n_tokens) -> [n_tokens, d] in
+# rows' dtype; rows n_live.. are zeros and their tokens ascend, repeated
+
+def _zeros(rows, n_tokens):
+    return jnp.zeros((n_tokens, rows.shape[1]), rows.dtype)
+
+
+def scatter_add(rows, token, n_live, n_tokens):
+    return _zeros(rows, n_tokens).at[token].add(rows)
+
+
+def live_chunks(chunk):
+    def form(rows, token, n_live, n_tokens):
+        def add(c):
+            at, out = c
+            return at + chunk, out.at[
+                lax.dynamic_slice_in_dim(token, at, chunk)].add(
+                    lax.dynamic_slice_in_dim(rows, at, chunk))
+        return lax.while_loop(lambda c: c[0] < n_live, add,
+                              (jnp.zeros((), jnp.int32),
+                               _zeros(rows, n_tokens)))[1]
+    return form
+
+
+def _dead_out_of_range(token, n_live, n_tokens):
+    return jnp.where(jnp.arange(token.shape[0]) < n_live, token, n_tokens)
+
+
+def drop_dead(rows, token, n_live, n_tokens):
+    return _zeros(rows, n_tokens).at[
+        _dead_out_of_range(token, n_live, n_tokens)].add(rows, mode="drop")
+
+
+def _by_token(rows, token, n_live, n_tokens):
+    """Rows and tokens in order of the token, the dead ones (token
+    ``n_tokens``) last."""
+    token, at = lax.sort((_dead_out_of_range(token, n_live, n_tokens),
+                          jnp.arange(token.shape[0])), num_keys=1)
+    return rows[at], token
+
+
+def sorted_add(rows, token, n_live, n_tokens):
+    rows, token = _by_token(rows, token, n_live, n_tokens)
+    return _zeros(rows, n_tokens).at[token].add(
+        rows, indices_are_sorted=True, mode="drop")
+
+
+def by_rank(ranks):
+    """A call a rank of a row within its token, each over unique indices
+    (a row of another rank goes to an index of its own past the end)."""
+    def form(rows, token, n_live, n_tokens):
+        rows, token = _by_token(rows, token, n_live, n_tokens)
+        at = jnp.arange(token.shape[0])
+        first = lax.cummax(jnp.where(
+            token != jnp.roll(token, 1), at, 0).at[0].set(0))
+        out = _zeros(rows, n_tokens)
+        for rank in range(ranks):
+            out = out.at[jnp.where(at - first == rank, token,
+                                   n_tokens + at)].add(
+                rows, unique_indices=True, mode="drop")
+        return out
+    return form
+
+
+ONEHOT_TILE = 256       # tokens a group of the one-hot product
+
+
+def onehot_tgmm(interpret):
+    """The sum on the MXU: rows sorted by token, ``onehot^T rows`` a tile of
+    256 tokens as one megablox ``tgmm`` over the tiles' rows; fp32 rows as a
+    bfloat16 high and low part, summed in fp32. MEASURED, NOT SHIPPED: its
+    custom call would be counted among the experts' products
+    (``benchmark/layer_metrics/moe_experts_roofline.json``)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    def form(rows, token, n_live, n_tokens):
+        rows, token = _by_token(rows, token, n_live, n_tokens)
+        d, tile = rows.shape[1], min(ONEHOT_TILE, n_tokens)
+        sizes = jnp.sum(
+            (token // tile)[:, None] == jnp.arange(n_tokens // tile),
+            axis=0, dtype=jnp.int32)
+        onehot = ((token % tile)[None, :]
+                  == jnp.arange(tile)[:, None]).astype(jnp.bfloat16)
+        parts = rows.astype(jnp.bfloat16)
+        if rows.dtype == jnp.float32:
+            parts = jnp.concatenate(
+                [parts, (rows - parts.astype(jnp.float32)).astype(
+                    jnp.bfloat16)], axis=1)
+        out = tgmm(
+            onehot, parts, sizes, jnp.float32,
+            (min(512, rows.shape[0]), tile, min(1024, d)),
+            interpret=interpret).reshape(n_tokens, -1)
+        return sum(out[:, i:i + d]
+                   for i in range(0, out.shape[1], d)).astype(rows.dtype)
+    return form
+
+
+def row_sums(rehearse: bool, ranks: int) -> dict:
+    """The forms by the letters of ISSUE 33; (a) first: the others are held
+    to it. (b) at the shipped chunk IS the shipped function (in rehearsal
+    its chunk is the whole toy buffer)."""
+    from horovod_tpu.parallel import moe
+    return {"a:scatter-add": scatter_add,
+            "b:live-chunks-256": live_chunks(8 if rehearse else 256),
+            "b:live-chunks-512": live_chunks(16 if rehearse else 512),
+            "b:live-chunks-1024 (moe.tokens_from_rows)":
+                moe.tokens_from_rows,
+            "b:live-chunks-2048": live_chunks(64 if rehearse else 2048),
+            "c:dead-dropped": drop_dead,
+            "d:sorted": sorted_add,
+            "e:by-rank-unique": by_rank(ranks),
+            "f:onehot-tgmm": onehot_tgmm(rehearse)}
+
+
+def routed_tokens(t: int, k: int, n_experts: int, held: int, n_live: int,
+                  n_rows: int, seed: int):
+    """``(token [n_rows], n_live)`` of the first buffer, as ``topk_order``
+    leaves them, for a router whose held experts ``0 .. held - 1`` draw
+    about ``n_live`` of the ``t x k`` assignments."""
+    from horovod_tpu.parallel import moe
+    rng = np.random.RandomState(seed)
+    scores = rng.rand(t, n_experts)
+    lo, hi = -1.0, 1.0      # the held experts' handicap, by bisection
+    for _ in range(30):
+        lift = (lo + hi) / 2
+        lifted = scores + lift * (np.arange(n_experts) < held)
+        expert = np.argpartition(-lifted, k - 1, axis=1)[:, :k]
+        lo, hi = (lift, hi) if (expert < held).sum() < n_live else (lo, lift)
+    expert = jnp.asarray(expert, jnp.int32)
+    route = moe.TopKRoute(
+        expert, jnp.ones(expert.shape, jnp.float32),
+        jnp.bincount(expert.reshape(-1), length=n_experts).astype(jnp.int32))
+    token, _, sizes = moe.topk_order(route, 0, held)
+    return token[:n_rows], jnp.minimum(jnp.sum(sizes), n_rows)
+
+
+def rows(args) -> dict:
+    t, k, n_experts, held, n_rows, d, lives = (
+        (64, 4, 16, 4, 128, 128, (16, 64, 100)) if args.rehearse else
+        (8192, 8, 128, 8, args.rows, 2048, (2048, 4096, 8192)))
+    calls = 2 if args.rehearse else 50
+    forms = row_sums(args.rehearse, min(k, held))
+    out = {"device": jax.devices()[0].device_kind, "rows": n_rows,
+           "tokens": t, "d": d, "ms": {}}
+    for n_live in lives:
+        token, live = routed_tokens(t, k, n_experts, held, n_live, n_rows,
+                                    seed=n_live)
+        cut = (jnp.arange(n_rows) < live)[:, None]
+        for dtype in (jnp.float32, jnp.bfloat16):
+            x = jnp.where(cut, jax.random.normal(
+                jax.random.PRNGKey(n_live), (n_rows, d), dtype), 0)
+            want = None
+            for name, form in forms.items():
+                fn = jax.jit(functools.partial(form, n_tokens=t))
+                try:
+                    got = np.asarray(fn(x, token, live), np.float32)
+                    want = got if want is None else want
+                    rec = {"ms": ms_per_call(fn, (x, token, live), calls),
+                           "against_first": float(
+                               np.abs(got - want).max() / np.abs(want).max())}
+                except Exception as e:  # a form the compiler refuses, kept
+                    rec = {"failed": str(e).replace("\n", " ")[:300]}
+                key = "%s live=%d %s" % (name, int(live),
+                                         jnp.dtype(dtype).name)
+                out["ms"][key] = rec
+                print(key, rec, flush=True)
+            # the transpose: the rows of [t, d] by token
+            g = jax.random.normal(jax.random.PRNGKey(1), (t, d), dtype)
+            gather = jax.jit(lambda g, token: g[token])
+            key = "gather live=%d %s" % (int(live), jnp.dtype(dtype).name)
+            out["ms"][key] = {"ms": ms_per_call(gather, (g, token), calls)}
+            print(key, out["ms"][key], flush=True)
+    return out
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", required=True)
+    ap.add_argument("--mode", choices=("products", "rows"),
+                    default="products")
+    ap.add_argument("--rows", type=int, default=5120)
+    ap.add_argument("--experts", type=int, default=8)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    if not args.rehearse and jax.devices()[0].platform != "tpu":
+        sys.exit("grouped_matmul_sweep: no TPU here; times come from the "
+                 "chip only")
+    out = {"products": products, "rows": rows}[args.mode](args)
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as fh:
         json.dump(out, fh, indent=1)
