@@ -272,9 +272,13 @@ def main():
                     ("held_share", "hvd_tpu_moe_held_assignment_share"),
                     ("buffer_fill", "hvd_tpu_moe_buffer_fill"),
                     ("load_max_over_mean",
-                     "hvd_tpu_moe_expert_load_max_over_mean")):
+                     "hvd_tpu_moe_expert_load_max_over_mean"),
+                    ("row_sum_rows_over_live",
+                     "hvd_tpu_moe_row_sum_rows_over_live")):
                 for layer, value in enumerate(routing[name]):
                     registry().gauge(gauge).set(value, layer=str(layer))
+            registry().gauge("hvd_tpu_moe_row_sum").set(
+                1, form=routing["row_sum_form"])
             registry().gauge("hvd_tpu_moe_dropped_assignments").set(
                 routing["dropped"])
     else:
@@ -316,8 +320,9 @@ def main():
         if by_mixer:
             report["layers_by_mixer"] = dict(sorted(by_mixer.items()))
         if stats:
-            report["routing"] = {k: np.round(v, 4).tolist()
-                                 for k, v in routing.items()}
+            report["routing"] = {
+                k: v if isinstance(v, str) else np.round(v, 4).tolist()
+                for k, v in routing.items()}
     if cfg.n_loops > 1:
         # logged with the loss: whether the exit gate has collapsed
         from horovod_tpu.metrics import registry

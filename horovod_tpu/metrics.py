@@ -234,8 +234,23 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
     "hvd_tpu_moe_buffer_fill": (
         "gauge", "The held experts' assignments of the last logged step "
                  "over the rows of the dispatch buffer, by expert layer "
-                 "(0.4 under an even router; the row sums' work follows "
-                 "it; past 1 a second buffer ran)"),
+                 "(0.4 under an even router; the chunked row sums' work "
+                 "follows it; past 1 a second buffer ran)"),
+    "hvd_tpu_moe_row_sum": (
+        "gauge", "1 under the form of the routed experts' row sums (the "
+                 "combine; the dispatch's backward pass) the step was "
+                 "built with, label form: gather (every token's top-k rows "
+                 "gathered and added in fp32) or chunks (a scatter-add of "
+                 "the live rows, 1,024 at a time). A function of tokens, "
+                 "top-k, experts and experts held alone "
+                 "(parallel/moe.py row_sum_form): a gather where the share "
+                 "held is large enough"),
+    "hvd_tpu_moe_row_sum_rows_over_live": (
+        "gauge", "Rows one row sum of the last logged step visited over "
+                 "the held experts' assignments, by expert layer: tokens x "
+                 "top-k over them as a gather (experts / held under an "
+                 "even router: 4 for a quarter), the whole chunks over "
+                 "them as chunks (1 to 1.3)"),
     "hvd_tpu_moe_expert_load_max_over_mean": (
         "gauge", "The fullest expert's assignments over the mean over all "
                  "experts, last logged step, by expert layer (1.0: even; "

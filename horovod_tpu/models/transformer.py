@@ -40,8 +40,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..common import scopes
 from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
                             moe_dispatch, moe_experts, moe_layer_p,
-                            router_bias_update, topk_buffer_rows,
-                            topk_moe_held, topk_route)
+                            router_bias_update, row_sum_form, row_sum_rows,
+                            topk_buffer_rows, topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
@@ -1148,15 +1148,25 @@ def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
     moe_top_k`` assignments that land on the held experts (``held /
     n_experts`` under an even router) and the fullest expert's load over
     the mean load; the held assignments over the rows of the dispatch
-    buffer for ``n_tokens`` tokens (what the row sums' work follows; past
-    1 a second buffer ran); and the assignments no expert was counted for
-    (0: the layer drops nothing)."""
+    buffer for ``n_tokens`` tokens (past 1 a second buffer ran); the form
+    of the row sums the step was built with
+    (:func:`~horovod_tpu.parallel.moe.row_sum_form`) and the rows one of
+    them visited over the live ones (``T x k`` over the held assignments as
+    a gather, once more for every further slab or buffer they fill; the
+    whole chunks over them otherwise); and the assignments no
+    expert was counted for (0: the layer drops nothing)."""
     counts = np.asarray(counts, np.float64)
     total = n_tokens * cfg.moe_top_k
     held = counts[:, cfg.first_expert:cfg.first_expert + cfg.held]
+    shape = (n_tokens, cfg.moe_top_k, cfg.n_experts, cfg.held)
     return {"held_share": (held.sum(axis=1) / total).tolist(),
-            "buffer_fill": (held.sum(axis=1) / topk_buffer_rows(
-                n_tokens, cfg.moe_top_k, cfg.n_experts, cfg.held)).tolist(),
+            "buffer_fill": (held.sum(axis=1)
+                            / topk_buffer_rows(*shape)).tolist(),
+            "row_sum_form": row_sum_form(*shape),
+            "row_sum_rows_over_live": [
+                row_sum_rows(*shape, int(live), cfg.d_model
+                             * jnp.dtype(cfg.dtype).itemsize) / max(live, 1.0)
+                for live in held.sum(axis=1)],
             "load_max_over_mean": (counts.max(axis=1)
                                    / counts.mean(axis=1)).tolist(),
             "dropped": float(np.abs(total - counts.sum(axis=1)).sum())}

@@ -160,6 +160,8 @@ def test_transformer_lm_example_conv_pattern():
     assert "'layers_by_mixer': {'attention': 1, 'conv': 4}" in r.stdout, \
         r.stdout
     assert "'dropped': 0.0" in r.stdout and "held_share" in r.stdout
+    # (64 live rows: under a chunk, so the row sums are chunks)
+    assert "'row_sum_form': 'chunks'" in r.stdout, r.stdout
     assert "'head_size': '8'" in r.stdout, r.stdout
 
 

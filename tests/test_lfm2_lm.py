@@ -361,6 +361,32 @@ def test_a_mesh_takes_the_same_step(stepped, mesh):
             _close(got[leaf], wanted[leaf], 2e-4)
 
 
+@pytest.mark.parametrize("held, mesh", [(0, (1, 1, 1)), (2, (1, 1, 1)),
+                                        (2, (2, 1, 1))],
+                         ids=["whole", "share", "share-data2"])
+def test_the_step_with_its_row_sums_as_gathers(monkeypatch, held, mesh):
+    """The rehearsal's shapes stay under a chunk of live rows, so the steps
+    of this file add their rows in chunks; the cell's are gathers
+    (``moe.row_sum_form``). The same step in the gather form, through the
+    scans, the checkpoints and a mesh: the loss, the counts and every
+    leaf's gradient, the routers' too where every expert is held."""
+    cfg = dataclasses.replace(SMALL, experts_held=held)
+    params, (inputs, targets) = _params(cfg), _tokens()
+    with jax.default_matmul_precision("highest"):
+        want = _sgd_step(cfg, _params(cfg), inputs, targets)
+        monkeypatch.setattr(moe, "row_sum_form", lambda *shape: "gather")
+        _, loss, stats, applied = _sgd_step(cfg, params, inputs, targets,
+                                            _mesh(*mesh))
+    assert float(loss) == pytest.approx(float(want[1]), rel=TIGHT)
+    assert np.array_equal(np.asarray(stats["expert_counts"]),
+                          np.asarray(want[2]["expert_counts"]))
+    got, wanted = _leaves(applied), _leaves(want[3])
+    assert bool(np.any(wanted["['layers']['router']"])) == (held == 0)
+    for leaf in LEAVES:
+        if not leaf.endswith("['router_bias']"):
+            _close(got[leaf], wanted[leaf], 2e-4)
+
+
 def test_the_gradient_sums_of_a_data_mesh_cover_the_conv_leaves():
     axes = tfm.grad_reduce_axes(_mesh(4), SMALL)
     early = tfm._in_backward(axes, SMALL)
@@ -706,6 +732,13 @@ def test_the_sparse_expert_cells_weights_are_drawn_as_before():
     assert got == pytest.approx(SPARSE_WEIGHT_SUM, rel=1e-6)
 
 
-def test_the_examples_gauges_are_declared():
+@pytest.mark.parametrize("gauge", [
+    "hvd_tpu_lm_layers", "hvd_tpu_moe_row_sum",
+    "hvd_tpu_moe_row_sum_rows_over_live"])
+def test_the_examples_gauges_are_declared(gauge):
     from horovod_tpu.metrics import METRIC_SPECS
-    assert METRIC_SPECS["hvd_tpu_lm_layers"][0] == "gauge"
+    assert METRIC_SPECS[gauge][0] == "gauge"
+    example = os.path.join(os.path.dirname(BENCH), "examples",
+                           "transformer_lm.py")
+    with open(example) as fh:
+        assert '"%s"' % gauge in fh.read()

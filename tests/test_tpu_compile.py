@@ -346,8 +346,8 @@ def test_the_conv_attention_cells_step_and_the_memory_it_states(
     expert layers, 2 rows of 8,192 tokens, ``remat="block"``) for the v5e:
     the attention kernel's forward TWICE (the one attention layer's
     recomputation is real: ``remat_barrier``) and its fused backward once,
-    the grouped products through megablox, and the compiler's
-    ``memory_analysis`` as ``benchmark/configs/lfm2-8b-a1b.json`` states it,
+    the grouped products through megablox, the row sums as gathers, and the
+    compiler's ``memory_analysis`` as ``benchmark/configs/lfm2-8b-a1b.json`` states it,
     under the 14.4 GB (90% of the chip) a cell may reach."""
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
@@ -384,6 +384,18 @@ def test_the_conv_attention_cells_step_and_the_memory_it_states(
     assert "gmm" in calls and "tgmm" in calls
     assert not [c for c in calls if "_dq" in c or "flash" in c
                 or "mha" in c or "ragged" in c], calls
+    # a quarter of the experts held: the row sums are gathers of the 2 x
+    # 8,192 tokens' rows, one a choice (moe.row_sum_form), out of slabs of
+    # half the buffer (80 MiB: moe.NEAR_BYTES), and nothing is scattered
+    # under the routed experts' scopes on either pass
+    moved = re.findall(r" (gather|scatter)\(.*op_name=\"[^\"]*moe_"
+                       r"(combine|dispatch)", compiled.as_text())
+    assert moved and {kind for kind, _ in moved} == {"gather"}, moved
+    for scope in (r"/moe_combine/", r"transpose\(jvp\(moe_dispatch\)\)/"):
+        assert re.search(r"bf16\[16384,2048\]\S* gather\(.*" + scope
+                         + "gather", compiled.as_text()), scope
+        assert re.search(r"bf16\[20480,2048\]\S* dynamic-slice\(.*" + scope,
+                         compiled.as_text()), scope
     found = compiled.memory_analysis()
     stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
     live = found.argument_size_in_bytes + found.temp_size_in_bytes

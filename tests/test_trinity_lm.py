@@ -207,13 +207,23 @@ def test_the_step_returns_the_counts_and_moves_the_bias_by_them(stepped):
 def test_the_gauges_of_where_the_router_sent_the_step():
     """``routing_stats`` of hand-made counts: a share of 2 of 8 experts,
     256 tokens, top 2, a buffer of 512 rows; layer 0 even, layer 1 with
-    every assignment on the held two (the buffer exactly full)."""
+    every assignment on the held two (the buffer exactly full). Under a
+    chunk of live rows the row sums are chunks, and a chunk is the whole
+    buffer here; at 64 times the tokens they are a gather of the 32,768
+    choices, whatever the held experts drew."""
     cfg = dataclasses.replace(SMALL, experts_held=2, first_expert=4)
     assert moe.topk_buffer_rows(256, 2, 8, 2) == 512
-    got = tfm.routing_stats(np.array([[64] * 8, [0] * 4 + [256] * 2 + [0] * 2]),
-                            cfg, 256)
+    counts = np.array([[64] * 8, [0] * 4 + [256] * 2 + [0] * 2])
+    got = tfm.routing_stats(counts, cfg, 256)
     assert got == {"held_share": [0.25, 1.0], "buffer_fill": [0.25, 1.0],
+                   "row_sum_form": "chunks",
+                   "row_sum_rows_over_live": [4.0, 1.0],
                    "load_max_over_mean": [1.0, 4.0], "dropped": 0.0}
+    got = tfm.routing_stats(counts * 64, cfg, 256 * 64)
+    assert got["row_sum_form"] == "gather"
+    # (the second layer's 32,768 held assignments fill the buffer of 20,480
+    # rows and a second: each gathers every choice)
+    assert got["row_sum_rows_over_live"] == [4.0, 2.0]
 
 
 def test_the_optimizer_never_touches_the_bias():
@@ -417,13 +427,16 @@ def test_the_bias_enters_the_choice_and_not_the_weight_nor_the_gradient():
     assert not np.asarray(g_bias).any() and np.asarray(g_router).any()
 
 
-def _indexed(fn, *args):
-    """The ``scatter`` and ``gather`` operations of ``fn``'s lowered text,
-    ``(rows, elements)``: those that move whole rows of a matrix and those
-    that move single elements."""
+def _indexed(fn, *args, what=("gather", "scatter")):
+    """The ``scatter`` and ``gather`` operations (``what``: either alone)
+    of ``fn``'s lowered text, ``(rows, elements)``: those that move whole
+    rows of a matrix and those that move single elements."""
     text = jax.jit(fn).lower(*args).as_text()
     rows = elements = 0
     for line in text.split("\n"):
+        if '"stablehlo.%s"' % what[0] not in line and \
+                '"stablehlo.%s"' % what[-1] not in line:
+            continue
         if '"stablehlo.gather"' in line:
             width = re.search(r"slice_sizes = array<i64: ([\d, ]+)>",
                               line).group(1).split(", ")[-1]
@@ -500,24 +513,72 @@ def _expert_weights(held, d=16, f=8, seed=2):
             jax.random.normal(ks[2], (held, f, d)) * 0.3)
 
 
-@pytest.mark.parametrize("t", [256, 2048], ids=["one-buffer", "buffers"])
-@pytest.mark.parametrize("forced, first, held, buffers", [
+FORMS = ["gather", "chunks"]
+
+
+@pytest.fixture
+def form(request, monkeypatch):
+    """The form of the row sums, whatever the shape would have it be."""
+    monkeypatch.setattr(moe, "row_sum_form", lambda *shape: request.param)
+    return request.param
+
+
+# 8 experts, 2 a token: (the experts the bias forces every token onto, the
+# first held, how many, the buffers the forced routing fills at 2,048 tokens)
+ROUTINGS = pytest.mark.parametrize("forced, first, held, buffers", [
     (None, 0, 8, 1), (None, 2, 4, 1), ((0, 1), 0, 2, 2), ((6, 7), 0, 2, 1),
     ((3, 5), 2, 2, 1), ((2, 3), 2, 2, 2), ((0, 1), 0, 1, 2)],
     ids=["even-all", "even-share", "all-to-held", "all-to-absent",
          "all-to-one-of-share", "all-to-share", "all-to-one-held"])
-def test_no_assignment_is_dropped(t, forced, first, held, buffers):
+SIZES = pytest.mark.parametrize("t", [256, 2048],
+                                ids=["one-buffer", "buffers"])
+
+
+def _forced_bias(forced):
+    return jnp.zeros(8) if forced is None else \
+        jnp.zeros(8).at[jnp.array(forced)].set(10.0)
+
+
+@SIZES
+@ROUTINGS
+def test_the_places_are_the_sorts(t, forced, first, held, buffers):
+    """``topk_places`` against ``topk_order``'s own sort, to the row: the
+    row a held choice is placed at holds its token and its weight, the
+    places are the first rows of the sorted order, each once, and a choice
+    of an absent expert has none."""
+    route = moe.topk_route(
+        jax.random.normal(jax.random.PRNGKey(0), (t, 16)),
+        jax.random.normal(jax.random.PRNGKey(1), (16, 8)),
+        _forced_bias(forced), 2, 2.826, True)
+    token, weight, sizes = moe.topk_order(route, first, held)
+    places = moe.topk_places(route, first, held)
+    assert int(places.live) == int(sizes.sum())
+    place, mine = np.asarray(places.place).T, np.asarray(places.mine).T
+    local = np.asarray(route.expert) - first
+    assert np.array_equal(mine, (local >= 0) & (local < held))
+    assert not place[~mine].any()
+    assert np.array_equal(np.sort(place[mine]), np.arange(int(sizes.sum())))
+    assert np.array_equal(np.asarray(token)[place[mine]],
+                          np.nonzero(mine)[0])
+    assert np.array_equal(np.asarray(weight)[place[mine]],
+                          np.asarray(route.weight)[mine])
+
+
+@pytest.mark.parametrize("form", FORMS, indirect=True)
+@SIZES
+@ROUTINGS
+def test_no_assignment_is_dropped(t, forced, first, held, buffers, form):
     """Whatever the router does, with every token sent to the same two
-    experts too: the held experts' part is the dense loop's, to the last
-    token, and so is its gradient. At 2,048 tokens a share that draws more
-    than twice its even part fills the buffer more than once and the further
-    buffers run (``buffers``: how many the forced routing fills there); at
-    256 tokens one buffer holds every assignment."""
+    experts too, and in either form of the row sums: the held experts' part
+    is the dense loop's, to the last token, and so is its gradient. At
+    2,048 tokens a share that draws more than twice its even part fills the
+    buffer more than once and the further buffers run (``buffers``: how
+    many the forced routing fills there); at 256 tokens one buffer holds
+    every assignment."""
     k = 2
     x = jax.random.normal(jax.random.PRNGKey(0), (t, 16))
     router = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
-    bias = jnp.zeros(8) if forced is None else \
-        jnp.zeros(8).at[jnp.array(forced)].set(10.0)
+    bias = _forced_bias(forced)
     wg, wu, wd = _expert_weights(held)
     rows = moe.topk_buffer_rows(t, k, 8, held)
     assert rows % 512 == 0 or rows == t * k
@@ -566,13 +627,15 @@ def _routed_by_hand(t):
         np.bincount(expert.ravel(), minlength=8), jnp.int32)), n_rows
 
 
+@pytest.mark.parametrize("form", FORMS, indirect=True)
 @pytest.mark.parametrize("case", ["eight-rows-a-token",
                                   "a-token-on-the-boundary"])
-def test_no_assignment_is_dropped_at_the_edges(case):
+def test_no_assignment_is_dropped_at_the_edges(case, form):
     """Every token with 8 live rows (top 8 of 8 experts, all held); and a
     token whose rows are the last of one buffer and the first of the next,
     so that its sum is made of two buffers' (and two chunks') parts: the
-    dense loop's output and gradient, the routing weights' too."""
+    dense loop's output and gradient, the routing weights' too, in either
+    form of the row sums."""
     if case == "eight-rows-a-token":
         x = jax.random.normal(jax.random.PRNGKey(0), (256, 16))
         route = moe.topk_route(
@@ -601,7 +664,8 @@ def test_no_assignment_is_dropped_at_the_edges(case):
         _close(g, w, 1e-4)
 
 
-def test_the_combine_accumulates_in_float32_and_rounds_once():
+@pytest.mark.parametrize("form", FORMS, indirect=True)
+def test_the_combine_accumulates_in_float32_and_rounds_once(form):
     """bfloat16 tokens and experts, every token with 8 live rows: what the
     dense loop gives when it adds its 8 terms in float32 and rounds once,
     to one bfloat16 ulp; added in bfloat16, a third of the sums differ."""
@@ -626,16 +690,24 @@ def test_the_combine_accumulates_in_float32_and_rounds_once():
     assert np.mean(np.asarray(in_bfloat16, np.float32) != want) > 0.3
 
 
-def test_the_held_experts_index_rows_and_nothing_else():
+@pytest.mark.parametrize("form", FORMS, indirect=True)
+def test_the_held_experts_index_rows_and_nothing_else(form):
     """The ``(row, element)`` scatters and gathers of ``topk_moe_held``'s
-    lowered text. Forward 2: the dispatch's row gather and the combine's
-    row sum. Its gradient as a share takes it (the route a constant) 6: the
-    forward's two, the buffer run again in the backward pass (its sum is
-    dead code there), and the two transposes, a gather for the sum and a
-    sum for the gather. No single element is gathered or scattered: the
-    sort carries the tokens and the weights. With a gradient through the
-    routing weights (every expert held) ONE scatter of elements more, the
-    sort's own transpose."""
+    lowered text; no single element is gathered or scattered in either
+    form: the sort carries the tokens and the weights. As chunks, forward
+    2: the dispatch's row gather and the combine's row sum. Its gradient as
+    a share takes it (the route a constant) 6: the forward's two, the
+    buffer run again in the backward pass (its sum is dead code there), and
+    the two transposes, a gather for the sum and a sum for the gather. With
+    a gradient through the routing weights (every expert held) ONE scatter
+    of elements more, the sort's own transpose. As a gather NOTHING is
+    scattered on either pass, rows or elements: every sum is a row gather a
+    choice, two here (forward 3: the dispatch's and the combine's two; the
+    gradient 11: the forward's three, the buffer run again with its
+    combine, the combine's three, ``g[token]`` for the rows and ``y`` at
+    the places for the weights (dead code where they are constants), and
+    the dispatch's two), and the weights take their gradient where the
+    choices are, not through the sort."""
     x = jax.random.normal(jax.random.PRNGKey(0), (256, 16))
     route = moe.topk_route(
         x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)), jnp.zeros(8),
@@ -650,9 +722,74 @@ def test_the_held_experts_index_rows_and_nothing_else():
         return jnp.sum(held(*args) ** 2)
 
     args = (x, route.weight, wg, wu, wd)
-    assert _indexed(held, *args) == (2, 0)
-    assert _indexed(jax.grad(total, (0, 2, 3, 4)), *args) == (6, 0)
-    assert _indexed(jax.grad(total, (0, 1, 2, 3, 4)), *args) == (6, 1)
+    programs = (held, jax.grad(total, (0, 2, 3, 4)),
+                jax.grad(total, (0, 1, 2, 3, 4)))
+    assert [_indexed(fn, *args) for fn in programs] == {
+        "chunks": [(2, 0), (6, 0), (6, 1)],
+        "gather": [(3, 0), (11, 0), (11, 0)]}[form]
+    if form == "gather":
+        assert [_indexed(fn, *args, what=("scatter",))
+                for fn in programs] == [(0, 0)] * 3
+
+
+@pytest.mark.parametrize("dtype, out, grad", [
+    (jnp.float32, 1e-6, 1e-6), (jnp.bfloat16, 2.0 ** -7, 4.6e-3)],
+    ids=["float32", "bfloat16"])
+def test_the_gather_form_against_the_chunked_form(monkeypatch, dtype, out,
+                                                  grad):
+    """One layer, the same inputs, the two forms of its row sums: in
+    float32 they differ in the order of a token's at most k additions
+    (1e-6 of the largest value); in bfloat16 the combine is the same
+    float32 sum rounded once, and the dispatch's backward pass, which the
+    chunks add in bfloat16 and the gather in float32, is within the 4.6e-3
+    the chip read between the forms of the sum (PERF.md section 6, PR 33)."""
+    t, k, first, held = 2048, 4, 2, 4
+    x = jax.random.normal(jax.random.PRNGKey(0), (t, 16))
+    route = moe.topk_route(
+        x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)), jnp.zeros(8),
+        k, 2.826, True)
+    args = tuple(a.astype(dtype) for a in (x,) + _expert_weights(held))
+
+    def both(form):
+        monkeypatch.setattr(moe, "row_sum_form", lambda *shape: form)
+        layer = lambda *a: moe.topk_moe_held(  # noqa: E731
+            a[0], route, *a[1:], first)
+        return jax.jit(layer)(*args), jax.jit(jax.grad(
+            lambda *a: jnp.sum(layer(*a).astype(jnp.float32) ** 2),
+            (0, 1, 2, 3)))(*args)
+
+    (y_g, d_g), (y_c, d_c) = both("gather"), both("chunks")
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    _close(f32(y_g), f32(y_c), out)
+    for g, c in zip(d_g, d_c):
+        _close(f32(g), f32(c), grad)
+
+
+@pytest.mark.parametrize("shape, form, rows_over_live, one_more", [
+    # the conv/attention cell: 2 x 8,192 tokens, 4 of 32, 8 held; past half
+    # of its buffer of 40,960 rows (two slabs of 80 MiB) every choice is
+    # gathered once more, and again for a second buffer
+    ((16384, 4, 32, 8), "gather", 4.0, {20481: 2, 40961: 3}),
+    # the sparse-expert cell: 8,192 tokens, 8 of 128, 8 held: 4 chunks
+    ((8192, 8, 128, 8), "chunks", 1.0, {4097: 5120 / 65536}),
+    # a whole layer of either: 256 MiB of rows, three slabs
+    ((16384, 4, 32, 32), "gather", 3.0, {}),
+    ((8192, 8, 128, 128), "gather", 3.0, {}),
+    # under a chunk of live rows (the rehearsals' programs): one chunk
+    ((128, 2, 8, 4), "chunks", 2.0, {129: 1.0})],
+    ids=["conv-attention-cell", "sparse-expert-cell", "whole-4-of-32",
+         "whole-8-of-128", "under-a-chunk"])
+def test_the_form_of_the_row_sums_follows_the_share_held(
+        shape, form, rows_over_live, one_more):
+    """One function of ``(T, k, E, held)``; and the rows a sum visits over
+    the live ones at an even router's load, rows of 2,048 in bfloat16
+    (``one_more``: live rows to the rows visited, in units of ``T x k``)."""
+    t, k, n_experts, held = shape
+    assert moe.row_sum_form(*shape) == form
+    even = t * k * held // n_experts
+    assert moe.row_sum_rows(*shape, even, 4096) / even == rows_over_live
+    for live, visited in one_more.items():
+        assert moe.row_sum_rows(*shape, live, 4096) == visited * t * k
 
 
 # -- the share test (model-configs guide, section 4) -----------------------

@@ -26,13 +26,20 @@ tokens, the tokens as ``topk_order`` leaves them for a router that sends
 ``--live`` (2,048 / 4,096 / 8,192) of the ``tokens x --top-k`` (8)
 assignments to the ``--experts`` (8) held experts of ``--router-outputs``
 (128); the rows past the last held assignment hold zeros. Every form is held
-to the first one's result.
+to the first one's result. Form (h) (ISSUE 35) is the shipped gather form (a
+gather a choice out of slabs of at most ``moe.NEAR_BYTES``, one fused pass
+over what they bring), the places of the rows found inside the timed call
+(``place`` times that alone), and ``h:combine`` the combine as that form
+runs it: bf16 rows in, each times its choice's weight, fp32 out.
 
     chiprun --chips 1 -- python tools/grouped_matmul_sweep.py --mode rows \\
         --rows 10240 --out chiprun_out/row_sums.json
     chiprun --chips 1 -- python tools/grouped_matmul_sweep.py --mode rows \\
         --rows 40960 --tokens 16384 --top-k 4 --router-outputs 32 \\
         --live 8192 16384 32768 --out chiprun_out/row_sums_40960.json
+    chiprun --chips 1 -- python tools/grouped_matmul_sweep.py --mode rows \
+        --rows 40960 --tokens 16384 --top-k 4 --router-outputs 32 \
+        --live 4096 8192 16384 --forms b h --out chiprun_out/row_sums_h.json
 
 ``--rehearse``: tiny sizes, interpreted on the CPU: a test of the script.
 """
@@ -242,12 +249,13 @@ def onehot_tgmm(interpret):
 
 
 def gathered(ranks):
-    """The sum as a GATHER (ISSUE 34's finding: on the v5e a row gather
-    costs 7-40 ns a row where a row scatter-add costs 86-270): rows sorted
-    by token, every token's first row found by bisection, and its at most
-    ``ranks`` rows gathered and summed in fp32. MEASURED, NOT SHIPPED: in
-    ``moe.topk_moe_held`` the routing sort would carry each assignment's
-    place, and the map would cost no second sort (PERF.md section 7)."""
+    """The sum as a gather, as ISSUE 34 tried it: rows sorted by token,
+    every token's first row found by bisection, and its at most ``ranks``
+    rows gathered and summed in fp32. MEASURED, NOT SHIPPED, and no measure
+    of a gather either: what it timed was its own sort of the rows,
+    ``searchsorted``'s 16 rounds of element gathers, the element gather of
+    the places, and ``T x ranks`` fp32 rows where the buffer is bf16 (ISSUE
+    35). Form (h) needs none of the four."""
     def form(rows, token, n_live, n_tokens):
         token, at = lax.sort((_dead_out_of_range(token, n_live, n_tokens),
                               jnp.arange(token.shape[0])), num_keys=1)
@@ -258,6 +266,39 @@ def gathered(ranks):
         return jnp.sum(jnp.where(mine[..., None], got.astype(jnp.float32),
                                  0.0), axis=1).astype(rows.dtype)
     return form
+
+
+# -- the gather form as shipped (ISSUE 35): these take the route too, and
+# find the rows' places from it inside the timed call ----------------------
+
+def places_alone(held, rows, token, n_live, route, n_tokens):
+    from horovod_tpu.parallel import moe
+    return moe.topk_places(route, 0, held).within(0, rows.shape[0])
+
+
+def shipped_gather(held, rows, token, n_live, route, n_tokens):
+    """``moe.tokens_from_rows`` in its gather form: the dispatch's backward
+    pass as shipped (bf16 rows; fp32 rows for the comparison with (a))."""
+    from horovod_tpu.parallel import moe
+    return moe.tokens_from_rows(
+        rows, token, places_alone(held, rows, token, n_live, route,
+                                  n_tokens), n_tokens)
+
+
+def shipped_combine(held, rows, token, n_live, route, n_tokens):
+    """``moe.topk_combine``'s sum in the gather form: bf16 rows, each times
+    its choice's weight, summed in fp32."""
+    from horovod_tpu.parallel import moe
+    return moe.rows_at_places(
+        rows.astype(jnp.bfloat16), places_alone(
+            held, rows, token, n_live, route, n_tokens), route.weight)
+
+
+def routed_forms(held: int) -> dict:
+    return {"h:gathered (moe.tokens_from_rows)":
+                functools.partial(shipped_gather, held),
+            "h:combine (bf16 rows, weighted, fp32 out)":
+                functools.partial(shipped_combine, held)}
 
 
 def row_sums(rehearse: bool, ranks: int) -> dict:
@@ -280,9 +321,10 @@ def row_sums(rehearse: bool, ranks: int) -> dict:
 
 def routed_tokens(t: int, k: int, n_experts: int, held: int, n_live: int,
                   n_rows: int, seed: int):
-    """``(token [n_rows], n_live)`` of the first buffer, as ``topk_order``
-    leaves them, for a router whose held experts ``0 .. held - 1`` draw
-    about ``n_live`` of the ``t x k`` assignments."""
+    """``(token [n_rows], n_live, route)`` of the first buffer, as
+    ``topk_order`` leaves them, for a router whose held experts ``0 .. held
+    - 1`` draw about ``n_live`` of the ``t x k`` assignments (weights of
+    1)."""
     from horovod_tpu.parallel import moe
     rng = np.random.RandomState(seed)
     scores = rng.rand(t, n_experts)
@@ -297,7 +339,7 @@ def routed_tokens(t: int, k: int, n_experts: int, held: int, n_live: int,
         expert, jnp.ones(expert.shape, jnp.float32),
         jnp.bincount(expert.reshape(-1), length=n_experts).astype(jnp.int32))
     token, _, sizes = moe.topk_order(route, 0, held)
-    return token[:n_rows], jnp.minimum(jnp.sum(sizes), n_rows)
+    return token[:n_rows], jnp.minimum(jnp.sum(sizes), n_rows), route
 
 
 def rows(args) -> dict:
@@ -306,25 +348,31 @@ def rows(args) -> dict:
         (args.tokens, args.top_k, args.router_outputs, args.experts,
          args.rows, 2048, tuple(args.live or (2048, 4096, 8192))))
     calls = 2 if args.rehearse else 50
-    forms = {name: form for name, form in row_sums(
-        args.rehearse, min(k, held)).items()
+    forms = {name: form for name, form in {
+        **row_sums(args.rehearse, min(k, held)),
+        **routed_forms(held)}.items()
         if not args.forms or name[0] in ["a"] + args.forms}
     out = {"device": jax.devices()[0].device_kind, "rows": n_rows,
            "tokens": t, "d": d, "ms": {}}
     for n_live in lives:
-        token, live = routed_tokens(t, k, n_experts, held, n_live, n_rows,
-                                    seed=n_live)
+        token, live, route = routed_tokens(t, k, n_experts, held, n_live,
+                                           n_rows, seed=n_live)
         cut = (jnp.arange(n_rows) < live)[:, None]
         for dtype in (jnp.float32, jnp.bfloat16):
             x = jnp.where(cut, jax.random.normal(
                 jax.random.PRNGKey(n_live), (n_rows, d), dtype), 0)
             want = None
             for name, form in forms.items():
+                if "combine" in name and dtype != jnp.float32:
+                    continue
                 fn = jax.jit(functools.partial(form, n_tokens=t))
+                # (the shipped form finds its places from the route, inside
+                # the timed call)
+                xs = (x, token, live) + ((route,) if name[0] == "h" else ())
                 try:
-                    got = np.asarray(fn(x, token, live), np.float32)
+                    got = np.asarray(fn(*xs), np.float32)
                     want = got if want is None else want
-                    rec = {"ms": ms_per_call(fn, (x, token, live), calls),
+                    rec = {"ms": ms_per_call(fn, xs, calls),
                            "against_first": float(
                                np.abs(got - want).max() / np.abs(want).max())}
                 except Exception as e:  # a form the compiler refuses, kept
@@ -338,6 +386,12 @@ def rows(args) -> dict:
             gather = jax.jit(lambda g, token: g[token])
             key = "gather live=%d %s" % (int(live), jnp.dtype(dtype).name)
             out["ms"][key] = {"ms": ms_per_call(gather, (g, token), calls)}
+            print(key, out["ms"][key], flush=True)
+        if not args.forms or "h" in args.forms:
+            key = "place live=%d" % int(live)
+            out["ms"][key] = {"ms": ms_per_call(
+                jax.jit(functools.partial(places_alone, held, n_tokens=t)),
+                (x, token, live, route), calls)}
             print(key, out["ms"][key], flush=True)
     return out
 
