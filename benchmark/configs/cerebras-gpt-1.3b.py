@@ -72,8 +72,9 @@ def kernel_costs(cfg: TransformerConfig, rows: int) -> dict:
     """What the attention kernels of ONE step on one chip must do, from
     shapes: operations as in :func:`flops_per_sample` (6 causal-half
     matmuls of T x T x head a head a row a layer; the backward's
-    recomputation of the scores is not counted, so a flash backward can
-    reach 6/7 of its compute roofline at best), bytes as q, k, v, o read or
+    recomputation of the scores is not counted, so the fused splash
+    backward, 5 matmuls for the 4 counted, can reach 4/5 of its compute
+    roofline at best), bytes as q, k, v, o read or
     written once forward and q, k, v, o, do read and dq, dk, dv written
     once backward (12 passes over [rows, heads, T, head] in bfloat16)."""
     t, layers = cfg.max_seq, cfg.n_layers

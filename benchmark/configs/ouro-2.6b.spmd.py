@@ -32,9 +32,9 @@ project = files.load_module(os.path.join(
 # 2,048 and not the timed 4,096: the reference's float32 backward, even with
 # its layers recomputed, needs 10.2 GB at 4,096 positions and 5.9 GB at 2,048
 # beside 2 GB of weights (v5e compiler, PR 28), and the cell's peak_hbm_gb
-# is to be the training step's. At 2,048 under remat="block" the kernel
-# selector picks the same flash kernels, at the same block sizes, as at
-# 4,096 (parallel/flash_attention.py _select_kernel).
+# is to be the training step's. At 2,048 under remat="block" the call goes
+# to the same stock splash kernels, one fused backward, at the same block
+# sizes as at 4,096 (parallel/flash_attention.py splash_geometry, PR 31).
 # Both are projected on 8 seeded directions a leaf; the error is the worst
 # difference over leaves and directions as a share of what a direction
 # reads of a vector of the longer side's length (a leaf off by a share e of

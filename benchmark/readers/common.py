@@ -28,8 +28,8 @@ def dig(record: dict, path: str):
 
 
 def traced_devices(ctx: dict):
-    """(device summary, benchmark-side spans, traced steps) of every chip
-    that was traced."""
+    """(device summary, the host's spans, traced steps) of every chip that
+    was traced."""
     traced = ctx["record"].get("traced")
     if not traced:
         return []

@@ -9,9 +9,9 @@ and without them a cell over four chips reads what its one-chip twin does.
 A fusion has one ``op_name``, its root's: where XLA fused across a scope's
 edge, the whole fusion's time goes to the root's scope.
 
-It reads ``record["traced"]["scoped"]``, the device entries scopes.py
-makes of the profiler's file. Nothing to read (None) where the record has
-none (today's worker: scopes.py says why) or no operation matched: a
+It reads the ``scopes`` that xplane.py's summary holds beside ``labels``
+(the worker records them since PR 36). Nothing to read (None) where the
+record has none (a record written before) or no operation matched: a
 program without the scope.
 
 ``closes`` names the metrics that, with this one and the collectives, must
@@ -22,12 +22,13 @@ line.
 import files
 import stats
 import tracecalc
+from common import traced_devices
 
 
 def scoped_devices(ctx):
     """(device entry with ``scopes``, traced steps) of every traced chip."""
-    traced = ctx["record"].get("traced") or {}
-    return [(dev, traced["steps"]) for dev in traced.get("scoped", [])]
+    return [(dev, steps) for dev, _, steps in traced_devices(ctx)
+            if "scopes" in dev]
 
 
 def collective():
@@ -44,10 +45,10 @@ def per_step_ms(ctx, spec):
     match = stats.matcher(spec["match"])
     exclude = stats.matcher(spec.get("exclude", []))
     moves = collective()
-    return [sum(self_ns for _, _, self_ns, i in dev["ops"]
-                if match(dev["scopes"][i]) and not exclude(dev["scopes"][i])
-                and not moves(dev["labels"][i])) / 1e6 / steps
-            for dev, steps in scoped_devices(ctx)]
+    return [tracecalc.matched_self_ns(
+        dev, lambda label: not moves(label),
+        lambda scope: match(scope) and not exclude(scope)) / 1e6 / steps
+        for dev, steps in scoped_devices(ctx)]
 
 
 def say_what_is_left(ctx, spec, own):
@@ -62,7 +63,7 @@ def say_what_is_left(ctx, spec, own):
     busy = mean([stats.total(tracecalc.busy(dev)) / 1e6 / steps
                  for dev, steps in devices])
     total = own + sum(parts.values())
-    ctx.setdefault("notes", []).append(
+    ctx["notes"].append(
         "scopes: " + " + ".join(f"{k} {v:.3f}" for k, v in parts.items())
         + f" + this {own:.3f} = {total:.3f} ms a step against "
         f"{busy:.3f} ms busy: {(total - busy) / busy * 100:+.4f}%")

@@ -171,7 +171,8 @@ def traced_device(ctx: dict) -> dict:
 
 
 def breakdown(ctx: dict) -> dict:
-    """Of the first chip of the trace."""
+    """Of the first chip of the trace: the operations that took most time
+    by pass, scope and name, and the idle gaps by span."""
     trace = ctx["record"]["traced"]["trace"]
     dev = trace["devices"][0]
     return {"device_ops": tracecalc.top_ops(dev),
@@ -217,7 +218,8 @@ def reduce(cell: dict, rec: dict, trace: int, rehearse: bool):
                                         rec["traced"]["stamps"][1:])]
         say(f"tracing: step_ms_p50 {stats.median(traced) * 1e3:.4f} traced "
             f"against {e2e['step_ms_p50']:.4f} untraced "
-            f"({rec['traced']['steps']} traced steps)")
+            f"({rec['traced']['steps']} traced steps); the profiler's file "
+            f"walked in {rec['traced'].get('walk_s', float('nan')):.2f} s")
         say("end to end (untraced window of this run): " + json.dumps(e2e))
         for note in ctx["notes"]:
             say(note)

@@ -48,8 +48,11 @@ def test_reduced_names_no_width():
         spec = files.load_json(files.config_path(c["name"]))
         for key in c["reduced"]:
             assert key in spec and key in spec["published"]
+            # a width: a size, a key ending in _dim or _rank, a head size;
+            # the catalog's num_hidden_layers is a depth
             assert not re.search(
-                r"(_dim|_rank|embd|hidden|inner|intermediate|head)", key)
+                r"(_dim$|_rank$|embd|inner|intermediate|head|expansion|"
+                r"per_tok|(hidden|latent|state|proj\w*)_(size|width)$)", key)
 
 
 def test_every_file_resolves():
@@ -67,6 +70,24 @@ def test_every_file_resolves():
         spec, read = files.layer_metric(m["name"])
         assert callable(read) and spec["doc"]
     assert files.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+
+
+def test_every_metric_file_is_declared():
+    """No metric is read by hand and not declared: a file in
+    ``layer_metrics/`` is a line of BENCHMARK.json's ``per_layer``, and a
+    metric of only some cells lists them (a later PR's cell is refused
+    where a metric that moves its end-to-end metric has no list)."""
+    folder = os.path.join(files.HERE, "layer_metrics")
+    declared = {m["name"]: m for m in files.benchmark_json()["per_layer"]}
+    assert {f[:-5] for f in os.listdir(folder) if f.endswith(".json")} == \
+        set(declared)
+    assert {f[:-3] for f in os.listdir(folder) if f.endswith(".py")} <= \
+        set(declared)
+    for name, m in declared.items():
+        if files.layer_metric(name)[0]["reader"] == "trace_scopes":
+            assert m["workloads"] and m["source"] == "device_trace"
+            assert (m["unit"], m["better"], m["moves"]) == \
+                ("ms", "lower", "step_ms_p50")
 
 
 def test_unknown_device_is_an_error():

@@ -2,10 +2,7 @@
 device entry written by hand, whose numbers are worked out below, and on
 the recorded four-chip trace of a program that has no such pair."""
 
-import gzip
 import os
-
-from jax.profiler import ProfileData
 
 import files
 import xplane
@@ -49,9 +46,8 @@ def test_a_start_without_its_done_reads_nothing():
 
 
 def test_the_parents_program_has_no_pair():
-    with gzip.open(os.path.join(
-            DATA, "lm-spmd-4chip-dp.chip0.1step.xplane.pb.gz")) as f:
-        trace = xplane.summarize(ProfileData.from_serialized_xspace(f.read()))
+    trace = xplane.summarize_file(os.path.join(
+        DATA, "lm-spmd-4chip-dp.chip0.1step.xplane.pb.gz"))
     ctx = {"record": {"traced": {"steps": 1, "trace": trace}}}
     assert read("grad_reduce_wait_ms_per_step", ctx) == 0.0
     assert read("grad_reduce_span_ms_per_step", ctx) == 0.0

@@ -109,18 +109,21 @@ def test_a_wrong_model_fails_the_cells_checks(defect, limits):
 
 def test_the_traced_line_has_every_metric_of_the_cell(capsys):
     """One step of the cell's own program as the v5e's profiler recorded it
-    (my chip run, PR 34: ``chiprun_out/lfm2_traced``, the third of its eight
-    traced steps, cut by make_fixture.py), reduced with the costs the
-    configuration counts from shapes: every per-layer metric declared for
-    the cell is on the line, and the three that this cell brought read what
-    that run's own line read (35.93 ms, 23.30%, 55.77% over its 8 steps).
-    ``test_run.py``'s case of this cell reads the plain decoder's trace, in
-    which no MQA kernel and no grouped product ran."""
+    (my chip run, PR 36; cut by make_fixture.py with every operation's
+    op_name), reduced with the record that run left: the costs the
+    configuration counts from shapes are the record's, every per-layer
+    metric declared for the cell is on the line, and the three that this
+    cell brought read what the ledger's PR 35 line reads (35.94 ms, 23.30%,
+    55.82% over 8 steps). Every grouped product lies under the scope
+    ``experts``: the roofline reads by name and scope what the name alone
+    read."""
     import run
+    import trace_ops
     from test_run import chip_record, declared_for
     model, cfg = cell_config()
-    rec = chip_record("lfm2-spmd-1chip-ep4share-8k.1step.xplane.pb.gz", 1,
-                      kernel_costs=model.kernel_costs(cfg, 2))
+    rec = chip_record(CELL)
+    assert json.loads(json.dumps(model.kernel_costs(cfg, 2))) == \
+        rec["kernel_costs"]
     line = run.reduce(files.cell(CELL), rec, 1, False)
     assert "left out of the line" not in capsys.readouterr().out
     assert set(line["metrics"]) == declared_for(CELL)
@@ -129,4 +132,11 @@ def test_the_traced_line_has_every_metric_of_the_cell(capsys):
                                                                    abs=0.3)
     assert read["head64_attn_kernel_roofline"] == pytest.approx(23.3, abs=0.3)
     assert read["ep4_moe_experts_roofline"] == pytest.approx(55.8, abs=0.8)
+    spec, _ = files.layer_metric("ep4_moe_experts_roofline")
+    ctx = {"record": rec}
+    by_name = {k: v for k, v in spec.items() if k != "scope"}
+    assert trace_ops.per_step_ms(ctx, {**spec, "what": "self"}) == \
+        trace_ops.per_step_ms(ctx, {**by_name, "what": "self"})
+    assert trace_ops.per_step_ms(ctx, {
+        **spec, "what": "self", "scope": "moe_combine"}) == [0.0]
     assert 0 < read["mfu_pct"] < 100

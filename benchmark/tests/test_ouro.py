@@ -114,12 +114,12 @@ LOOPED = "jit(train_step)/transpose(jvp())/loop/while/body/closed_call/"
 
 
 @pytest.mark.parametrize("name,scope_names,reads,leaves", [
-    ("exit_head_loss_ms_per_step",
-     (program.HEAD, program.LOSS, program.EXIT_GATE),
+    ("head_loss_ms_per_step", (program.HEAD, program.LOSS),
      [LOOPED + "checkpoint/rematted_computation/head/btd,vd->btv/dot_general",
-      "jit(train_step)/jvp()/loop/while/body/closed_call/exit_gate/mul",
       LOOPED + "checkpoint/loss/exp"],
-     [LOOPED + "layers/while/body/closed_call/checkpoint/attn/header/mul"]),
+     [LOOPED + "layers/while/body/closed_call/checkpoint/attn/header/mul",
+      # 0.13 ms of the gate (PR 28) is not the exits' heads and losses
+      "jit(train_step)/jvp()/loop/while/body/closed_call/exit_gate/mul"]),
     ("rope_ms_per_step", (program.ROPE,),
      [LOOPED + "layers/while/body/closed_call/checkpoint/attn/rope/mul",
       "jit(train_step)/jvp()/rope/cos"],
@@ -128,10 +128,11 @@ LOOPED = "jit(train_step)/transpose(jvp())/loop/while/body/closed_call/"
      [LOOPED + "checkpoint/rematted_computation/head/btd,vd->btv/dot_general"],
      [LOOPED + "checkpoint/head/btd,vd->btv/dot_general"]),
 ])
-def test_the_metric_files_read_by_hand(name, scope_names, reads, leaves):
-    """The three files of PR 28 that benchmark/scopes.py prints and
-    BENCHMARK.json does not declare: they quote the program's scope names
-    and read the op_names jax writes under the loop and under remat."""
+def test_the_metric_files_read_the_loops_scopes(name, scope_names, reads, leaves):
+    """The files that read the looped model's scopes (PR 28; declared for
+    its cell since PR 36, the exits under ``head_loss_ms_per_step``): they
+    quote the program's scope names and read the op_names jax writes under
+    the loop and under remat."""
     spec, _ = files.layer_metric(name)
     assert spec["reader"] == "trace_scopes" and spec["doc"]
     (pattern,) = spec["match"]

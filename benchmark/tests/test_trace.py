@@ -2,7 +2,6 @@
 collective time: on a trace written by hand, whose numbers are worked out
 below, and on a trace recorded on the v5e (data/, see make_fixture.py)."""
 
-import gzip
 import json
 import os
 
@@ -35,11 +34,12 @@ OPS = [("%while.1 = (s32[]{:T(128)}, " + T + ") while(%tuple.1), body=%b", 0, 10
         "calls=%f.3", 160, 10),
        ("~%all-reduce-start.1 = f32[1,25557032]{1,0} all-reduce-start(%x), "
         "replica_groups={{0,1,2,3}}", 100, 25)]
+# the program's span (``hvd.``) lies inside the benchmark's ``bench.wait``
 SPANS = [("bench.step", 0, 60), ("bench.grad", 5, 20), ("bench.wait", 141, 24),
-         ("not.ours", 0, 500)]
+         ("hvd.replay.wait", 145, 10), ("not.ours", 0, 500)]
 
 
-def by_hand() -> ProfileData:
+def by_hand() -> bytes:
     meta = {name: i + 1 for i, (name, *_) in enumerate(OPS)}
     text = ['planes { id: 1 name: "/device:TPU:0"']
     for name, i in meta.items():
@@ -71,8 +71,7 @@ def by_hand() -> ProfileData:
                     f'duration_ps: {dur * 10**6} }}')
     text.append('  }')
     text.append('}')
-    return ProfileData.from_serialized_xspace(
-        ProfileData.text_proto_to_serialized_xspace("\n".join(text)))
+    return ProfileData.text_proto_to_serialized_xspace("\n".join(text))
 
 
 @pytest.fixture(scope="module")
@@ -84,8 +83,9 @@ def test_summary_shape(summary):
     (dev,) = summary["devices"]
     assert dev["plane"] == "/device:TPU:0"
     assert len(dev["ops"]) == len(OPS) - 1 and len(dev["async"]) == 1
-    assert [s[0] for s in summary["spans"]] == [
-        "bench.step", "bench.grad", "bench.wait"]       # ours only
+    assert [s[0] for s in summary["spans"]] == [        # ours only
+        "bench.step", "bench.grad", "bench.wait", "hvd.replay.wait"]
+    assert set(dev["scopes"]) == {""}       # no tf_op was written
     labels = {tracecalc.op_name(x): x for x in dev["labels"]}
     assert labels["splash_mha_fwd_residuals.4"] == (
         "splash_mha_fwd_residuals.4 | custom-call tpu_custom_call | "
@@ -113,13 +113,18 @@ def test_self_time_of_a_nest(summary):
     assert selfs["while.1"] == pytest.approx(60e3)      # 100 - 20 - 20
     assert selfs["fusion.1"] == pytest.approx(20e3)
     assert sum(selfs.values()) == pytest.approx(135e3)  # = busy time
-    top = dict(tracecalc.top_ops(dev))
-    assert top["while bf16[4,2048,2048]"] == pytest.approx(60e-6)
-    assert top["all-reduce f32[8]"] == pytest.approx(10e-6)
-    assert top["fusion kLoop f32[64]"] == pytest.approx(10e-6)
-    assert top["multiply_fusion (fusion kLoop) f32[64]"] == pytest.approx(8e-6)
-    assert top["splash_mha_fwd_residuals bf16[4,16,2048,128]"] == \
+    top = dict(tracecalc.top_ops(dev))      # no op_name: no pass, no scope
+    assert top["- | while bf16[4,2048,2048]"] == pytest.approx(60e-6)
+    assert top["- | all-reduce f32[8]"] == pytest.approx(10e-6)
+    assert top["- | fusion kLoop f32[64]"] == pytest.approx(10e-6)
+    assert top["- | multiply_fusion (fusion kLoop) f32[64]"] == \
+        pytest.approx(8e-6)
+    assert top["- | splash_mha_fwd_residuals bf16[4,16,2048,128]"] == \
         pytest.approx(20e-6)
+    # a record written before PR 36 has no scopes: the group alone
+    old = {k: v for k, v in dev.items() if k != "scopes"}
+    assert dict(tracecalc.top_ops(old))["while bf16[4,2048,2048]"] == \
+        pytest.approx(60e-6)
 
 
 def test_kernel_and_collective_time(summary):
@@ -148,10 +153,14 @@ def test_roofline_share(summary):
 def test_idle_gaps_by_span(summary):
     (dev,) = summary["devices"]
     gaps = dict(tracecalc.idle_by_span(dev, summary["spans"]))
-    # [140,160] falls in bench.wait; [110,120] and [125,130] in no span
-    assert gaps["bench.wait"] == pytest.approx(20e-6)
+    # [140,160]: its middle falls in bench.wait and, inside that, in the
+    # program's own span, the innermost; [110,120] and [125,130] in no span
+    assert gaps["hvd.replay.wait"] == pytest.approx(20e-6)
+    assert "bench.wait" not in gaps
     assert gaps["(no span)"] == pytest.approx(15e-6)
     assert tracecalc.span_at(summary["spans"], 10e3) == "bench.grad"
+    assert tracecalc.span_at(summary["spans"], 143e3) == "bench.wait"
+    assert tracecalc.span_at(summary["spans"], 156e3) == "bench.wait"
 
 
 def test_intervals():
@@ -175,8 +184,38 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
 
 
 def recorded(name: str) -> dict:
-    with gzip.open(os.path.join(DATA, name)) as f:
-        return xplane.summarize(ProfileData.from_serialized_xspace(f.read()))
+    return xplane.summarize_file(os.path.join(DATA, name))
+
+
+def pass_and_scope(op_name: str) -> str:
+    return f"{tracecalc.pass_of(op_name)} {tracecalc.scope_path(op_name)}"
+
+
+def test_pass_and_scope_of_an_op_name():
+    """What jax wraps around every operation goes, the program's scopes
+    stay, the primitive goes; the pass is read off the wrapping."""
+    layer = "layers/while/body/closed_call/"
+    assert pass_and_scope(
+        f"jit(train_step)/transpose(jvp())/{layer}ffn/btd,df->btf/"
+        "dot_general") == "bwd layers/ffn"
+    assert pass_and_scope(
+        f"jit(train_step)/jvp(layers)/while/body/closed_call/ffn/while/body/"
+        "experts/jit(gmm)/pallas_call") == "fwd layers/ffn/experts"
+    assert pass_and_scope(
+        f"jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+        "checkpoint/rematted_computation/attn/attn_full/"
+        "vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/"
+        "splash_mqa_fwd_residuals/pallas_call") == \
+        "remat layers/attn/attn_full/splash_mqa_fwd_residuals"
+    assert pass_and_scope(
+        "jit(train_step)/shard_map/transpose(jvp(embed))/scatter-add") == \
+        "bwd embed"
+    # jvp( under the optimizer's scope is the optimizer's, as the metrics
+    # read it; an operation XLA made itself has no op_name
+    assert pass_and_scope("jit(hvd_apply_update)/optimizer/jvp(mul)") == \
+        "opt optimizer"
+    assert pass_and_scope("jit(f)/multi_optimizer/mul") == "- multi_optimizer"
+    assert pass_and_scope("") == "- "
 
 
 def test_recorded_lm_trace():
@@ -206,9 +245,9 @@ def test_recorded_lm_trace():
     spec, read = files.layer_metric("collective_ms_per_step")
     assert read(ctx, spec) == 0.0       # one chip: there is none
     top = tracecalc.top_ops(dev, 3)
-    assert [g for g, _ in top] == ["fusion kOutput bf16[4,2048,2048]",
-                                   "fusion kOutput f32[50257,2048]",
-                                   "fusion kOutput bf16[4,2048,50257]"]
+    assert [g for g, _ in top] == ["- | fusion kOutput bf16[4,2048,2048]",
+                                   "- | fusion kOutput f32[50257,2048]",
+                                   "- | fusion kOutput bf16[4,2048,50257]"]
     assert top[0][1] / 2 == pytest.approx(26.713e-3, abs=1e-5)
 
 
@@ -233,5 +272,5 @@ def test_recorded_four_chip_trace():
     assert tracecalc.idle_by_span(dev, summary["spans"]) == [
         ["bench.wait", pytest.approx(1.795e-6)]]
     top = dict(tracecalc.top_ops(dev, 100))
-    assert top["psum (all-reduce) f32[50257,2048]"] == \
+    assert top["- | psum (all-reduce) f32[50257,2048]"] == \
         pytest.approx(7.240126e-3)

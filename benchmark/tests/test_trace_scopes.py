@@ -1,9 +1,8 @@
-"""The device trace by scope (scopes.py, readers/trace_scopes.py and the
-five metric files that read it): on a trace written by hand and on two
-steps of ``lm-spmd-1chip`` recorded on the v5e with the scopes in the
-program (my chip run, PR 24; cut by make_scope_fixture.py)."""
+"""The device trace by scope (xplane.py's ``scopes``,
+readers/trace_scopes.py and the metric files that read them): on a trace
+written by hand and on two steps of ``lm-spmd-1chip`` recorded on the v5e
+with the scopes in the program (my chip run, PR 24)."""
 
-import gzip
 import os
 import re
 
@@ -64,8 +63,8 @@ def by_hand() -> bytes:
 
 
 def ctx_of(summary: dict, steps: int) -> dict:
-    return {"record": {"traced": {"scoped": summary["devices"],
-                                  "steps": steps}}, "notes": []}
+    return {"record": {"traced": {"trace": summary, "steps": steps}},
+            "notes": []}
 
 
 def read(ctx: dict, name: str):
@@ -74,7 +73,7 @@ def read(ctx: dict, name: str):
 
 
 def test_by_hand():
-    summary = scopes.summarize(by_hand())
+    summary = xplane.summarize(by_hand())
     (dev,) = summary["devices"]
     assert dev["plane"] == "/device:TPU:0" and len(dev["ops"]) == len(HAND)
     # one label, two scopes: two operations
@@ -83,6 +82,10 @@ def test_by_hand():
     assert twice == [HAND[1][2], HAND[7][2]]
     assert dev["scopes"][dev["ops"][5][3]] == ""        # the copy has none
     assert dev["ops"][0][:3] == [0.0, 100e3, 50e3]      # 100 - 20 - 30
+    # the breakdown's names: pass, scope path, group
+    assert [g for g, _ in tracecalc.top_ops(dev, 3)] == [
+        "fwd layers | while f32[64]", "bwd layers/ffn | fusion kLoop f32[64]",
+        "fwd layers/attn | fusion kLoop f32[64]"]
     ctx = ctx_of(summary, 2)
     # microseconds over two steps, in milliseconds a step
     assert read(ctx, "fwd_ms_per_step") == pytest.approx((50 + 20) / 2e3)
@@ -97,33 +100,22 @@ def test_by_hand():
 
 
 def test_a_record_without_scopes_reads_nothing():
-    """Today's worker records xplane.py's summary alone: the reader then
+    """A record written before PR 36 holds labels alone: the reader then
     says nothing, and does not raise."""
-    with gzip.open(SCOPED) as f:
-        summary = xplane.summarize(ProfileData.from_serialized_xspace(
-            f.read()))
-    ctx = {"record": {"traced": {"trace": summary, "steps": 2}}}
+    summary = xplane.summarize_file(SCOPED)
+    for dev in summary["devices"]:
+        del dev["scopes"]
+    ctx = ctx_of(summary, 2)
     assert all(read(ctx, name) is None for name in METRICS)
     assert all(read({"record": {}}, name) is None for name in METRICS)
+    assert ctx["notes"] == []
 
 
 def test_recorded_scoped_trace():
-    summary = scopes.summarize_file(SCOPED)
+    summary = xplane.summarize_file(SCOPED)
     (dev,) = summary["devices"]
-    # the same operations and times as the reduction every other metric
-    # goes through
-    with gzip.open(SCOPED) as f:
-        (old,) = xplane.summarize(ProfileData.from_serialized_xspace(
-            f.read()))["devices"]
-    assert len(dev["ops"]) == len(old["ops"]) == 1916
-    first = old["ops"][0][0]    # that summary's clock starts at the module
-    for a, b in zip(dev["ops"], old["ops"]):
-        # ProfileData gives whole nanoseconds, the file picoseconds; a
-        # while's self time gathers the difference of all it holds
-        assert a[:2] == pytest.approx([b[0] - first, b[1]], abs=2.0)
-        assert a[2] == pytest.approx(b[2], abs=1e3)
-        assert dev["labels"][a[3]] == old["labels"][b[3]]
-    assert [m[0].split("(")[0] for m in old["modules"]] == \
+    assert len(dev["ops"]) == 1916
+    assert [m[0].split("(")[0] for m in dev["modules"]] == \
         ["jit_train_step"] * 2
     ctx = ctx_of(summary, 2)
     got = {name: read(ctx, name) for name in METRICS}
@@ -139,9 +131,7 @@ def test_recorded_scoped_trace():
     assert got["unscoped_ms_per_step"] < 0.03 * busy
     # an attention kernel lies under the scope 'attn', and the metric that
     # matches labels is not moved by a scope of that name
-    spec, reader = files.layer_metric("attn_kernel_ms_per_step")
-    assert reader({"record": {"traced": {
-        "trace": {"devices": [old], "spans": []}, "steps": 2}}}, spec) == \
+    assert read(ctx, "attn_kernel_ms_per_step") == \
         pytest.approx(13.14, abs=0.01)
     kernels = {dev["scopes"][i] for *_, i in dev["ops"]
                if "splash" in dev["labels"][i]}
@@ -149,6 +139,39 @@ def test_recorded_scoped_trace():
     top = dict(scopes.by_prefix(dev))
     assert top["jit(train_step)/optimizer"] / 2 == \
         pytest.approx(12.3479e-3, abs=1e-6)
+    # the breakdown names the pass and the layer: PR 24's program spent
+    # most in the head's backward (ISSUE 36 expected a layer's), and the
+    # layers' matmuls follow under their own scopes
+    named = tracecalc.top_ops(dev)
+    assert named[0][0] == "bwd head | fusion kOutput bf16[4,2048,2048]"
+    assert named[0][1] / 2 == pytest.approx(10.92e-3, abs=0.01e-3)
+    assert "bwd layers/ffn | fusion kOutput bf16[4,2048,8192]" in dict(named)
+    assert len(named) == 10 and all(
+        g.split()[0] in ("fwd", "bwd") for g, _ in named)
+
+
+@pytest.mark.parametrize("name", sorted(
+    f for f in os.listdir(DATA) if f.endswith(".xplane.pb.gz")))
+def test_the_walker_reads_what_profiledata_read(name):
+    """Until PR 36 ``jax.profiler.ProfileData`` read the file for every
+    metric but the scopes': the one walker gives its events bit for bit,
+    whole nanoseconds on one clock, the three lines and the spans."""
+    raw = xplane.read_file(os.path.join(DATA, name))
+    (dev,) = xplane.summarize(raw)["devices"]
+    profile = ProfileData.from_serialized_xspace(raw)
+    (plane,) = [p for p in profile.planes
+                if xplane.DEVICE_PLANE.match(p.name)]
+    lines = {line.name: list(line.events) for line in plane.lines}
+    # the summary's clock starts at the earliest event kept, a span's too
+    t0 = lines[xplane.MODULES_LINE][0].start_ns - dev["modules"][0][1]
+    for key, line in (("ops", xplane.OPS_LINE), ("async", xplane.ASYNC_LINE)):
+        events = lines.get(line, [])
+        assert [o[:2] for o in dev[key]] == [
+            [e.start_ns - t0, e.duration_ns] for e in events]
+        assert [dev["labels"][o[-1]] for o in dev[key]] == [
+            xplane.label_of(e.name) for e in events]
+    assert [m[0] for m in dev["modules"]] == [
+        e.name for e in lines[xplane.MODULES_LINE]]
 
 
 @pytest.mark.parametrize("name", ["lm-spmd-1chip.2steps.xplane.pb.gz",
@@ -156,7 +179,7 @@ def test_recorded_scoped_trace():
 def test_recordings_without_scopes(name):
     """The older recordings kept no ``tf_op``: nothing is forward, backward
     or optimizer, and everything but the collectives is unscoped."""
-    summary = scopes.summarize_file(os.path.join(DATA, name))
+    summary = xplane.summarize_file(os.path.join(DATA, name))
     (dev,) = summary["devices"]
     assert set(dev["scopes"]) == {""}
     steps = 2 if "2steps" in name else 1
@@ -175,8 +198,19 @@ def test_the_metric_files_quote_the_programs_names():
     """The patterns are data; the names are the program's
     (horovod_tpu/common/scopes.py), in the forms jax gives them."""
     from horovod_tpu.common import scopes as program
-    spec = {n: files.layer_metric(n)[0] for n in METRICS}
-    assert sorted(spec) == sorted(scopes.scope_metrics())
+    names = scopes.scope_metrics()
+    assert set(METRICS) < set(names) and len(names) == 10
+    spec = {n: files.layer_metric(n)[0] for n in names}
+    # every scope a file quotes is one the program writes
+    written = {v for k, v in vars(program).items()
+               if k.isupper() and isinstance(v, str)}
+    for n in names:
+        for quoted in re.findall(r"\[/\(\]\)\(?([a-z_|]+)\)?\(", "".join(
+                spec[n]["match"] + spec[n].get("exclude", []))):
+            assert set(quoted.split("|")) <= written, (n, quoted)
+    for n in ("moe_experts_roofline", "ep4_moe_experts_roofline"):
+        assert files.layer_metric(n)[0]["scope"] == \
+            f"(^|[/(]){program.EXPERTS}([/)]|$)"
     (optimizer,) = spec["optimizer_ms_per_step"]["match"]
     assert program.OPTIMIZER in optimizer
     assert optimizer in spec["fwd_ms_per_step"]["exclude"]
@@ -195,5 +229,5 @@ def test_the_metric_files_quote_the_programs_names():
                     "jit(f)/jvp()/multihead/mul", "jit(f)/closs/add"]:
         assert not re.search(head_loss, op_name)
         assert not re.search(optimizer, op_name)
-    for name in METRICS:
-        assert spec[name]["doc"]
+    for name in names:
+        assert spec[name]["doc"] and "by hand" not in spec[name]["doc"]

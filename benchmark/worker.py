@@ -247,9 +247,11 @@ def main() -> int:
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         run(TRACED_STEPS, stamps, scratch)
         jax.profiler.stop_trace()
+        t_walk = time.monotonic()
+        trace = xplane.summarize_file(xplane.newest_xplane(trace_dir))
         record["traced"] = {
-            "steps": TRACED_STEPS, "stamps": stamps,
-            "trace": xplane.summarize_file(xplane.newest_xplane(trace_dir))}
+            "steps": TRACED_STEPS, "stamps": stamps, "trace": trace,
+            "walk_s": time.monotonic() - t_walk}
         if job.probe is not None:
             record["probes"] = []
             for _ in range(PROBE_STEPS):
