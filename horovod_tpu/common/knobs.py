@@ -453,10 +453,6 @@ KNOB_SPECS: Dict[str, dict] = {
     "HOROVOD_TIMELINE_MARK_CYCLES": {
         "type": "bool", "default": "0",
         "help": "Mark engine cycle boundaries in the timeline."},
-    "HOROVOD_TIMELINE_NATIVE": {
-        "type": "bool", "default": "1",
-        "help": "Use the native timeline writer when available; =0 "
-                "forces the pure-Python writer."},
     # -- fault injection ----------------------------------------------------
     "HOROVOD_TPU_FAULTS": {
         "type": "spec", "default": "",

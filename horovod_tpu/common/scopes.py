@@ -14,12 +14,19 @@ TPU the profiler keeps each executed operation's ``op_name`` as the
 ``tf_op`` stat of the event's metadata; ``benchmark/scopes.py``,
 ``benchmark/readers/trace_scopes.py`` and their metric files read these
 names, and ``docs/observability.md`` lists them ("Reading a device trace
-by scope"). A fusion has one ``op_name``, its root's.
+by scope"). A fusion has one ``op_name``, its root's. The benchmark's
+worker keeps every executed operation's ``op_name`` in its record, and the
+traced line carries the by-scope metrics (PR 36).
 
 jax leaves metadata out of the persistent compilation cache's key, so a
 program cached before a scope was written is served without it. The
 program's name IS part of the key: a change to what the scopes cover that
 must show in traces takes a new program name with it.
+
+The host's side of the same trace: :func:`host_span` writes a span of the
+product's host code on the profiler's clock, the one the device lines are
+on (``HOST_SPANS``; ``docs/observability.md``, "Host spans on the
+profiler's clock").
 """
 
 from __future__ import annotations
@@ -75,3 +82,33 @@ def apply_update(optimizer, grads, opt_state, params):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state
 
+
+# host spans: the product's host code on the profiler's clock, ``hvd.<name>``
+# on the calling thread's line of the trace's ``/host:CPU`` plane
+# optimizer.py: DistributedEagerOptimizer.update_and_apply (the sharded
+# and the delta-Adasum twins write the outer span only)
+OPT_UPDATE_AND_APPLY = "opt.update_and_apply"   # entry to the return of the
+#                                                 unawaited outputs
+OPT_FLATTEN = "opt.flatten"             # tree_flatten(grads), _sparse_ks
+OPT_REDUCE = "opt.reduce"               # _reduce_async; no world of one
+OPT_APPLY_LOOKUP = "opt.apply_lookup"   # _apply_fn: the key and the cache
+OPT_APPLY_DISPATCH = "opt.apply_dispatch"   # the call of hvd_apply_update
+# core/engine.py, core/replay.py: where the other stacks time themselves
+ENGINE_GROUPED_ALLREDUCE = "engine.grouped_allreduce"   # the caller's thread
+ENGINE_DISPATCH = "engine.dispatch"     # Engine._dispatch: XLA_DISPATCH
+ENGINE_COMPILE_DISPATCH = "engine.compile_dispatch"     # ... a fresh builder
+REPLAY_LAUNCH = "replay.launch"         # StepReplay._launch
+ENGINE_WAIT = "engine.wait"             # the waits that count host_blocks
+ENGINE_FETCH = "engine.fetch"           # the read that counts host_fetches
+HOST_SPANS = (
+    OPT_UPDATE_AND_APPLY, OPT_FLATTEN, OPT_REDUCE, OPT_APPLY_LOOKUP,
+    OPT_APPLY_DISPATCH, ENGINE_GROUPED_ALLREDUCE, ENGINE_DISPATCH,
+    ENGINE_COMPILE_DISPATCH, REPLAY_LAUNCH, ENGINE_WAIT, ENGINE_FETCH)
+
+
+def host_span(name: str):
+    """A context manager that writes the span ``hvd.<name>`` (``name`` one
+    of ``HOST_SPANS``) into a running ``jax.profiler`` trace. With no
+    profiler session it costs the TraceMe's own check, 0.6 us; there is no
+    switch, no other clock and no other consumer."""
+    return jax.profiler.TraceAnnotation("hvd." + name)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import env as env_mod
+from ..common import scopes
 from ..common.exceptions import DuplicateNameError, HorovodInternalError
 from ..faults import failpoint
 from ..common.lru import lru_get, lru_put, lru_touch
@@ -174,11 +175,12 @@ class Handle:
         # "actual blocking waits" counter the chained-eager tests assert on)
         if not self._done and not self.poll():
             self._engine.host_blocks += 1
-            if self._group is not None:
-                self._group.wait()
-            else:
-                for g in self._garrs:
-                    _translate_failure(g.block_until_ready)
+            with scopes.host_span(scopes.ENGINE_WAIT):
+                if self._group is not None:
+                    self._group.wait()
+                else:
+                    for g in self._garrs:
+                        _translate_failure(g.block_until_ready)
             self._finish()
         if self._error is not None:
             raise self._error
@@ -1246,9 +1248,8 @@ class Engine:
         response). A fresh builder means this call traced + compiled, which
         dwarfs a real dispatch — labeled separately so timelines stay
         readable."""
-        activity = ("XLA_COMPILE_AND_DISPATCH"
-                    if getattr(self, "_last_builder_fresh", False)
-                    else "XLA_DISPATCH")
+        fresh = getattr(self, "_last_builder_fresh", False)
+        activity = "XLA_COMPILE_AND_DISPATCH" if fresh else "XLA_DISPATCH"
         self._last_builder_fresh = False
         if isinstance(names, str):
             names = [names]
@@ -1260,7 +1261,9 @@ class Engine:
         self._count_dispatch()
         t0 = time.perf_counter()
         try:
-            return _translate_failure(fn, *args)
+            with scopes.host_span(scopes.ENGINE_COMPILE_DISPATCH if fresh
+                                  else scopes.ENGINE_DISPATCH):
+                return _translate_failure(fn, *args)
         finally:
             if self.trace is not None:
                 self.trace.record_dispatch(names, activity,
@@ -1717,6 +1720,13 @@ class Engine:
         (controller.cc:652-773). ``codec`` overrides the engine's wire
         codec for this call (the optimizer's ``compression=`` argument,
         ISSUE 13); None defers to HOROVOD_TPU_COMPRESSION."""
+        with scopes.host_span(scopes.ENGINE_GROUPED_ALLREDUCE):
+            return self._grouped_allreduce(tensors, name, op,
+                                           prescale_factor,
+                                           postscale_factor, codec)
+
+    def _grouped_allreduce(self, tensors, name, op, prescale_factor,
+                           postscale_factor, codec) -> List[Handle]:
         tensors = [jnp.asarray(t) for t in tensors]
         sub = self._consume_substitute()
         for t in tensors:
@@ -2660,9 +2670,10 @@ class Engine:
         one host round-trip; ``host_fetches`` counts them so tests (and the
         bench) can assert the steady-state eager path performs none."""
         self.host_fetches += 1
-        local = self.backend.from_replicated(garr)
-        return _translate_failure(np.asarray, local).reshape(
-            self.backend.size(), *vec_shape)
+        with scopes.host_span(scopes.ENGINE_FETCH):
+            local = self.backend.from_replicated(garr)
+            return _translate_failure(np.asarray, local).reshape(
+                self.backend.size(), *vec_shape)
 
     def _exchange_sizes(self, local_vec: np.ndarray) -> np.ndarray:
         """Tiny metadata allgather used by unequal allgather/alltoall; the

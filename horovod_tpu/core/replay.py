@@ -44,6 +44,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence
 import numpy as np
 import jax.numpy as jnp
 
+from ..common import scopes
 from ..common.lru import lru_get, lru_put
 from ..metrics import registry as metrics_registry
 from ..ops import collectives as _C
@@ -128,7 +129,8 @@ class _Bound:
     def synchronize(self):
         if not self._group.ready():
             self._engine.host_blocks += 1
-            self._group.wait()
+            with scopes.host_span(scopes.ENGINE_WAIT):
+                self._group.wait()
         return self.result()
 
 
@@ -784,6 +786,10 @@ class StepReplay:
         self._cands = []
 
     def _launch(self, stream: tuple, padded: bool = False):
+        with scopes.host_span(scopes.REPLAY_LAUNCH):
+            self._launch_armed(stream, padded)
+
+    def _launch_armed(self, stream: tuple, padded: bool):
         from . import engine as engine_mod
         eng = self.engine
         ent = self._seen.get(stream)

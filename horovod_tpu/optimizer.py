@@ -918,6 +918,10 @@ class DistributedEagerOptimizer:
         grad→reduce→apply phases of one step and consecutive steps all
         overlap on-device, the way the reference overlaps backward compute
         with hook-fired async allreduces (torch/optimizer.py:100-135)."""
+        with scopes.host_span(scopes.OPT_UPDATE_AND_APPLY):
+            return self._update_and_apply(grads, opt_state, params)
+
+    def _update_and_apply(self, grads, opt_state, params):
         if self.backward_passes_per_step > 1:
             if self._accum is None:
                 self._accum = grads
@@ -936,15 +940,19 @@ class DistributedEagerOptimizer:
             return self._sharded_update_and_apply(grads, opt_state, params)
         eng = self._engine()
         size = eng.backend.size()
-        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        with scopes.host_span(scopes.OPT_FLATTEN):
+            leaves, treedef = jax.tree_util.tree_flatten(grads)
+            sparse_ks = ([None] * len(leaves) if size == 1
+                         else self._sparse_ks(grads, leaves, treedef))
         if size == 1:
             reduced_c, ctxs = leaves, [None] * len(leaves)
-            sparse_ks = [None] * len(leaves)
         else:
-            sparse_ks = self._sparse_ks(grads, leaves, treedef)
-            reduced_c, ctxs = self._reduce_async(leaves, sparse_ks)
-        return self._apply_fn(treedef, ctxs, sparse_ks,
-                              size)(reduced_c, opt_state, params)
+            with scopes.host_span(scopes.OPT_REDUCE):
+                reduced_c, ctxs = self._reduce_async(leaves, sparse_ks)
+        with scopes.host_span(scopes.OPT_APPLY_LOOKUP):
+            fn = self._apply_fn(treedef, ctxs, sparse_ks, size)
+        with scopes.host_span(scopes.OPT_APPLY_DISPATCH):
+            return fn(reduced_c, opt_state, params)
 
 
 def DistributedOptimizer(inner: optax.GradientTransformation, op: ReduceOp = Average,
@@ -1098,6 +1106,10 @@ class DistributedDeltaAdasumOptimizer:
         """Local inner step -> Adasum-reduce the delta -> apply. Returns
         (new_params, new_opt_state); on intermediate accumulation passes
         params are returned unchanged."""
+        with scopes.host_span(scopes.OPT_UPDATE_AND_APPLY):
+            return self._update_and_apply(grads, opt_state, params)
+
+    def _update_and_apply(self, grads, opt_state, params):
         if self.backward_passes_per_step > 1:
             if self._accum is None:
                 self._accum = grads

@@ -6,12 +6,6 @@ boost lock-free SPSC queue + writer thread (timeline.h:66-75), here a
 ``queue.SimpleQueue``. Per-tensor lifecycle: ENQUEUE (analogous to the
 NEGOTIATING phase, controller.cc:809-821 — SPMD needs no negotiation so the
 span covers enqueue→completion) then the op activity span.
-
-The hot path writes through the native C++ writer (native/src/timeline.cc,
-loaded via ctypes — the parity analog of the reference's writer thread) when
-the native library is available; this Python writer thread is the fallback.
-Set ``HOROVOD_TIMELINE_NATIVE=0`` to force the Python writer (tests exercise
-both).
 """
 
 from __future__ import annotations
@@ -36,19 +30,12 @@ _MAX_TIDS = 4096
 _OVERFLOW_TIDS = 64
 
 
-def _native_enabled() -> bool:
-    return os.environ.get("HOROVOD_TIMELINE_NATIVE", "1").strip().lower() \
-        not in ("0", "false", "no", "off")
-
-
 class Timeline:
     def __init__(self, path: str, mark_cycles: bool = False, pid: int = 0):
         self.path = path
         self.mark_cycles = mark_cycles
-        # Chrome-trace pid for every Python-writer event: the rank, so two
-        # ranks' timelines can be overlaid (the native writer predates the
-        # cross-rank work and still stamps pid 0; horovod_tpu/trace.py's
-        # merger remaps pids from the published segments instead).
+        # Chrome-trace pid of every event: the rank, so two ranks'
+        # timelines can be overlaid.
         self.pid = pid
         self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._thread: Optional[threading.Thread] = None
@@ -59,45 +46,24 @@ class Timeline:
         self._pending = {}
         self._tids = {}
         self._next_tid = 1
-        self._native = None  # ctypes lib when the C++ writer owns the file
 
     # -- lifecycle ---------------------------------------------------------
 
     def start(self):
         if self._running:
             return
-        if _native_enabled():
-            from . import native
-            lib = native.load()
-            # The native writer is a process-wide singleton (one open file);
-            # a second concurrent Timeline falls back to the Python writer.
-            if lib is not None:
-                d = os.path.dirname(self.path)
-                if d:
-                    os.makedirs(d, exist_ok=True)
-                if lib.hvd_timeline_open(self.path.encode()) == 0:
-                    self._native = lib
         self._running = True
-        if self._native is None:
-            self._thread = threading.Thread(target=self._writer,
-                                            name="hvd-timeline", daemon=True)
-            self._thread.start()
+        self._thread = threading.Thread(target=self._writer,
+                                        name="hvd-timeline", daemon=True)
+        self._thread.start()
 
     def stop(self):
         if not self._running:
             return
         self._running = False
-        if self._native is not None:
-            self._native.hvd_timeline_close()
-            self._native = None
-            return
         self._q.put(None)
         self._thread.join(timeout=5)
         self._thread = None
-
-    @property
-    def native_active(self) -> bool:
-        return self._native is not None
 
     # -- event recording (any thread) -------------------------------------
 
@@ -133,11 +99,6 @@ class Timeline:
         args = {"tensor": name, "bytes": nbytes}
         if corr is not None:
             args["corr"] = corr
-        if self._native is not None:
-            self._native.hvd_timeline_event(
-                b"B", kind.upper().encode(), int(self._ts_us()), 0,
-                self._tid(name), json.dumps(args).encode())
-            return
         self._q.put({"name": kind.upper(), "ph": "B", "ts": self._ts_us(),
                      "pid": self.pid, "tid": self._tid(name), "args": args})
 
@@ -150,12 +111,6 @@ class Timeline:
                          name)
             return
         corr = self._pending.pop(name, None)
-        if self._native is not None:
-            args = (json.dumps({"corr": corr}).encode()
-                    if corr is not None else None)
-            self._native.hvd_timeline_event(
-                b"E", b"", int(self._ts_us()), 0, self._tid(name), args)
-            return
         ev = {"name": "", "ph": "E", "ts": self._ts_us(),
               "pid": self.pid, "tid": self._tid(name)}
         if corr is not None:
@@ -163,11 +118,6 @@ class Timeline:
         self._q.put(ev)
 
     def record_activity(self, name: str, activity: str, dur_us: float):
-        if self._native is not None:
-            self._native.hvd_timeline_event(
-                b"X", activity.encode(), int(self._ts_us() - dur_us),
-                int(dur_us), self._tid(name), None)
-            return
         self._q.put({"name": activity, "ph": "X", "ts": self._ts_us() - dur_us,
                      "dur": dur_us, "pid": self.pid, "tid": self._tid(name)})
 
@@ -176,11 +126,6 @@ class Timeline:
         REPLAY_CAPTURE when a stream arms, REPLAY_REPLAY per fused-launch
         step, REPLAY_FALLBACK / REPLAY_INVALIDATE with the reason."""
         name = f"REPLAY_{event.upper()}"
-        if self._native is not None:
-            args = json.dumps({"detail": detail}).encode() if detail else None
-            self._native.hvd_timeline_event(
-                b"i", name.encode(), int(self._ts_us()), 0, 0, args)
-            return
         ev = {"name": name, "ph": "i", "ts": self._ts_us(), "pid": self.pid,
               "tid": 0, "s": "p"}
         if detail:
@@ -192,20 +137,11 @@ class Timeline:
         name -> number and renders as a stacked counter row riding the same
         trace as the spans. The MetricsEmitter samples wire-byte and
         dispatch rates from the metrics registry through this."""
-        if self._native is not None:
-            self._native.hvd_timeline_event(
-                b"C", name.encode(), int(self._ts_us()), 0, 0,
-                json.dumps(values).encode())
-            return
         self._q.put({"name": name, "ph": "C", "ts": self._ts_us(),
                      "pid": self.pid, "tid": 0, "args": dict(values)})
 
     def mark_cycle(self):
         if not self.mark_cycles:
-            return
-        if self._native is not None:
-            self._native.hvd_timeline_event(
-                b"i", b"CYCLE", int(self._ts_us()), 0, 0, None)
             return
         self._q.put({"name": "CYCLE", "ph": "i", "ts": self._ts_us(),
                      "pid": self.pid, "tid": 0, "s": "g"})

@@ -1,39 +1,5 @@
-"""Build script: packaging metadata lives in pyproject.toml; this adds the
-native-library pre-build (parity role: the reference's setup.py compiles the
-C++ core — setup.py:46-51 — here a plain shared object loaded via ctypes since
-pybind11 is unavailable)."""
-
-import os
-import sys
+"""Build script: packaging metadata lives in pyproject.toml."""
 
 from setuptools import setup
-from setuptools.command.build_py import build_py
 
-
-class BuildPyWithNative(build_py):
-    def run(self):
-        super().run()
-        # Best-effort: compile the ctypes native layer next to the sources in
-        # the build tree. Failure is non-fatal — the loader compiles on
-        # demand, and every native consumer has a Python fallback.
-        try:
-            # Load the loader module directly from its file — importing the
-            # horovod_tpu package would pull in jax/numpy, which are absent
-            # in a PEP 517 isolated build env (build requires = setuptools).
-            import importlib.util
-            here = os.path.dirname(os.path.abspath(__file__))
-            spec = importlib.util.spec_from_file_location(
-                "_hvd_native_build",
-                os.path.join(here, "horovod_tpu", "native", "__init__.py"))
-            native = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(native)
-            out = os.path.join(self.build_lib, "horovod_tpu", "native",
-                               os.path.basename(native.lib_path()))
-            if os.path.isdir(os.path.dirname(out)):
-                native.build(out, quiet=False)
-        except Exception as e:  # no g++ etc.
-            print(f"warning: native layer not prebuilt ({e}); "
-                  f"will build on first use", file=sys.stderr)
-
-
-setup(cmdclass={"build_py": BuildPyWithNative})
+setup()

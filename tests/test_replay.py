@@ -261,16 +261,12 @@ def test_timeline_records_replay_events(tmp_path):
     import os
     from horovod_tpu.timeline import Timeline
     path = os.path.join(tmp_path, "tl.json")
-    os.environ["HOROVOD_TIMELINE_NATIVE"] = "0"
-    try:
-        tl = Timeline(path)
-        tl.start()
-        tl.record_replay("capture", "armed after 3 identical steps")
-        tl.record_replay("replay", "161 tensors in 1 launch")
-        tl.record_replay("fallback", "signature divergence at op 0")
-        tl.stop()
-    finally:
-        os.environ.pop("HOROVOD_TIMELINE_NATIVE", None)
+    tl = Timeline(path)
+    tl.start()
+    tl.record_replay("capture", "armed after 3 identical steps")
+    tl.record_replay("replay", "161 tensors in 1 launch")
+    tl.record_replay("fallback", "signature divergence at op 0")
+    tl.stop()
     events = json.load(open(path))
     names = [e["name"] for e in events]
     assert "REPLAY_CAPTURE" in names
