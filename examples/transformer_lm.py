@@ -270,6 +270,7 @@ def main():
                                     args.batch * args.seq)
             for name, gauge in (
                     ("held_share", "hvd_tpu_moe_held_assignment_share"),
+                    ("buffer_rows", "hvd_tpu_moe_buffer_rows"),
                     ("buffer_fill", "hvd_tpu_moe_buffer_fill"),
                     ("load_max_over_mean",
                      "hvd_tpu_moe_expert_load_max_over_mean"),

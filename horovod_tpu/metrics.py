@@ -231,11 +231,21 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "step, the share that landed on the experts this program "
                  "holds, by expert layer (held / experts under an even "
                  "router; what the absent experts would do is left out)"),
+    "hvd_tpu_moe_buffer_rows": (
+        "gauge", "Rows of the dispatch buffer the routed experts ran in "
+                 "the last logged step, by expert layer: the tight size "
+                 "(1.25 x an even router's share of the assignments) "
+                 "where the held experts' assignments fit it, else the "
+                 "wide one (2.5 x). The layer chooses inside the step "
+                 "from the router's own counts (parallel/moe.py "
+                 "topk_buffer_rows)"),
     "hvd_tpu_moe_buffer_fill": (
         "gauge", "The held experts' assignments of the last logged step "
-                 "over the rows of the dispatch buffer, by expert layer "
-                 "(0.4 under an even router; the chunked row sums' work "
-                 "follows it; past 1 a second buffer ran)"),
+                 "over the rows of the dispatch buffer that ran "
+                 "(hvd_tpu_moe_buffer_rows), by expert layer (0.8 under "
+                 "an even router, which fits the tight buffer; the "
+                 "chunked row sums' work follows the assignments; past 1 "
+                 "a second buffer ran, a wide one)"),
     "hvd_tpu_moe_row_sum": (
         "gauge", "1 under the form of the routed experts' row sums (the "
                  "combine; the dispatch's backward pass) the step was "

@@ -1147,8 +1147,11 @@ def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
     gauges ``hvd_tpu_moe_*``: by layer the share of the ``n_tokens x
     moe_top_k`` assignments that land on the held experts (``held /
     n_experts`` under an even router) and the fullest expert's load over
-    the mean load; the held assignments over the rows of the dispatch
-    buffer for ``n_tokens`` tokens (past 1 a second buffer ran); the form
+    the mean load; the rows of the dispatch buffer the layer ran for those
+    held assignments (:func:`~horovod_tpu.parallel.moe.topk_buffer_rows`,
+    the layer's own rule: the tight size where they fit it, else the wide
+    one) and the held assignments over them (past 1 a second buffer ran,
+    and it was a wide one); the form
     of the row sums the step was built with
     (:func:`~horovod_tpu.parallel.moe.row_sum_form`) and the rows one of
     them visited over the live ones (``T x k`` over the held assignments as
@@ -1157,16 +1160,17 @@ def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
     expert was counted for (0: the layer drops nothing)."""
     counts = np.asarray(counts, np.float64)
     total = n_tokens * cfg.moe_top_k
-    held = counts[:, cfg.first_expert:cfg.first_expert + cfg.held]
+    held = counts[:, cfg.first_expert:cfg.first_expert + cfg.held].sum(axis=1)
     shape = (n_tokens, cfg.moe_top_k, cfg.n_experts, cfg.held)
-    return {"held_share": (held.sum(axis=1) / total).tolist(),
-            "buffer_fill": (held.sum(axis=1)
-                            / topk_buffer_rows(*shape)).tolist(),
+    buffer_rows = topk_buffer_rows(*shape, held)
+    return {"held_share": (held / total).tolist(),
+            "buffer_rows": buffer_rows.tolist(),
+            "buffer_fill": (held / buffer_rows).tolist(),
             "row_sum_form": row_sum_form(*shape),
             "row_sum_rows_over_live": [
                 row_sum_rows(*shape, int(live), cfg.d_model
                              * jnp.dtype(cfg.dtype).itemsize) / max(live, 1.0)
-                for live in held.sum(axis=1)],
+                for live in held],
             "load_max_over_mean": (counts.max(axis=1)
                                    / counts.mean(axis=1)).tolist(),
             "dropped": float(np.abs(total - counts.sum(axis=1)).sum())}

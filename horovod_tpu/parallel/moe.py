@@ -322,7 +322,7 @@ def row_sum_rows(n_tokens: int, k: int, n_experts: int, experts_held: int,
     held experts, a row of the buffer ``row_bytes`` long: as a gather ``T x
     k`` for every slab of every buffer that holds a live row
     (:func:`_slabs`), else the live rows in whole chunks."""
-    n_rows = topk_buffer_rows(n_tokens, k, n_experts, experts_held)
+    n_rows = topk_buffer_rows(n_tokens, k, n_experts, experts_held, n_live)
     if row_sum_form(n_tokens, k, n_experts, experts_held) != "gather":
         chunk = math.gcd(n_rows, ROW_CHUNK)
         return -(-n_live // chunk) * chunk
@@ -582,21 +582,52 @@ def topk_combine(y, packed: TopKPacked, n_tokens: int, weight=None):
                                 jnp.sum(packed.group_sizes), n_tokens)
 
 
-# the dispatch buffer's rows over an even router's share of the assignments:
-# of 48 readings of the benchmark cell's layers (3 seeds, 4 batches, 4
-# layers; v5e, PR 32) the held experts drew 0.51 to 2.10 times their even
-# share
+# The dispatch buffer has two sizes, each a factor times an even router's
+# share of the ``T x k`` assignments, and a layer runs the one that fits the
+# held assignments its router counted (:func:`topk_buffer_rows`).
+# BUFFER_FACTOR, the wide one, with further buffers of it past it: of 48
+# readings of the sparse-expert cell's layers (3 seeds, 4 batches, 4 layers;
+# v5e, PR 32) the held experts drew 0.51 to 2.10 times their even share (of
+# 960, PR 38: 0.36 to 2.05), and a second buffer costs 9 ms there.
+# TIGHT_FACTOR, ONE buffer and no loop where the loads allow: every XLA pass
+# over the buffer touches all of its rows, live or dead, and a loop carries
+# its sums in and out. On the v5e (PR 38; 960 readings a cell: 4 seeds, 60
+# steps, 4 layers) the conv/attention cell's layers, a quarter of the experts
+# held, drew 0.977 to 1.026 times even, and the sparse-expert cell's, a
+# sixteenth, fit 1.125 / 1.25 / 1.5 / 2 times even in 74 / 83 / 94 / 99.9%
+# of the readings. A step of the first at 1.125 / 1.25 / 1.5: 286.5 / 287.7
+# / 290.2 ms (314.5 at the wide size alone: the buffer-sized passes 27.4 ->
+# 12.8 ms, the loop's carries and copies 9.1 -> 0); of the second at 1.25 /
+# 1.5, two seeds: 249.4, 252.1 / 250.3, 251.2 (258.8, 259.8). 1.25 and not
+# 1.125: those loads are uniform random tokens through a settled bias, the
+# evenest a router gets, and a layer that does not fit pays the wide price
 BUFFER_FACTOR = 2.5
+TIGHT_FACTOR = 1.25
+
+
+def topk_buffer_sizes(n_tokens: int, k: int, n_experts: int,
+                      experts_held: int) -> Tuple[int, int]:
+    """``(tight, wide)``: the two sizes of the dispatch buffer in rows,
+    ``TIGHT_FACTOR`` and ``BUFFER_FACTOR`` times an even router's share of
+    the ``T x k`` assignments, each in multiples of 512 and at most all of
+    them (with every expert held both are ``T x k``)."""
+    even = n_tokens * k * experts_held / n_experts
+    return tuple(min(n_tokens * k,
+                     -(-int(math.ceil(even * factor)) // 512) * 512)
+                 for factor in (TIGHT_FACTOR, BUFFER_FACTOR))
 
 
 def topk_buffer_rows(n_tokens: int, k: int, n_experts: int,
-                     experts_held: int) -> int:
-    """Rows of the dispatch buffer: ``BUFFER_FACTOR`` times an even router's
-    share of the ``T x k`` assignments, in multiples of 512 and at most all
-    of them."""
-    even = n_tokens * k * experts_held / n_experts
-    return min(n_tokens * k,
-               -(-int(math.ceil(even * BUFFER_FACTOR)) // 512) * 512)
+                     experts_held: int, n_held):
+    """Rows of the dispatch buffer a layer runs when ``n_held`` of its
+    assignments are of held experts: the tight size where they fit it (one
+    buffer then holds them all), else the wide one (and as many buffers of
+    it as they fill). ``n_held``: a count, an array of counts or the traced
+    sum of the layer's own ``group_sizes``; :func:`topk_moe_held` decides by
+    this inside the step, ``models/transformer.py routing_stats`` reports
+    by it on the host."""
+    tight, wide = topk_buffer_sizes(n_tokens, k, n_experts, experts_held)
+    return tight + (wide - tight) * (n_held > tight)
 
 
 def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
@@ -605,32 +636,37 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
     ``weight_e Expert_e(x)`` for every token that chose them, ``[T, d]`` in
     ``x``'s dtype; what the absent experts would add is left out.
 
-    No assignment is ever dropped, and the buffer is never ``T x k`` rows:
-    it holds ``BUFFER_FACTOR`` times an even router's share
-    (:func:`topk_buffer_rows`), and the sorted assignments go through it a
-    buffer at a time, as many buffers as they fill: one wherever the held
-    experts draw less than ``BUFFER_FACTOR`` times their even share; the
-    further buffers are the no-drop guarantee under any routing, and what
-    the tests force.
-    That is a ``lax.while_loop`` on each pass, under a ``custom_vjp`` whose
-    backward pass runs a buffer again and pulls the cotangent back through
-    it, buffer by buffer: the layer keeps its inputs and nothing else, and
-    a buffer that is not needed costs nothing. The rows come back to the
-    tokens, on both passes, in the form :func:`row_sum_form` answers from
-    the share held. (Differentiated through, a
-    scan with a ``lax.cond`` a buffer handed out every weight's zero
-    cotangent and a [T, d] of zeros for each buffer it did not run: 80 ms of
-    a 350 ms step on the v5e, PERF.md PR 32.)"""
+    No assignment is ever dropped, and the buffer is never ``T x k`` rows
+    on a share: it fits the loads the router counted
+    (:func:`topk_buffer_rows`, a ``lax.cond`` on the sum of ``group_sizes``,
+    the same on both passes). Where the held assignments fit the tight size,
+    ONE buffer of it holds them all. Where they do not, the sorted
+    assignments go through buffers of the wide size, as many as they fill:
+    one wherever the held experts draw less than ``BUFFER_FACTOR`` times
+    their even share; the further buffers are the no-drop guarantee under
+    any routing, and what the tests force. With every expert held the two
+    sizes are one and no conditional is built.
+    The wide path is a ``lax.while_loop`` on each pass; either path is under
+    a ``custom_vjp`` whose backward pass runs a buffer again and pulls the
+    cotangent back through it, buffer by buffer: the layer keeps its inputs
+    and nothing else, and a buffer that is not needed costs nothing. The
+    rows come back to the tokens, on both passes, in the form
+    :func:`row_sum_form` answers from the share held. (Differentiated
+    through, a scan with a ``lax.cond`` a buffer handed out every weight's
+    zero cotangent and a [T, d] of zeros for each buffer it did not run: 80
+    ms of a 350 ms step on the v5e, PERF.md PR 32; the conditional here is
+    inside each pass, and each branch returns the whole result.)"""
     t, k = route.expert.shape
     held, n_experts = wg.shape[0], route.counts.shape[0]
     # cast once, ahead of every buffer: the loop then carries the weights'
     # cotangents in the compute dtype, not three fp32 copies
     wg, wu, wd = (w.astype(x.dtype) for w in (wg, wu, wd))
-    n_rows = topk_buffer_rows(t, k, n_experts, held)
+    tight, wide = topk_buffer_sizes(t, k, n_experts, held)
     token, weight, group_sizes = topk_order(route, first_expert, held)
-    # whole buffers: a slice past the end would be moved back over rows
-    # already taken (the padding is past the last held assignment: dead)
-    token, weight = (jnp.pad(a, (0, -(t * k) % n_rows))
+    # whole wide buffers: a slice past the end would be moved back over rows
+    # already taken (the padding is past the last held assignment: dead);
+    # the tight buffer is the first rows of at most T x k
+    token, weight = (jnp.pad(a, (0, -(t * k) % wide))
                      for a in (token, weight))
     order = (token, group_sizes)
     if row_sum_form(t, k, n_experts, held) == "gather":
@@ -640,7 +676,7 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
                   topk_places(route, first_expert, held))
         weight = route.weight
 
-    def buffer(start, order, x, weight, wg, wu, wd):
+    def buffer(n_rows, start, order, x, weight, wg, wu, wd):
         token, group_sizes, *gather = order
         row_weight, places = gather or (weight, None)
         packed = topk_dispatch(x, token, row_weight, group_sizes, start,
@@ -649,31 +685,46 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
         return topk_combine(y, packed, t, weight)
 
     def over_the_buffers(group_sizes, body, init):
-        """``body(start, carry)`` for the start of every buffer the held
-        assignments reach: at least the first."""
+        """``body(start, carry)`` for the start of every wide buffer the
+        held assignments reach: at least the first."""
         n_held = jnp.sum(group_sizes)
         return lax.while_loop(
             lambda c: jnp.logical_or(c[0] == 0, c[0] < n_held),
-            lambda c: (c[0] + n_rows, body(c[0], c[1])),
+            lambda c: (c[0] + wide, body(c[0], c[1])),
             (jnp.zeros((), n_held.dtype), init))[1]
+
+    def by_fit(group_sizes, one_tight, all_wide, *operands):
+        """``one_tight(*operands)`` where the held assignments fit the
+        tight buffer, else ``all_wide(*operands)``."""
+        if tight == wide:
+            return all_wide(*operands)
+        return lax.cond(
+            topk_buffer_rows(t, k, n_experts, held, jnp.sum(group_sizes))
+            == tight, one_tight, all_wide, *operands)
 
     @jax.custom_vjp
     def run(order, *args):
-        return over_the_buffers(
-            order[1],
-            lambda start, out: out + buffer(start, order, *args),
-            jnp.zeros((t, x.shape[1]), jnp.float32))
+        return by_fit(
+            order[1], functools.partial(buffer, tight, 0),
+            lambda order, *args: over_the_buffers(
+                order[1],
+                lambda start, out: out + buffer(wide, start, order, *args),
+                jnp.zeros((t, x.shape[1]), jnp.float32)),
+            order, *args)
 
     def run_bwd(kept, g):
-        order, *args = kept
+        def pull_back(n_rows, start, g, order, *args):
+            return jax.vjp(functools.partial(buffer, n_rows, start, order),
+                           *args)[1](g)
 
-        def pull_back(start, sums):
-            got = jax.vjp(functools.partial(buffer, start, order),
-                          *args)[1](g)
-            return jax.tree_util.tree_map(jnp.add, sums, got)
-
-        return (None,) + over_the_buffers(
-            order[1], pull_back, tuple(jnp.zeros_like(a) for a in args))
+        return (None,) + by_fit(
+            kept[0][1], functools.partial(pull_back, tight, 0),
+            lambda g, order, *args: over_the_buffers(
+                order[1],
+                lambda start, sums: jax.tree_util.tree_map(
+                    jnp.add, sums, pull_back(wide, start, g, order, *args)),
+                tuple(jnp.zeros_like(a) for a in args)),
+            g, *kept)
 
     run.defvjp(lambda *kept: (run(*kept), kept), run_bwd)
     return run(order, x, weight, wg, wu, wd).astype(x.dtype)

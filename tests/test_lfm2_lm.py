@@ -387,6 +387,44 @@ def test_the_step_with_its_row_sums_as_gathers(monkeypatch, held, mesh):
             _close(got[leaf], wanted[leaf], 2e-4)
 
 
+@pytest.mark.parametrize("form", ["gather", "chunks"])
+@pytest.mark.parametrize("sizes, ran", [
+    ((48, 128), 48), ((8, 128), 128), ((8, 16), 16)],
+    ids=["under-tight", "between", "past-wide"])
+def test_the_step_with_a_buffer_fitted_to_its_loads(monkeypatch, sizes, ran,
+                                                    form):
+    """The rehearsal's 128 assignments a layer make both sizes of the
+    dispatch buffer 128 rows, so the steps of this file build no
+    conditional; the cell's sizes differ (``moe.topk_buffer_sizes``). The
+    same step of a share of 2 of 8 experts (32 held assignments a layer
+    under an even router) with sizes that differ, through the scans, the
+    checkpoints and the conditional on both passes: a tight buffer that
+    holds every layer's, one that holds none beside a wide one that does,
+    and a wide one they fill several times over; the loss, the counts and
+    every leaf's gradient are the one buffer's, and ``routing_stats``
+    reports the size each layer ran."""
+    cfg = dataclasses.replace(SMALL, experts_held=2)
+    params, (inputs, targets) = _params(cfg), _tokens()
+    with jax.default_matmul_precision("highest"):
+        monkeypatch.setattr(moe, "row_sum_form", lambda *shape: form)
+        want = _sgd_step(cfg, _params(cfg), inputs, targets)
+        monkeypatch.setattr(moe, "topk_buffer_sizes", lambda *shape: sizes)
+        _, loss, stats, applied = _sgd_step(cfg, params, inputs, targets)
+    assert float(loss) == pytest.approx(float(want[1]), rel=TIGHT)
+    counts = np.asarray(stats["expert_counts"])
+    assert np.array_equal(counts, np.asarray(want[2]["expert_counts"]))
+    held = counts[:, :2].sum(axis=1)
+    assert ((held > 8) & (held <= 48)).all()
+    got = tfm.routing_stats(counts, cfg, inputs.size)
+    assert got["buffer_rows"] == [ran] * 4
+    assert got["buffer_fill"] == (held / ran).tolist()
+    assert (max(got["buffer_fill"]) > 1) == (ran == 16)
+    got, wanted = _leaves(applied), _leaves(want[3])
+    for leaf in LEAVES:
+        if not leaf.endswith("['router_bias']"):
+            _close(got[leaf], wanted[leaf], 2e-4)
+
+
 def test_the_gradient_sums_of_a_data_mesh_cover_the_conv_leaves():
     axes = tfm.grad_reduce_axes(_mesh(4), SMALL)
     early = tfm._in_backward(axes, SMALL)
@@ -734,7 +772,7 @@ def test_the_sparse_expert_cells_weights_are_drawn_as_before():
 
 @pytest.mark.parametrize("gauge", [
     "hvd_tpu_lm_layers", "hvd_tpu_moe_row_sum",
-    "hvd_tpu_moe_row_sum_rows_over_live"])
+    "hvd_tpu_moe_row_sum_rows_over_live", "hvd_tpu_moe_buffer_rows"])
 def test_the_examples_gauges_are_declared(gauge):
     from horovod_tpu.metrics import METRIC_SPECS
     assert METRIC_SPECS[gauge][0] == "gauge"

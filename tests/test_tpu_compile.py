@@ -377,25 +377,36 @@ def test_the_conv_attention_cells_step_and_the_memory_it_states(
     compiled = tfm.make_train_step(mesh, cfg, opt).lower(
         on_mesh(shapes), on_mesh(jax.eval_shape(opt.init, shapes)), tok,
         tok).compile()
+    text = compiled.as_text()
     calls = re.findall(r"%((?:splash|flash|gmm|tgmm|ragged)[\w\-]*?)"
-                       r"(?:\.\d+)? = ", compiled.as_text())
+                       r"(?:\.\d+)? = ", text)
     assert calls.count("splash_mqa_fwd_residuals") == 2, calls
     assert calls.count("splash_mqa_dkv_no_residuals") == 1, calls
     assert "gmm" in calls and "tgmm" in calls
     assert not [c for c in calls if "_dq" in c or "flash" in c
                 or "mha" in c or "ragged" in c], calls
+    # the buffer fits the loads (moe.topk_buffer_rows): a conditional a
+    # pass in each of the two expert stacks' scans, the grouped products
+    # over the tight buffer's 20,480 rows in one branch and over the wide
+    # one's 40,960 in the other
+    assert len(re.findall(r" conditional\(", text)) == 4
+    for rows in (20480, 40960):
+        assert re.search(r"%%gmm[.\d]* = bf16\[%d,1792\]" % rows, text), rows
     # a quarter of the experts held: the row sums are gathers of the 2 x
     # 8,192 tokens' rows, one a choice (moe.row_sum_form), out of slabs of
-    # half the buffer (80 MiB: moe.NEAR_BYTES), and nothing is scattered
-    # under the routed experts' scopes on either pass
+    # at most 96 MiB (moe.NEAR_BYTES): the tight buffer is one (80 MiB, no
+    # copy), the wide one two halves sliced out of it; and nothing is
+    # scattered under the routed experts' scopes on either pass
     moved = re.findall(r" (gather|scatter)\(.*op_name=\"[^\"]*moe_"
-                       r"(combine|dispatch)", compiled.as_text())
+                       r"(combine|dispatch)", text)
     assert moved and {kind for kind, _ in moved} == {"gather"}, moved
     for scope in (r"/moe_combine/", r"transpose\(jvp\(moe_dispatch\)\)/"):
         assert re.search(r"bf16\[16384,2048\]\S* gather\(.*" + scope
-                         + "gather", compiled.as_text()), scope
-        assert re.search(r"bf16\[20480,2048\]\S* dynamic-slice\(.*" + scope,
-                         compiled.as_text()), scope
+                         + "gather", text), scope
+        assert re.search(r"bf16\[20480,2048\]\S* dynamic-slice\(.*"
+                         r"branch_0_fun/.*" + scope, text), scope
+        assert not re.search(r"bf16\[20480,2048\]\S* dynamic-slice\(.*"
+                             r"branch_1_fun/.*" + scope, text)
     found = compiled.memory_analysis()
     stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
     live = found.argument_size_in_bytes + found.temp_size_in_bytes

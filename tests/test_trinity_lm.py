@@ -206,24 +206,54 @@ def test_the_step_returns_the_counts_and_moves_the_bias_by_them(stepped):
 
 def test_the_gauges_of_where_the_router_sent_the_step():
     """``routing_stats`` of hand-made counts: a share of 2 of 8 experts,
-    256 tokens, top 2, a buffer of 512 rows; layer 0 even, layer 1 with
-    every assignment on the held two (the buffer exactly full). Under a
-    chunk of live rows the row sums are chunks, and a chunk is the whole
-    buffer here; at 64 times the tokens they are a gather of the 32,768
-    choices, whatever the held experts drew."""
+    256 tokens, top 2, a buffer of 512 rows at either size; layer 0 even,
+    layer 1 with every assignment on the held two (the buffer exactly
+    full). Under a chunk of live rows the row sums are chunks, and a chunk
+    is the whole buffer here; at 64 times the tokens they are a gather of
+    the 32,768 choices, whatever the held experts drew, the even layer
+    runs the tight buffer of 10,240 rows and the full one the wide one of
+    20,480 and a second: the rows ``moe.topk_buffer_rows``, the layer's own
+    rule, answers for the same held counts."""
     cfg = dataclasses.replace(SMALL, experts_held=2, first_expert=4)
-    assert moe.topk_buffer_rows(256, 2, 8, 2) == 512
+    assert moe.topk_buffer_sizes(256, 2, 8, 2) == (512, 512)
     counts = np.array([[64] * 8, [0] * 4 + [256] * 2 + [0] * 2])
     got = tfm.routing_stats(counts, cfg, 256)
-    assert got == {"held_share": [0.25, 1.0], "buffer_fill": [0.25, 1.0],
-                   "row_sum_form": "chunks",
+    assert got == {"held_share": [0.25, 1.0], "buffer_rows": [512, 512],
+                   "buffer_fill": [0.25, 1.0], "row_sum_form": "chunks",
                    "row_sum_rows_over_live": [4.0, 1.0],
                    "load_max_over_mean": [1.0, 4.0], "dropped": 0.0}
     got = tfm.routing_stats(counts * 64, cfg, 256 * 64)
     assert got["row_sum_form"] == "gather"
-    # (the second layer's 32,768 held assignments fill the buffer of 20,480
-    # rows and a second: each gathers every choice)
+    assert moe.topk_buffer_sizes(256 * 64, 2, 8, 2) == (10240, 20480)
+    assert got["buffer_rows"] == [10240, 20480] == [
+        int(moe.topk_buffer_rows(256 * 64, 2, 8, 2, n_held))
+        for n_held in (8192, 32768)]
+    assert got["buffer_fill"] == [0.8, 1.6]
+    # (the second layer's 32,768 held assignments fill the wide buffer of
+    # 20,480 rows and a second: each gathers every choice)
     assert got["row_sum_rows_over_live"] == [4.0, 2.0]
+
+
+@pytest.mark.parametrize("shape, sizes", [
+    ((16384, 4, 32, 8), (20480, 40960)), ((8192, 8, 128, 8), (5120, 10240)),
+    ((16384, 4, 32, 32), (65536, 65536)), ((2048, 2, 8, 1), (1024, 1536)),
+    ((256, 2, 8, 4), (512, 512))],
+    ids=["conv-attention-cell", "sparse-expert-cell", "whole-layer",
+         "one-of-eight", "rehearsal"])
+def test_the_buffer_fits_the_held_count(shape, sizes):
+    """The two sizes from the shape (1.25 and 2.5 times an even share, in
+    512s, at most ``T x k``), and ONE function from a held count to the
+    rows that run: the tight size up to and with its last row, the wide one
+    past it, for a count, an array of counts and a traced count alike."""
+    tight, wide = moe.topk_buffer_sizes(*shape)
+    assert (tight, wide) == sizes
+    held = np.array([0, tight // 2, tight, tight + 1, wide, 3 * wide])
+    want = np.where(held <= tight, tight, wide)
+    assert np.array_equal(moe.topk_buffer_rows(*shape, held), want)
+    assert [moe.topk_buffer_rows(*shape, int(n)) for n in held] \
+        == want.tolist()
+    assert np.array_equal(jax.jit(lambda n: moe.topk_buffer_rows(
+        *shape, n))(jnp.asarray(held, jnp.int32)), want)
 
 
 def test_the_optimizer_never_touches_the_bias():
@@ -524,12 +554,18 @@ def form(request, monkeypatch):
 
 
 # 8 experts, 2 a token: (the experts the bias forces every token onto, the
-# first held, how many, the buffers the forced routing fills at 2,048 tokens)
-ROUTINGS = pytest.mark.parametrize("forced, first, held, buffers", [
-    (None, 0, 8, 1), (None, 2, 4, 1), ((0, 1), 0, 2, 2), ((6, 7), 0, 2, 1),
-    ((3, 5), 2, 2, 1), ((2, 3), 2, 2, 2), ((0, 1), 0, 1, 2)],
+# first held, how many, and at 2,048 tokens the size of the buffer the
+# forced routing runs and the buffers of it it fills): under the tight size,
+# between the two, past the wide one
+ROUTINGS = pytest.mark.parametrize("forced, first, held, size, buffers", [
+    (None, 0, 8, "wide", 1), (None, 2, 4, "tight", 1),
+    ((0, 1), 0, 2, "wide", 2), ((6, 7), 0, 2, "tight", 1),
+    ((3, 5), 2, 2, "wide", 1), ((2, 3), 2, 2, "wide", 2),
+    ((0, 1), 0, 1, "wide", 2), ((2, 7), 2, 4, "tight", 1),
+    ((2, 3), 2, 4, "wide", 1)],
     ids=["even-all", "even-share", "all-to-held", "all-to-absent",
-         "all-to-one-of-share", "all-to-share", "all-to-one-held"])
+         "all-to-one-of-share", "all-to-share", "all-to-one-held",
+         "half-to-one-of-four", "all-to-two-of-four"])
 SIZES = pytest.mark.parametrize("t", [256, 2048],
                                 ids=["one-buffer", "buffers"])
 
@@ -541,7 +577,7 @@ def _forced_bias(forced):
 
 @SIZES
 @ROUTINGS
-def test_the_places_are_the_sorts(t, forced, first, held, buffers):
+def test_the_places_are_the_sorts(t, forced, first, held, size, buffers):
     """``topk_places`` against ``topk_order``'s own sort, to the row: the
     row a held choice is placed at holds its token and its weight, the
     places are the first rows of the sorted order, each once, and a choice
@@ -567,21 +603,25 @@ def test_the_places_are_the_sorts(t, forced, first, held, buffers):
 @pytest.mark.parametrize("form", FORMS, indirect=True)
 @SIZES
 @ROUTINGS
-def test_no_assignment_is_dropped(t, forced, first, held, buffers, form):
+def test_no_assignment_is_dropped(t, forced, first, held, size, buffers,
+                                  form):
     """Whatever the router does, with every token sent to the same two
     experts too, and in either form of the row sums: the held experts' part
     is the dense loop's, to the last token, and so is its gradient. At
-    2,048 tokens a share that draws more than twice its even part fills the
-    buffer more than once and the further buffers run (``buffers``: how
-    many the forced routing fills there); at 256 tokens one buffer holds
-    every assignment."""
+    2,048 tokens a share runs the tight buffer where its held assignments
+    fit 1.25 times their even part and the wide one where they do not, and
+    one that draws more than 2.5 times its even part fills the wide buffer
+    more than once and the further buffers run (``size``, ``buffers``: what
+    the forced routing runs there); at 256 tokens the two sizes are one
+    buffer that holds every assignment."""
     k = 2
     x = jax.random.normal(jax.random.PRNGKey(0), (t, 16))
     router = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
     bias = _forced_bias(forced)
     wg, wu, wd = _expert_weights(held)
-    rows = moe.topk_buffer_rows(t, k, 8, held)
-    assert rows % 512 == 0 or rows == t * k
+    tight, wide = moe.topk_buffer_sizes(t, k, 8, held)
+    assert all(rows % 512 == 0 or rows == t * k for rows in (tight, wide))
+    assert (tight < wide) == (t == 2048 and held < 8)
 
     def run(layer, x, router, wg, wu, wd):
         route = moe.topk_route(x, router, bias, k, 2.826, True)
@@ -600,6 +640,9 @@ def test_no_assignment_is_dropped(t, forced, first, held, buffers, form):
     if forced is not None:
         assert [int(route.counts[e]) for e in forced] == [t, t]
     n_held = int(route.counts[first:first + held].sum())
+    rows = moe.topk_buffer_rows(t, k, 8, held, n_held)
+    if t == 2048:
+        assert rows == {"tight": tight, "wide": wide}[size]
     assert max(-(-n_held // rows), 1) == (buffers if t == 2048 else 1)
     _close(got, want, 1e-5)
     grads = [jax.jit(jax.grad(
@@ -609,14 +652,75 @@ def test_no_assignment_is_dropped(t, forced, first, held, buffers, form):
         _close(g, w, 1e-4)
 
 
-def _routed_by_hand(t):
-    """A route of 2 choices a token over 8 experts, 0-2 held, in which ONE
-    token's two rows are the last of the first buffer and the first of the
-    second: expert 0 takes the first ``n_rows - t`` tokens, expert 1 every
-    token, expert 2 the last token alone (the others' second choice is the
-    absent expert 5)."""
-    n_rows = moe.topk_buffer_rows(t, 2, 8, 3)
-    assert t < n_rows < 2 * t
+@pytest.mark.parametrize("form", FORMS, indirect=True)
+@pytest.mark.parametrize("forced, size, buffers", [
+    (None, "tight", 1), ((3, 5), "wide", 1), ((2, 3), "wide", 2)],
+    ids=["under-tight", "between", "past-wide"])
+def test_both_passes_run_the_buffer_the_loads_fit(monkeypatch, forced, size,
+                                                  buffers, form):
+    """Which buffers RUN, by the rows the grouped products are handed (a
+    callback where they are called, so only a branch that is taken
+    reports): the forward pass one tight buffer where the held assignments
+    fit it, else the wide ones they fill; its gradient the same buffers
+    twice, the forward's and the backward's own (it runs a buffer again):
+    the backward pass takes the branch the forward took."""
+    t, k, first, held = 2048, 2, 2, 2
+    sizes = dict(zip(("tight", "wide"), moe.topk_buffer_sizes(t, k, 8, held)))
+    assert sizes == {"tight": 1536, "wide": 2560}
+    ran, swiglu = [], moe.grouped_swiglu
+
+    def reporting(rows, *rest):
+        jax.debug.callback(lambda: ran.append(rows.shape[0]))
+        return swiglu(rows, *rest)
+
+    monkeypatch.setattr(moe, "grouped_swiglu", reporting)
+    x = jax.random.normal(jax.random.PRNGKey(0), (t, 16))
+    route = moe.topk_route(
+        x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)),
+        _forced_bias(forced), k, 2.826, True)
+    args = (x,) + _expert_weights(held)
+    layer = lambda *a: moe.topk_moe_held(  # noqa: E731
+        a[0], route, *a[1:], first)
+    jax.block_until_ready(jax.jit(layer)(*args))
+    jax.effects_barrier()
+    assert ran == [sizes[size]] * buffers
+    del ran[:]
+    jax.block_until_ready(jax.jit(jax.grad(
+        lambda *a: jnp.sum(layer(*a) ** 2), (0, 1, 2, 3)))(*args))
+    jax.effects_barrier()
+    assert ran == [sizes[size]] * (2 * buffers)
+
+
+@pytest.mark.parametrize("t, held, conditionals", [
+    (2048, 8, 0), (2048, 4, 1), (256, 4, 0)],
+    ids=["every-expert-held", "a-share", "a-share-in-one-buffer"])
+def test_a_conditional_only_where_the_two_sizes_differ(t, held, conditionals):
+    """The lowered text of the layer and of its gradient: one ``case`` a
+    pass where a share's tight buffer is smaller than its wide one; none
+    with every expert held, nor where both sizes are all ``T x k``
+    assignments: the program is the one loop over buffers it was."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (t, 16))
+    route = moe.topk_route(
+        x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)), jnp.zeros(8),
+        2, 2.826, True)
+    args = (x,) + _expert_weights(held)
+    tight, wide = moe.topk_buffer_sizes(t, 2, 8, held)
+    assert (tight < wide) == bool(conditionals)
+    layer = lambda *a: moe.topk_moe_held(a[0], route, *a[1:], 0)  # noqa: E731
+    for fn, passes in ((layer, 1), (jax.grad(
+            lambda *a: jnp.sum(layer(*a) ** 2), (0, 1, 2, 3)), 2)):
+        text = jax.jit(fn).lower(*args).as_text()
+        assert text.count("stablehlo.case") == conditionals * passes
+        assert "stablehlo.while" in text
+
+
+def _routed_by_hand(t, n_rows):
+    """A route of 2 choices a token over 8 experts, 0-2 held, with ``n_rows
+    + 1`` held assignments, in which ONE token's two rows are the last of
+    the first ``n_rows`` and the one after them: expert 0 takes the first
+    ``n_rows - t`` tokens, expert 1 every token, expert 2 the last token
+    alone (the others' second choice is the absent expert 5)."""
+    assert t <= n_rows < 2 * t
     at = np.arange(t)
     expert = np.stack([np.where(at < n_rows - t, 0, 1),
                        np.where(at < n_rows - t, 1, 5)], axis=1)
@@ -624,16 +728,20 @@ def _routed_by_hand(t):
     weight = jax.random.uniform(jax.random.PRNGKey(3), (t, 2), jnp.float32,
                                 0.2, 1.0)
     return moe.TopKRoute(jnp.asarray(expert, jnp.int32), weight, jnp.asarray(
-        np.bincount(expert.ravel(), minlength=8), jnp.int32)), n_rows
+        np.bincount(expert.ravel(), minlength=8), jnp.int32))
 
 
 @pytest.mark.parametrize("form", FORMS, indirect=True)
 @pytest.mark.parametrize("case", ["eight-rows-a-token",
-                                  "a-token-on-the-boundary"])
+                                  "a-token-on-the-boundary",
+                                  "one-past-the-tight-buffer",
+                                  "the-tight-buffer-full"])
 def test_no_assignment_is_dropped_at_the_edges(case, form):
-    """Every token with 8 live rows (top 8 of 8 experts, all held); and a
-    token whose rows are the last of one buffer and the first of the next,
-    so that its sum is made of two buffers' (and two chunks') parts: the
+    """Every token with 8 live rows (top 8 of 8 experts, all held); a
+    token whose rows are the last of one wide buffer and the first of the
+    next, so that its sum is made of two buffers' (and two chunks') parts;
+    one assignment more than the tight buffer holds (the wide one runs),
+    and exactly as many as it holds (it runs, full to its last row): the
     dense loop's output and gradient, the routing weights' too, in either
     form of the row sums."""
     if case == "eight-rows-a-token":
@@ -642,14 +750,26 @@ def test_no_assignment_is_dropped_at_the_edges(case, form):
             x, jax.random.normal(jax.random.PRNGKey(1), (16, 8)),
             jnp.zeros(8), 8, 2.826, True)
         held = 8
-        assert moe.topk_buffer_rows(256, 8, 8, held) == 256 * 8
+        assert moe.topk_buffer_sizes(256, 8, 8, held) == (256 * 8,) * 2
     else:
         x = jax.random.normal(jax.random.PRNGKey(0), (4096, 16))
-        route, n_rows = _routed_by_hand(4096)
         held = 3
+        tight, wide = moe.topk_buffer_sizes(4096, 2, 8, held)
+        assert (tight, wide) == (4096, 7680)
+        n_rows = wide if case == "a-token-on-the-boundary" else tight
+        route = _routed_by_hand(4096, n_rows)
+        if case == "the-tight-buffer-full":
+            # (the last token's second choice absent too: one row fewer)
+            route = route._replace(
+                expert=route.expert.at[-1, 1].set(5),
+                counts=route.counts.at[2].add(-1).at[5].add(1))
         token, _, sizes = moe.topk_order(route, 0, held)
-        assert int(sizes.sum()) == n_rows + 1
-        assert token[n_rows - 1] == token[n_rows] == 4095
+        n_held = int(sizes.sum())
+        assert n_held == n_rows + (case != "the-tight-buffer-full")
+        assert moe.topk_buffer_rows(4096, 2, 8, held, n_held) == (
+            tight if case == "the-tight-buffer-full" else wide)
+        assert token[n_rows - 1] == 4095
+        assert n_held == n_rows or token[n_rows] == 4095
     wg, wu, wd = _expert_weights(held)
 
     def total(layer):
@@ -766,12 +886,15 @@ def test_the_gather_form_against_the_chunked_form(monkeypatch, dtype, out,
 
 
 @pytest.mark.parametrize("shape, form, rows_over_live, one_more", [
-    # the conv/attention cell: 2 x 8,192 tokens, 4 of 32, 8 held; past half
-    # of its buffer of 40,960 rows (two slabs of 80 MiB) every choice is
-    # gathered once more, and again for a second buffer
-    ((16384, 4, 32, 8), "gather", 4.0, {20481: 2, 40961: 3}),
-    # the sparse-expert cell: 8,192 tokens, 8 of 128, 8 held: 4 chunks
-    ((8192, 8, 128, 8), "chunks", 1.0, {4097: 5120 / 65536}),
+    # the conv/attention cell: 2 x 8,192 tokens, 4 of 32, 8 held: the tight
+    # buffer of 20,480 rows is one slab of 80 MiB; past it, in the wide
+    # buffer of 40,960 rows (two such slabs), every choice is gathered once
+    # more, and again for a second buffer
+    ((16384, 4, 32, 8), "gather", 4.0, {20480: 1, 20481: 2, 40961: 3}),
+    # the sparse-expert cell: 8,192 tokens, 8 of 128, 8 held: 4 chunks; the
+    # tight buffer of 5,120 rows is 5 chunks, the wide one goes on
+    ((8192, 8, 128, 8), "chunks", 1.0, {4097: 5120 / 65536,
+                                        5121: 6144 / 65536}),
     # a whole layer of either: 256 MiB of rows, three slabs
     ((16384, 4, 32, 32), "gather", 3.0, {}),
     ((8192, 8, 128, 128), "gather", 3.0, {}),
