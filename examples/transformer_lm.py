@@ -33,7 +33,17 @@ not rotate, ``a`` one that does, ``c`` a layer whose mixer is the gated short
 convolution (three taps; ``--pattern caccc --dense-layers 1`` with
 ``--kv-heads``, ``--qk-norm`` is a model of the kind of
 benchmark/configs/lfm2-8b-a1b.json, whose layers by kind of mixer go to the
-gauge ``hvd_tpu_lm_layers``), repeated over ``--n-layers``; the first
+gauge ``hvd_tpu_lm_layers``), repeated over ``--n-layers``; the capital
+letters are layers of ONE sublayer, as ``hybrid_override_pattern`` writes
+them: ``M`` a Mamba-2 state-space mixer alone (``--ssm-heads``,
+``--ssm-head-dim``, ``--ssm-state``, ``--ssm-groups``, ``--ssm-chunk``,
+``--conv-kernel``; the chunks of a row go to the gauge
+``hvd_tpu_lm_scan_chunks``), ``*`` attention alone, which rotates nothing, and
+``E`` the routed-expert FFN alone
+(``--pattern "MEMEM*E" --expert-ffn relu2 --shared-experts 1 --shared-ff
+...`` is a model of the kind of
+benchmark/configs/nemotron-3-nano-30b-a3b.json); of the other letters the
+first
 ``--dense-layers`` keep
 the dense FFN, the others route ``--top-k`` of ``--experts`` sigmoid-scored
 SwiGLU experts of ``--expert-ff`` beside ``--shared-experts``, of which
@@ -112,13 +122,26 @@ def main():
                     help="one letter a layer of a period: s (window), f "
                          "(full attention, no rotation), a (full attention, "
                          "rotated), c (the gated short convolution), e.g. "
-                         "sssf, caccc")
+                         "sssf, caccc; layers of one sublayer: M (Mamba-2 "
+                         "alone), * (attention alone, no rotation), E (the "
+                         "routed experts alone), e.g. 'MEMEM*E'")
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--dense-layers", type=int, default=1)
     ap.add_argument("--experts", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=2)
     ap.add_argument("--expert-ff", type=int, default=0)
     ap.add_argument("--shared-experts", type=int, default=0)
+    ap.add_argument("--shared-ff", type=int, default=0,
+                    help="the shared experts' width together where it is "
+                         "not --shared-experts x --expert-ff")
+    ap.add_argument("--expert-ffn", choices=["swiglu", "relu2"],
+                    default="swiglu")
+    ap.add_argument("--ssm-heads", type=int, default=0)
+    ap.add_argument("--ssm-head-dim", type=int, default=64)
+    ap.add_argument("--ssm-state", type=int, default=128)
+    ap.add_argument("--ssm-groups", type=int, default=1)
+    ap.add_argument("--ssm-chunk", type=int, default=128)
+    ap.add_argument("--conv-kernel", type=int, default=3)
     ap.add_argument("--route-scale", type=float, default=1.0)
     ap.add_argument("--experts-held", type=int, default=0)
     ap.add_argument("--first-expert", type=int, default=0)
@@ -146,12 +169,21 @@ def main():
     if args.pattern:
         kinds = {"s": dict(window=args.window), "f": dict(rope=False),
                  "a": {}, "c": dict(mixer="conv")}
+        alone = {"M": dict(mixer="mamba2", experts=None),
+                 "*": dict(rope=False, experts=None),
+                 "E": dict(mixer="none", experts=True)}
+        letters = [args.pattern[i % len(args.pattern)]
+                   for i in range(args.n_layers)]
         pattern = dict(
             layers=tuple(
-                LayerKind(**kinds[args.pattern[i % len(args.pattern)]],
-                          experts=i >= args.dense_layers)
-                for i in range(args.n_layers)),
+                LayerKind(**alone[letter]) if letter in alone else
+                LayerKind(**kinds[letter], experts=i >= args.dense_layers)
+                for i, letter in enumerate(letters)),
             moe_top_k=args.top_k, d_ff_expert=args.expert_ff or args.d_ff,
+            expert_ffn=args.expert_ffn, d_ff_shared=args.shared_ff,
+            conv_kernel=args.conv_kernel, ssm_heads=args.ssm_heads,
+            ssm_head_dim=args.ssm_head_dim, ssm_state=args.ssm_state,
+            ssm_groups=args.ssm_groups, ssm_chunk=args.ssm_chunk,
             n_shared_experts=args.shared_experts,
             route_scale=args.route_scale, experts_held=args.experts_held,
             first_expert=args.first_expert,
@@ -222,6 +254,9 @@ def main():
         by_mixer = collections.Counter(k.mixer for k in cfg.layers)
         for mixer, n in by_mixer.items():
             registry().gauge("hvd_tpu_lm_layers").set(n, mixer=mixer)
+        if cfg.has_mamba:
+            registry().gauge("hvd_tpu_lm_scan_chunks").set(
+                args.seq // cfg.ssm_chunk, chunk=str(cfg.ssm_chunk))
         attn = None
         if cfg.attention == "flash" and mesh.shape["seq"] == 1:
             # what the step's local attention call runs on this backend, and

@@ -56,11 +56,20 @@ ATTN_GATE = "attn_gate"         # the gate's projection, sigmoid and product
 CONV_MIXER = "conv_mixer"       # where such a layer has attn: rmsnorm, the two
 #                                 projections, the residual
 SHORT_CONV = "short_conv"       # inside it: the two gates and the taps
+# a layer whose mixer is the Mamba-2 state-space mixer (LayerKind.mixer
+# "mamba2"; models/transformer.py _mamba_mix, parallel/ssd.py)
+MAMBA_MIXER = "mamba_mixer"     # where such a layer has attn: rmsnorm, the
+#                                 products with ssm_in and ssm_out, dt's
+#                                 softplus, the residual
+SSM_CONV = "ssm_conv"           # inside it: the taps, their bias, the SiLU
+SSM_SCAN = "ssm_scan"           # ... everything of ssd_chunked
+SSM_GATE_NORM = "ssm_gate_norm"  # ... the gate, then the norm over groups
 # inside ffn, the routed-expert layer (parallel/moe.py topk_*)
 ROUTER = "router"               # fp32 scores, top-k, weights, counts
 MOE_DISPATCH = "moe_dispatch"   # the sort by expert and the gather
-EXPERTS = "experts"             # the held experts' three grouped matmuls
-SHARED_EXPERT = "shared_expert"  # the SwiGLU every token takes
+EXPERTS = "experts"             # the held experts' grouped matmuls (three of
+#                                 a SwiGLU, two around relu(.)^2)
+SHARED_EXPERT = "shared_expert"  # the expert every token takes
 MOE_COMBINE = "moe_combine"     # weighted scatter-add back to the tokens
 # optimizer.py and the step builders
 OPTIMIZER = "optimizer"         # inner.update + optax.apply_updates

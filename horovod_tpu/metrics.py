@@ -210,8 +210,17 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
     "hvd_tpu_lm_layers": (
         "gauge", "Layers of the model's per-layer pattern by the kind of "
                  "their mixer (label mixer: attention, conv: the gated "
-                 "short convolution); examples/transformer_lm.py sets it "
-                 "when it has built its step from --pattern"),
+                 "short convolution, mamba2: the state-space mixer, none: "
+                 "a layer of its FFN alone); examples/transformer_lm.py "
+                 "sets it when it has built its step from --pattern"),
+    # parallel/ssd.py ssd_chunked (ISSUE 39)
+    "hvd_tpu_lm_scan_chunks": (
+        "gauge", "Chunks of one row that a mamba2 layer's chunked scan "
+                 "carries its state across, sequence length over "
+                 "TransformerConfig.ssm_chunk (label chunk: the chunk's "
+                 "tokens): the steps of the one sequential loop of the "
+                 "scan; examples/transformer_lm.py sets it when the pattern "
+                 "it built has such a layer"),
     # parallel/flash_attention.py attention_kernel (ISSUE 31; window: 32;
     # head_size: 34)
     "hvd_tpu_attn_kernel": (

@@ -43,6 +43,7 @@ from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
                             router_bias_update, row_sum_form, row_sum_rows,
                             topk_buffer_rows, topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
+from ..parallel.ssd import ssd_chunked
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -58,14 +59,18 @@ class LayerKind:
     j < window``; 0: every ``j <= i``. ``rope``: under ``positions="rope"``,
     whether this layer rotates q and k (False: no position signal of its
     own). ``experts``: the routed-expert FFN (``moe_top_k`` of
-    ``n_experts`` and the shared experts) in place of the dense one.
+    ``n_experts`` and the shared experts) in place of the dense one; None:
+    no FFN at all, the layer is its mixer ALONE.
     ``mixer``: what mixes the tokens of a layer, "attention" (the block as
-    it was) or "conv", the gated short convolution of :func:`_conv_mix`
+    it was), "conv", the gated short convolution of :func:`_conv_mix`
     (no window, no rotation, no attention leaves: ``conv_in``, ``conv_w``,
-    ``conv_out`` in their place)."""
+    ``conv_out`` in their place), "mamba2", the state-space mixer of
+    :func:`mamba_mix` (the ``ssm_*`` leaves), or "none": the layer is its
+    FFN ALONE. A layer of one sublayer is ``h + F(N(h))``: one norm leaf
+    (``ln1`` of a mixer, ``ln2`` of an FFN) and one residual."""
     window: int = 0
     rope: bool = True
-    experts: bool = False
+    experts: Optional[bool] = False
     mixer: str = "attention"
 
 
@@ -158,9 +163,20 @@ class TransformerConfig:
     # :data:`_STACKS`), and the pattern runs in its order as one scan a run
     # of like layers. Empty: ``n_layers`` of the one block, as before.
     layers: Tuple[LayerKind, ...] = ()
-    # ``LayerKind.mixer == "conv"``: the taps of the depthwise causal
-    # convolution.
+    # The taps of the depthwise causal convolution of a "conv" or a
+    # "mamba2" mixer.
     conv_kernel: int = 3
+    # ``LayerKind.mixer == "mamba2"`` (:func:`mamba_mix`): ``ssm_heads``
+    # heads of ``ssm_head_dim`` (their product is the mixer's inner width),
+    # each with a state of ``ssm_head_dim x ssm_state``; ``ssm_groups``
+    # groups of B and C (head ``h`` reads group ``h // (heads / groups)``)
+    # and of the gated norm; the scan's chunk. The taps always have a bias,
+    # and ``dt_bias`` is drawn as :data:`SSM_DT_INIT` says.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
     # Under ``remat="block"`` a run of ONE layer keeps ``jax.checkpoint``'s
     # barriers (``prevent_cse``): XLA unrolls a scan of one iteration and,
     # without them, merges the recomputation with the forward pass it
@@ -173,8 +189,11 @@ class TransformerConfig:
     # The routed-expert FFN of a ``LayerKind.experts`` layer
     # (parallel/moe.py ``topk_*``): ``moe_top_k`` of ``n_experts`` by
     # sigmoid scores, weights normalised over the chosen (``route_norm``)
-    # times ``route_scale``, SwiGLU experts of width ``d_ff_expert``,
-    # ``n_shared_experts`` of the same width that every token takes, no
+    # times ``route_scale``, experts of width ``d_ff_expert``
+    # (``expert_ffn``: "swiglu", ``(silu(x wg) * (x wu)) wd``, or "relu2",
+    # ``relu(x wu)^2 wd`` with no gate), ``n_shared_experts`` of the same
+    # form that every token takes, as one expert ``d_ff_shared`` wide (0:
+    # ``n_shared_experts * d_ff_expert``), no
     # capacity and no drop. This program holds experts ``first_expert ..
     # first_expert + experts_held - 1`` (0 held: all) and leaves the others'
     # part out. ``router_bias_rate``: after a step ``b_e += rate *
@@ -182,7 +201,9 @@ class TransformerConfig:
     # :func:`make_train_step`, outside the optimizer.
     moe_top_k: int = 0
     d_ff_expert: int = 0
+    expert_ffn: str = "swiglu"
     n_shared_experts: int = 0
+    d_ff_shared: int = 0
     route_scale: float = 1.0
     route_norm: bool = True
     experts_held: int = 0
@@ -194,6 +215,7 @@ class TransformerConfig:
     def __post_init__(self):
         for name, known in (("positions", ("none", "rope")),
                             ("ffn", ("gelu", "swiglu")),
+                            ("expert_ffn", ("swiglu", "relu2")),
                             ("norm", ("pre", "sandwich"))):
             if getattr(self, name) not in known:
                 raise ValueError(f"unknown {name} {getattr(self, name)!r}; "
@@ -218,13 +240,20 @@ class TransformerConfig:
                                  "pass and brings its own expert layers: "
                                  "not with n_loops > 1 or use_moe")
             for kind in self.layers:
-                if kind.mixer not in ("attention", "conv"):
+                if kind.mixer not in _MIXERS:
                     raise ValueError(f"unknown mixer {kind.mixer!r}; "
-                                     f"expected 'attention' or 'conv'")
-                if kind.mixer == "conv" and kind.window:
-                    raise ValueError("a conv layer has no window: it sees "
-                                     "conv_kernel tokens")
-            _n_lead(self)
+                                     f"expected one of {_MIXERS}")
+                if kind.mixer != "attention" and kind.window:
+                    raise ValueError(
+                        f"a layer whose mixer is {kind.mixer!r} has no "
+                        f"window: only attention masks by distance")
+                _stack_of(kind)
+            if self.has_mamba and not (
+                    self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                    and self.ssm_heads % self.ssm_groups == 0):
+                raise ValueError(
+                    "a mamba2 layer needs ssm_heads, ssm_head_dim, "
+                    "ssm_state, and ssm_groups that divide the heads")
         if self.has_experts and not (
                 0 < self.moe_top_k <= self.n_experts and self.d_ff_expert
                 and self.first_expert + self.held <= self.n_experts):
@@ -248,8 +277,13 @@ class TransformerConfig:
         return any(kind.experts for kind in self.layers)
 
     @property
-    def has_conv(self) -> bool:
-        return any(kind.mixer == "conv" for kind in self.layers)
+    def has_mamba(self) -> bool:
+        return any(kind.mixer == "mamba2" for kind in self.layers)
+
+    @property
+    def shared_width(self) -> int:
+        """The hidden width of the one expert the shared experts make."""
+        return self.d_ff_shared or self.n_shared_experts * self.d_ff_expert
 
     @property
     def held(self) -> int:
@@ -264,28 +298,42 @@ class ExpertRoutes(NamedTuple):
     counts: jax.Array   # [..., n_experts] int32: assignments to each expert
 
 
-def _n_lead(cfg: TransformerConfig) -> int:
-    """The leading layers of a per-layer pattern that have the dense FFN;
-    every layer after them routes experts."""
-    kinds = cfg.layers
-    n_lead = next((i for i, kind in enumerate(kinds) if kind.experts),
-                  len(kinds))
-    if not all(kind.experts for kind in kinds[n_lead:]):
-        raise ValueError("layers: a dense-FFN layer after an expert layer "
-                         "has no stack to live in (dense layers lead)")
-    return n_lead
+_MIXERS = ("attention", "conv", "mamba2", "none")
 
-
-# The stack a kind of layer's leaves live in, by (mixer, routed experts):
-# every layer of the kind, in the order they run, on the leaves' leading
-# axis. The two attention stacks are the names a pattern had before the
-# mixer kind existed.
+# The stack a kind of layer's leaves live in, by (mixer, FFN: False the
+# dense one, True the routed experts, None none): every layer of the kind,
+# in the order they run, on the leaves' leading axis. The two attention
+# stacks are the names a pattern had before the mixer kind existed. The
+# one rule of a pattern: every layer has a stack. The layers of a stack
+# need not follow one another (a run of them is one scan:
+# :func:`_segments`), so dense layers may stand after expert layers and
+# mixer-only layers between them. A kind that no model has brought yet
+# (the scan with an FFN in its layer, the conv mixer or the dense FFN
+# alone) has no stack and is refused by name.
 _STACKS = {("attention", False): "dense_layers", ("attention", True): "layers",
-           ("conv", False): "conv_dense_layers", ("conv", True): "conv_layers"}
+           ("conv", False): "conv_dense_layers", ("conv", True): "conv_layers",
+           # layers of ONE sublayer
+           ("attention", None): "attn_mixers",
+           ("mamba2", None): "mamba_mixers", ("none", True): "expert_ffns"}
+
+# What a mamba2 mixer's ``ssm_dt_bias`` starts as: the inverse softplus of
+# a log-uniform step in ``[min, max]``, floored (Mamba-2's ``time_step_min``,
+# ``time_step_max``, ``time_step_floor`` as published with every model of
+# the kind so far).
+SSM_DT_INIT = {"min": 1e-3, "max": 1e-1, "floor": 1e-4}
 
 
 def _stack_of(kind: LayerKind) -> str:
-    return _STACKS[kind.mixer, kind.experts]
+    found = _STACKS.get((kind.mixer, kind.experts))
+    if found is None:
+        raise ValueError(
+            f"layers: a layer with mixer {kind.mixer!r} and experts="
+            f"{kind.experts!r} has no stack to live in: a layer is an "
+            f"attention or conv mixer with its FFN (experts False: dense, "
+            f"True: routed), or ONE sublayer: an attention or mamba2 mixer "
+            f"alone (experts None) or the routed experts alone (mixer "
+            f"'none', experts True)")
+    return found
 
 
 def layer_rows(cfg: TransformerConfig) -> list:
@@ -373,16 +421,12 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
     expert's weights are drawn from its global number, so every share of
     the experts draws the same expert the same."""
     D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    _n_lead(cfg)
-    sandwich = cfg.norm == "sandwich"
 
-    def norms(n):
-        leaves = {"ln1": jnp.ones((n, D), jnp.float32),
-                  "ln2": jnp.ones((n, D), jnp.float32)}
-        if sandwich:
-            leaves.update({"ln1_post": jnp.ones((n, D), jnp.float32),
-                           "ln2_post": jnp.ones((n, D), jnp.float32)})
-        return leaves
+    def norms(n, sublayers):    # "ln1" a mixer's, "ln2" an FFN's
+        posts = [ln + "_post" for ln in sublayers] \
+            if cfg.norm == "sandwich" else []
+        return {ln: jnp.ones((n, D), jnp.float32)
+                for ln in sublayers + posts}
 
     def attention(k, n):
         ks = jax.random.split(k, 4)
@@ -399,11 +443,39 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
                                      cfg.conv_kernel),
                 "conv_out": _norm_init(ks[2], (n, D, D), D)}
 
+    def mamba(k, n):
+        # A_log = log U[1, 16]; dt_bias the inverse softplus of a
+        # log-uniform step; D = 1; the taps' bias as a Conv1d draws it
+        hs, taps = cfg.ssm_heads, cfg.conv_kernel
+        inner = hs * cfg.ssm_head_dim
+        conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        ks = jax.random.split(jax.random.fold_in(k, 5), 6)
+        lo, hi = math.log(SSM_DT_INIT["min"]), math.log(SSM_DT_INIT["max"])
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            ks[3], (n, hs), jnp.float32, lo, hi)), SSM_DT_INIT["floor"])
+        return {
+            "ssm_in": _norm_init(ks[0], (n, D, inner + conv + hs), D),
+            "ssm_conv_w": _norm_init(ks[1], (n, taps, conv), taps),
+            "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "ssm_A_log": jnp.log(jax.random.uniform(
+                ks[4], (n, hs), jnp.float32, 1.0, 16.0)),
+            "ssm_D": jnp.ones((n, hs), jnp.float32),
+            "ssm_conv_b": jax.random.uniform(
+                ks[5], (n, conv), jnp.float32, -1.0, 1.0) * taps ** -0.5,
+            "ssm_norm": jnp.ones((n, inner), jnp.float32),
+            "ssm_out": _norm_init(ks[2], (n, inner, D), inner)}
+
     def swiglu(k, lead, f, prefix=""):
         ks = jax.random.split(k, 3)
         return {prefix + "wg": _norm_init(ks[0], lead + (D, f), D),
                 prefix + "wu": _norm_init(ks[1], lead + (D, f), D),
                 prefix + "wd": _norm_init(ks[2], lead + (f, D), f)}
+
+    def expert_ffn(k, lead, f, prefix):
+        leaves = swiglu(k, lead, f, prefix)
+        if cfg.expert_ffn == "relu2":       # no gate: two matrices
+            del leaves[prefix + "wg"]
+        return leaves
 
     def routed(k, n):
         E, Fe = cfg.n_experts, cfg.d_ff_expert
@@ -411,34 +483,41 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
             jax.random.fold_in(k, 1), 3)
 
         def expert(e):      # [n, ...] leaves of global expert e
-            return swiglu(jax.random.fold_in(k_exp, e), (n,), Fe, "e")
+            return expert_ffn(jax.random.fold_in(k_exp, e), (n,), Fe, "e")
 
         leaves = {"router": _norm_init(k_route, (n, D, E), D),
                   "router_bias": jnp.zeros((n, E), jnp.float32),
                   **jax.vmap(expert, out_axes=1)(
                       cfg.first_expert + jnp.arange(cfg.held))}
         if cfg.n_shared_experts:
-            leaves.update(swiglu(k_shared, (n,), cfg.n_shared_experts * Fe,
-                                 "shared_"))
+            leaves.update(expert_ffn(k_shared, (n,), cfg.shared_width,
+                                     "shared_"))
         return leaves
 
     k_dense, k_moe = jax.random.split(key)
-    # (the conv stacks draw from keys the attention stacks never used)
+    # (the conv stacks draw from keys the attention stacks never used, and
+    # the one-sublayer stacks from keys those four never used)
     keys = {"dense_layers": k_dense, "layers": k_moe,
             "conv_dense_layers": jax.random.fold_in(k_dense, 2),
-            "conv_layers": jax.random.fold_in(k_moe, 2)}
+            "conv_layers": jax.random.fold_in(k_moe, 2),
+            "attn_mixers": jax.random.fold_in(k_dense, 14),
+            "mamba_mixers": jax.random.fold_in(k_dense, 16),
+            "expert_ffns": jax.random.fold_in(k_moe, 18)}
+    mixers = {"attention": attention, "conv": conv, "mamba2": mamba,
+              "none": lambda k, n: {}}
     n_of = collections.Counter(_stack_of(kind) for kind in cfg.layers)
     out = {}
     for (mixer, experts), stack in _STACKS.items():
         n, k = n_of[stack], keys[stack]
         if not n:
             continue
-        if not experts and cfg.ffn != "swiglu":
+        if experts is False and cfg.ffn != "swiglu":
             raise ValueError("a per-layer pattern's dense layers are SwiGLU")
         out[stack] = {
-            **norms(n),
-            **(attention(k, n) if mixer == "attention" else conv(k, n)),
-            **(routed(k, n) if experts else
+            **norms(n, ["ln1"] * (mixer != "none")
+                    + ["ln2"] * (experts is not None)),
+            **mixers[mixer](k, n),
+            **({} if experts is None else routed(k, n) if experts else
                swiglu(jax.random.fold_in(k, 1), (n,), cfg.d_ff))}
     return out
 
@@ -520,19 +599,25 @@ def _init_layers(k_layers, cfg: TransformerConfig) -> dict:
     return layers
 
 
-def _layer_specs(cfg: TransformerConfig, experts: bool,
+def _layer_specs(cfg: TransformerConfig, experts: Optional[bool],
                  mixer: str = "attention") -> dict:
     """PartitionSpecs of one stack's leaves: the homogeneous stack's, or a
-    pattern's stack of one kind of layer (``mixer``; ``experts``: with the
-    routed-expert FFN)."""
-    layers = {"ln1": P(), "ln2": P()}
-    if mixer == "conv":
+    pattern's stack of one kind of layer (``mixer``; ``experts``:
+    :class:`LayerKind`'s)."""
+    sublayers = ["ln1"] * (mixer != "none") + ["ln2"] * (experts is not None)
+    layers = {ln: P() for ln in sublayers}
+    if mixer == "mamba2":
+        # whole on every shard: :func:`mamba_mix` refuses tensor > 1
+        layers.update({leaf: P() for leaf in (
+            "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
+            "ssm_A_log", "ssm_D", "ssm_norm", "ssm_out")})
+    elif mixer == "conv":
         # the channels split over tensor like the FFN's hidden dim: the
         # columns of the three gates, the taps, the rows of the output
         layers.update({"conv_in": P(None, None, None, TENSOR_AXIS),
                        "conv_w": P(None, None, TENSOR_AXIS),
                        "conv_out": P(None, TENSOR_AXIS)})
-    else:
+    elif mixer == "attention":
         layers.update({
             "wq": P(None, None, TENSOR_AXIS),
             "wk": P(None, None, TENSOR_AXIS),
@@ -552,6 +637,11 @@ def _layer_specs(cfg: TransformerConfig, experts: bool,
             layers.update({"shared_wg": P(None, None, TENSOR_AXIS),
                            "shared_wu": P(None, None, TENSOR_AXIS),
                            "shared_wd": P(None, TENSOR_AXIS)})
+        if cfg.expert_ffn == "relu2":       # no gate
+            del layers["ewg"]
+            layers.pop("shared_wg", None)
+    elif experts is None:                   # the mixer alone
+        pass
     elif cfg.use_moe:
         # experts sharded over the tensor axis (EP replaces TP for the FFN);
         # the router stays replicated
@@ -566,7 +656,7 @@ def _layer_specs(cfg: TransformerConfig, experts: bool,
         layers.update({"w1": P(None, None, TENSOR_AXIS),
                        "w2": P(None, TENSOR_AXIS)})
     if cfg.norm == "sandwich":
-        layers.update({"ln1_post": P(), "ln2_post": P()})
+        layers.update({ln + "_post": P() for ln in sublayers})
     return layers
 
 
@@ -756,6 +846,71 @@ def _conv_mix(x, lp, *, cfg: TransformerConfig,
     return out
 
 
+def mamba_mix(x, lp, *, cfg: TransformerConfig,
+              seq_size: Optional[int] = None,
+              tensor_size: Optional[int] = None):
+    """The Mamba-2 state-space mixer over the normed ``x [B, T, D]`` with
+    one layer's leaves ``lp``. With ``H = cfg.ssm_heads`` heads of ``P =
+    cfg.ssm_head_dim``, ``G = cfg.ssm_groups`` groups and a state of ``N =
+    cfg.ssm_state``: ``(z, xBC, dt) = split(x ssm_in)`` at ``H P``, ``H P +
+    2 G N`` and ``H``; ``xBC = silu(conv(xBC))``, depthwise and causal over
+    ``cfg.conv_kernel`` taps with a bias; ``(X, B, C) = split(xBC)``; ``dt =
+    softplus(dt + ssm_dt_bias)``, ``A = -exp(ssm_A_log)``; the scan
+    (:func:`~horovod_tpu.parallel.ssd.ssd_chunked` in chunks of
+    ``cfg.ssm_chunk``); ``y = RMSNorm(y * silu(z))`` over each of the ``G``
+    groups of channels, the gate BEFORE the norm, with the learned scale
+    ``ssm_norm``; ``y ssm_out``. The two projections and the scan's
+    products in ``cfg.dtype`` with float32 accumulation; the taps, the
+    softplus, the decays and the gated norm in float32.
+
+    Under ``tensor > 1`` it refuses: ``ssm_in`` is one matrix whose columns
+    are z, X, B, C and dt side by side, and splitting heads and groups over
+    the axis needs them apart.
+
+    Public: a check of ONE layer's mixer against the recurrence calls it
+    with a row of the stack's leaves
+    (``benchmark/configs/nemotron-3-nano-30b-a3b.py``)."""
+    if seq_size is not None and seq_size > 1:
+        raise ValueError(
+            "the mamba2 mixer under sequence parallelism (seq > 1, the mesh "
+            "of ring and Ulysses attention) needs the state and the last "
+            "conv_kernel - 1 tokens of the shard before it: no such "
+            "exchange here")
+    if tensor_size is not None and tensor_size > 1:
+        raise ValueError(
+            "the mamba2 mixer under tensor parallelism (tensor > 1): its "
+            "ssm_in leaf holds z, X, B, C and dt side by side, and heads "
+            "and groups are not split over the axis")
+    dt_, f32 = cfg.dtype, jnp.float32
+    (bsz, t, _), hs, p = x.shape, cfg.ssm_heads, cfg.ssm_head_dim
+    g, n = cfg.ssm_groups, cfg.ssm_state
+    inner, gn = hs * p, g * n
+    z, xbc, dt = jnp.split(
+        jnp.einsum("btd,de->bte", x, lp["ssm_in"].astype(dt_)),
+        [inner, 2 * inner + 2 * gn], axis=-1)
+    with jax.named_scope(scopes.SSM_CONV):
+        taps = lp["ssm_conv_w"]
+        u = jnp.pad(xbc.astype(f32), ((0, 0), (taps.shape[0] - 1, 0), (0, 0)))
+        v = sum(taps[j] * u[:, j:j + t] for j in range(taps.shape[0])) \
+            + lp["ssm_conv_b"]
+        # (rounded here, once and under the scope: the scan's products take
+        # X, B and C in the compute dtype)
+        xs, b, c = jnp.split(jax.nn.silu(v).astype(dt_),
+                             [inner, inner + gn], axis=-1)
+    y = ssd_chunked(
+        xs.reshape(bsz, t, hs, p),
+        jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"]),
+        -jnp.exp(lp["ssm_A_log"]), b.reshape(bsz, t, g, n),
+        c.reshape(bsz, t, g, n), lp["ssm_D"], cfg.ssm_chunk)
+    with jax.named_scope(scopes.SSM_GATE_NORM):
+        y = (y.reshape(bsz, t, inner) * jax.nn.silu(z.astype(f32))).reshape(
+            bsz, t, g, inner // g)
+        y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        y = (y.reshape(bsz, t, inner) * lp["ssm_norm"]).astype(dt_)
+    return jnp.einsum("bte,ed->btd", y, lp["ssm_out"].astype(dt_))
+
+
 def _residual(h, out, lp, post: str, cfg: TransformerConfig):
     """How a sublayer's output joins the stream: ``h + out``, under
     sandwich norms ``h + N'(out)`` with the scale ``lp[post]``."""
@@ -779,9 +934,14 @@ def _dense_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int],
     """``gelu(x w1) w2`` or ``(silu(x wg) * (x wu)) wd``, the hidden dim
     split over ``tensor`` inside a shard_map (``reduce``: summed over it
     here). ``prefix``: the leaves' names begin with it (``"shared_"``: an
-    expert layer's shared experts, one SwiGLU as wide as all of them)."""
+    expert layer's shared experts, one expert of ``cfg.expert_ffn``'s form
+    as wide as all of them: a SwiGLU, or ``relu(x wu)^2 wd``)."""
     dt = cfg.dtype
-    if cfg.ffn == "swiglu" or prefix:
+    if prefix and cfg.expert_ffn == "relu2":
+        u = jnp.square(jax.nn.relu(jnp.einsum(
+            "btd,df->btf", x, lp[prefix + "wu"].astype(dt))))
+        out = jnp.einsum("btf,fd->btd", u, lp[prefix + "wd"].astype(dt))
+    elif cfg.ffn == "swiglu" or prefix:
         u = jax.nn.silu(jnp.einsum(
             "btd,df->btf", x, lp[prefix + "wg"].astype(dt))) * jnp.einsum(
             "btd,df->btf", x, lp[prefix + "wu"].astype(dt))
@@ -853,7 +1013,8 @@ def _expert_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
                        cfg.route_scale, cfg.route_norm, cfg.route_eps)
     if cfg.held < cfg.n_experts:
         route = route._replace(weight=lax.stop_gradient(route.weight))
-    out = topk_moe_held(tok, route, lp["ewg"], lp["ewu"], lp["ewd"],
+    # (no ``ewg`` leaf: relu2 experts, two products and no gate)
+    out = topk_moe_held(tok, route, lp.get("ewg"), lp["ewu"], lp["ewd"],
                         cfg.first_expert).reshape(b, t, d)
     if cfg.n_shared_experts:
         with jax.named_scope(scopes.SHARED_EXPERT):
@@ -878,20 +1039,24 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
     ``(lp, which)``, ``which`` the index into ``kinds`` of the layer at
     hand, and the attention call (its window, its rotation) is a
     ``lax.switch`` on it; a conv layer's mixer is :func:`_conv_mix`, under
-    the scope ``conv_mixer`` where an attention layer has ``attn``.
+    the scope ``conv_mixer`` where an attention layer has ``attn``, a mamba2
+    layer's :func:`mamba_mix` under ``mamba_mixer``; a layer of one
+    sublayer (:class:`LayerKind`) runs that one alone.
     ``routes``: an expert layer's :class:`ExpertRoutes`, else None.
     ``prevent_cse``: ``cfg.remat_barrier``, for a run of one layer. The
     other arguments are :func:`_attn_mix`'s."""
-    experts = bool(kinds) and kinds[0].experts
-    conv = bool(kinds) and kinds[0].mixer == "conv"
+    # the FFN: False dense, True routed experts, None none
+    experts = kinds[0].experts if kinds else False
+    mixer = kinds[0].mixer if kinds else "attention"
+    other_mix = {"conv": _conv_mix, "mamba2": mamba_mix}.get(mixer)
 
     def mix_of(kind: Optional[LayerKind]):
         window, rotate, scope = (0, True, None) if kind is None else (
             kind.window, kind.rope,
             scopes.ATTN_WINDOW if kind.window else scopes.ATTN_FULL)
         mix = functools.partial(
-            _conv_mix, cfg=cfg, seq_size=seq_size, tensor_size=tensor_size
-        ) if conv else functools.partial(
+            other_mix, cfg=cfg, seq_size=seq_size, tensor_size=tensor_size
+        ) if other_mix else functools.partial(
             _attn_mix, cfg=cfg, rope=rope if rotate else None,
             seq_size=seq_size, tensor_size=tensor_size, causal=causal,
             under_remat=under_remat, window=window, scope=scope)
@@ -906,19 +1071,15 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
     if cfg.remat not in ("none", "block", "attention"):
         raise ValueError(f"unknown remat mode {cfg.remat!r}; "
                          f"expected 'none', 'block', or 'attention'")
-    # (conv layers differ in nothing their mixer reads: one mix)
-    mixes = [mix_of(kind) for kind in (kinds[:1] if conv else kinds)] \
-        or [mix_of(None)]
+    # (conv and mamba2 layers differ in nothing their mixer reads: one mix;
+    # a layer of its FFN alone has none)
+    mixes = [] if mixer == "none" else [mix_of(kind) for kind in (
+        kinds[:1] if other_mix else kinds)] or [mix_of(None)]
+    mix_scope = {"conv": scopes.CONV_MIXER,
+                 "mamba2": scopes.MAMBA_MIXER}.get(mixer, scopes.ATTN)
 
-    def layer(carry, lp):
-        h, aux_sum = carry
-        routes, mix = None, mixes[0]
-        if kinds:       # a pattern's scan: (leaves, the layer's kind)
-            lp, which = lp
-            if len(mixes) > 1:
-                mix = lambda x, lp: lax.switch(which, mixes, x, lp)  # noqa: E731
-        h = _attn_sublayer(h, lp, cfg, mix,
-                           scopes.CONV_MIXER if conv else scopes.ATTN)
+    def ffn_sublayer(h, aux_sum, lp):
+        routes = None
         with jax.named_scope(scopes.FFN):
             x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
             if experts:
@@ -928,7 +1089,19 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
                 aux_sum = aux_sum + aux
             else:
                 out = _dense_ffn(x, lp, cfg, tensor_size)
-            h = _residual(h, out, lp, "ln2_post", cfg)
+            return _residual(h, out, lp, "ln2_post", cfg), aux_sum, routes
+
+    def layer(carry, lp):
+        h, aux_sum = carry
+        routes = None
+        if kinds:       # a pattern's scan: (leaves, the layer's kind)
+            lp, which = lp
+        if mixes:
+            mix = mixes[0] if len(mixes) == 1 else (
+                lambda x, lp: lax.switch(which, mixes, x, lp))
+            h = _attn_sublayer(h, lp, cfg, mix, mix_scope)
+        if experts is not None:
+            h, aux_sum, routes = ffn_sublayer(h, aux_sum, lp)
         return (h, aux_sum), routes
 
     if cfg.remat == "block":
@@ -964,7 +1137,6 @@ def _run_pattern(params, h, cfg: TransformerConfig, block, grad_axes):
     :func:`_run_passes`'s ``layer_grad_axes``. Returns ``(h, routes)``:
     :class:`ExpertRoutes` over the expert layers in their order, None
     without any."""
-    _n_lead(cfg)
     carry, routes = (h, jnp.zeros((), jnp.float32)), []
     with jax.named_scope(scopes.LAYERS):
         for stack, lo, kinds in _segments(cfg):
@@ -1335,9 +1507,10 @@ def grad_reduce_axes(mesh: Mesh, cfg: TransformerConfig):
         param_specs(cfg), is_leaf=lambda s: isinstance(s, P))
 
 
-# the expert layers' stacks (a pattern's leading dense layers are summed
-# after the backward pass, as since PR 32) and an untied head
-_IN_BACKWARD = ("layers", "conv_layers", "lm_head")
+# every stack of a pattern but the two of its dense attention and conv
+# layers (summed after the backward pass, as since PR 32), and an untied head
+_IN_BACKWARD = tuple(stack for stack in _STACKS.values() if stack not in (
+    "dense_layers", "conv_dense_layers")) + ("lm_head",)
 
 
 def _in_backward(axes, cfg: TransformerConfig):
@@ -1504,9 +1677,14 @@ def _refuse_loop_and_untied_head(cfg: TransformerConfig, builder: str) -> None:
     norm, the output gate, the embedding's multiplier) run there as in
     :func:`make_train_step`: they are the one ``_block``'s."""
     if cfg.layers:
-        conv = ("; its conv mixer (LayerKind.mixer='conv') and the stacks "
-                "of leaves it brings have none either"
-                if cfg.has_conv else "")
+        conv = "".join(
+            f"; its {mixer} mixer (LayerKind.mixer={mixer!r}) and the "
+            f"stacks of leaves it brings have none either"
+            for mixer in ("conv", "mamba2")
+            if any(kind.mixer == mixer for kind in cfg.layers))
+        if any(kind.mixer == "none" or kind.experts is None
+               for kind in cfg.layers):
+            conv += "; nor have its layers of one sublayer"
         raise ValueError(
             f"{builder} runs one homogeneous stack: got a per-layer pattern "
             f"(layers, {len(cfg.layers)} kinds), whose dense and expert "

@@ -7,7 +7,8 @@ the caller.
    :func:`moe_layer_p` puts ``lax.all_to_all`` between them), below.
 2. Sigmoid top-k with no capacity and no drop, SwiGLU experts and a selection
    bias that balances the load without an auxiliary loss
-   (:func:`topk_route`, :func:`topk_dispatch`, :func:`grouped_swiglu`,
+   (:func:`topk_route`, :func:`topk_dispatch`, :func:`grouped_swiglu` or
+   :func:`grouped_relu2` where an expert is ``relu(x wu)^2 wd``,
    :func:`topk_combine`; :func:`topk_moe_held` runs them on ONE chip's
    share of the experts with no exchange), at the end of this file.
 
@@ -480,8 +481,14 @@ def gmm_tiles(m: int, k: int, n: int) -> tuple:
     tile cut to the largest multiple of 128 under it that divides the
     dimension (1792 = 2 x 896: no ragged last tile, whose masked part the
     kernel multiplies all the same), the dimension itself where it is
-    smaller or has no such divisor. Every call gets its own, the backward's
-    two too: they contract over another dimension than the forward's."""
+    smaller, the tile with a ragged last one where nothing divides (1856 =
+    14.5 x 128 under a hidden size of 2688: 1024 + 832. Alone on the v5e the
+    two products of relu2 experts of 1856, forward + backward over 7,680
+    rows, take 4.32 ms so, 3.68 with the 1856 whole, 4.41 / 4.78 at 640 /
+    512; inside the whole step the weights' gradient at a whole 1856 ran out
+    of VMEM on the chip: PERF.md section 6, PR 39). Every call gets its own,
+    the backward's two too: they contract over another dimension than the
+    forward's."""
     def tile(size, most):
         fits = [t for t in range(most, 127, -128) if size % t == 0]
         return fits[0] if fits else min(most, size)
@@ -537,6 +544,15 @@ def grouped_swiglu(rows, group_sizes, wg, wu, wd):
     with jax.named_scope(scopes.EXPERTS):
         u = jax.nn.silu(grouped_matmul(rows, wg, group_sizes)) \
             * grouped_matmul(rows, wu, group_sizes)
+        return grouped_matmul(u, wd, group_sizes)
+
+
+def grouped_relu2(rows, group_sizes, wu, wd):
+    """The held experts' FFN where an expert has no gate, ``relu(x wu_e)^2
+    wd_e`` for the rows of each expert ``e``: two grouped matrix products.
+    ``wu`` [held, d, f]; ``wd`` [held, f, d]."""
+    with jax.named_scope(scopes.EXPERTS):
+        u = jnp.square(jax.nn.relu(grouped_matmul(rows, wu, group_sizes)))
         return grouped_matmul(u, wd, group_sizes)
 
 
@@ -635,6 +651,8 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
     held experts ``first_expert .. first_expert + wg.shape[0] - 1`` of
     ``weight_e Expert_e(x)`` for every token that chose them, ``[T, d]`` in
     ``x``'s dtype; what the absent experts would add is left out.
+    ``wg`` None: the experts have no gate (:func:`grouped_relu2` in place of
+    :func:`grouped_swiglu`).
 
     No assignment is ever dropped, and the buffer is never ``T x k`` rows
     on a share: it fits the loads the router counted
@@ -657,10 +675,10 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
     ms of a 350 ms step on the v5e, PERF.md PR 32; the conditional here is
     inside each pass, and each branch returns the whole result.)"""
     t, k = route.expert.shape
-    held, n_experts = wg.shape[0], route.counts.shape[0]
     # cast once, ahead of every buffer: the loop then carries the weights'
     # cotangents in the compute dtype, not three fp32 copies
-    wg, wu, wd = (w.astype(x.dtype) for w in (wg, wu, wd))
+    ws = tuple(w.astype(x.dtype) for w in (wg, wu, wd) if w is not None)
+    held, n_experts = wu.shape[0], route.counts.shape[0]
     tight, wide = topk_buffer_sizes(t, k, n_experts, held)
     token, weight, group_sizes = topk_order(route, first_expert, held)
     # whole wide buffers: a slice past the end would be moved back over rows
@@ -676,12 +694,13 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
                   topk_places(route, first_expert, held))
         weight = route.weight
 
-    def buffer(n_rows, start, order, x, weight, wg, wu, wd):
+    def buffer(n_rows, start, order, x, weight, *ws):
         token, group_sizes, *gather = order
         row_weight, places = gather or (weight, None)
         packed = topk_dispatch(x, token, row_weight, group_sizes, start,
                                n_rows, places)
-        y = grouped_swiglu(packed.rows, packed.group_sizes, wg, wu, wd)
+        ffn = grouped_swiglu if len(ws) == 3 else grouped_relu2
+        y = ffn(packed.rows, packed.group_sizes, *ws)
         return topk_combine(y, packed, t, weight)
 
     def over_the_buffers(group_sizes, body, init):
@@ -727,7 +746,7 @@ def topk_moe_held(x, route: TopKRoute, wg, wu, wd, first_expert: int = 0):
             g, *kept)
 
     run.defvjp(lambda *kept: (run(*kept), kept), run_bwd)
-    return run(order, x, weight, wg, wu, wd).astype(x.dtype)
+    return run(order, x, weight, *ws).astype(x.dtype)
 
 
 def router_bias_update(bias, counts, rate: float):

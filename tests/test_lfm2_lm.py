@@ -570,8 +570,10 @@ def test_sequence_parallel_meshes_refuse_a_conv_layer_by_name(attention):
 @pytest.mark.parametrize("changes, words", [
     ({"layers": (K(mixer="mamba"),) + KINDS[1:]}, "unknown mixer"),
     ({"layers": (K(window=8, mixer=CONV),) + KINDS[1:]}, "has no window"),
-    ({"layers": KINDS[1:] + KINDS[:1]},
-     "dense-FFN layer after an expert layer"),
+    # (a dense layer after the expert layers has run since PR 39; a layer
+    # with neither a mixer nor an FFN has no stack)
+    ({"layers": KINDS[1:] + (K(mixer="none", experts=None),)},
+     "has no stack to live in"),
 ])
 def test_a_pattern_that_cannot_run_is_refused_by_name(changes, words):
     with pytest.raises(ValueError, match=words):

@@ -40,13 +40,18 @@ def sweep():
     # bfloat16 parts keep 16 bits), bfloat16 to its own; form (h), the
     # shipped gather, both ways, and the combine as it runs it (bfloat16
     # rows in, float32 out) beside the float32 ones
-    ("rows", 11 * 6 + 3, 1e-2)])
+    ("rows", 11 * 6 + 3, 1e-2),
+    # the two products of relu2 experts (ISSUE 39), and the program's own
+    # call under two other rules for a dimension no multiple of 128 divides
+    ("products --form relu2 --tile-where-none-divides 0 128", 7 + 2, 1e-2)],
+    ids=["products", "rows", "relu2"])
 def test_every_form_computes_what_the_first_does(sweep, monkeypatch,
                                                  tmp_path, mode, forms,
                                                  band):
     out = tmp_path / "sweep.json"
+    mode, *more = mode.split()
     monkeypatch.setattr(sys, "argv", [TOOL, "--mode", mode, "--rehearse",
-                                      "--out", str(out)])
+                                      "--out", str(out)] + more)
     sweep.main()
     timed = {name: rec for name, rec in json.loads(out.read_text())[
         "ms"].items() if name.split()[0] not in ("gather", "place")}

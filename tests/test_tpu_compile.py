@@ -414,3 +414,70 @@ def test_the_conv_attention_cells_step_and_the_memory_it_states(
     assert stated["parameters"] == 507_820_288 == sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
     assert live / 1e9 == pytest.approx(stated["live_gb"], rel=0.02)
+
+
+def test_the_state_space_cells_step_and_the_memory_it_states(
+        topo, pallas_branch):
+    """``nemotron3-spmd-1chip-ep16share-8k``'s own program, whole (MEMEM*E:
+    three Mamba-2 layers, one attention layer, three routed-expert layers, 2
+    rows of 8,192 tokens, ``remat="block"``) for the v5e: the attention
+    kernel at 32 query over 2 KV heads of 128, forward TWICE (its
+    recomputation is real: ``remat_barrier``) and its fused backward once;
+    the relu2 experts' grouped products through megablox at 1856, a width
+    no multiple of 128 divides, with no ragged-dot fallback; the chunked
+    scan's loop over 64 chunks; and the compiler's ``memory_analysis`` as
+    ``benchmark/configs/nemotron-3-nano-30b-a3b.json`` states it, under the
+    14.4 GB (90% of the chip) a cell may reach."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [p for p in (os.path.join(bench, "readers"), bench)
+                    if p not in sys.path]
+    import files
+    config, cell = "nemotron-3-nano-30b-a3b", \
+        "nemotron3-spmd-1chip-ep16share-8k"
+    model = files.load_module(os.path.join(
+        bench, "configs", config + ".py"), "bench_config_nemotron3")
+    spec = files.load_json(files.config_path(config))
+    traffic = files.load_json(files.traffic_path(files.cell(cell)["traffic"]))
+    cfg = model.transformer_config(spec, traffic, False)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
+                (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
+    opt = model.optimizer()
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    held = NamedSharding(mesh, P())
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=held), tree)
+
+    tok = jax.ShapeDtypeStruct(
+        (traffic["rows_per_chip"], cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS)))
+    compiled = tfm.make_train_step(mesh, cfg, opt).lower(
+        on_mesh(shapes), on_mesh(jax.eval_shape(opt.init, shapes)), tok,
+        tok).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%((?:splash|flash|gmm|tgmm|ragged)[\w\-]*?)"
+                       r"(?:\.\d+)? = ", text)
+    assert calls.count("splash_mqa_fwd_residuals") == 2, calls
+    assert calls.count("splash_mqa_dkv_no_residuals") == 1, calls
+    assert "gmm" in calls and "tgmm" in calls
+    assert not [c for c in calls if "_dq" in c or "flash" in c
+                or "mha" in c or "ragged" in c], calls
+    # the buffer fits the loads: a conditional a pass in each of the three
+    # expert layers' runs, the two grouped products over the tight buffer's
+    # 7,680 rows in one branch and over the wide one's 15,360 in the other
+    assert len(re.findall(r" conditional\(", text)) == 6
+    for rows in (7680, 15360):
+        assert re.search(r"%%gmm[.\d]* = bf16\[%d,1856\]" % rows, text), rows
+    # the scan's state is carried over 64 chunks: a loop under ssm_scan
+    assert re.search(r"while\(.*op_name=\"[^\"]*ssm_scan/while", text)
+    found = compiled.memory_analysis()
+    stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
+    live = found.argument_size_in_bytes + found.temp_size_in_bytes
+    assert live < 14.4e9
+    assert stated["parameters"] == 528_093_120 == sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    assert live / 1e9 == pytest.approx(stated["live_gb"], rel=0.02)

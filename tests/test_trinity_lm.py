@@ -984,7 +984,7 @@ DENSE = K(8, True, False)
 ])
 def test_the_leading_dense_layers_and_the_expert_layers(kinds, n_lead):
     cfg = dataclasses.replace(SMALL, n_layers=len(kinds), layers=kinds)
-    assert tfm._n_lead(cfg) == n_lead
+    assert sum(not kind.experts for kind in kinds) == n_lead
     shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
                                                     cfg))
     assert ("dense_layers" in shapes) == bool(n_lead)
@@ -1016,8 +1016,8 @@ def test_any_order_of_kinds_runs_against_the_reference(reference, model,
 
 
 @pytest.mark.parametrize("changes, words", [
-    ({"layers": (DENSE, A, DENSE), "n_layers": 3},
-     "dense-FFN layer after an expert layer"),
+    ({"layers": (DENSE, A, K(mixer="none", experts=None)), "n_layers": 3},
+     "has no stack to live in"),
     ({"n_loops": 2}, "not with n_loops > 1 or use_moe"),
     ({"moe_top_k": 0}, "an expert layer needs"),
     ({"n_kv_heads": 3}, "must divide"),

@@ -90,7 +90,8 @@ SECTIONS = [
         "SliceAggregator", "TelemetryRoute"]),
     ("Estimator & store", "horovod_tpu", []),
     ("Models", "horovod_tpu.models.transformer", [
-        "TransformerConfig", "init_params", "forward_block", "lean_lm_loss",
+        "TransformerConfig", "LayerKind", "init_params", "forward_block",
+        "mamba_mix", "lean_lm_loss",
         "make_train_step", "make_spmd_loss", "shard_params",
         "forward_exits", "exit_distribution"]),
     ("", "horovod_tpu.models.vit", ["ViT", "ViT_B16", "ViT_S16"]),
@@ -100,6 +101,7 @@ SECTIONS = [
     ("", "horovod_tpu.parallel.ulysses", ["ulysses_attention_p"]),
     ("", "horovod_tpu.parallel.flash_attention", ["flash_attention_local",
                                                    "attention_kernel"]),
+    ("", "horovod_tpu.parallel.ssd", ["ssd_chunked"]),
     ("", "horovod_tpu.parallel.moe", ["moe_layer_p", "MoEParams"]),
     ("", "horovod_tpu.parallel.pipeline", []),
     ("Ops", "horovod_tpu.ops.sync_batch_norm", []),

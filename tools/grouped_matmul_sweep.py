@@ -41,6 +41,17 @@ runs it: bf16 rows in, each times its choice's weight, fp32 out.
         --rows 40960 --tokens 16384 --top-k 4 --router-outputs 32 \
         --live 4096 8192 16384 --forms b h --out chiprun_out/row_sums_h.json
 
+ISSUE 39 (relu2 experts of 1856 = 14.5 x 128 under a hidden size of 2688):
+``--form relu2`` times the TWO products of ``grouped_relu2`` in place of the
+SwiGLU's three, ``--hidden`` is the rows' width, and ``--tile-where-none-
+divides`` adds, for each size given, ``moe.grouped_matmul``'s own calls
+under a ``gmm_tiles`` that cuts a dimension no multiple of 128 divides to
+that tile (0: the whole dimension) where the shipped rule takes 1024.
+
+    chiprun --chips 1 -- python tools/grouped_matmul_sweep.py --form relu2 \
+        --hidden 2688 --width 1856 --rows 8192 --live 6144 \
+        --tile-where-none-divides 0 512 640 --out chiprun_out/gmm_1856.json
+
 ``--rehearse``: tiny sizes, interpreted on the CPU: a test of the script.
 """
 
@@ -74,6 +85,27 @@ def swiglu(matmul):
     return ffn
 
 
+def relu2(matmul):
+    def ffn(rows, sizes, wu, wd):
+        return matmul(jnp.square(jax.nn.relu(matmul(rows, wu, sizes))), wd,
+                      sizes)
+    return ffn
+
+
+def tiles_with(fallback: int):
+    """``moe.gmm_tiles`` but for a dimension that no multiple of 128
+    divides: cut to ``fallback`` (0: left whole)."""
+    from horovod_tpu.parallel import moe
+
+    def rule(m, k, n):
+        def tile(size, most):
+            fits = [t for t in range(most, 127, -128) if size % t == 0]
+            return fits[0] if fits else min(fallback or size, size)
+        return (min(moe.GMM_TILING[0], m), tile(k, moe.GMM_TILING[1]),
+                tile(n, moe.GMM_TILING[2]))
+    return rule
+
+
 def interpreted_megablox():
     """The megablox kernels interpreted, for ``moe.grouped_matmul``'s own
     calls on the CPU too (a rehearsal, a test)."""
@@ -86,17 +118,22 @@ def interpreted_megablox():
         backend.tgmm = functools.partial(backend.tgmm, interpret=True)
 
 
-def candidates(interpret: bool, width: int = 1024) -> dict:
+def candidates(interpret: bool, width: int = 1024, fallbacks=()) -> dict:
+    """name -> (the grouped product, the ``gmm_tiles`` it runs under: None
+    the shipped one)."""
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     from horovod_tpu.parallel import moe
-    found = {"ragged_dot": lambda x, w, s: lax.ragged_dot(x, w, s)}
+    found = {"ragged_dot": (lambda x, w, s: lax.ragged_dot(x, w, s), None)}
     for tiling in TILINGS + (TILINGS_896 if width % 1024 else ()):
         found["gmm:%d/%d/%d" % tiling] = (
             lambda x, w, s, tiling=tiling: megablox.gmm(
-                x, w, s, x.dtype, tiling, interpret=interpret))
+                x, w, s, x.dtype, tiling, interpret=interpret), None)
     if interpret:
         interpreted_megablox()
-    found["gmm_tiles (moe.grouped_matmul's)"] = moe._gmm
+    found["gmm_tiles (moe.grouped_matmul's)"] = (moe._gmm, None)
+    for fallback in fallbacks:
+        found["gmm_tiles, %s where none divides" % (fallback or "whole")] = (
+            moe._gmm, tiles_with(fallback))
     return found
 
 
@@ -116,8 +153,10 @@ def products(args) -> dict:
     if len(args.live or ()) > 1:    # a table a fill of the buffer
         return {"live=%d" % live: products(argparse.Namespace(
             **{**vars(args), "live": [live]})) for live in args.live}
-    rows, d, f = (512, 128, 128) if args.rehearse else (args.rows, 2048,
-                                                        args.width)
+    from horovod_tpu.parallel import moe
+    rows, d, f = (512, 128, 128) if args.rehearse else (
+        args.rows, args.hidden, args.width)
+    gated = args.form == "swiglu"
     rng = np.random.RandomState(0)
     live = args.live[0] if args.live and not args.rehearse else rows * 4 // 5
     sizes = rng.multinomial(live, [1 / args.experts] * args.experts)
@@ -125,16 +164,21 @@ def products(args) -> dict:
     xs = [jax.random.normal(keys[0], (rows, d), jnp.bfloat16),
           jnp.asarray(sizes, jnp.int32)] + [
         jax.random.normal(k, (args.experts,) + s, jnp.bfloat16) * 0.02
-        for k, s in zip(keys[1:], ((d, f), (d, f), (f, d)))]
+        for k, s in zip(keys[1:], ((d, f),) * (1 + gated) + ((f, d),))]
     out = {"device": jax.devices()[0].device_kind, "rows": rows, "width": f,
            "group_sizes": sizes.tolist(), "ms": {}}
     want = None
-    for name, matmul in candidates(args.rehearse, args.width).items():
-        ffn = swiglu(matmul)
+    shipped = moe.gmm_tiles
+    for name, (matmul, rule) in candidates(
+            args.rehearse, args.width,
+            args.tile_where_none_divides or ()).items():
+        ffn = (swiglu if gated else relu2)(matmul)
         fwd = jax.jit(ffn)
         step = jax.jit(jax.grad(
             lambda *a: jnp.sum(ffn(*a).astype(jnp.float32) ** 2),
-            (0, 2, 3, 4)))
+            (0,) + tuple(range(2, len(xs)))))
+        # (traced at the first call below, both passes: under the rule)
+        moe.gmm_tiles = rule or shipped
         try:
             got = np.asarray(fwd(*xs), np.float32)[:sizes.sum()]
             want = got if want is None else want
@@ -145,6 +189,8 @@ def products(args) -> dict:
                                           / np.abs(want).max())}
         except Exception as e:      # a tiling the compiler refuses, kept
             rec = {"failed": str(e).replace("\n", " ")[:300]}
+        finally:
+            moe.gmm_tiles = shipped
         out["ms"][name] = rec
         print(name, rec, flush=True)
     return out
@@ -408,6 +454,12 @@ def main() -> None:
     ap.add_argument("--experts", type=int, default=8)
     ap.add_argument("--width", type=int, default=1024,
                     help="products: the experts' width")
+    ap.add_argument("--hidden", type=int, default=2048,
+                    help="products: the rows' width")
+    ap.add_argument("--form", choices=("swiglu", "relu2"), default="swiglu",
+                    help="products: the SwiGLU's three or relu2's two")
+    ap.add_argument("--tile-where-none-divides", type=int, nargs="*",
+                    help="products: see the module's first words")
     ap.add_argument("--live", type=int, nargs="*",
                     help="rows of the buffer that hold an assignment")
     ap.add_argument("--tokens", type=int, default=8192)
