@@ -38,7 +38,8 @@ letters are layers of ONE sublayer, as ``hybrid_override_pattern`` writes
 them: ``M`` a Mamba-2 state-space mixer alone (``--ssm-heads``,
 ``--ssm-head-dim``, ``--ssm-state``, ``--ssm-groups``, ``--ssm-chunk``,
 ``--conv-kernel``; the chunks of a row go to the gauge
-``hvd_tpu_lm_scan_chunks``), ``*`` attention alone, which rotates nothing, and
+``hvd_tpu_lm_scan_chunks``, the form of the scan to
+``hvd_tpu_lm_scan_kernel``), ``*`` attention alone, which rotates nothing, and
 ``E`` the routed-expert FFN alone
 (``--pattern "MEMEM*E" --expert-ffn relu2 --shared-experts 1 --shared-ff
 ...`` is a model of the kind of
@@ -254,9 +255,19 @@ def main():
         by_mixer = collections.Counter(k.mixer for k in cfg.layers)
         for mixer, n in by_mixer.items():
             registry().gauge("hvd_tpu_lm_layers").set(n, mixer=mixer)
+        scan = None
         if cfg.has_mamba:
             registry().gauge("hvd_tpu_lm_scan_chunks").set(
                 args.seq // cfg.ssm_chunk, chunk=str(cfg.ssm_chunk))
+            # which form of the scan the step runs on this backend: a
+            # property of the shape a chip holds
+            from horovod_tpu.parallel.ssd import scan_form
+            rows = args.batch // mesh.shape["data"]
+            scan = scan_form(
+                (rows, args.seq, cfg.ssm_heads, cfg.ssm_head_dim),
+                (rows, args.seq, cfg.ssm_groups, cfg.ssm_state),
+                cfg.ssm_chunk)
+            registry().gauge("hvd_tpu_lm_scan_kernel").set(1, **scan)
         attn = None
         if cfg.attention == "flash" and mesh.shape["seq"] == 1:
             # what the step's local attention call runs on this backend, and
@@ -353,6 +364,8 @@ def main():
         report["grad_reduce_in_backward_share"] = round(in_backward, 4)
         if attn:
             report["attn_kernel"] = attn
+        if scan:
+            report["scan_kernel"] = scan
         if by_mixer:
             report["layers_by_mixer"] = dict(sorted(by_mixer.items()))
         if stats:

@@ -221,6 +221,19 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "tokens): the steps of the one sequential loop of the "
                  "scan; examples/transformer_lm.py sets it when the pattern "
                  "it built has such a layer"),
+    # parallel/ssd.py scan_form (ISSUE 40)
+    "hvd_tpu_lm_scan_kernel": (
+        "gauge", "1 on the label set that says what a mamba2 layer's scan "
+                 "runs on this backend: form (kernel: the Pallas kernels "
+                 "with their written backward, a chunk's decay and scores "
+                 "and the carried states in VMEM; chunked: jax.numpy with "
+                 "autodiff's backward), the chunk's tokens and "
+                 "heads_per_block (the heads of a group, which one step of "
+                 "the kernels' grid takes together; 0 for chunked). A "
+                 "function of the backend and the shapes a chip holds "
+                 "alone (parallel/ssd.py scan_form); "
+                 "examples/transformer_lm.py sets it where it sets "
+                 "hvd_tpu_lm_scan_chunks"),
     # parallel/flash_attention.py attention_kernel (ISSUE 31; window: 32;
     # head_size: 34)
     "hvd_tpu_attn_kernel": (

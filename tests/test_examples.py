@@ -165,6 +165,31 @@ def test_transformer_lm_example_conv_pattern():
     assert "'head_size': '8'" in r.stdout, r.stdout
 
 
+def test_transformer_lm_example_state_space_pattern():
+    """A state-space/attention sparse-expert decoder of layers of ONE
+    sublayer from the example's flags (the kind of
+    benchmark/configs/nemotron-3-nano-30b-a3b.json): the layers by kind of
+    mixer and the form of the scan (off the TPU the jax.numpy one) are
+    reported with the loss."""
+    r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
+              "--mesh", "data=2", "--d-model", "32", "--n-layers", "7",
+              "--n-heads", "4", "--kv-heads", "2", "--vocab", "128",
+              "--seq", "32", "--batch", "4", "--steps", "2", "--attention",
+              "flash", "--positions", "none", "--ffn", "swiglu",
+              "--norm-eps", "1e-5", "--remat", "block", "--untied-head",
+              "--pattern", "MEMEM*E", "--ssm-heads", "4", "--ssm-head-dim",
+              "8", "--ssm-state", "16", "--ssm-groups", "2", "--ssm-chunk",
+              "16", "--experts", "8", "--top-k", "2", "--expert-ff", "16",
+              "--expert-ffn", "relu2", "--shared-experts", "1",
+              "--shared-ff", "32", "--experts-held", "4",
+              "--router-bias-rate", "0.001"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "'layers_by_mixer': {'attention': 1, 'mamba2': 3, 'none': 3}" \
+        in r.stdout, r.stdout
+    assert ("'scan_kernel': {'form': 'chunked', 'chunk': '16', "
+            "'heads_per_block': '0'}") in r.stdout, r.stdout
+
+
 def test_transformer_lm_example_looped():
     """The looped decoder from the example's flags: RoPE, SwiGLU, sandwich
     norms, an untied head and four passes under remat, the sequence split

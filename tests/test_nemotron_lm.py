@@ -24,6 +24,7 @@ from jax.sharding import Mesh
 
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import moe
+from horovod_tpu.parallel import ssd
 from horovod_tpu.parallel.ssd import ssd_chunked
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -357,6 +358,177 @@ def test_the_scan_is_causal_to_the_bit():
                           np.asarray(later[:, :40]))
 
 
+# -- the scan's kernels, interpreted (the CPU has no Mosaic): the smallest
+# shapes that tile, against the recurrence and against the chunked form ------
+
+# rows, tokens, heads, head, groups, state, chunk
+TILED = {"a-pair-of-64": (1, 256, 2, 64, 1, 128, 128),
+         "two-groups": (2, 384, 4, 64, 2, 128, 128),
+         "a-head-a-tile": (1, 256, 2, 128, 2, 128, 128),
+         "a-chunk-of-256": (1, 512, 2, 64, 1, 128, 256)}
+
+
+def _tiled_inputs(shape, dt_value=None, seed=0):
+    rows, t, h, p, g, n, chunk = TILED[shape]
+    return _scan_inputs(t, dt_value, seed, rows, h, p, g, n), chunk
+
+
+def _interpreted(chunk):
+    return jax.jit(lambda *a: ssd.ssd_kernels(*a, chunk, interpret=True))
+
+
+@pytest.mark.parametrize("shape", sorted(TILED))
+@pytest.mark.parametrize("dt_value", [None, 1e-3, 1e-1],
+                         ids=["drawn", "smallest", "largest"])
+def test_the_scans_kernels_against_the_recurrence(reference, shape, dt_value):
+    args, chunk = _tiled_inputs(shape, dt_value)
+    with jax.default_matmul_precision("highest"):
+        got = _interpreted(chunk)(*args)
+        want = jax.jit(lambda *a: _recurrence(reference, *a))(*args)
+        spec = jax.jit(lambda *a: ssd._ssd_numpy(*a, chunk))(*args)
+    assert got.dtype == jnp.float32 and got.shape == args[0].shape
+    _close(got, want, 1e-5)
+    # the same sums in the same order as the chunked form's, but for the
+    # order inside a product
+    _close(got, spec, 2e-6)
+
+
+@pytest.mark.parametrize("shape", ["two-groups", "a-head-a-tile"])
+def test_the_scans_kernels_gradients_against_the_recurrences(reference,
+                                                             shape):
+    """The written backward, all six arguments."""
+    args, chunk = _tiled_inputs(shape, seed=2)
+    g = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(ssd.ssd_kernels(
+            *a, chunk, interpret=True) * g), range(6)))(*args)
+        want = jax.jit(jax.grad(
+            lambda *a: jnp.sum(_recurrence(reference, *a) * g),
+            range(6)))(*args)
+    for got_, want_ in zip(got, want):
+        assert got_.shape == want_.shape and got_.dtype == want_.dtype
+        _close(got_, want_, 1e-4)
+
+
+def test_the_scans_kernels_under_checkpoint_keep_no_states_the_first_time():
+    """``jax.checkpoint`` around the scan: the forward pass runs the kernel
+    that writes ``y`` alone, the recomputation the one that keeps the
+    chunks' incoming states, and the gradients are the same."""
+    args, chunk = _tiled_inputs("a-pair-of-64", seed=3)
+
+    def loss(*a):
+        return jnp.sum(jnp.square(ssd.ssd_kernels(*a, chunk,
+                                                  interpret=True)))
+
+    def kernels(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"])
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                found += kernels(inner)
+        return found
+
+    again = jax.grad(jax.checkpoint(loss), range(6))
+    assert kernels(jax.make_jaxpr(again)(*args).jaxpr) == [
+        "ssd_scan_fwd", "ssd_scan_fwd_states", "ssd_scan_bwd"]
+    assert kernels(jax.make_jaxpr(jax.grad(loss, range(6)))(
+        *args).jaxpr) == ["ssd_scan_fwd_states", "ssd_scan_bwd"]
+    assert kernels(jax.make_jaxpr(loss)(*args).jaxpr) == ["ssd_scan_fwd"]
+    again = jax.jit(again)
+    for got_, want_ in zip(again(*args), jax.jit(jax.grad(loss, range(6)))(
+            *args)):
+        assert np.array_equal(np.asarray(got_), np.asarray(want_))
+
+
+@pytest.mark.parametrize("shape", ["a-pair-of-64", "two-groups"])
+def test_the_scans_kernels_in_bfloat16_are_no_further_off_than_the_chunked_form(
+        reference, shape):
+    """bfloat16 X, B and C: against the float32 recurrence ON THE SAME
+    operands the kernels read what the chunked form reads (they round where
+    it rounds), the result and every gradient."""
+    (x, dt, a, b, c, d), chunk = _tiled_inputs(shape, seed=4)
+    args = (x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+            c.astype(jnp.bfloat16), d)
+    exact = tuple(v.astype(jnp.float32) for v in args)
+    g = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def both(form):
+        return jax.jit(lambda *a: (form(*a), *jax.grad(
+            lambda *a_: jnp.sum(form(*a_) * g), range(6))(*a)))
+
+    def off(got, want):
+        return float(jnp.sqrt(jnp.sum(jnp.square(
+            got.astype(jnp.float32) - want)) / jnp.sum(jnp.square(want))))
+
+    with jax.default_matmul_precision("highest"):
+        want = both(lambda *a: _recurrence(reference, *a))(*exact)
+    kernels = both(lambda *a: ssd.ssd_kernels(*a, chunk, interpret=True))(
+        *args)
+    chunked = both(lambda *a: ssd._ssd_numpy(*a, chunk))(*args)
+    assert kernels[0].dtype == jnp.float32
+    assert off(kernels[0], want[0]) <= 1.03 * off(chunked[0], want[0])
+    assert off(kernels[0], want[0]) < 2.5e-3    # the cell's own limit
+    for got_, spec_, want_ in zip(kernels[1:], chunked[1:], want[1:]):
+        assert got_.dtype == spec_.dtype
+        assert off(got_, want_) <= 1.25 * off(spec_, want_) + 1e-5
+
+
+def test_the_scans_kernels_are_causal_to_the_bit():
+    (x, dt, a, b, c, d), chunk = _tiled_inputs("two-groups")
+    run = _interpreted(chunk)
+    whole = run(x, dt, a, b, c, d)
+    later = run(x.at[:, 200:].set(7.0), dt, a, b.at[:, 200:].set(-3.0),
+                c.at[:, 200:].set(5.0), d)
+    assert np.array_equal(np.asarray(whole[:, :200]),
+                          np.asarray(later[:, :200]))
+
+
+# the cell's own shapes: 2 rows of 8,192, 64 heads of 64, 8 groups of 128
+CELL = ((2, 8192, 64, 64), (2, 8192, 8, 128), 128)
+
+
+@pytest.mark.parametrize("backend, x, bc, chunk, form, heads", [
+    ("cpu", *CELL, "chunked", 0),
+    ("tpu", *CELL, "kernel", 8),
+    ("tpu", (1, 8192, 64, 64), (1, 8192, 8, 128), 128, "kernel", 8),
+    ("tpu", (2, 128, 8, 8), (2, 128, 2, 16), 16, "chunked", 0),
+    ("tpu", (2, 8192, 64, 64), (2, 8192, 8, 128), 64, "chunked", 0),
+    ("tpu", (2, 8064, 64, 64), (2, 8064, 8, 128), 96, "chunked", 0),
+    ("tpu", (2, 8192, 64, 64), (2, 8192, 8, 64), 128, "chunked", 0),
+    ("tpu", (2, 8192, 64, 64), (2, 8192, 64, 128), 128, "chunked", 0),
+    ("tpu", (2, 8192, 64, 96), (2, 8192, 16, 128), 128, "chunked", 0),
+    ("tpu", (2, 8192, 16, 128), (2, 8192, 8, 128), 256, "kernel", 2),
+    ("tpu", (1, 2048, 16, 64), (1, 2048, 1, 128), 512, "chunked", 0),
+    ("gpu", *CELL, "chunked", 0),
+], ids=["the-cpu", "the-cell", "the-cells-checks-one-row",
+        "the-rehearsal", "a-chunk-of-64", "a-chunk-of-96", "a-state-of-64",
+        "a-head-a-group", "a-head-of-96", "heads-of-128",
+        "blocks-past-the-vmem", "a-gpu"])
+def test_the_scans_form_is_a_function_of_the_backend_and_the_shapes(
+        monkeypatch, backend, x, bc, chunk, form, heads):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ssd.scan_form(x, bc, chunk) == {
+        "form": form, "chunk": str(chunk), "heads_per_block": str(heads)}
+
+
+def test_the_scan_runs_the_form_its_shapes_give(monkeypatch):
+    """``ssd_chunked`` asks ``scan_form`` and nothing else: here, on the
+    CPU, the chunked form at shapes that would tile; told "kernel", the
+    kernels (which the CPU cannot lower uninterpreted: the call is seen,
+    not run)."""
+    (x, dt, a, b, c, d), chunk = _tiled_inputs("a-pair-of-64")
+    text = jax.jit(lambda *a_: ssd_chunked(*a_, chunk)).lower(
+        x, dt, a, b, c, d).as_text(debug_info=True)
+    assert "ssm_scan/while" in text and "pallas_call" not in text
+    seen = []
+    monkeypatch.setattr(ssd, "scan_form", lambda *shapes: {"form": "kernel"})
+    monkeypatch.setattr(ssd, "ssd_kernels", lambda *a_: seen.append(a_[-1])
+                        or a_[0].astype(jnp.float32))
+    ssd_chunked(x, dt, a, b, c, d, chunk)
+    assert seen == [chunk]
+
+
 def test_the_mixer_against_the_reference(reference):
     one = dataclasses.replace(SMALL, layers=(M,), n_layers=1)
     lp = {k: v[0] for k, v in _params(one)["mamba_mixers"].items()}
@@ -665,7 +837,8 @@ def test_the_examples_gauges_are_declared():
                            "transformer_lm.py")
     with open(example) as fh:
         text = fh.read()
-    for gauge in ("hvd_tpu_lm_layers", "hvd_tpu_lm_scan_chunks"):
+    for gauge in ("hvd_tpu_lm_layers", "hvd_tpu_lm_scan_chunks",
+                  "hvd_tpu_lm_scan_kernel"):
         assert METRIC_SPECS[gauge][0] == "gauge"
         assert '"%s"' % gauge in text
     assert "mamba2" in METRIC_SPECS["hvd_tpu_lm_layers"][1]
