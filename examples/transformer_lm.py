@@ -43,8 +43,15 @@ them: ``M`` a Mamba-2 state-space mixer alone (``--ssm-heads``,
 ``E`` the routed-expert FFN alone
 (``--pattern "MEMEM*E" --expert-ffn relu2 --shared-experts 1 --shared-ff
 ...`` is a model of the kind of
-benchmark/configs/nemotron-3-nano-30b-a3b.json); of the other letters the
-first
+benchmark/configs/nemotron-3-nano-30b-a3b.json); ``l`` is a layer whose
+mixer is latent attention (``--q-lora-rank``, ``--kv-lora-rank``,
+``--qk-nope-dim``, ``--qk-rope-dim``, ``--v-head-dim``), and ``--mtp-depth 1
+--mtp-weight 0.1`` adds the multi-token-prediction module after the pattern,
+whose term of the last step goes to the gauge ``hvd_tpu_lm_mtp_loss`` and
+whose weight to ``hvd_tpu_lm_mtp_weight`` (``--pattern l --dense-layers 1
+--untied-head --shared-experts 1 --route-scale 2.5 --mtp-depth 1`` is a model
+of the kind of benchmark/configs/joyai-llm-flash.json); of the other letters
+the first
 ``--dense-layers`` keep
 the dense FFN, the others route ``--top-k`` of ``--experts`` sigmoid-scored
 SwiGLU experts of ``--expert-ff`` beside ``--shared-experts``, of which
@@ -123,7 +130,8 @@ def main():
                     help="one letter a layer of a period: s (window), f "
                          "(full attention, no rotation), a (full attention, "
                          "rotated), c (the gated short convolution), e.g. "
-                         "sssf, caccc; layers of one sublayer: M (Mamba-2 "
+                         "sssf, caccc, l (latent attention); layers of one "
+                         "sublayer: M (Mamba-2 "
                          "alone), * (attention alone, no rotation), E (the "
                          "routed experts alone), e.g. 'MEMEM*E'")
     ap.add_argument("--window", type=int, default=0)
@@ -143,6 +151,15 @@ def main():
     ap.add_argument("--ssm-groups", type=int, default=1)
     ap.add_argument("--ssm-chunk", type=int, default=128)
     ap.add_argument("--conv-kernel", type=int, default=3)
+    ap.add_argument("--q-lora-rank", type=int, default=0)
+    ap.add_argument("--kv-lora-rank", type=int, default=0)
+    ap.add_argument("--qk-nope-dim", type=int, default=0)
+    ap.add_argument("--qk-rope-dim", type=int, default=0)
+    ap.add_argument("--v-head-dim", type=int, default=0)
+    ap.add_argument("--mtp-depth", type=int, default=0,
+                    help="1: the multi-token-prediction module after the "
+                         "pattern, one more block and a second loss")
+    ap.add_argument("--mtp-weight", type=float, default=0.1)
     ap.add_argument("--route-scale", type=float, default=1.0)
     ap.add_argument("--experts-held", type=int, default=0)
     ap.add_argument("--first-expert", type=int, default=0)
@@ -169,7 +186,7 @@ def main():
     pattern = {}
     if args.pattern:
         kinds = {"s": dict(window=args.window), "f": dict(rope=False),
-                 "a": {}, "c": dict(mixer="conv")}
+                 "a": {}, "c": dict(mixer="conv"), "l": dict(mixer="mla")}
         alone = {"M": dict(mixer="mamba2", experts=None),
                  "*": dict(rope=False, experts=None),
                  "E": dict(mixer="none", experts=True)}
@@ -185,6 +202,10 @@ def main():
             conv_kernel=args.conv_kernel, ssm_heads=args.ssm_heads,
             ssm_head_dim=args.ssm_head_dim, ssm_state=args.ssm_state,
             ssm_groups=args.ssm_groups, ssm_chunk=args.ssm_chunk,
+            q_lora_rank=args.q_lora_rank, kv_lora_rank=args.kv_lora_rank,
+            qk_nope_dim=args.qk_nope_dim, qk_rope_dim=args.qk_rope_dim,
+            v_head_dim=args.v_head_dim, mtp_depth=args.mtp_depth,
+            mtp_weight=args.mtp_weight,
             n_shared_experts=args.shared_experts,
             route_scale=args.route_scale, experts_held=args.experts_held,
             first_expert=args.first_expert,
@@ -287,6 +308,15 @@ def main():
                                         under_remat=cfg.remat != "none",
                                         window=window)
                 registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
+            if cfg.has_mla:     # q/k heads of one size, v heads of another
+                latent = (rows, cfg.n_heads, args.seq,
+                          cfg.qk_nope_dim + cfg.qk_rope_dim)
+                attn = attention_kernel(latent, latent, causal=True,
+                                        under_remat=cfg.remat != "none",
+                                        v_head_size=cfg.v_head_dim)
+                registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
+        if cfg.mtp_depth:
+            registry().gauge("hvd_tpu_lm_mtp_weight").set(cfg.mtp_weight)
         opt_state = opt.init(params)
         tok_sh = NamedSharding(mesh, P("data", "seq"))
         if args.sp_layout == "zigzag":
@@ -309,7 +339,11 @@ def main():
                                                    inputs, targets)
         loss = float(loss)
         dt = (time.perf_counter() - t0) / args.steps
-        if stats:
+        if stats and "mtp_loss" in stats[0]:
+            # logged with the loss: the second head's term of it
+            registry().gauge("hvd_tpu_lm_mtp_loss").set(
+                float(stats[0]["mtp_loss"]))
+        if stats and "expert_counts" in stats[0]:
             # logged with the loss: where the router sent the last step
             from horovod_tpu.models.transformer import routing_stats
             routing = routing_stats(stats[0]["expert_counts"], cfg,
@@ -368,7 +402,9 @@ def main():
             report["scan_kernel"] = scan
         if by_mixer:
             report["layers_by_mixer"] = dict(sorted(by_mixer.items()))
-        if stats:
+        if stats and "mtp_loss" in stats[0]:
+            report["mtp_loss"] = round(float(stats[0]["mtp_loss"]), 4)
+        if stats and "expert_counts" in stats[0]:
             report["routing"] = {
                 k: v if isinstance(v, str) else np.round(v, 4).tolist()
                 for k, v in routing.items()}

@@ -64,6 +64,24 @@ MAMBA_MIXER = "mamba_mixer"     # where such a layer has attn: rmsnorm, the
 SSM_CONV = "ssm_conv"           # inside it: the taps, their bias, the SiLU
 SSM_SCAN = "ssm_scan"           # ... everything of ssd_chunked
 SSM_GATE_NORM = "ssm_gate_norm"  # ... the gate, then the norm over groups
+# a layer whose mixer is latent attention (LayerKind.mixer "mla";
+# models/transformer.py _mla_mix)
+MLA_MIXER = "mla_mixer"         # where such a layer has attn: rmsnorm, the
+#                                 low-rank projections, the kernel, the
+#                                 output projection, the residual
+MLA_Q = "mla_q"                 # inside it: wq_a, its norm, wq_b, q put
+#                                 together again after the rotation
+MLA_KV = "mla_kv"               # ... wkv_a, its norm, wkv_b, the one
+#                                 rotated key broadcast over the heads and
+#                                 joined to each head's own part
+ATTN_LATENT = "attn_latent"     # ... the attention call (q/k heads of
+#                                 qk_nope_dim + qk_rope_dim, v heads of
+#                                 v_head_dim); 'rope' stands beside these
+# the multi-token-prediction module (cfg.mtp_depth; _mtp_module): its
+# block's scopes, 'head' and 'loss' keep their names inside it
+MTP = "mtp"                     # the whole module
+MTP_PROJ = "mtp_proj"           # inside it: the next token's embedding, the
+#                                 two norms, the product with proj
 # inside ffn, the routed-expert layer (parallel/moe.py topk_*)
 ROUTER = "router"               # fp32 scores, top-k, weights, counts
 MOE_DISPATCH = "moe_dispatch"   # the sort by expert and the gather

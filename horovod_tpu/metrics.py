@@ -210,9 +210,24 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
     "hvd_tpu_lm_layers": (
         "gauge", "Layers of the model's per-layer pattern by the kind of "
                  "their mixer (label mixer: attention, conv: the gated "
-                 "short convolution, mamba2: the state-space mixer, none: "
+                 "short convolution, mamba2: the state-space mixer, mla: "
+                 "latent attention, none: "
                  "a layer of its FFN alone); examples/transformer_lm.py "
                  "sets it when it has built its step from --pattern"),
+    # models/transformer.py _mtp_module (ISSUE 41)
+    "hvd_tpu_lm_mtp_loss": (
+        "gauge", "The multi-token-prediction module's term CE_mtp of the "
+                 "last logged step's loss CE + mtp_weight x CE_mtp: the "
+                 "mean cross-entropy of the module's head, position i "
+                 "against the token after the next, over the T - 1 "
+                 "positions a row that score one (the step's fourth "
+                 "value's mtp_loss; examples/transformer_lm.py sets it "
+                 "under --mtp-depth 1)"),
+    "hvd_tpu_lm_mtp_weight": (
+        "gauge", "TransformerConfig.mtp_weight, what the step's objective "
+                 "multiplies the multi-token-prediction module's term by; "
+                 "examples/transformer_lm.py sets it when it has built a "
+                 "step with --mtp-depth 1"),
     # parallel/ssd.py ssd_chunked (ISSUE 39)
     "hvd_tpu_lm_scan_chunks": (
         "gauge", "Chunks of one row that a mamba2 layer's chunked scan "
@@ -235,14 +250,15 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "examples/transformer_lm.py sets it where it sets "
                  "hvd_tpu_lm_scan_chunks"),
     # parallel/flash_attention.py attention_kernel (ISSUE 31; window: 32;
-    # head_size: 34)
+    # head_size: 34; v_head_size: 41)
     "hvd_tpu_attn_kernel": (
         "gauge", "1 on the label set that says what the model's local "
                  "attention call runs on this backend: kernel (splash, "
                  "flash: the two stock Pallas kernels; materialized), the "
                  "forward's block_q and block_kv, fused_bwd (1: dq "
-                 "comes out of the dkv kernel), window (0: none) and "
-                 "head_size. A "
+                 "comes out of the dkv kernel), window (0: none), "
+                 "head_size (q's and k's) and v_head_size (latent "
+                 "attention: 192 beside 128). A "
                  "function of the call's shape, causal, under_remat and "
                  "window alone; examples/transformer_lm.py sets it when it "
                  "has built its step, once for each kind of layer of a "

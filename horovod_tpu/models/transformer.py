@@ -65,8 +65,12 @@ class LayerKind:
     it was), "conv", the gated short convolution of :func:`_conv_mix`
     (no window, no rotation, no attention leaves: ``conv_in``, ``conv_w``,
     ``conv_out`` in their place), "mamba2", the state-space mixer of
-    :func:`mamba_mix` (the ``ssm_*`` leaves), or "none": the layer is its
-    FFN ALONE. A layer of one sublayer is ``h + F(N(h))``: one norm leaf
+    :func:`mamba_mix` (the ``ssm_*`` leaves), "mla", latent attention
+    (:func:`_mla_mix`: low-rank q and KV projections with their norms, q/k
+    heads of ``qk_nope_dim + qk_rope_dim`` of which the last ``qk_rope_dim``
+    rotate, ONE rotated key for all heads, v heads of ``v_head_dim``; no
+    window), or "none": the layer is its FFN ALONE. A layer of one sublayer
+    is ``h + F(N(h))``: one norm leaf
     (``ln1`` of a mixer, ``ln2`` of an FFN) and one residual."""
     window: int = 0
     rope: bool = True
@@ -211,6 +215,31 @@ class TransformerConfig:
     router_bias_rate: float = 0.0
     # what ``route_norm`` adds to the sum of the chosen scores
     route_eps: float = 1e-20
+    # ``LayerKind.mixer == "mla"`` (:func:`_mla_mix`), all five or none:
+    # ``c_q = N(x wq_a)`` of ``q_lora_rank``, q ``n_heads`` heads of
+    # ``qk_nope_dim + qk_rope_dim`` from it; ``x wkv_a`` is ``kv_lora_rank
+    # + qk_rope_dim`` wide: the normed latent, from which every head's
+    # ``qk_nope_dim`` of k and ``v_head_dim`` of v, and the ONE key part
+    # all heads share, rotated (as q's last ``qk_rope_dim``) by
+    # ``rope_theta`` over ``qk_rope_dim``; scores scaled by ``(qk_nope_dim
+    # + qk_rope_dim) ** -0.5``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # The multi-token-prediction module (arXiv:2412.19437 section 2.2;
+    # :func:`_mtp_module`), after a per-layer pattern: 0 none, 1 ONE module
+    # (more are refused until a model brings them). With ``h_i`` the
+    # stack's output at position i BEFORE the final norm and ``e_{i+1}``
+    # the SHARED embedding of the next token, ``u_i = [N_e(e_{i+1}) ;
+    # N_h(h_i)] proj``, one more block of the kind of the pattern's last
+    # layer over ``u``, a final norm of its own, the SHARED head; position
+    # i is scored against the token after the next, the row's last position
+    # left out. The step's objective is ``CE + mtp_weight * CE_mtp``. Its
+    # leaves live under ``params["mtp"]``, each with a leading [mtp_depth].
+    mtp_depth: int = 0
+    mtp_weight: float = 0.1
 
     def __post_init__(self):
         for name, known in (("positions", ("none", "rope")),
@@ -248,12 +277,39 @@ class TransformerConfig:
                         f"a layer whose mixer is {kind.mixer!r} has no "
                         f"window: only attention masks by distance")
                 _stack_of(kind)
+            if self.has_mla:
+                if not (self.q_lora_rank and self.kv_lora_rank
+                        and self.qk_nope_dim and self.qk_rope_dim
+                        and self.v_head_dim) or self.qk_rope_dim % 2:
+                    raise ValueError(
+                        "an mla layer needs q_lora_rank, kv_lora_rank, "
+                        "qk_nope_dim, an even qk_rope_dim and v_head_dim, "
+                        "all five")
+                if self.positions == "rope" and any(
+                        kind.mixer == "attention" and kind.rope
+                        for kind in self.layers):
+                    raise ValueError(
+                        "an mla layer rotates qk_rope_dim of the head and "
+                        "an attention layer the whole head: one pattern "
+                        "has one rotation table (_rope_tables)")
             if self.has_mamba and not (
                     self.ssm_heads and self.ssm_head_dim and self.ssm_state
                     and self.ssm_heads % self.ssm_groups == 0):
                 raise ValueError(
                     "a mamba2 layer needs ssm_heads, ssm_head_dim, "
                     "ssm_state, and ssm_groups that divide the heads")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(
+                f"mtp_depth {self.mtp_depth}: one multi-token-prediction "
+                f"module (1) or none (0); a chain of them is refused until "
+                f"a model brings it")
+        if self.mtp_depth and not (
+                self.layers and self.layers[-1].mixer != "none"
+                and self.layers[-1].experts is not None):
+            raise ValueError(
+                "the multi-token-prediction module (mtp_depth) follows a "
+                "per-layer pattern (layers) and runs one more block of the "
+                "kind of its last layer: a mixer with its FFN")
         if self.has_experts and not (
                 0 < self.moe_top_k <= self.n_experts and self.d_ff_expert
                 and self.first_expert + self.held <= self.n_experts):
@@ -281,6 +337,10 @@ class TransformerConfig:
         return any(kind.mixer == "mamba2" for kind in self.layers)
 
     @property
+    def has_mla(self) -> bool:
+        return any(kind.mixer == "mla" for kind in self.layers)
+
+    @property
     def shared_width(self) -> int:
         """The hidden width of the one expert the shared experts make."""
         return self.d_ff_shared or self.n_shared_experts * self.d_ff_expert
@@ -298,7 +358,7 @@ class ExpertRoutes(NamedTuple):
     counts: jax.Array   # [..., n_experts] int32: assignments to each expert
 
 
-_MIXERS = ("attention", "conv", "mamba2", "none")
+_MIXERS = ("attention", "conv", "mamba2", "mla", "none")
 
 # The stack a kind of layer's leaves live in, by (mixer, FFN: False the
 # dense one, True the routed experts, None none): every layer of the kind,
@@ -312,9 +372,14 @@ _MIXERS = ("attention", "conv", "mamba2", "none")
 # alone) has no stack and is refused by name.
 _STACKS = {("attention", False): "dense_layers", ("attention", True): "layers",
            ("conv", False): "conv_dense_layers", ("conv", True): "conv_layers",
+           ("mla", False): "mla_dense_layers", ("mla", True): "mla_layers",
            # layers of ONE sublayer
            ("attention", None): "attn_mixers",
            ("mamba2", None): "mamba_mixers", ("none", True): "expert_ffns"}
+
+# Where the multi-token-prediction module's leaves live in the parameters
+# (``cfg.mtp_depth``): a stack to :func:`router_bias_step`, in no pattern.
+MTP = "mtp"
 
 # What a mamba2 mixer's ``ssm_dt_bias`` starts as: the inverse softplus of
 # a log-uniform step in ``[min, max]``, floored (Mamba-2's ``time_step_min``,
@@ -329,10 +394,10 @@ def _stack_of(kind: LayerKind) -> str:
         raise ValueError(
             f"layers: a layer with mixer {kind.mixer!r} and experts="
             f"{kind.experts!r} has no stack to live in: a layer is an "
-            f"attention or conv mixer with its FFN (experts False: dense, "
-            f"True: routed), or ONE sublayer: an attention or mamba2 mixer "
-            f"alone (experts None) or the routed experts alone (mixer "
-            f"'none', experts True)")
+            f"attention, conv or mla mixer with its FFN (experts False: "
+            f"dense, True: routed), or ONE sublayer: an attention or mamba2 "
+            f"mixer alone (experts None) or the routed experts alone "
+            f"(mixer 'none', experts True)")
     return found
 
 
@@ -370,6 +435,9 @@ def _expert_rows(cfg: TransformerConfig) -> dict:
         if kind.experts:
             rows.setdefault(_stack_of(kind), []).append(at)
             at += 1
+    if cfg.mtp_depth and cfg.layers[-1].experts:
+        # the module's block lies in no stack; its row is the last
+        rows[MTP] = [at]
     return rows
 
 
@@ -465,6 +533,18 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
             "ssm_norm": jnp.ones((n, inner), jnp.float32),
             "ssm_out": _norm_init(ks[2], (n, inner, D), inner)}
 
+    def mla(k, n):
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        ks = jax.random.split(jax.random.fold_in(k, 9), 5)
+        return {"wq_a": _norm_init(ks[0], (n, D, rq), D),
+                "q_a_norm": jnp.ones((n, rq), jnp.float32),
+                "wq_b": _norm_init(ks[1], (n, rq, H, dn + dr), rq),
+                "wkv_a": _norm_init(ks[2], (n, D, rkv + dr), D),
+                "kv_a_norm": jnp.ones((n, rkv), jnp.float32),
+                "wkv_b": _norm_init(ks[3], (n, rkv, H, dn + dv), rkv),
+                "wo": _norm_init(ks[4], (n, H, dv, D), H * dv)}
+
     def swiglu(k, lead, f, prefix=""):
         ks = jax.random.split(k, 3)
         return {prefix + "wg": _norm_init(ks[0], lead + (D, f), D),
@@ -500,25 +580,39 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
     keys = {"dense_layers": k_dense, "layers": k_moe,
             "conv_dense_layers": jax.random.fold_in(k_dense, 2),
             "conv_layers": jax.random.fold_in(k_moe, 2),
+            "mla_dense_layers": jax.random.fold_in(k_dense, 10),
+            "mla_layers": jax.random.fold_in(k_moe, 10),
             "attn_mixers": jax.random.fold_in(k_dense, 14),
             "mamba_mixers": jax.random.fold_in(k_dense, 16),
             "expert_ffns": jax.random.fold_in(k_moe, 18)}
     mixers = {"attention": attention, "conv": conv, "mamba2": mamba,
-              "none": lambda k, n: {}}
-    n_of = collections.Counter(_stack_of(kind) for kind in cfg.layers)
-    out = {}
-    for (mixer, experts), stack in _STACKS.items():
-        n, k = n_of[stack], keys[stack]
-        if not n:
-            continue
+              "mla": mla, "none": lambda k, n: {}}
+
+    def leaves_of(mixer, experts, n, k):    # n layers of one kind, stacked
         if experts is False and cfg.ffn != "swiglu":
             raise ValueError("a per-layer pattern's dense layers are SwiGLU")
-        out[stack] = {
+        return {
             **norms(n, ["ln1"] * (mixer != "none")
                     + ["ln2"] * (experts is not None)),
             **mixers[mixer](k, n),
             **({} if experts is None else routed(k, n) if experts else
                swiglu(jax.random.fold_in(k, 1), (n,), cfg.d_ff))}
+
+    n_of = collections.Counter(_stack_of(kind) for kind in cfg.layers)
+    out = {stack: leaves_of(mixer, experts, n_of[stack], keys[stack])
+           for (mixer, experts), stack in _STACKS.items() if n_of[stack]}
+    if cfg.mtp_depth:
+        # the module: the block (of the kind of the pattern's last layer),
+        # the two norms of what it joins, the product that joins them, and
+        # its own final norm; the embedding and the head are the model's
+        n, last, k = cfg.mtp_depth, cfg.layers[-1], jax.random.fold_in(
+            k_moe, 20)
+        out[MTP] = {**leaves_of(last.mixer, last.experts, n, k),
+                    "enorm": jnp.ones((n, D), jnp.float32),
+                    "hnorm": jnp.ones((n, D), jnp.float32),
+                    "proj": _norm_init(jax.random.fold_in(k, 21),
+                                       (n, 2 * D, D), 2 * D),
+                    "ln_f": jnp.ones((n, D), jnp.float32)}
     return out
 
 
@@ -617,6 +711,11 @@ def _layer_specs(cfg: TransformerConfig, experts: Optional[bool],
         layers.update({"conv_in": P(None, None, None, TENSOR_AXIS),
                        "conv_w": P(None, None, TENSOR_AXIS),
                        "conv_out": P(None, TENSOR_AXIS)})
+    elif mixer == "mla":
+        # whole on every shard: :func:`_mla_mix` refuses tensor > 1
+        layers.update({leaf: P() for leaf in (
+            "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b",
+            "wo")})
     elif mixer == "attention":
         layers.update({
             "wq": P(None, None, TENSOR_AXIS),
@@ -670,6 +769,10 @@ def param_specs(cfg: TransformerConfig):
                                                   kind.mixer)
     else:
         specs["layers"] = _layer_specs(cfg, experts=False)
+    if cfg.mtp_depth:
+        last = cfg.layers[-1]
+        specs[MTP] = {**_layer_specs(cfg, last.experts, last.mixer),
+                      "enorm": P(), "hnorm": P(), "proj": P(), "ln_f": P()}
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
     if cfg.n_loops > 1:
@@ -685,7 +788,9 @@ def _rmsnorm(x, scale, eps: float = 1e-6):
 
 def _rope_tables(cfg: TransformerConfig, t_local: int,
                  seq_size: Optional[int]):
-    """``(cos, sin)`` [T_local, head_dim / 2] in fp32 at the GLOBAL
+    """``(cos, sin)`` [T_local, head_dim / 2] (under latent attention
+    ``qk_rope_dim / 2``: the part of the head that rotates) in fp32 at the
+    GLOBAL
     positions of the tokens this shard holds: block ``r`` of the sequence
     under the contiguous layout, stripes ``(r, 2n-1-r)`` under zigzag
     (``parallel.ring_attention.zigzag_indices``), so ring and Ulysses
@@ -694,7 +799,7 @@ def _rope_tables(cfg: TransformerConfig, t_local: int,
     if cfg.positions != "rope":
         return None
     with jax.named_scope(scopes.ROPE):
-        half = cfg.head_dim // 2
+        half = (cfg.qk_rope_dim if cfg.has_mla else cfg.head_dim) // 2
         inv_freq = cfg.rope_theta ** (
             -jnp.arange(half, dtype=jnp.float32) / half)
         pos = jnp.arange(t_local)
@@ -814,6 +919,74 @@ def _attn_mix(x, lp, *, cfg: TransformerConfig, rope=None,
     if tensor_size is not None:
         out = lax.psum(out, TENSOR_AXIS)
     return out
+
+
+def _mla_mix(x, lp, *, cfg: TransformerConfig, rope=None,
+             seq_size: Optional[int] = None,
+             tensor_size: Optional[int] = None, causal: bool = True,
+             under_remat: bool = False, scope: Optional[str] = None):
+    """Latent attention over the normed ``x [B, T, D]`` with one layer's
+    seven leaves ``lp``. With ``H = cfg.n_heads``, ``dn, dr, dv =
+    cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim``: ``c_q = N(x wq_a)``
+    (``cfg.q_lora_rank`` wide, the learned scale ``q_a_norm``); ``q = c_q
+    wq_b`` as H heads of ``[q_nope dn | q_rope dr]``; ``x wkv_a`` is
+    ``cfg.kv_lora_rank + dr`` wide: ``c_kv = N(its first kv_lora_rank)``
+    (``kv_a_norm``) and ``k_rope``, its last ``dr``, ONE key part that all H
+    heads share; ``c_kv wkv_b`` as H heads of ``[k_nope dn | v dv]``;
+    ``q_rope`` and ``k_rope`` rotated by ``rope`` (:func:`_rope_tables`
+    over ``dr``; :func:`_rope`'s rotate-half form: a model published with
+    interleaved pairs takes the fixed permutation of those ``dr`` columns of
+    ``wq_b`` and ``wkv_a`` with it); ``k_h = [k_nope_h | k_rope]``; causal
+    softmax of ``q_h . k_h (dn + dr) ** -0.5``; ``o_h = P v_h``; the output
+    ``concat(o_h) wo``. No bias. The products in ``cfg.dtype`` with fp32
+    accumulation, the two norms and the rotation in fp32; the shared key is
+    broadcast over the heads here, and nothing of ``[T, T]`` exists outside
+    the attention call (:func:`~horovod_tpu.parallel.flash_attention.
+    flash_attention_local` with q/k heads of ``dn + dr`` beside v heads of
+    ``dv``, under ``scope``). ``under_remat``: :func:`_attn_mix`'s.
+
+    Under ``seq > 1`` and ``tensor > 1`` it refuses: ring and Ulysses
+    attention take one head size, and the low-rank leaves are not split
+    over the axis."""
+    if seq_size is not None and seq_size > 1:
+        raise ValueError(
+            "the mla mixer (latent attention) under sequence parallelism "
+            "(seq > 1): ring and Ulysses attention take q, k and v heads of "
+            "one size and know no key shared by the heads")
+    if tensor_size is not None and tensor_size > 1:
+        raise ValueError(
+            "the mla mixer (latent attention) under tensor parallelism "
+            "(tensor > 1): its low-rank leaves (wq_a, wkv_a and their "
+            "norms) are whole on every shard and the heads of wq_b, wkv_b "
+            "and wo are not split over the axis")
+    dt, dn, rkv = cfg.dtype, cfg.qk_nope_dim, cfg.kv_lora_rank
+    with jax.named_scope(scopes.MLA_Q):
+        c_q = _rmsnorm(jnp.einsum("btd,dr->btr", x, lp["wq_a"].astype(dt)),
+                       lp["q_a_norm"], cfg.norm_eps)
+        # straight into the kernels' layout, [B, H, T, dn + dr]
+        q = jnp.einsum("btr,rhk->bhtk", c_q, lp["wq_b"].astype(dt))
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+    with jax.named_scope(scopes.MLA_KV):
+        kv_a = jnp.einsum("btd,dr->btr", x, lp["wkv_a"].astype(dt))
+        c_kv = _rmsnorm(kv_a[..., :rkv], lp["kv_a_norm"], cfg.norm_eps)
+        k_rope = kv_a[..., rkv:]                        # [B, T, dr]
+        # (the leaf cut, not its product: k's part goes on into a
+        # concatenation and v's into the kernel, each whole)
+        wkv_b = lp["wkv_b"].astype(dt)
+        k_nope = jnp.einsum("btr,rhk->bhtk", c_kv, wkv_b[..., :dn])
+        v = jnp.einsum("btr,rhk->bhtk", c_kv, wkv_b[..., dn:])
+    if rope is not None:
+        with jax.named_scope(scopes.ROPE):
+            q_rope, k_rope = _rope(q_rope, *rope), _rope(k_rope, *rope)
+    with jax.named_scope(scopes.MLA_Q):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope(scopes.MLA_KV):
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, None], k_nope.shape[:3] + k_rope.shape[-1:])], axis=-1)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        att = flash_attention_local(q, k, v, causal=causal, layout="bhtk",
+                                    under_remat=under_remat)
+    return jnp.einsum("bhtk,hkd->btd", att, lp["wo"].astype(dt))
 
 
 def _conv_mix(x, lp, *, cfg: TransformerConfig,
@@ -1040,7 +1213,8 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
     hand, and the attention call (its window, its rotation) is a
     ``lax.switch`` on it; a conv layer's mixer is :func:`_conv_mix`, under
     the scope ``conv_mixer`` where an attention layer has ``attn``, a mamba2
-    layer's :func:`mamba_mix` under ``mamba_mixer``; a layer of one
+    layer's :func:`mamba_mix` under ``mamba_mixer``, an mla layer's
+    :func:`_mla_mix` under ``mla_mixer``; a layer of one
     sublayer (:class:`LayerKind`) runs that one alone.
     ``routes``: an expert layer's :class:`ExpertRoutes`, else None.
     ``prevent_cse``: ``cfg.remat_barrier``, for a run of one layer. The
@@ -1057,6 +1231,10 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
         mix = functools.partial(
             other_mix, cfg=cfg, seq_size=seq_size, tensor_size=tensor_size
         ) if other_mix else functools.partial(
+            _mla_mix, cfg=cfg, rope=rope if rotate else None,
+            seq_size=seq_size, tensor_size=tensor_size, causal=causal,
+            under_remat=under_remat, scope=scopes.ATTN_LATENT
+        ) if mixer == "mla" else functools.partial(
             _attn_mix, cfg=cfg, rope=rope if rotate else None,
             seq_size=seq_size, tensor_size=tensor_size, causal=causal,
             under_remat=under_remat, window=window, scope=scope)
@@ -1075,8 +1253,8 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
     # a layer of its FFN alone has none)
     mixes = [] if mixer == "none" else [mix_of(kind) for kind in (
         kinds[:1] if other_mix else kinds)] or [mix_of(None)]
-    mix_scope = {"conv": scopes.CONV_MIXER,
-                 "mamba2": scopes.MAMBA_MIXER}.get(mixer, scopes.ATTN)
+    mix_scope = {"conv": scopes.CONV_MIXER, "mamba2": scopes.MAMBA_MIXER,
+                 "mla": scopes.MLA_MIXER}.get(mixer, scopes.ATTN)
 
     def ffn_sublayer(h, aux_sum, lp):
         routes = None
@@ -1163,9 +1341,57 @@ def _run_pattern(params, h, cfg: TransformerConfig, block, grad_axes):
     return carry[0], routes[0] if routes else None
 
 
+def _mtp_module(params, h, nxt, cfg: TransformerConfig, block, grad_axes,
+                seq_size: Optional[int], tensor_size: Optional[int]):
+    """The multi-token-prediction module (``cfg.mtp_depth``; arXiv:2412.19437
+    section 2.2) over the stack's output ``h [B, T, D]`` BEFORE the final
+    norm and the next tokens ``nxt [B, T]``: ``u = [N_e(Emb(nxt)) ; N_h(h)]
+    proj`` (the model's own embedding; scope ``mtp_proj``), one more block
+    of the kind of the pattern's last layer over ``u`` with the module's own
+    leaves (``block(kinds, prevent_cse)`` is :func:`_block`'s body, as
+    :func:`_run_pattern` calls it: a scan over the leading [mtp_depth] axis
+    of the leaves, under the same ``remat``), the module's own final norm.
+    Returns ``(the normed state, the block's ExpertRoutes with a leading
+    [1], None without experts)``; the head is the caller's, the model's own.
+    ``grad_axes``: the mesh axes the gradient of every leaf of
+    ``params["mtp"]`` is summed over where the backward pass produces it.
+    Everything under the scope ``mtp``."""
+    if (seq_size or 1) > 1 or (tensor_size or 1) > 1:
+        raise ValueError(
+            "the multi-token-prediction module (mtp_depth) under seq > 1 or "
+            "tensor > 1: the next token of a shard's last position lies on "
+            "the next shard, and the module's leaves are whole on every "
+            "shard; make_train_step over data alone runs it")
+    mp, kind = params[MTP], cfg.layers[-1]
+    if grad_axes:
+        mp = {k: _sum_in_backward(v, grad_axes[k]) for k, v in mp.items()}
+    own = {k: mp[k][0] for k in ("enorm", "hnorm", "proj", "ln_f")}
+
+    def joined(h, own):
+        with jax.named_scope(scopes.MTP_PROJ):
+            both = jnp.concatenate([
+                _rmsnorm(_embed(params, nxt, cfg), own["enorm"],
+                         cfg.norm_eps),
+                _rmsnorm(h, own["hnorm"], cfg.norm_eps)], axis=-1)
+            return jnp.einsum("bte,ed->btd", both,
+                              own["proj"].astype(cfg.dtype))
+
+    if cfg.remat == "block":    # as a run of one layer
+        joined = jax.checkpoint(joined, prevent_cse=cfg.remat_barrier)
+    with jax.named_scope(scopes.MTP):
+        u = joined(h, own)
+        leaves = {k: v for k, v in mp.items() if k not in own}
+        (u, _), routes = lax.scan(
+            block((kind,), cfg.remat_barrier),
+            (u, jnp.zeros((), jnp.float32)),
+            (leaves, jnp.zeros((cfg.mtp_depth,), jnp.int32)))
+        with jax.named_scope(scopes.HEAD):
+            return _rmsnorm(u, own["ln_f"], cfg.norm_eps), routes
+
+
 def _run_passes(params, tokens, cfg: TransformerConfig,
                 seq_size: Optional[int], tensor_size: Optional[int],
-                causal: bool, exit_fn, layer_grad_axes=None):
+                causal: bool, exit_fn, layer_grad_axes=None, mtp=None):
     """The model up to its exits, over a *local* token block
     [B_local, T_local]: the embedding, then ``cfg.n_loops`` passes over the
     scanned stack with the same weights, each ended by the final norm.
@@ -1184,22 +1410,38 @@ def _run_passes(params, tokens, cfg: TransformerConfig,
     stack of layers (``"layers"``; a pattern's other stacks), for every
     leaf of the stack the mesh axes its gradient is summed over inside the
     backward scan, as each layer's backward produces it.
+
+    ``mtp``: under ``cfg.mtp_depth``, ``(the next tokens [B_local, T_local],
+    mtp_exit_fn)``; the exits are then ``(exit_fn's, mtp_exit_fn(the
+    module's normed state))`` and the module's block's routes the last row
+    of ``routes`` (:func:`_mtp_module`). None: the module does not run.
     """
     h = _embed(params, tokens, cfg)
     rope = _rope_tables(cfg, tokens.shape[1], seq_size)
     layer_grad_axes = layer_grad_axes or {}
     if cfg.layers:
-        h, routes = _run_pattern(
-            params, h, cfg, functools.partial(
-                _block, cfg, rope, seq_size, tensor_size, causal,
-                cfg.remat != "none"), layer_grad_axes)
+        block = functools.partial(_block, cfg, rope, seq_size, tensor_size,
+                                  causal, cfg.remat != "none")
+        h, routes = _run_pattern(params, h, cfg, block, layer_grad_axes)
         if cfg.remat == "block":
             # the exit recomputes from the normed state, as under n_loops >
             # 1 below: the logits and their cotangent are not kept while
             # the layers' backward runs
             exit_fn = jax.checkpoint(exit_fn, prevent_cse=False)
-        return (exit_fn(_final_norm(params, h, cfg)),
-                jnp.zeros((), jnp.float32), routes)
+        exits = exit_fn(_final_norm(params, h, cfg))
+        if cfg.mtp_depth and mtp is not None:
+            nxt, mtp_exit_fn = mtp
+            if cfg.remat == "block":
+                mtp_exit_fn = jax.checkpoint(mtp_exit_fn, prevent_cse=False)
+            h, found = _mtp_module(params, h, nxt, cfg, block,
+                                   layer_grad_axes.get(MTP), seq_size,
+                                   tensor_size)
+            with jax.named_scope(scopes.MTP):   # its head, its loss
+                exits = (exits, mtp_exit_fn(h))
+            if found is not None:
+                routes = ExpertRoutes(*(jnp.concatenate(part) for part in
+                                        zip(routes, found)))
+        return exits, jnp.zeros((), jnp.float32), routes
     layer = _block(cfg, rope, seq_size, tensor_size, causal,
                    under_remat=cfg.remat != "none")
     layer_grad_axes = layer_grad_axes.get("layers")
@@ -1308,9 +1550,27 @@ def forward_routes(params, tokens, cfg: TransformerConfig):
     """Single-shard forward of a model with routed-expert layers: ``(logits
     [B, T, V] in cfg.dtype, ExpertRoutes)``, every expert layer's choices
     and counts beside what they led to."""
+    if cfg.mtp_depth:
+        raise ValueError(
+            "forward_routes knows the tokens alone: a model with a "
+            "multi-token-prediction module (mtp_depth) needs the next "
+            "tokens too, and forward_heads takes them")
     h, _, routes = _run_passes(params, tokens, cfg, None, None, True,
                                lambda h: h)
     return _head(params, h, cfg), routes
+
+
+def forward_heads(params, tokens, targets, cfg: TransformerConfig):
+    """Single-shard forward of a model with a multi-token-prediction module
+    (``cfg.mtp_depth``): ``((logits, mtp_logits), ExpertRoutes)``, both [B,
+    T, V] in cfg.dtype. ``targets`` is ``tokens`` shifted by one, as the
+    step takes it: ``logits[:, i]`` scores ``targets[:, i]`` and
+    ``mtp_logits[:, i]`` scores ``targets[:, i + 1]`` (its last position
+    scores nothing). The routes' last row is the module's block's."""
+    (h, h_mtp), _, routes = _run_passes(
+        params, tokens, cfg, None, None, True, lambda h: h,
+        mtp=(targets, lambda h: h))
+    return (_head(params, h, cfg), _head(params, h_mtp, cfg)), routes
 
 
 def routing_stats(counts, cfg: TransformerConfig, n_tokens: int) -> dict:
@@ -1406,14 +1666,19 @@ def _mean_xent(logits, targets):
 def _local_loss_and_routes(params, inputs, targets, cfg, seq_size=None,
                            tensor_size=None, grad_axes=None):
     """(sum over the local tokens of what each pays, their number, aux,
-    routes): the cross-entropy of the one exit, or under ``n_loops > 1``
-    every exit's, weighted by the gate (:func:`_exit_loss`); ``routes`` is
-    :func:`_run_passes`'s. ``grad_axes``: the leaves whose gradients
+    routes, mtp): the cross-entropy of the one exit, or under ``n_loops >
+    1`` every exit's, weighted by the gate (:func:`_exit_loss`); ``routes``
+    is :func:`_run_passes`'s. ``mtp``: under ``cfg.mtp_depth`` the second
+    term, ``(sum, number)`` over the positions of the multi-token-prediction
+    module that score something: position i against ``targets[i + 1]``
+    from the embedding of ``targets[i]``, the row's last left out; else
+    None. The head and the embedding serve both terms, and autodiff sums
+    their two gradients. ``grad_axes``: the leaves whose gradients
     :func:`make_train_step` sums inside the backward pass, each with its
     mesh axes."""
     grad_axes = grad_axes or {}
 
-    def exit_fn(h):
+    def exit_fn(h, targets=targets):
         head = params
         if "lm_head" in grad_axes:
             # complete when the head's backward ends: summed there
@@ -1422,11 +1687,21 @@ def _local_loss_and_routes(params, inputs, targets, cfg, seq_size=None,
         nll = _lean_xent(_head(head, h, cfg), targets)
         return nll if cfg.n_loops == 1 else (nll, _gate_logit(params, h))
 
-    exits, aux, routes = _run_passes(params, inputs, cfg, seq_size,
-                                     tensor_size, True, exit_fn, grad_axes)
+    def mtp_exit_fn(h):
+        # (the last position's target is a token of the row, any: masked)
+        nll = exit_fn(h, jnp.roll(targets, -1, axis=1))
+        return nll * (jnp.arange(nll.shape[1]) < nll.shape[1] - 1)
+
+    exits, aux, routes = _run_passes(
+        params, inputs, cfg, seq_size, tensor_size, True, exit_fn, grad_axes,
+        mtp=(targets, mtp_exit_fn) if cfg.mtp_depth else None)
+    mtp = None
+    if cfg.mtp_depth:
+        exits, mtp_nll = exits
+        mtp = (jnp.sum(mtp_nll), mtp_nll.shape[0] * (mtp_nll.shape[1] - 1))
     per_token = exits if cfg.n_loops == 1 else _exit_loss(
         *exits, cfg.exit_entropy_weight)
-    return jnp.sum(per_token), per_token.size, aux, routes
+    return jnp.sum(per_token), per_token.size, aux, routes, mtp
 
 
 def _local_loss(*args, **kwargs):
@@ -1434,15 +1709,28 @@ def _local_loss(*args, **kwargs):
     return _local_loss_and_routes(*args, **kwargs)[:3]
 
 
+def lm_loss_terms(params, inputs, targets, cfg: TransformerConfig):
+    """Single-shard ``(CE, CE_mtp)``: the mean next-token cross-entropy and,
+    under ``cfg.mtp_depth``, the multi-token-prediction module's mean over
+    the ``T - 1`` positions a row that score something (else None); the
+    objective is ``CE + cfg.mtp_weight * CE_mtp``."""
+    total, count, _, _, mtp = _local_loss_and_routes(params, inputs, targets,
+                                                     cfg)
+    return total / count, None if mtp is None else mtp[0] / mtp[1]
+
+
 def lean_lm_loss(params, inputs, targets, cfg: TransformerConfig):
     """Single-shard LM loss: the mean over tokens of :func:`_local_loss`,
     what :func:`make_spmd_loss` sums over its shards."""
-    total, count, aux = _local_loss(params, inputs, targets, cfg)
+    total, count, aux, _, mtp = _local_loss_and_routes(params, inputs,
+                                                       targets, cfg)
     loss = total / count
     if cfg.use_moe:
         # same load-balancing term the SPMD loss applies (make_spmd_loss);
         # silently dropping it would let the router collapse
         loss = loss + cfg.moe_aux_weight * aux
+    if mtp is not None:
+        loss = loss + cfg.mtp_weight * mtp[0] / mtp[1]
     return loss
 
 
@@ -1463,6 +1751,14 @@ def _mesh_loss(total, n, aux, cfg: TransformerConfig):
     return lax.pmean(loss, TENSOR_AXIS)
 
 
+def _mesh_mtp_loss(total, count, d_size: int):
+    """The replicated second term, inside the shard_map, of every shard's
+    ``mtp`` of :func:`_local_loss_and_routes` (``seq`` and ``tensor`` are 1
+    there: :func:`_mtp_module`)."""
+    return lax.pmean(lax.psum(total, (DATA_AXIS, SEQ_AXIS))
+                     / (count * d_size), TENSOR_AXIS)
+
+
 def make_spmd_loss(mesh: Mesh, cfg: TransformerConfig):
     """Build loss(params, inputs, targets) -> replicated scalar, with the whole
     computation shard_mapped over the (data, seq, tensor) mesh. Forward-only
@@ -1474,9 +1770,12 @@ def make_spmd_loss(mesh: Mesh, cfg: TransformerConfig):
     tok_spec = P(DATA_AXIS, SEQ_AXIS)
 
     def body(params, inputs, targets):
-        total, count, aux = _local_loss(params, inputs, targets, cfg,
-                                        s_size, t_size)
-        return _mesh_loss(total, count * d_size * s_size, aux, cfg)
+        total, count, aux, _, mtp = _local_loss_and_routes(
+            params, inputs, targets, cfg, s_size, t_size)
+        loss = _mesh_loss(total, count * d_size * s_size, aux, cfg)
+        if mtp is not None:
+            loss = loss + cfg.mtp_weight * _mesh_mtp_loss(*mtp, d_size)
+        return loss
 
     # check_vma=False on every platform: the stock Pallas kernels (flash,
     # splash, the ring segments — taken on TPU) declare no ``vma`` on their
@@ -1507,10 +1806,13 @@ def grad_reduce_axes(mesh: Mesh, cfg: TransformerConfig):
         param_specs(cfg), is_leaf=lambda s: isinstance(s, P))
 
 
-# every stack of a pattern but the two of its dense attention and conv
-# layers (summed after the backward pass, as since PR 32), and an untied head
+# every stack of a pattern but those of its dense attention, conv and mla
+# layers (summed after the backward pass, as since PR 32), the
+# multi-token-prediction module's leaves, and an untied head (where the
+# module is there too, at each of its two uses)
 _IN_BACKWARD = tuple(stack for stack in _STACKS.values() if stack not in (
-    "dense_layers", "conv_dense_layers")) + ("lm_head",)
+    "dense_layers", "conv_dense_layers", "mla_dense_layers")) + (
+    MTP, "lm_head")
 
 
 def _in_backward(axes, cfg: TransformerConfig):
@@ -1599,7 +1901,12 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
     With routed-expert layers (``cfg.has_experts``) the step returns a
     fourth value, ``{"expert_counts": [expert layers, n_experts] int32}``:
     the assignments of the step's tokens, over the whole mesh, to every
-    expert, the expert layers in the order they run. From them it moves
+    expert, the expert layers in the order they run (the
+    multi-token-prediction module's block's, where there is one, as the
+    last row). Under ``cfg.mtp_depth``, and only there, the fourth value
+    also has ``"mtp_loss"``, the module's term ``CE_mtp`` of the loss ``CE
+    + cfg.mtp_weight * CE_mtp`` the step returns and descends. From the
+    counts it moves
     each layer's selection bias (the ``router_bias`` leaf of every stack of
     expert layers: :func:`router_bias_step`) by
     :func:`~horovod_tpu.parallel.moe.router_bias_update` at
@@ -1624,42 +1931,51 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, optimizer):
             # check_vma=False a psum transposes to a psum, so the mesh-wide
             # sums stay out of the differentiated function (the 1/t_size is
             # what the transpose of its pmean over tensor leaves here)
-            total, count, aux, routes = _local_loss_and_routes(
+            total, count, aux, routes, mtp = _local_loss_and_routes(
                 p, inputs, targets, cfg, s_size, t_size, early)
             n = count * d_size * s_size
             objective = total / n
             if cfg.use_moe:
                 objective = objective + cfg.moe_aux_weight * aux / (
                     d_size * s_size)
+            if mtp is not None:
+                objective = objective + cfg.mtp_weight * mtp[0] / (
+                    mtp[1] * d_size)
             return objective / t_size, (total, n, aux,
-                                        routes.counts if routed else None)
+                                        routes.counts if routed else None,
+                                        mtp)
 
-        (_, (total, n, aux, counts)), grads = jax.value_and_grad(
+        (_, (total, n, aux, counts, mtp)), grads = jax.value_and_grad(
             local, has_aux=True)(params)
         grads = {**grads, **jax.tree_util.tree_map(
             _sum_grad, {k: grads[k] for k in late}, late)}
         loss = _mesh_loss(total, n, aux, cfg)
+        if mtp is not None:
+            mtp = _mesh_mtp_loss(*mtp, d_size)
+            loss = loss + cfg.mtp_weight * mtp
         if not routed:
-            return loss, grads
+            return (loss, grads) + (() if mtp is None else (mtp,))
         if d_size * s_size > 1:     # every shard's tokens
             counts = lax.psum(counts, (DATA_AXIS, SEQ_AXIS))
-        return loss, grads, counts
+        return (loss, grads, counts) + (() if mtp is None else (mtp,))
 
     # check_vma=False for the reason given in make_spmd_loss
     grad_fn = jax.shard_map(
         body, mesh=mesh, in_specs=(specs, tok_spec, tok_spec),
-        out_specs=(P(), specs) + ((P(),) if routed else ()), check_vma=False)
+        out_specs=(P(), specs) + (P(),) * (routed + bool(cfg.mtp_depth)),
+        check_vma=False)
 
     def train_step(params, opt_state, inputs, targets):     # scopes.TRAIN_STEP
-        loss, grads, *counts = grad_fn(params, inputs, targets)
+        loss, grads, *found = grad_fn(params, inputs, targets)
         biases = {stack: params[stack]["router_bias"]
                   for stack in _expert_rows(cfg)}
         params, opt_state = scopes.apply_update(optimizer, grads, opt_state,
                                                 params)
-        if not routed:
-            return params, opt_state, loss
-        params = router_bias_step(params, counts[0], cfg, biases)
-        return params, opt_state, loss, {"expert_counts": counts[0]}
+        stats = {"mtp_loss": found.pop()} if cfg.mtp_depth else {}
+        if routed:
+            params = router_bias_step(params, found[0], cfg, biases)
+            stats = {"expert_counts": found[0], **stats}
+        return (params, opt_state, loss) + ((stats,) if stats else ())
 
     return jax.jit(train_step, donate_argnums=(0, 1),
                    compiler_options=_overlap_compiler_options(mesh))
@@ -1680,11 +1996,15 @@ def _refuse_loop_and_untied_head(cfg: TransformerConfig, builder: str) -> None:
         conv = "".join(
             f"; its {mixer} mixer (LayerKind.mixer={mixer!r}) and the "
             f"stacks of leaves it brings have none either"
-            for mixer in ("conv", "mamba2")
+            for mixer in ("conv", "mamba2", "mla")
             if any(kind.mixer == mixer for kind in cfg.layers))
         if any(kind.mixer == "none" or kind.experts is None
                for kind in cfg.layers):
             conv += "; nor have its layers of one sublayer"
+        if cfg.mtp_depth:
+            conv += (f"; nor has its multi-token-prediction module "
+                     f"(mtp_depth={cfg.mtp_depth}), whose block and second "
+                     f"loss follow the last stage's head")
         raise ValueError(
             f"{builder} runs one homogeneous stack: got a per-layer pattern "
             f"(layers, {len(cfg.layers)} kinds), whose dense and expert "

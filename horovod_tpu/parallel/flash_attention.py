@@ -141,6 +141,22 @@ def splash_geometry(t: int, d: int, causal: bool, under_remat: bool,
     pads the head to its 128 lanes, so at the published 64 it reads about
     half the share of its roofline.
 
+    Q and k heads of 192 beside v heads of 128 (latent attention; PR 41, same
+    tool, 2 x 32 x 8192 x (192, 128) causal under ``jax.checkpoint``, 36
+    geometries that fit; ms forward, forward + forward + backward): the
+    causal blocks of heads of 128 once more, 15.11, 50.21, against 15.30 for
+    a kv block of 2048 forward, 15.65-16.43 for the other forwards, 50.96
+    with the backward's q block at 512 (what ``d > 128`` gave before),
+    51.1-57.9 for the other fused backwards and 57.91 with dq in a kernel of
+    its own. The kernel lays a head of 192 out on 256 lanes: the same call
+    with q and k zero-padded to 256 OUTSIDE the kernel (exact: the scores do
+    not change) has the same temporaries to the byte (2.95 GB) and reads
+    15.96, 51.06, the two pad copies slower; so a pair costs 384 lanes for
+    320 in the two products over the head, and the kernel reads at most
+    five sixths of what a kernel that multiplied the 128 and the 64 apart
+    would. The fused backward at q 1024 fits the 16 MiB at 192 where it does
+    not at 256, so only heads ABOVE 192 take the smaller block.
+
     Not causal (in no cell: ViT's lengths never reach the kernel; 4 x 16 x
     2048 x 128 in the same sweep): no block is masked, a smaller kv block
     skips nothing, so the kv block is 2048 where it divides ``t``, in
@@ -148,7 +164,7 @@ def splash_geometry(t: int, d: int, causal: bool, under_remat: bool,
     forward, 3.12 forward + backward, against 1.14 and 4.03 for the blocks
     before PR 31 and 1.18 and 3.32 for the causal blocks."""
     del under_remat, window     # one answer on this backend: see above
-    wide = d > 128
+    wide = d > 192
     kv = 1024 if causal or wide or t % 2048 else 2048
     return SplashBlocks(block_q=1024, block_kv=kv,
                         block_kv_compute=512 if causal else 1024,
@@ -156,48 +172,57 @@ def splash_geometry(t: int, d: int, causal: bool, under_remat: bool,
                         block_kv_dkv_compute=1024)
 
 
-def _kernel_and_why(q_shape, kv_shape, window: int = 0) -> tuple:
-    """``(kernel, why)`` for q and k/v of [B, H, T, D] on this backend:
+def _kernel_and_why(q_shape, kv_shape, window: int = 0,
+                    v_head_size: int = 0) -> tuple:
+    """``(kernel, why)`` for q and k/v of [B, H, T, D] on this backend
+    (``v_head_size``: the width of a V head where it is not K's):
     "splash", "flash" or "materialized", and for materialized attention on
     a TPU the reason in words (``""`` off the TPU, where it is the only
     choice). Materialized attention off the TPU and for sequence lengths the
     kernels' 128-row blocks do not divide (ViT's 197 and 17 tokens); splash
     for what :func:`_splash_refusal` admits; the stock flash kernel for the
     rest (rectangular q/kv, T not a multiple of 1024, other head sizes) and
-    with ``HOROVOD_SPLASH`` off. The stock flash kernel knows no window and
-    no grouped KV heads: what splash refuses of those is materialized."""
+    with ``HOROVOD_SPLASH`` off. The stock flash kernel knows no window, no
+    grouped KV heads and no V head of another size than q's and k's: what
+    splash refuses of those is materialized."""
     if not flash_available():
         return "materialized", ""
     if q_shape[2] % 128 or kv_shape[2] % 128:
         return "materialized", (
             f"sequence lengths q={q_shape[2]} kv={kv_shape[2]} are not "
             f"multiples of 128")
-    refused = _splash_refusal(q_shape, kv_shape) if splash_available() \
-        else "HOROVOD_SPLASH is off"
+    v_head_size = v_head_size or kv_shape[3]
+    refused = _splash_refusal(q_shape, kv_shape, v_head_size) \
+        if splash_available() else "HOROVOD_SPLASH is off"
     if not refused:
         return "splash", ""
-    if window or q_shape[1] != kv_shape[1]:
+    if window or q_shape[1] != kv_shape[1] or v_head_size != q_shape[3]:
         return "materialized", (
-            f"the stock flash kernel knows no window (here {window}) and no "
+            f"the stock flash kernel knows no window (here {window}), no "
             f"grouped KV heads (here {kv_shape[1]} under {q_shape[1]} query "
-            f"heads), and splash does not take the shape: {refused}")
+            f"heads) and no V head of another size than q's and k's (here "
+            f"{v_head_size} beside {q_shape[3]}), and splash does not take "
+            f"the shape: {refused}")
     return "flash", ""
 
 
-def _select_kernel(q_shape, kv_shape, window: int = 0) -> str:
-    return _kernel_and_why(q_shape, kv_shape, window)[0]
+def _select_kernel(q_shape, kv_shape, window: int = 0,
+                   v_head_size: int = 0) -> str:
+    return _kernel_and_why(q_shape, kv_shape, window, v_head_size)[0]
 
 
 def attention_kernel(q_shape, kv_shape, causal: bool = True,
-                     under_remat: bool = False, window: int = 0) -> dict:
+                     under_remat: bool = False, window: int = 0,
+                     v_head_size: int = 0) -> dict:
     """What :func:`flash_attention_local` runs for q and k/v of [B, H, T, D]
     on this backend, as the labels of the gauge ``hvd_tpu_attn_kernel``:
     ``kernel`` ("splash", "flash", "materialized"), the forward's
     ``block_q`` and ``block_kv``, whether the backward is one fused kernel,
-    the ``window`` (0: none) and the ``head_size``. A function of the shapes, ``causal``,
-    ``under_remat`` and ``window`` alone; the call itself dispatches on
-    it."""
-    kernel = _select_kernel(q_shape, kv_shape, window)
+    the ``window`` (0: none), the ``head_size`` of q and k and the
+    ``v_head_size`` (``v_head_size`` 0: as wide as k's). A function of the
+    shapes, ``causal``, ``under_remat`` and ``window`` alone; the call
+    itself dispatches on it."""
+    kernel = _select_kernel(q_shape, kv_shape, window, v_head_size)
     if kernel == "splash":
         g = splash_geometry(q_shape[2], q_shape[3], causal, under_remat,
                             window)
@@ -208,7 +233,8 @@ def attention_kernel(q_shape, kv_shape, causal: bool = True,
         blocks = (0, 0, False)
     return {"kernel": kernel, "block_q": str(blocks[0]),
             "block_kv": str(blocks[1]), "fused_bwd": str(int(blocks[2])),
-            "window": str(window), "head_size": str(q_shape[3])}
+            "window": str(window), "head_size": str(q_shape[3]),
+            "v_head_size": str(v_head_size or kv_shape[3])}
 
 
 @functools.lru_cache(maxsize=32)
@@ -239,10 +265,17 @@ def _splash_kernel(h: int, t: int, d: int, causal: bool, under_remat: bool,
         return make(mask, head_shards=1, q_seq_shards=1, block_sizes=bs)
 
 
-def _splash_refusal(q_shape, kv_shape) -> str:
-    """Why splash does not take q and k/v of [B, H, T, D]; ``""`` where it
-    does."""
+# (q/k head, v head) pairs of unequal sizes that splash takes: each was
+# built, fitted and timed on the v5e before it came here (:func:`splash_geometry`
+# has the readings). 192 = 128 + 64 rotated beside 128: latent attention.
+_UNEQUAL_HEADS = ((192, 128),)
+
+
+def _splash_refusal(q_shape, kv_shape, v_head_size: int = 0) -> str:
+    """Why splash does not take q and k of [B, H, T, D] with v heads of
+    ``v_head_size`` (0: ``D``); ``""`` where it does."""
     _, h, t, d = q_shape
+    v_head_size = v_head_size or d
     # square attention only: the mask is built (t, t); rectangular q/kv
     # (cross-attention, chunked decode) falls back to the flash kernel
     if kv_shape[2] != t or kv_shape[3] != d:
@@ -251,6 +284,16 @@ def _splash_refusal(q_shape, kv_shape) -> str:
         return f"{t} positions are not a multiple of 1024"
     if h % kv_shape[1]:
         return f"{kv_shape[1]} KV heads do not divide {h} query heads"
+    if v_head_size != d:
+        # the stock kernels keep ``head_dim_qk`` and ``head_dim_v`` apart;
+        # what is admitted is what was measured
+        if (d, v_head_size) in _UNEQUAL_HEADS and kv_shape[1] == h:
+            return ""
+        return (f"q/k heads of {d} beside v heads of {v_head_size}: only "
+                f"{_UNEQUAL_HEADS} with as many KV heads as query heads "
+                f"were built and timed; another pair needs its blocks "
+                f"fitted (tests/test_tpu_compile.py) and swept "
+                f"(tools/attn_sweep.py) before splash_geometry answers it")
     # a head of 64 fills half of the kernel's 128 lanes (its
     # ``head_dim_v_repeats`` slices to the head's width). Admitted where it
     # was measured on the v5e (PERF.md section 6, PR 34): the MQA form,
@@ -264,8 +307,8 @@ def _splash_refusal(q_shape, kv_shape) -> str:
     return ""
 
 
-def _splash_ok(q_shape, kv_shape) -> bool:
-    return not _splash_refusal(q_shape, kv_shape)
+def _splash_ok(q_shape, kv_shape, v_head_size: int = 0) -> bool:
+    return not _splash_refusal(q_shape, kv_shape, v_head_size)
 
 
 def _flash_block(q_t: int, kv_t: int) -> int:
@@ -327,7 +370,9 @@ def flash_attention_local(q, k, v, causal: bool = True,
     depend on it (:func:`splash_geometry`: on this backend it does not).
     ``window`` > 0: key ``j`` is visible from query ``i`` iff ``0 <= i - j
     < window``. ``k`` and ``v`` may have fewer heads than ``q``, a divisor
-    of its number: query head ``n`` reads KV head ``n // (H / H_kv)``."""
+    of its number: query head ``n`` reads KV head ``n // (H / H_kv)``. ``v``'s
+    heads may be of another size than ``q``'s and ``k``'s (the result's are
+    ``v``'s; the scores are scaled by ``q``'s)."""
     if layout not in ("bthk", "bhtk"):
         raise ValueError(f"unknown attention layout {layout!r}")
     bhtk = layout == "bhtk"
@@ -339,7 +384,7 @@ def flash_attention_local(q, k, v, causal: bool = True,
         return shape if bhtk else (shape[0], shape[2], shape[1], shape[3])
 
     kernel, why = _kernel_and_why(as_bhtk(q.shape), as_bhtk(k.shape),
-                                  window)
+                                  window, v.shape[-1])
     if kernel == "materialized":
         # The Pallas kernels want both sequence lengths divisible by their
         # blocks (128 at least); unaligned lengths (ViT-B/16 at 224px -> 197
@@ -371,7 +416,7 @@ def flash_attention_local(q, k, v, causal: bool = True,
                                     grouped=True)
             out = jax.vmap(jax.vmap(splash))(
                 q.reshape(b, k.shape[1], group, t, d), k, v
-            ).reshape(b, h, t, d)
+            ).reshape(b, h, t, v.shape[-1])
     else:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as _fa)

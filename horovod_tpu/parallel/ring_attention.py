@@ -534,7 +534,8 @@ def local_attention(q, k, v, causal: bool = True, window: int = 0):
     non-SP path: [B, T, H, D] -> [B, T, H, D]. ``window`` > 0: key ``j`` is
     visible from query ``i`` iff ``0 <= i - j < window``. ``k`` and ``v``
     may have fewer heads than ``q``: query head ``n`` reads KV head ``n //
-    (H / H_kv)``, and K and V are not repeated."""
+    (H / H_kv)``, and K and V are not repeated. ``v``'s heads may be of
+    another size than ``q``'s and ``k``'s: the result's are ``v``'s."""
     B, T, H, D = q.shape
     grouped = k.shape[2] != H
     if grouped:     # [B, T, H_kv, G, D]: a KV head's group of query heads
@@ -553,5 +554,5 @@ def local_attention(q, k, v, causal: bool = True, window: int = 0):
                      p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     if grouped:
-        out = out.reshape(B, T, H, D)
+        out = out.reshape(B, T, H, v.shape[-1])
     return out.astype(q.dtype)

@@ -593,7 +593,7 @@ def test_heads_of_64_reach_splash_at_the_causal_blocks(on_tpu):
                                 under_remat=True)
     assert found == {"kernel": "splash", "block_q": "1024",
                      "block_kv": "1024", "fused_bwd": "1", "window": "0",
-                     "head_size": "64"}
+                     "head_size": "64", "v_head_size": "64"}
     assert fa._select_kernel((2, 32, 8192, 96), (2, 8, 8192, 96)) \
         == "materialized"
     assert fa._select_kernel((2, 32, 8192, 32), (2, 32, 8192, 32)) == "flash"

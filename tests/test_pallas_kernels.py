@@ -226,8 +226,8 @@ def test_which_kernel_a_shape_reaches(on_tpu, monkeypatch, q, kv, mode, want):
         labels = fa.attention_kernel(q, kv, True, under_remat)
         assert labels["kernel"] == want
         assert set(labels) == {"kernel", "block_q", "block_kv", "fused_bwd",
-                               "window", "head_size"}
-        assert labels["head_size"] == str(q[3])
+                               "window", "head_size", "v_head_size"}
+        assert labels["head_size"] == labels["v_head_size"] == str(q[3])
         if want == "flash":
             block = "1024" if q[2] % 1024 == 0 == kv[2] % 1024 else "128"
             assert (labels["block_q"], labels["fused_bwd"]) == (block, "0")
@@ -239,8 +239,51 @@ def test_off_the_tpu_attention_is_materialized_and_the_gauge_is_declared():
     sq = (4, 16, 2048, 128)
     assert fa.attention_kernel(sq, sq) == {
         "kernel": "materialized", "block_q": "0", "block_kv": "0",
-        "fused_bwd": "0", "window": "0", "head_size": "128"}
+        "fused_bwd": "0", "window": "0", "head_size": "128",
+        "v_head_size": "128"}
     assert METRIC_SPECS["hvd_tpu_attn_kernel"][0] == "gauge"
+
+
+@pytest.mark.parametrize("q, kv, v_head, want", [
+    # latent attention: q/k heads of 192 = 128 + 64 rotated, v heads of 128
+    ((2, 32, 8192, 192), None, 128, "splash"),
+    ((1, 2, 2048, 192), None, 128, "splash"),
+    # another unequal pair was never built or timed: no kernel takes it
+    ((2, 32, 8192, 256), None, 128, "materialized"),
+    ((2, 32, 8192, 192), None, 64, "materialized"),
+    # the pair under fewer KV heads: the MQA form at it was never built
+    ((2, 32, 8192, 192), (2, 4, 8192, 192), 128, "materialized"),
+    # splash's other conditions hold for it too
+    ((2, 32, 1536, 192), None, 128, "materialized"),
+    # equal heads of 192 are no multiple of 128: the stock flash kernel
+    ((2, 32, 8192, 192), None, 192, "flash"),
+])
+def test_which_kernel_unequal_head_sizes_reach(on_tpu, q, kv, v_head, want):
+    from horovod_tpu.parallel import flash_attention as fa
+    kv = kv or q
+    assert fa._select_kernel(q, kv, 0, v_head) == want
+    labels = fa.attention_kernel(q, kv, True, True, v_head_size=v_head)
+    assert (labels["kernel"], labels["head_size"], labels["v_head_size"]) \
+        == (want, str(q[3]), str(v_head))
+    if want == "materialized":
+        assert "192" in fa._kernel_and_why(q, kv, 0, v_head)[1] or \
+            "256" in fa._kernel_and_why(q, kv, 0, v_head)[1]
+
+
+def test_materialized_attention_takes_v_heads_of_another_size():
+    """Off the TPU, and for what no kernel takes: the result's heads are
+    v's, plainly and under grouped KV heads."""
+    import jax
+    from horovod_tpu.parallel.ring_attention import local_attention
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (2, 64, 4, 24))
+    for kv_heads in (4, 2):
+        k = jax.random.normal(ks[1], (2, 64, kv_heads, 24))
+        v = jax.random.normal(ks[2], (2, 64, kv_heads, 16))
+        out = local_attention(q, k, v)
+        assert out.shape == (2, 64, 4, 16)
+        wide = local_attention(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, 8),)))
+        np.testing.assert_allclose(out, wide[..., :16], rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -286,6 +329,60 @@ def test_chosen_splash_geometry_against_float32(on_tpu, monkeypatch, causal):
     fa._splash_kernel.cache_clear()
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        err = np.linalg.norm(g - r) / np.linalg.norm(r)
+        assert err < 6e-3, (name, err)
+
+
+def test_chosen_splash_geometry_at_unequal_heads_against_float32(
+        on_tpu, monkeypatch):
+    """The latent-attention call, q and k heads of 192 beside v heads of
+    128, causal, at the chosen blocks (interpreted here, 1 x 2 x 2048): dq,
+    dk, dv against a float32 materialized attention, relative L2. On the
+    v5e at 2 x 32 x 8192 the chosen geometry reads what
+    ``tools/attn_sweep.py errors --shapes mla`` gives (PERF.md section 6, PR
+    41); the band is the equal-heads test's."""
+    import functools
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    from horovod_tpu.parallel import flash_attention as fa
+    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
+        sk.make_splash_mha, interpret=True))
+    fa._splash_kernel.cache_clear()
+    t, d, dv = 2048, 192, 128
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, w = (jax.random.normal(key, (1, 2, t, width), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for key, width in zip(keys, (d, d, dv, dv)))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    def kernel(q, k, v):
+        return fa.flash_attention_local(q, k, v, causal=True, layout="bhtk",
+                                        under_remat=True)
+
+    def reference(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    assert fa.attention_kernel(q.shape, k.shape, True, True,
+                               v_head_size=dv)["kernel"] == "splash"
+    out = kernel(q, k, v)
+    assert out.shape == (1, 2, t, dv)
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(reference(q, k, v)),
+            atol=2e-2)
+    fa._splash_kernel.cache_clear()
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert g.shape == r.shape
         err = np.linalg.norm(g - r) / np.linalg.norm(r)
         assert err < 6e-3, (name, err)
 

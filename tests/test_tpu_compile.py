@@ -508,6 +508,81 @@ def test_the_state_space_cells_step_and_the_memory_it_states(
 LIVE_GB_WITH_THE_SCANS_KERNELS = 10.864
 
 
+def test_the_latent_attention_cells_step_and_the_memory_it_states(
+        topo, pallas_branch):
+    """``joyai-spmd-1chip-ep32share-8k``'s own program, whole (a dense
+    layer, four expert layers as one scan, the multi-token-prediction
+    module; latent attention in all six; 2 rows of 8,192 tokens,
+    ``remat="block"``) for the v5e: the attention kernel at q and k heads of
+    192 beside v heads of 128 through splash's MHA form, in each of the
+    three scans forward TWICE (the recomputation is real) and the fused
+    backward once, which is the compiler's word that the chosen blocks fit
+    VMEM at this pair of head sizes; the experts' grouped products through
+    megablox at 768 with no ragged-dot fallback; and the compiler's
+    ``memory_analysis`` under the 14.4 GB (90% of the chip) a cell may reach
+    and within 2% of what ``benchmark/configs/joyai-llm-flash.json``
+    states."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [p for p in (os.path.join(bench, "readers"), bench)
+                    if p not in sys.path]
+    import files
+    config, cell = "joyai-llm-flash", "joyai-spmd-1chip-ep32share-8k"
+    model = files.load_module(os.path.join(
+        bench, "configs", config + ".py"), "bench_config_joyai")
+    spec = files.load_json(files.config_path(config))
+    traffic = files.load_json(files.traffic_path(files.cell(cell)["traffic"]))
+    cfg = model.transformer_config(spec, traffic, False)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
+                (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
+    opt = model.optimizer()
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    held = NamedSharding(mesh, P())
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=held), tree)
+
+    tok = jax.ShapeDtypeStruct(
+        (traffic["rows_per_chip"], cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS)))
+    compiled = tfm.make_train_step(mesh, cfg, opt).lower(
+        on_mesh(shapes), on_mesh(jax.eval_shape(opt.init, shapes)), tok,
+        tok).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%((?:splash|flash|gmm|tgmm|ragged)[\w\-]*?)"
+                       r"(?:\.\d+)? = ", text)
+    # the dense layer's run, the expert layers' scan and the module's block
+    assert calls.count("splash_mha_fwd_residuals") == 6, calls
+    assert calls.count("splash_mha_dkv_no_residuals") == 3, calls
+    assert "gmm" in calls and "tgmm" in calls
+    assert not [c for c in calls if "_dq" in c or "flash" in c
+                or "mqa" in c or "ragged" in c], calls
+    # q and k at 192, v and the result at 128, under attn_latent
+    assert re.search(r"%splash_mha_fwd_residuals[.\d]* = [^\n]*"
+                     r"bf16\[2,32,8192,128\]", text)
+    assert re.search(
+        r"operand_layout_constraints=\{[^\n]*bf16\[2,32,8192,192\]\{3,2,1,0\},"
+        r" bf16\[2,32,8192,192\]\{3,2,1,0\}, bf16\[2,32,8192,128\]", text)
+    kernel = "mla_mixer/attn_latent/vmap(jit(_splash_attention))/splash_mha_"
+    assert "jvp(layers)/while/body/closed_call/" + kernel in text
+    # the module's block runs its own kernels, under mtp
+    assert "jvp(mtp)/while/body/closed_call/" + kernel in text
+    # the tight buffer's 5,120 rows at the experts' width of 768
+    assert re.search(r"%gmm[.\d]* = bf16\[5120,768\]", text)
+    found = compiled.memory_analysis()
+    stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
+    live = found.argument_size_in_bytes + found.temp_size_in_bytes
+    assert live < 14.4e9
+    assert stated["parameters"] == 491_697_408 == sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    assert live / 1e9 == pytest.approx(stated["live_gb"], rel=0.02)
+    assert found.temp_size_in_bytes / 1e9 == pytest.approx(
+        stated["temporaries_gb"], rel=0.02)
+
+
 @pytest.mark.parametrize("rows, remat", [(2, True), (1, False)],
                          ids=["the-cells-rows-remat", "the-checks-one-row"])
 def test_the_scan_call_alone_fits(topo, pallas_branch, rows, remat):

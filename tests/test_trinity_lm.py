@@ -368,7 +368,7 @@ def test_the_kernel_choice_for_a_window_and_grouped_heads(monkeypatch):
                                 under_remat=True, window=2048)
     assert found == {"kernel": "splash", "block_q": "1024",
                      "block_kv": "1024", "fused_bwd": "1", "window": "2048",
-                     "head_size": "128"}
+                     "head_size": "128", "v_head_size": "128"}
     assert fa.splash_geometry(8192, 128, True, True, 2048) \
         == fa.splash_geometry(8192, 128, True, True)
     assert fa._select_kernel((1, 32, 640, 128), (1, 4, 640, 128)) \

@@ -24,7 +24,12 @@ KV heads through the kernel's MQA form a KV head, ``band`` under a window
 of 2048 and ``gqa`` causal: the two kinds of layer of the sparse-expert
 cell; ``head64`` (ISSUE 34) is 2 x 32/8 x 8192 x 64 under
 ``jax.checkpoint``, causal: the one attention layer of the conv/attention
-cell, heads half as wide as the kernel's 128 lanes; ``short`` (8 rows of 1024) and ``full`` (``lm`` without the causal
+cell, heads half as wide as the kernel's 128 lanes; ``mla`` (ISSUE 41) is
+2 x 32 x 8192 causal under ``jax.checkpoint`` with q and k heads of 192 and
+v heads of 128 (``v_head``), the latent-attention cell's call, and
+``mla_pad256`` the same with q and k zero-padded to 256 OUTSIDE the kernel
+(``pad_qk``: the scores do not change), ``mla_4k`` one row of 4,096 of it for
+``errors``; ``short`` (8 rows of 1024) and ``full`` (``lm`` without the causal
 mask) are in no cell and are measured on ``--geometry``'s alone. ``--rehearse`` runs the same code interpreted on
 the CPU at T = 256: a test of the script, never a time.
 
@@ -54,8 +59,9 @@ import jax.numpy as jnp
 import numpy as np
 
 HEADS, HEAD = 16, 128
-# "heads", "kv_heads" (default HEADS of each), "head" (default HEAD) and
-# "window" (default none)
+# "heads", "kv_heads" (default HEADS of each), "head" (default HEAD),
+# "v_head" (default "head"), "pad_qk" (q and k zero-padded to it outside
+# the kernel; default none) and "window" (default none)
 SHAPES = {"lm": {"rows": 4, "t": 2048, "remat": False, "causal": True},
           "loop": {"rows": 1, "t": 4096, "remat": True, "causal": True},
           "band": {"rows": 1, "t": 8192, "remat": True, "causal": True,
@@ -64,6 +70,15 @@ SHAPES = {"lm": {"rows": 4, "t": 2048, "remat": False, "causal": True},
                   "heads": 32, "kv_heads": 4},
           "head64": {"rows": 2, "t": 8192, "remat": True, "causal": True,
                      "heads": 32, "kv_heads": 8, "head": 64},
+          "mla": {"rows": 2, "t": 8192, "remat": True, "causal": True,
+                  "heads": 32, "head": 192, "v_head": 128},
+          "mla_pad256": {"rows": 2, "t": 8192, "remat": True, "causal": True,
+                         "heads": 32, "head": 192, "v_head": 128,
+                         "pad_qk": 256},
+          # the same call over half the positions and one row, for
+          # ``errors``: the float32 scores of 8,192 positions do not fit
+          "mla_4k": {"rows": 1, "t": 4096, "remat": True, "causal": True,
+                     "heads": 32, "head": 192, "v_head": 128},
           # in no cell; measured on --geometry's alone
           "short": {"rows": 8, "t": 1024, "remat": False, "causal": True},
           "full": {"rows": 4, "t": 2048, "remat": False, "causal": False}}
@@ -75,6 +90,13 @@ REHEARSAL = {"lm": {"rows": 2, "t": 256, "remat": False, "causal": True},
                      "heads": 4, "kv_heads": 2},
              "head64": {"rows": 2, "t": 256, "remat": True, "causal": True,
                         "heads": 4, "kv_heads": 2, "head": 64},
+             "mla": {"rows": 1, "t": 256, "remat": True, "causal": True,
+                     "heads": 2, "head": 192, "v_head": 128},
+             "mla_pad256": {"rows": 1, "t": 256, "remat": True,
+                            "causal": True, "heads": 2, "head": 192,
+                            "v_head": 128, "pad_qk": 256},
+             "mla_4k": {"rows": 1, "t": 256, "remat": True, "causal": True,
+                        "heads": 2, "head": 192, "v_head": 128},
              "short": {"rows": 2, "t": 128, "remat": False, "causal": True},
              "full": {"rows": 2, "t": 256, "remat": False, "causal": False}}
 BLOCKS = (512, 1024, 2048)
@@ -112,9 +134,13 @@ def triples(blocks, t):
 
 
 def attention(geo: Geometry, shape: dict, interpret: bool):
-    """q [rows, heads, t, head], k, v [rows, kv_heads, t, head] -> the
-    attention, built as ``flash_attention_local`` builds it but for the
-    geometry."""
+    """q [rows, heads, t, head], k [rows, kv_heads, t, head], v [rows,
+    kv_heads, t, v_head] -> the attention, built as ``flash_attention_local``
+    builds it but for the geometry."""
+    if shape.get("pad_qk"):
+        inner = attention(geo, {**shape, "pad_qk": 0}, interpret)
+        pad = ((0, 0),) * 3 + ((0, shape["pad_qk"] - shape["head"]),)
+        return lambda q, k, v: inner(jnp.pad(q, pad), jnp.pad(k, pad), v)
     t, causal = shape["t"], shape["causal"]
     heads = shape.get("heads", HEADS)
     group = heads // shape.get("kv_heads", heads)
@@ -179,7 +205,8 @@ def programs(geo: Geometry, shape: dict, interpret: bool):
 def reference_grads(shape: dict):
     """The same gradient from a float32 materialized attention."""
     t = shape["t"]
-    group = shape.get("heads", HEADS) // shape.get("kv_heads", HEADS)
+    heads = shape.get("heads", HEADS)
+    group = heads // shape.get("kv_heads", heads)
 
     def loss(q, k, v, w):
         q, k, v, w = (x.astype(jnp.float32) for x in (q, k, v, w))
@@ -202,9 +229,11 @@ def reference_grads(shape: dict):
 
 def inputs(shape: dict, seed: int, sharding=None):
     heads = shape.get("heads", HEADS)
-    dims = [(shape["rows"], h, shape["t"], shape.get("head", HEAD))  # q k v w
-            for h in (heads,) + (shape.get("kv_heads", heads),) * 2
-            + (heads,)]
+    head = shape.get("head", HEAD)
+    dims = [(shape["rows"], h, shape["t"], d)                   # q k v w
+            for h, d in zip((heads,) + (shape.get("kv_heads", heads),) * 2
+                            + (heads,),
+                            (head,) * 2 + (shape.get("v_head", head),) * 2)]
     if sharding is not None:    # a described chip holds no array
         return [jax.ShapeDtypeStruct(d, jnp.bfloat16, sharding=sharding)
                 for d in dims]

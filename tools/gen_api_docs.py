@@ -91,9 +91,10 @@ SECTIONS = [
     ("Estimator & store", "horovod_tpu", []),
     ("Models", "horovod_tpu.models.transformer", [
         "TransformerConfig", "LayerKind", "init_params", "forward_block",
-        "mamba_mix", "lean_lm_loss",
+        "mamba_mix", "lean_lm_loss", "lm_loss_terms",
         "make_train_step", "make_spmd_loss", "shard_params",
-        "forward_exits", "exit_distribution"]),
+        "forward_exits", "exit_distribution", "forward_routes",
+        "forward_heads"]),
     ("", "horovod_tpu.models.vit", ["ViT", "ViT_B16", "ViT_S16"]),
     ("", "horovod_tpu.models.resnet", ["ResNet50", "ResNet101", "ResNet152"]),
     ("Parallelism kernels", "horovod_tpu.parallel.ring_attention", [
