@@ -588,6 +588,7 @@ def test_the_gauges_and_the_kernels_labels_are_declared():
     assert "v_head_size" in METRIC_SPECS["hvd_tpu_attn_kernel"][1]
     assert fa.attention_kernel((2, 32, 8192, 192), (2, 32, 8192, 192),
                                v_head_size=128)["v_head_size"] == "128"
+    assert "resident_dq" in METRIC_SPECS["hvd_tpu_attn_kernel"][1]
 
 
 # -- what refuses the new mixer and the module says so ---------------------

@@ -226,7 +226,7 @@ def test_which_kernel_a_shape_reaches(on_tpu, monkeypatch, q, kv, mode, want):
         labels = fa.attention_kernel(q, kv, True, under_remat)
         assert labels["kernel"] == want
         assert set(labels) == {"kernel", "block_q", "block_kv", "fused_bwd",
-                               "window", "head_size", "v_head_size"}
+                               "window", "head_size", "v_head_size", "bwd"}
         assert labels["head_size"] == labels["v_head_size"] == str(q[3])
         if want == "flash":
             block = "1024" if q[2] % 1024 == 0 == kv[2] % 1024 else "128"
@@ -240,8 +240,9 @@ def test_off_the_tpu_attention_is_materialized_and_the_gauge_is_declared():
     assert fa.attention_kernel(sq, sq) == {
         "kernel": "materialized", "block_q": "0", "block_kv": "0",
         "fused_bwd": "0", "window": "0", "head_size": "128",
-        "v_head_size": "128"}
+        "v_head_size": "128", "bwd": "materialized"}
     assert METRIC_SPECS["hvd_tpu_attn_kernel"][0] == "gauge"
+    assert "bwd" in METRIC_SPECS["hvd_tpu_attn_kernel"][1]
 
 
 @pytest.mark.parametrize("q, kv, v_head, want", [
@@ -270,6 +271,111 @@ def test_which_kernel_unequal_head_sizes_reach(on_tpu, q, kv, v_head, want):
             "256" in fa._kernel_and_why(q, kv, 0, v_head)[1]
 
 
+def _interpret_the_kernels(monkeypatch) -> list:
+    """Splash's kernels and the written backward through Pallas's
+    interpreter (the CPU cannot lower them); returns the list of the
+    written backward's calls, ``(t, d, dv)`` each."""
+    import functools
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    from horovod_tpu.parallel import flash_attention as fa
+    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
+        sk.make_splash_mha, interpret=True))
+    fa._splash_kernel.cache_clear()
+    written, real = [], fa.mha_bwd_resident_dq
+
+    def interpreted(q, k, v, lse, do, di, interpret=False):
+        written.append((q.shape[2], q.shape[3], v.shape[3]))
+        return real(q, k, v, lse, do, di, interpret=True)
+
+    monkeypatch.setattr(fa, "mha_bwd_resident_dq", interpreted)
+    return written
+
+
+@pytest.mark.parametrize("d, dv", [(128, 128), (192, 128)],
+                         ids=["heads-128", "q-k-192-v-128"])
+def test_the_written_backward_against_float32(on_tpu, monkeypatch, d, dv):
+    """The written backward (``mha_bwd_resident_dq``, interpreted) behind
+    splash's forward under ``jax.checkpoint``, causal, at blocks of 256 over
+    2,048 positions: eight kv blocks, so dq gathers up to eight blocks' sums
+    in its float32 rows, the diagonal blocks are masked and 28 of the 64
+    (kv, q) pairs are skipped; dq, dk, dv against a float32 materialized
+    attention, relative L2, to the band of the stock kernel's tests above."""
+    import jax
+    from horovod_tpu.parallel import flash_attention as fa
+    written = _interpret_the_kernels(monkeypatch)
+    monkeypatch.setattr(fa, "_RESIDENT_BLOCK", 256)
+    t = 2048
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, w = (jax.random.normal(key, (1, 2, t, width), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for key, width in zip(keys, (d, d, dv, dv)))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    @jax.checkpoint
+    def kernel(q, k, v):
+        return fa.flash_attention_local(q, k, v, layout="bhtk",
+                                        under_remat=True)
+
+    def reference(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    assert fa.backward_form(q.shape, k.shape, v_head_size=dv) \
+        == "resident_dq"
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+    fa._splash_kernel.cache_clear()
+    assert written == [(t, d, dv)]
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert g.shape == r.shape
+        err = np.linalg.norm(g - r) / np.linalg.norm(r)
+        assert err < 6e-3, (name, err)
+
+
+# The local attention call of each LM cell (a chip's rows), its window, and
+# the backward it runs on the TPU: the written one where splash takes a
+# causal call with as many KV heads as query heads, splash's fused kernel
+# under grouped KV heads (tools/attn_sweep.py --bwd-forms, PERF.md section
+# 6, PR 42: faster at all three MHA shapes measured)
+@pytest.mark.parametrize("backend, q, kv, v_head, window, form", [
+    ("tpu", (4, 16, 2048, 128), None, 0, 0, "resident_dq"),
+    ("tpu", (1, 16, 4096, 128), None, 0, 0, "resident_dq"),
+    ("tpu", (1, 32, 8192, 128), (1, 4, 8192, 128), 0, 2048, "splash_fused"),
+    ("tpu", (1, 32, 8192, 128), (1, 4, 8192, 128), 0, 0, "splash_fused"),
+    ("tpu", (2, 32, 8192, 64), (2, 8, 8192, 64), 0, 0, "splash_fused"),
+    ("tpu", (2, 32, 8192, 128), (2, 2, 8192, 128), 0, 0, "splash_fused"),
+    ("tpu", (2, 32, 8192, 192), None, 128, 0, "resident_dq"),
+    ("tpu", (1, 32, 131072, 192), None, 128, 0, "splash_fused"),
+    ("tpu", (1, 16, 1024, 128), None, 0, 0, "splash_fused"),
+    ("cpu", (2, 32, 8192, 192), None, 128, 0, "splash_fused"),
+], ids=["lm-spmd-1chip-and-4chip-dp", "ouro-loop4", "trinity-window",
+        "trinity-full", "lfm2-head64", "nemotron3-kv2", "joyai-latent",
+        "a-row-past-the-vmem", "one-block", "the-cpu"])
+def test_the_backward_form_is_a_function_of_the_backend_and_the_shapes(
+        monkeypatch, backend, q, kv, v_head, window, form):
+    import jax
+    from horovod_tpu.parallel import flash_attention as fa
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.delenv("HOROVOD_SPLASH", raising=False)
+    kv = kv or q
+    assert fa.backward_form(q, kv, True, window, v_head) == form
+    labels = fa.attention_kernel(q, kv, True, True, window, v_head)
+    assert labels["bwd"] == (form if backend == "tpu" else "materialized")
+    if form == "resident_dq":   # what the kernel holds fits the budget
+        assert fa.resident_dq_vmem(q[2], q[3], v_head or q[3]) \
+            <= fa._VMEM_BUDGET
+    # not causal: splash's own
+    assert fa.backward_form(q, kv, False, window, v_head) == "splash_fused"
+
+
 def test_materialized_attention_takes_v_heads_of_another_size():
     """Off the TPU, and for what no kernel takes: the result's heads are
     v's, plainly and under grouped KV heads."""
@@ -289,19 +395,16 @@ def test_materialized_attention_takes_v_heads_of_another_size():
 @pytest.mark.parametrize("causal", [True, False])
 def test_chosen_splash_geometry_against_float32(on_tpu, monkeypatch, causal):
     """dq, dk, dv of ``flash_attention_local`` with the stock splash kernel
-    at the chosen blocks (interpreted here) against a float32 materialized
-    attention: relative L2. On the v5e at the cells' shapes the causal
-    geometry reads dq 3.8e-3, dk 3.7e-3, dv 3.1e-3 at worst over 8 seeds
-    (``tools/attn_sweep.py errors``, PERF.md section 4); the band is 1.5
-    times that; a backward through scores rounded to 8 bits reads 3e-2."""
-    import functools
+    at the chosen blocks (interpreted here; the causal call's backward is
+    the written one since PR 42) against a float32 materialized attention:
+    relative L2. On the v5e at the cells' shapes the causal geometry reads
+    dq 3.8e-3, dk 3.7e-3, dv 3.1e-3 at worst over 8 seeds with splash's
+    backward, dq 3.7e-3 with the written one (``tools/attn_sweep.py
+    errors``, PERF.md section 4); the band is 1.5 times that; a backward
+    through scores rounded to 8 bits reads 3e-2."""
     import jax
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
     from horovod_tpu.parallel import flash_attention as fa
-    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
-        sk.make_splash_mha, interpret=True))
-    fa._splash_kernel.cache_clear()
+    written = _interpret_the_kernels(monkeypatch)
     t, d = 2048, 128
     q, k, v, w = (jax.random.normal(key, (1, 2, t, d), jnp.float32)
                   .astype(jnp.bfloat16)
@@ -327,6 +430,7 @@ def test_chosen_splash_geometry_against_float32(on_tpu, monkeypatch, causal):
     with jax.default_matmul_precision("highest"):
         want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
     fa._splash_kernel.cache_clear()
+    assert len(written) == causal
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
         err = np.linalg.norm(g - r) / np.linalg.norm(r)
@@ -336,19 +440,15 @@ def test_chosen_splash_geometry_against_float32(on_tpu, monkeypatch, causal):
 def test_chosen_splash_geometry_at_unequal_heads_against_float32(
         on_tpu, monkeypatch):
     """The latent-attention call, q and k heads of 192 beside v heads of
-    128, causal, at the chosen blocks (interpreted here, 1 x 2 x 2048): dq,
-    dk, dv against a float32 materialized attention, relative L2. On the
-    v5e at 2 x 32 x 8192 the chosen geometry reads what
-    ``tools/attn_sweep.py errors --shapes mla`` gives (PERF.md section 6, PR
-    41); the band is the equal-heads test's."""
-    import functools
+    128, causal, at the chosen blocks with the written backward
+    (interpreted here, 1 x 2 x 2048): dq, dk, dv against a float32
+    materialized attention, relative L2. On the v5e at 1 x 32 x 4096 the
+    chosen geometry reads what ``tools/attn_sweep.py errors --shapes
+    mla_4k`` gives (PERF.md section 4, PR 41 and PR 42); the band is the
+    equal-heads test's."""
     import jax
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
     from horovod_tpu.parallel import flash_attention as fa
-    monkeypatch.setattr(sk, "make_splash_mha", functools.partial(
-        sk.make_splash_mha, interpret=True))
-    fa._splash_kernel.cache_clear()
+    written = _interpret_the_kernels(monkeypatch)
     t, d, dv = 2048, 192, 128
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
     q, k, v, w = (jax.random.normal(key, (1, 2, t, width), jnp.float32)
@@ -380,6 +480,7 @@ def test_chosen_splash_geometry_at_unequal_heads_against_float32(
             np.asarray(out, np.float32), np.asarray(reference(q, k, v)),
             atol=2e-2)
     fa._splash_kernel.cache_clear()
+    assert written == [(t, d, dv)]
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
         assert g.shape == r.shape

@@ -16,6 +16,12 @@ Three stages, the first without a chip:
 ``errors``  on the chip: dq, dk, dv of chosen geometries against a float32
             materialized attention at ``highest`` precision, 8 seeds.
 
+``--bwd-forms`` (ISSUE 42; ``time`` and ``errors``): at each shape the two
+backwards ``flash_attention.backward_form`` chooses between, both behind the
+forward ``splash_geometry`` chooses: splash's fused kernel (``...:fused``)
+and the written ``mha_bwd_resident_dq`` (``...:resident``), and nothing
+else.
+
 Shapes: ``lm`` is 4 x 16 x 2048 x 128, differentiated plainly; ``loop`` is
 1 x 16 x 4096 x 128 under ``jax.checkpoint``, so its backward runs the
 forward again, as the looped cell's does; ``band`` and ``gqa`` (ISSUE 32)
@@ -38,6 +44,9 @@ the CPU at T = 256: a test of the script, never a time.
         --fit .bench_tree/attn_fit.json --out chiprun_out/attn_sweep.json
     ... errors --winners chiprun_out/attn_sweep.json --out <json>
     ... time --shapes full short --geometry 1024/1024/512:1024/1024/1024:fused
+    chiprun --chips 1 -- python tools/attn_sweep.py time --bwd-forms \\
+        --shapes mla loop lm --out chiprun_out/attn_forms.json
+    ... errors --bwd-forms --shapes mla_4k loop lm --out <json>
 """
 
 from __future__ import annotations
@@ -108,7 +117,9 @@ class Geometry:
     """One way to build the attention call. ``fwd`` and ``dkv`` are (block_q,
     block_kv, block_kv_compute); ``dq`` is (block_q, block_kv), None where
     the backward is one fused kernel. ``kernel`` "flash" is the stock flash
-    kernel with every block at ``fwd[0]``."""
+    kernel with every block at ``fwd[0]``; "resident" is splash's forward
+    with the written backward ``mha_bwd_resident_dq`` (its own blocks:
+    ``dkv`` is not read)."""
     kernel: str
     fwd: tuple
     dkv: tuple
@@ -122,6 +133,8 @@ class Geometry:
     def id(self) -> str:
         if self.kernel == "flash":
             return f"flash:{self.fwd[0]}"
+        if self.kernel == "resident":
+            return "splash:%d/%d/%d|resident" % self.fwd
         tail = "fused" if self.fused else "dq:%d/%d" % self.dq
         return "splash:%d/%d/%d|dkv:%d/%d/%d|%s" % (*self.fwd, *self.dkv, tail)
 
@@ -157,7 +170,7 @@ def attention(geo: Geometry, shape: dict, interpret: bool):
             q, k, v, causal=causal, sm_scale=scale, block_sizes=bs)
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
-    dq = ({} if geo.fused else
+    dq = ({} if geo.fused or geo.kernel == "resident" else
           {"block_q_dq": geo.dq[0], "block_kv_dq": geo.dq[1]})
     bs = sk.BlockSizes(
         block_q=geo.fwd[0], block_kv=geo.fwd[1], block_kv_compute=geo.fwd[2],
@@ -169,11 +182,17 @@ def attention(geo: Geometry, shape: dict, interpret: bool):
         else:
             one = (sm.CausalMask if causal else sm.FullMask)((t, t))
         if group == 1:
-            kernel = sk.make_splash_mha(
-                sm.MultiHeadMask([one] * heads), head_shards=1,
-                q_seq_shards=1, block_sizes=bs, interpret=interpret)
-            return lambda q, k, v: jax.vmap(kernel)(
-                (q * scale).astype(q.dtype), k, v)
+            def mha(residuals=False):
+                return sk.make_splash_mha(
+                    sm.MultiHeadMask([one] * heads), head_shards=1,
+                    q_seq_shards=1, block_sizes=bs, interpret=interpret,
+                    save_residuals=residuals)
+            if geo.kernel == "resident":
+                from horovod_tpu.parallel.flash_attention import _resident_dq
+                kernel = _resident_dq(mha(), mha(True), interpret)
+            else:
+                kernel = jax.vmap(mha())
+            return lambda q, k, v: kernel((q * scale).astype(q.dtype), k, v)
         kernel = sk.make_splash_mqa(
             sm.MultiHeadMask([one] * group), head_shards=1, q_seq_shards=1,
             block_sizes=bs, interpret=interpret)
@@ -336,7 +355,7 @@ def run_time(args) -> dict:
                 return None
             try:
                 fwd, step = programs(geo, shape, args.rehearse
-                                     and geo.kernel == "splash")
+                                     and geo.kernel != "flash")
                 rec = {"fwd_ms": ms_per_call(fwd, xs, calls)}
                 if stage != "fwd":
                     rec["step_ms"] = ms_per_call(step, xs, calls)
@@ -351,9 +370,10 @@ def run_time(args) -> dict:
             timed = [(ms, g) for ms, g in timed if ms is not None]
             return min(timed, key=lambda x: x[0])[1]
 
-        if args.geometry:       # these alone, no sweep
-            for text in args.geometry:
-                measure("final", parse(text))
+        if args.geometry or args.bwd_forms:     # these alone, no sweep
+            for geo in [parse(text) for text in args.geometry] + (
+                    bwd_forms(shape) if args.bwd_forms else []):
+                measure("final", geo)
             continue
         stages = stage_candidates(shape, blocks)
         fwd = best("fwd", stages["fwd"]).fwd
@@ -379,7 +399,7 @@ def run_errors(args) -> dict:
     for name in args.shapes:
         shape = shapes[name]
         ref = reference_grads(shape)
-        for geo in geometries(args):
+        for geo in geometries(args, shape):
             _, step = programs(geo, shape, args.rehearse)
             worst = {}
             for seed in range(args.seeds):
@@ -399,10 +419,32 @@ def run_errors(args) -> dict:
     return out
 
 
-def geometries(args) -> list:
-    """``--geometry``'s, then both backwards of each shape's winners from
-    ``--winners`` (the time stage's file)."""
+def chosen(shape: dict) -> tuple:
+    """The forward's blocks ``splash_geometry`` chooses for the shape."""
+    from horovod_tpu.parallel.flash_attention import splash_geometry
+    t = shape["t"]
+    g = splash_geometry(t, shape.get("head", HEAD), shape["causal"],
+                        shape["remat"])
+    return tuple(tuple(min(b, t) for b in blocks) for blocks in (
+        (g.block_q, g.block_kv, g.block_kv_compute),
+        (g.block_q_dkv, g.block_kv_dkv, g.block_kv_dkv_compute)))
+
+
+def bwd_forms(shape: dict) -> list:
+    """Splash's fused backward and the written one behind the chosen
+    forward."""
+    fwd, dkv = chosen(shape)
+    return [Geometry("splash", fwd, dkv, None),
+            Geometry("resident", fwd, dkv, None)]
+
+
+def geometries(args, shape: dict) -> list:
+    """``--geometry``'s, both backward forms with ``--bwd-forms``, then both
+    backwards of each shape's winners from ``--winners`` (the time stage's
+    file)."""
     found = [parse(text) for text in args.geometry]
+    if args.bwd_forms:
+        found += bwd_forms(shape)
     if args.winners:
         for won in json.load(open(args.winners)).values():
             if isinstance(won, dict) and "dkv_fused" in won:
@@ -415,11 +457,13 @@ def geometries(args) -> list:
 
 
 def parse(text: str) -> Geometry:
-    """``fwd_q/kv/c:dkv_q/kv/c:fused`` or ``...:dq_q/kv``."""
+    """``fwd_q/kv/c:dkv_q/kv/c:fused``, ``...:resident`` or
+    ``...:dq_q/kv``."""
     fwd, dkv, tail = text.split(":")
     three = lambda s: tuple(int(x) for x in s.split("/"))
-    return Geometry("splash", three(fwd), three(dkv),
-                    None if tail == "fused" else three(tail))
+    return Geometry("resident" if tail == "resident" else "splash",
+                    three(fwd), three(dkv),
+                    None if tail in ("fused", "resident") else three(tail))
 
 
 def main() -> None:
@@ -434,6 +478,9 @@ def main() -> None:
                          "q/kv/compute (may repeat): what errors compares; "
                          "time measures these alone, without its sweep")
     ap.add_argument("--winners", help="errors: the time stage's file")
+    ap.add_argument("--bwd-forms", action="store_true",
+                    help="time, errors: splash's fused backward and the "
+                         "written one, behind the chosen forward")
     ap.add_argument("--seeds", type=int, default=8)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
