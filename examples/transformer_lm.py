@@ -39,7 +39,8 @@ them: ``M`` a Mamba-2 state-space mixer alone (``--ssm-heads``,
 ``--ssm-head-dim``, ``--ssm-state``, ``--ssm-groups``, ``--ssm-chunk``,
 ``--conv-kernel``; the chunks of a row go to the gauge
 ``hvd_tpu_lm_scan_chunks``, the form of the scan to
-``hvd_tpu_lm_scan_kernel``), ``*`` attention alone, which rotates nothing, and
+``hvd_tpu_lm_scan_kernel``, that of its taps to ``hvd_tpu_lm_taps_kernel``),
+``*`` attention alone, which rotates nothing, and
 ``E`` the routed-expert FFN alone
 (``--pattern "MEMEM*E" --expert-ffn relu2 --shared-experts 1 --shared-ff
 ...`` is a model of the kind of
@@ -276,19 +277,25 @@ def main():
         by_mixer = collections.Counter(k.mixer for k in cfg.layers)
         for mixer, n in by_mixer.items():
             registry().gauge("hvd_tpu_lm_layers").set(n, mixer=mixer)
-        scan = None
+        scan = taps = None
         if cfg.has_mamba:
             registry().gauge("hvd_tpu_lm_scan_chunks").set(
                 args.seq // cfg.ssm_chunk, chunk=str(cfg.ssm_chunk))
             # which form of the scan the step runs on this backend: a
             # property of the shape a chip holds
-            from horovod_tpu.parallel.ssd import scan_form
+            from horovod_tpu.parallel.ssd import scan_form, taps_form
             rows = args.batch // mesh.shape["data"]
             scan = scan_form(
                 (rows, args.seq, cfg.ssm_heads, cfg.ssm_head_dim),
                 (rows, args.seq, cfg.ssm_groups, cfg.ssm_state),
                 cfg.ssm_chunk)
             registry().gauge("hvd_tpu_lm_scan_kernel").set(1, **scan)
+            inner = cfg.ssm_heads * cfg.ssm_head_dim
+            gn = cfg.ssm_groups * cfg.ssm_state
+            taps = taps_form(
+                (rows, args.seq, 2 * inner + 2 * gn + cfg.ssm_heads),
+                cfg.conv_kernel, (inner, gn, gn), inner)
+            registry().gauge("hvd_tpu_lm_taps_kernel").set(1, **taps)
         attn = None
         if cfg.attention == "flash" and mesh.shape["seq"] == 1:
             # what the step's local attention call runs on this backend, and
@@ -400,6 +407,7 @@ def main():
             report["attn_kernel"] = attn
         if scan:
             report["scan_kernel"] = scan
+            report["taps_kernel"] = taps
         if by_mixer:
             report["layers_by_mixer"] = dict(sorted(by_mixer.items()))
         if stats and "mtp_loss" in stats[0]:

@@ -43,7 +43,7 @@ from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
                             router_bias_update, row_sum_form, row_sum_rows,
                             topk_buffer_rows, topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
-from ..parallel.ssd import ssd_chunked
+from ..parallel.ssd import causal_conv_silu, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -1036,6 +1036,15 @@ def mamba_mix(x, lp, *, cfg: TransformerConfig,
     products in ``cfg.dtype`` with float32 accumulation; the taps, the
     softplus, the decays and the gated norm in float32.
 
+    The taps, their bias and the SiLU are
+    :func:`~horovod_tpu.parallel.ssd.causal_conv_silu` over the
+    projection's output as it is, and ``ssd.taps_form`` answers their form
+    from the backend and the shapes: ``"kernel"`` on a TPU where ``H P`` and
+    ``G N`` are whole blocks of 128 channels or more (Pallas kernels that
+    read ``xBC`` where it lies and write X, B and C apart, with a written
+    backward), ``"jnp"`` elsewhere (``jax.numpy`` with autodiff's
+    backward).
+
     Under ``tensor > 1`` it refuses: ``ssm_in`` is one matrix whose columns
     are z, X, B, C and dt side by side, and splitting heads and groups over
     the axis needs them apart.
@@ -1058,18 +1067,15 @@ def mamba_mix(x, lp, *, cfg: TransformerConfig,
     (bsz, t, _), hs, p = x.shape, cfg.ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
     inner, gn = hs * p, g * n
-    z, xbc, dt = jnp.split(
-        jnp.einsum("btd,de->bte", x, lp["ssm_in"].astype(dt_)),
-        [inner, 2 * inner + 2 * gn], axis=-1)
+    zxbcdt = jnp.einsum("btd,de->bte", x, lp["ssm_in"].astype(dt_))
+    z, dt = zxbcdt[..., :inner], zxbcdt[..., 2 * inner + 2 * gn:]
     with jax.named_scope(scopes.SSM_CONV):
-        taps = lp["ssm_conv_w"]
-        u = jnp.pad(xbc.astype(f32), ((0, 0), (taps.shape[0] - 1, 0), (0, 0)))
-        v = sum(taps[j] * u[:, j:j + t] for j in range(taps.shape[0])) \
-            + lp["ssm_conv_b"]
-        # (rounded here, once and under the scope: the scan's products take
-        # X, B and C in the compute dtype)
-        xs, b, c = jnp.split(jax.nn.silu(v).astype(dt_),
-                             [inner, inner + gn], axis=-1)
+        # (xBC read where it lies in the projection; rounded once, under
+        # the scope: the scan's products take X, B and C in the compute
+        # dtype)
+        xs, b, c = causal_conv_silu(zxbcdt, lp["ssm_conv_w"],
+                                    lp["ssm_conv_b"], (inner, gn, gn),
+                                    inner, dt_)
     y = ssd_chunked(
         xs.reshape(bsz, t, hs, p),
         jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"]),

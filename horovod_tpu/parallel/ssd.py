@@ -60,11 +60,24 @@ kernel), of ``dt`` and the log-decay (as columns, and the part of the
 log-decay's that sums over rows as rows) and of ``D`` (summed over the
 chunks in VMEM). The cumulative sum ``l`` of ``dt A`` is ``jax.numpy``
 outside the kernels with autodiff's backward: ``[B, T, H]`` float32, 4 MB.
+
+The taps in front of the scan, :func:`causal_conv_silu`: ``X``, ``B`` and
+``C`` are the SiLU of a causal depthwise convolution over T of ``xBC`` (the
+mixer's in-projection's columns beside the gate's), with a bias, in float32
+and rounded once. :func:`taps_form` answers its form: ``"jnp"``, autodiff's
+backward over shifted copies (the specification), or ``"kernel"``, two
+Pallas kernels under a ``jax.custom_vjp`` on a grid ``(channel blocks,
+rows, T blocks)``: the forward reads ``xBC``'s columns where they lie in the
+projection's output and writes ``X``, ``B``, ``C`` apart, the last 8 rows
+of a block carried in VMEM to the next; the backward walks T in reverse,
+computes ``v`` again, carries ``dv``'s first 8 rows back, and sums the
+taps' and the bias's cotangents in float32 in VMEM over every row.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import types
 
 import jax
@@ -485,3 +498,288 @@ def _scan_bwd(static, kept, g):
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd, optimize_remat=True)
+
+
+# -- the taps in front of the scan -------------------------------------------
+
+# Rows of T and channels a step of the taps' kernels takes: what
+# ``tools/scan_sweep.py --taps`` chose at the state-space cell's shapes
+TAPS_BLOCKS = (512, 512)
+
+
+def _block(size: int, largest: int, least: int) -> int:
+    """The largest of ``largest``, its half, its quarter ... down to
+    ``least`` that divides ``size``; 0 where none does."""
+    block = largest
+    while block >= least and size % block:
+        block //= 2
+    return block if block >= least else 0
+
+
+def taps_form(u_shape, taps: int, parts, start: int) -> dict:
+    """Which form :func:`causal_conv_silu` runs over the columns ``start :
+    start + sum(parts)`` of ``u [B, T, *]`` on this backend, as the labels
+    of the gauge ``hvd_tpu_lm_taps_kernel``: ``form`` (``"kernel"``: the
+    Pallas kernels; ``"jnp"``: ``jax.numpy``) and ``block_t``,
+    ``block_c``, the rows and channels one step of the kernels' grid takes
+    (0 for ``"jnp"``). A function of the backend and the shapes alone. The
+    kernels want a TPU; a block of channels, a multiple of 128, that divides
+    ``start`` and each part, so that each part is whole blocks read where it
+    lies; a block of T that divides T; and at most 8 taps (the rows carried
+    from block to block are one tile of 8)."""
+    t = u_shape[1]
+    width = math.gcd(start, *parts)
+    bt = _block(t, TAPS_BLOCKS[0], LANES)
+    bc = _block(width, TAPS_BLOCKS[1], LANES)
+    kernel = (jax.default_backend() == "tpu" and bt and bc
+              and 1 <= taps <= 8)
+    return {"form": "kernel" if kernel else "jnp",
+            "block_t": str(bt if kernel else 0),
+            "block_c": str(bc if kernel else 0)}
+
+
+def causal_conv_silu(u, w, b, parts, start: int, dtype):
+    """``silu(v)`` split into ``parts`` columns, where ``v_t = sum_j w_j
+    x_{t - K + 1 + j} + b`` is the causal depthwise convolution over T of
+    ``x``, the columns ``start : start + sum(parts)`` of ``u [B, T, *]``,
+    with the ``K`` taps ``w [K, C]`` and the bias ``b [C]``, ``x`` zero
+    before the row's start. The taps, the bias and the SiLU in float32, the
+    result rounded once to ``dtype``: the operands ``X``, ``B``, ``C`` of the
+    scan. :func:`taps_form` answers which form runs."""
+    form = taps_form(u.shape, w.shape[0], parts, start)
+    if form["form"] == "kernel":
+        return taps_kernels(u, w, b, parts, start, dtype,
+                            (int(form["block_t"]), int(form["block_c"])))
+    return _taps_numpy(u, w, b, parts, start, dtype)
+
+
+def _taps_numpy(u, w, b, parts, start: int, dtype):
+    """The ``"jnp"`` form, with autodiff's backward: the specification."""
+    t, taps = u.shape[1], w
+    xbc = u[..., start:start + sum(parts)]
+    u = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (taps.shape[0] - 1, 0),
+                                          (0, 0)))
+    v = sum(taps[j] * u[:, j:j + t] for j in range(taps.shape[0])) + b
+    ends = [sum(parts[:k + 1]) for k in range(len(parts) - 1)]
+    return tuple(jnp.split(jax.nn.silu(v).astype(dtype), ends, axis=-1))
+
+
+def taps_kernels(u, w, b, parts, start: int, dtype, blocks,
+                 interpret: bool = False):
+    """The ``"kernel"`` form of :func:`causal_conv_silu` at ``blocks``
+    ``(rows of T, channels)`` (``interpret``: through Pallas's interpreter,
+    for the CPU's tests)."""
+    static = (tuple(parts), start, jnp.dtype(dtype), tuple(blocks), interpret)
+    return _taps(static, u, w, b.reshape(1, -1))
+
+
+def _delayed(x, before, by: int):
+    """``x [R, C]`` moved ``by`` rows later (``by <= 8``), its first ``by``
+    rows the last of ``before [8, C]``, the rows just before ``x``."""
+    if by == 0:
+        return x
+    head = pltpu.roll(jnp.concatenate([before, x[:8]], axis=0), by, 0)[8:]
+    return jnp.concatenate([head, pltpu.roll(x, by, 0)[8:]], axis=0)
+
+
+def _advanced(x, after, by: int):
+    """``x [R, C]`` moved ``by`` rows earlier (``by <= 8``), its last
+    ``by`` rows the first of ``after [8, C]``, the rows just after ``x``."""
+    if by == 0:
+        return x
+    rows = x.shape[0]
+    tail = pltpu.roll(jnp.concatenate([x[rows - 8:], after], axis=0),
+                      16 - by, 0)[:8]
+    return jnp.concatenate([pltpu.roll(x, rows - by, 0)[:rows - 8], tail],
+                           axis=0)
+
+
+def _conv(x, before, w, b):
+    """``v`` of a block ``x [R, C]`` in float32 (the terms in the
+    ``"jnp"`` form's order, the bias last) and the delayed copies of ``x``
+    that the taps weigh."""
+    k = w.shape[0]
+    delayed = [_delayed(x, before, k - 1 - j) for j in range(k)]
+    v = w[0:1] * delayed[0]
+    for j in range(1, k):
+        v = v + w[j:j + 1] * delayed[j]
+    return v + b, delayed
+
+
+def _in_part(j, ends, part: int):
+    """Whether the channel block ``j`` is one of part ``part``'s
+    (``ends``: each part's last block + 1)."""
+    return (j >= (ends[part - 1] if part else 0)) & (j < ends[part])
+
+
+def _taps_forward_kernel(u_ref, w_ref, b_ref, *refs, ends):
+    """One block of one row: ``v``, the SiLU, the one rounding, written to
+    the output of the part the block's channels belong to. ``refs``: the
+    parts' outputs, then the scratch that carries the block's last 8 rows of
+    ``x`` to the next block of T."""
+    *outs, before_ref = refs
+    j, f32 = pl.program_id(0), jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        before_ref[...] = jnp.zeros_like(before_ref)
+
+    x = u_ref[0].astype(f32)
+    v, _ = _conv(x, before_ref[...], w_ref[...].astype(f32),
+                 b_ref[...].astype(f32))
+    before_ref[...] = x[x.shape[0] - 8:]
+    y = (v * jax.nn.sigmoid(v)).astype(outs[0].dtype)
+    for part, out in enumerate(outs):
+        @pl.when(_in_part(j, ends, part))
+        def _(out=out):
+            out[0] = y
+
+
+def _taps_backward_kernel(u_ref, tail_ref, w_ref, b_ref, *refs, ends):
+    """One block of one row, T last to first: ``v`` again, ``dv = g
+    silu'(v)`` from the cotangent of the block's part, ``dx``, and the
+    block's terms of ``dw`` and ``db`` added to float32 sums in VMEM (8
+    rows a tap, summed over them once, at the channels' last step).
+    ``refs``: the parts' cotangents; ``dx``, ``[dw; db]``; the scratch that
+    carries the first 8 rows of ``dv`` to the block before, and the sums."""
+    *gs, dx_ref, dwb_ref, after_ref, sums_ref = refs
+    j, r, i = (pl.program_id(axis) for axis in range(3))
+    last_r, last_i = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    f32 = jnp.float32
+
+    @pl.when(i == 0)
+    def _():
+        after_ref[...] = jnp.zeros_like(after_ref)
+
+    @pl.when((i == 0) & (r == 0))
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    x = u_ref[0].astype(f32)
+    # the 8 rows before the block; none before a row's first block
+    before = jnp.where(i == last_i, 0.0, tail_ref[0].astype(f32)[8:])
+    w = w_ref[...].astype(f32)
+    v, delayed = _conv(x, before, w, b_ref[...].astype(f32))
+    s = jax.nn.sigmoid(v)
+    slope = s * (1.0 + v * (1.0 - s))
+    k, rows = w.shape[0], x.shape[0]
+
+    def by_eights(a):
+        return jnp.sum(a.reshape(rows // 8, 8, a.shape[1]), axis=0)
+
+    for part, g_ref in enumerate(gs):
+        @pl.when(_in_part(j, ends, part))
+        def _(g_ref=g_ref):
+            dv = g_ref[0].astype(f32) * slope
+            after = after_ref[...]
+            dx = w[k - 1:k] * dv
+            for tap in range(k - 2, -1, -1):
+                dx = dx + w[tap:tap + 1] * _advanced(dv, after, k - 1 - tap)
+            dx_ref[0] = dx.astype(dx_ref.dtype)
+            after_ref[...] = dv[:8]
+            for tap in range(k):
+                sums_ref[tap] += by_eights(dv * delayed[tap])
+            sums_ref[k] += by_eights(dv)
+
+    @pl.when((i == last_i) & (r == last_r))
+    def _():
+        dwb_ref[...] = jnp.sum(sums_ref[...], axis=1)
+
+
+# Every axis in its order: a part's output is held while the other parts'
+# channel blocks run, and the sums of dw and db over the rows. 64 MiB of
+# VMEM: what the sweep's largest blocks need (512 x 512 needs under 16).
+_TAPS_SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=64 * 2 ** 20)
+
+
+def _taps_specs(u_shape, static, backward: bool):
+    """The grid ``(channel blocks, rows, T blocks)``, T last and
+    sequential (in reverse for the backward), and the blocks:
+    ``(grid, ends, specs)``. A part's output (or cotangent) is visited only
+    by its own channel blocks; before them its index stays on the block it
+    will take first and after them on the one it took last, so that nothing
+    moves it in between."""
+    parts, start, _, (bt, bc), _ = static
+    bsz, t = u_shape[:2]
+    nt, first = t // bt, start // bc
+    ends = [sum(parts[:k + 1]) // bc for k in range(len(parts))]
+
+    def at(i):
+        return nt - 1 - i if backward else i
+
+    def part(k):
+        lo, hi = (ends[k - 1] if k else 0), ends[k]
+
+        def index(j, r, i):
+            inside, before = (j >= lo) & (j < hi), j < lo
+            return (jnp.where(inside, r, jnp.where(before, 0, bsz - 1)),
+                    jnp.where(inside, at(i), at(jnp.where(before, 0, nt - 1))),
+                    jnp.clip(j - lo, 0, hi - lo - 1))
+        return pl.BlockSpec((1, bt, bc), index)
+
+    specs = {
+        "u": pl.BlockSpec((1, bt, bc), lambda j, r, i: (r, at(i), first + j)),
+        # the 16 rows before the block (the first block's own where none)
+        "tail": pl.BlockSpec((1, 16, bc), lambda j, r, i: (
+            r, jnp.maximum(at(i) * (bt // 16) - 1, 0), first + j)),
+        "w": lambda taps: pl.BlockSpec((taps, bc), lambda j, r, i: (0, j)),
+        "parts": [part(k) for k in range(len(parts))],
+        "dx": pl.BlockSpec((1, bt, bc), lambda j, r, i: (r, at(i), j))}
+    return (ends[-1], bsz, nt), ends, specs
+
+
+def _taps_forward(static, u, w, b):
+    parts, _, dtype, (bt, bc), interpret = static
+    grid, ends, specs = _taps_specs(u.shape, static, backward=False)
+    bsz, t = u.shape[:2]
+    return tuple(pl.pallas_call(
+        functools.partial(_taps_forward_kernel, ends=ends), grid=grid,
+        in_specs=[specs["u"], specs["w"](w.shape[0]), specs["w"](1)],
+        out_specs=specs["parts"],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, p), dtype) for p in parts],
+        scratch_shapes=[pltpu.VMEM((8, bc), jnp.float32)],
+        compiler_params=_TAPS_SEQUENTIAL, interpret=interpret,
+        name="ssm_taps_fwd",
+    )(u, w, b))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _taps(static, u, w, b):
+    """The taps over ``u [B, T, *]``'s columns from ``start``, the taps
+    ``w [K, C]`` and the bias ``b [1, C]``; ``static``: ``(parts, start,
+    dtype, (block_t, block_c), interpret)``. What the backward keeps is the
+    three operands."""
+    return _taps_forward(static, u, w, b)
+
+
+def _taps_fwd(static, u, w, b):
+    return _taps_forward(static, u, w, b), (u, w, b)
+
+
+def _taps_bwd(static, kept, gs):
+    parts, start, _, (_, bc), interpret = static
+    u, w, b = kept
+    grid, ends, specs = _taps_specs(u.shape, static, backward=True)
+    bsz, t = u.shape[:2]
+    k, width = w.shape[0], sum(parts)
+    dx, dwb = pl.pallas_call(
+        functools.partial(_taps_backward_kernel, ends=ends), grid=grid,
+        in_specs=[specs["u"], specs["tail"], specs["w"](k), specs["w"](1),
+                  *specs["parts"]],
+        out_specs=[specs["dx"], specs["w"](k + 1)],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, width), u.dtype),
+                   jax.ShapeDtypeStruct((k + 1, width), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((8, bc), jnp.float32),
+                        pltpu.VMEM((k + 1, 8, bc), jnp.float32)],
+        compiler_params=_TAPS_SEQUENTIAL, interpret=interpret,
+        name="ssm_taps_bwd",
+    )(u, u, w, b, *gs)
+    # (the cotangent of the columns the taps do not read is zero; XLA adds
+    # it to the cotangents of the other slices of ``u`` in one pass)
+    du = jnp.pad(dx, ((0, 0), (0, 0), (start, u.shape[2] - start - width)))
+    return du, dwb[:k].astype(w.dtype), dwb[k:].astype(b.dtype)
+
+
+_taps.defvjp(_taps_fwd, _taps_bwd)

@@ -169,8 +169,8 @@ def test_transformer_lm_example_state_space_pattern():
     """A state-space/attention sparse-expert decoder of layers of ONE
     sublayer from the example's flags (the kind of
     benchmark/configs/nemotron-3-nano-30b-a3b.json): the layers by kind of
-    mixer and the form of the scan (off the TPU the jax.numpy one) are
-    reported with the loss."""
+    mixer and the forms of the scan and of its taps (off the TPU the
+    jax.numpy ones) are reported with the loss."""
     r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
               "--mesh", "data=2", "--d-model", "32", "--n-layers", "7",
               "--n-heads", "4", "--kv-heads", "2", "--vocab", "128",
@@ -188,6 +188,8 @@ def test_transformer_lm_example_state_space_pattern():
         in r.stdout, r.stdout
     assert ("'scan_kernel': {'form': 'chunked', 'chunk': '16', "
             "'heads_per_block': '0'}") in r.stdout, r.stdout
+    assert ("'taps_kernel': {'form': 'jnp', 'block_t': '0', "
+            "'block_c': '0'}") in r.stdout, r.stdout
 
 
 def test_transformer_lm_example_looped():

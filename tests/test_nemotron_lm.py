@@ -11,6 +11,7 @@ kinds leave alone.
 """
 
 import dataclasses
+import functools
 import hashlib
 import os
 import sys
@@ -529,6 +530,202 @@ def test_the_scan_runs_the_form_its_shapes_give(monkeypatch):
     assert seen == [chunk]
 
 
+# -- the taps' kernels, interpreted: 2 rows, 3 blocks of T, X, B and C in 4
+# blocks of channels read from the middle of a wider operand ----------------
+
+TAPS_PARTS, TAPS_START = (256, 128, 128), 128
+
+
+def _taps_inputs(dtype, rows=2, t=768, seed=0):
+    """The operand ``[rows, T, 128 + 512 + 64]`` (X, B, C from column 128,
+    the last 64 columns nobody reads), the taps and the bias drawn as
+    ``init_params`` draws them, and a cotangent for each part."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    width = sum(TAPS_PARTS)
+    u = jax.random.normal(ks[0], (rows, t, TAPS_START + width + 64))
+    w = jax.random.normal(ks[1], (4, width)) * 0.5
+    b = jax.random.uniform(ks[2], (width,), jnp.float32, -0.5, 0.5)
+    gs = [jax.random.normal(k, (rows, t, p)).astype(dtype)
+          for k, p in zip(jax.random.split(ks[3], 3), TAPS_PARTS)]
+    return (u.astype(dtype), w, b), gs
+
+
+def _taps_interpreted(dtype, blocks):
+    return lambda u, w, b: ssd.taps_kernels(
+        u, w, b, TAPS_PARTS, TAPS_START, dtype, blocks, interpret=True)
+
+
+def _taps_numpy(dtype):
+    return lambda u, w, b: ssd._taps_numpy(u, w, b, TAPS_PARTS, TAPS_START,
+                                           dtype)
+
+
+@pytest.mark.parametrize("blocks", [(256, 128), (128, 128)],
+                         ids=["3-blocks-of-t", "6-blocks-of-t"])
+@pytest.mark.parametrize("dtype, du_tol", [(jnp.float32, 1e-6),
+                                           (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_taps_kernels_against_the_jnp_form(blocks, dtype, du_tol):
+    """The parts, and the cotangents of the operand, the taps and the bias.
+    The sums are the ``"jnp"`` form's in float32 with one rounding of each
+    result: the parts to the bit, the cotangents to their order of sums (in
+    bfloat16 ``du`` rounds once on each side: an element may land a unit of
+    the last place apart)."""
+    args, gs = _taps_inputs(dtype)
+
+    def both(form):
+        def weighed(*a):
+            return sum(jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
+                       for o, g in zip(form(*a), gs))
+        return jax.jit(lambda *a: (form(*a), jax.grad(weighed, range(3))(
+            *a)))
+
+    (got, got_d), (want, want_d) = (both(f)(*args) for f in (
+        _taps_interpreted(dtype, blocks), _taps_numpy(dtype)))
+    for got_, want_ in zip(got, want):
+        assert got_.dtype == want_.dtype == dtype
+        assert np.array_equal(np.asarray(got_), np.asarray(want_))
+    for got_, want_, tol in zip(got_d, want_d, (du_tol, 1e-6, 1e-6)):
+        assert got_.shape == want_.shape and got_.dtype == want_.dtype
+        _close(got_.astype(jnp.float32), want_.astype(jnp.float32), tol)
+    # nothing reaches the columns the taps do not read
+    du = np.asarray(got_d[0])
+    assert not du[..., :TAPS_START].any() and not du[..., -64:].any()
+
+
+def test_the_taps_kernels_are_causal_and_each_row_starts_from_zeros():
+    """A row's outputs before a position do not see the row from there on;
+    a row's first positions see nothing of the row before it (its first
+    block starts from zeros, not from the last rows the kernel carried)."""
+    (u, w, b), _ = _taps_inputs(jnp.float32)
+    run = jax.jit(_taps_interpreted(jnp.float32, (256, 128)))
+    whole = np.concatenate([np.asarray(p) for p in run(u, w, b)], axis=-1)
+    later = np.concatenate([np.asarray(p) for p in run(
+        u.at[:, 300:].set(7.0), w, b)], axis=-1)
+    assert np.array_equal(whole[:, :300], later[:, :300])
+    assert not np.array_equal(whole[:, 300:], later[:, 300:])
+    other_row = np.concatenate([np.asarray(p) for p in run(
+        u.at[0].set(-3.0), w, b)], axis=-1)
+    assert np.array_equal(whole[1], other_row[1])
+    # the first positions of a row are the taps over zeros before it
+    first = jax.nn.silu(w[3] * u[:, 0, TAPS_START:TAPS_START + 512] + b)
+    _close(jnp.asarray(whole[:, 0]), first, 1e-6)
+
+
+def test_the_taps_kernels_under_checkpoint_run_the_forward_twice():
+    """``jax.checkpoint`` around the taps and what reads their outputs: the
+    forward kernel in the forward pass and again in the backward (which
+    reads the outputs), then the written backward, which keeps nothing but
+    the operands; the gradients are the same bits."""
+    args, gs = _taps_inputs(jnp.bfloat16)
+
+    def loss(*a):
+        return sum(jnp.sum(jnp.square(o.astype(jnp.float32))
+                           * g.astype(jnp.float32))
+                   for o, g in zip(_taps_interpreted(jnp.bfloat16, (256, 128))(
+                       *a), gs))
+
+    def kernels(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"])
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                found += kernels(inner)
+        return found
+
+    again = jax.value_and_grad(jax.checkpoint(loss), range(3))
+    assert kernels(jax.make_jaxpr(again)(*args).jaxpr) == [
+        "ssm_taps_fwd", "ssm_taps_fwd", "ssm_taps_bwd"]
+    for got_, want_ in zip(jax.tree_util.tree_leaves(jax.jit(again)(*args)),
+                           jax.tree_util.tree_leaves(jax.jit(
+                               jax.value_and_grad(loss, range(3)))(*args))):
+        assert np.array_equal(np.asarray(got_), np.asarray(want_))
+
+
+# the in-projection's output, taps, X, B and C's widths, where X starts
+TAPS_CELL = ((2, 8192, 10304), 4, (4096, 1024, 1024), 4096)
+
+
+@pytest.mark.parametrize("backend, shape, taps, parts, start, form, blocks", [
+    ("cpu", *TAPS_CELL, "jnp", (0, 0)),
+    ("tpu", *TAPS_CELL, "kernel", ssd.TAPS_BLOCKS),
+    ("tpu", (1, 8192, 10304), 4, (4096, 1024, 1024), 4096, "kernel",
+     ssd.TAPS_BLOCKS),
+    ("tpu", (2, 32, 192), 4, (64, 32, 32), 64, "jnp", (0, 0)),
+    ("tpu", (2, 8192, 10304), 4, (4096, 1024, 1024), 4032, "jnp", (0, 0)),
+    ("tpu", (2, 8064, 10304), 4, (4096, 1024, 1024), 4096, "kernel",
+     (128, ssd.TAPS_BLOCKS[1])),
+    ("tpu", (2, 8000, 10304), 4, (4096, 1024, 1024), 4096, "jnp", (0, 0)),
+    ("tpu", (2, 8192, 10304), 9, (4096, 1024, 1024), 4096, "jnp", (0, 0)),
+    ("tpu", (2, 8192, 2688), 4, (1024, 128, 128), 1024, "kernel",
+     (ssd.TAPS_BLOCKS[0], 128)),
+    ("gpu", *TAPS_CELL, "jnp", (0, 0)),
+], ids=["the-cpu", "the-cell", "the-cells-checks-one-row", "the-rehearsal",
+        "a-part-off-the-lanes", "t-of-8064", "t-of-8000", "nine-taps", "parts-of-128",
+        "a-gpu"])
+def test_the_taps_form_is_a_function_of_the_backend_and_the_shapes(
+        monkeypatch, backend, shape, taps, parts, start, form, blocks):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ssd.taps_form(shape, taps, parts, start) == {
+        "form": form, "block_t": str(blocks[0]), "block_c": str(blocks[1])}
+
+
+def test_the_mixer_runs_the_taps_form_its_shapes_give(monkeypatch):
+    """``mamba_mix`` asks ``taps_form`` and nothing else: on the CPU and at
+    the rehearsal's widths the ``"jnp"`` form; told "kernel", the kernels
+    with the projection's output as it is, X, B, C from column ``H P``."""
+    one = dataclasses.replace(SMALL, layers=(M,), n_layers=1)
+    lp = {k: v[0] for k, v in _params(one)["mamba_mixers"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, 64))
+    text = jax.jit(lambda x_: tfm.mamba_mix(x_, lp, cfg=one)).lower(
+        x).as_text(debug_info=True)
+    assert "ssm_conv/split" in text and "pallas_call" not in text
+    seen = []
+
+    def kernels(u, w, b, parts, start, dtype, blocks):
+        seen.append((u.shape, parts, start, dtype, blocks))
+        return ssd._taps_numpy(u, w, b, parts, start, dtype)
+
+    monkeypatch.setattr(ssd, "taps_form", lambda *shapes: {
+        "form": "kernel", "block_t": "16", "block_c": "32"})
+    monkeypatch.setattr(ssd, "taps_kernels", kernels)
+    tfm.mamba_mix(x, lp, cfg=one)
+    inner, gn = 64, 32
+    assert seen == [((2, 32, 2 * inner + 2 * gn + 8), (inner, gn, gn), inner,
+                     jnp.dtype(jnp.float32), (16, 32))]
+
+
+def test_the_mixer_with_the_taps_kernels_against_the_jnp_form(monkeypatch):
+    """A mixer whose X, B and C tile (2 heads of 64, one group of state 128:
+    X 128, B and C 128 each from column 128), its output and the gradients
+    of its leaves with the taps' kernels interpreted against the ``"jnp"``
+    form."""
+    cfg = dataclasses.replace(
+        SMALL, layers=(M,), n_layers=1, ssm_heads=2, ssm_head_dim=64,
+        ssm_groups=1, ssm_state=128, ssm_chunk=64, max_seq=256)
+    lp = {k: v[0] for k, v in _params(cfg)["mamba_mixers"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 256, 64))
+    g = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def run():
+        return jax.jit(lambda x_, lp_: (
+            tfm.mamba_mix(x_, lp_, cfg=cfg), jax.grad(
+                lambda x__, lp__: jnp.sum(tfm.mamba_mix(x__, lp__, cfg=cfg)
+                                          * g), (0, 1))(x_, lp_)))(x, lp)
+
+    with jax.default_matmul_precision("highest"):
+        want = run()
+        monkeypatch.setattr(ssd, "taps_form", lambda *shapes: {
+            "form": "kernel", "block_t": "128", "block_c": "128"})
+        monkeypatch.setattr(ssd, "taps_kernels", functools.partial(
+            ssd.taps_kernels, interpret=True))
+        got = run()
+    for got_, want_ in zip(jax.tree_util.tree_leaves(got),
+                           jax.tree_util.tree_leaves(want)):
+        _close(got_, want_, 1e-5)
+
+
 def test_the_mixer_against_the_reference(reference):
     one = dataclasses.replace(SMALL, layers=(M,), n_layers=1)
     lp = {k: v[0] for k, v in _params(one)["mamba_mixers"].items()}
@@ -838,7 +1035,7 @@ def test_the_examples_gauges_are_declared():
     with open(example) as fh:
         text = fh.read()
     for gauge in ("hvd_tpu_lm_layers", "hvd_tpu_lm_scan_chunks",
-                  "hvd_tpu_lm_scan_kernel"):
+                  "hvd_tpu_lm_scan_kernel", "hvd_tpu_lm_taps_kernel"):
         assert METRIC_SPECS[gauge][0] == "gauge"
         assert '"%s"' % gauge in text
     assert "mamba2" in METRIC_SPECS["hvd_tpu_lm_layers"][1]

@@ -430,7 +430,8 @@ def test_the_state_space_cells_step_and_the_memory_it_states(
     recomputation is real: ``remat_barrier``) and its fused backward once;
     the relu2 experts' grouped products through megablox at 1856, a width
     no multiple of 128 divides, with no ragged-dot fallback; the scan's
-    kernels in place of a loop over 64 chunks; and the compiler's
+    kernels in place of a loop over 64 chunks; the taps' kernels in place of
+    autodiff's float32 passes over xBC; and the compiler's
     ``memory_analysis``, under the 14.4 GB (90% of the chip) a cell may
     reach and under what ``benchmark/configs/nemotron-3-nano-30b-a3b.json``
     states of the parent's program."""
@@ -495,22 +496,40 @@ def test_the_state_space_cells_step_and_the_memory_it_states(
     assert not re.search(r"while\(.*op_name=\"[^\"]*ssm_scan", text)
     assert not re.search(r"f32\[[\d,]*128,128\][^\n]*op_name=\"[^\"]*"
                          r"ssm_scan", text)
+    # the taps run as their kernels (parallel/ssd.py taps_form), under
+    # ssm_conv: each layer's forward twice (the recomputation is real: the
+    # scan's backward reads X, B and C) and the written backward once; no
+    # float32 copy of xBC reaches HBM, and no array of the mixer as wide as
+    # a tile of lanes is laid out with T minor (what is: the gated norm's
+    # [2, 8192, 8] statistics and dt's [2, 8192, 64], narrower than a tile)
+    taps = re.findall(r"%\w*(ssm_taps_(?:fwd|bwd))\w*?(?:\.\d+)? = [^\n]*"
+                      r"custom-call\([^\n]*op_name=\"([^\"]*)\"", text)
+    assert sorted(name for name, _ in taps) == [
+        "ssm_taps_bwd"] * 3 + ["ssm_taps_fwd"] * 6, taps
+    for _, op_name in taps:
+        assert "/mamba_mixer/ssm_conv/" in op_name, op_name
+    assert not re.search(r"f32\[2,8192,6144\][^\n]*op_name=\"[^\"]*"
+                         r"ssm_conv", text)
+    t_minor = {width for width, line in re.findall(
+        r"\[2,8192,(\d+)\]\{1,2,0([^\n]*)", text) if "mamba_mixer" in line}
+    assert t_minor and max(int(w) for w in t_minor) < 128, t_minor
     found = compiled.memory_analysis()
     stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
     live = found.argument_size_in_bytes + found.temp_size_in_bytes
     assert live < 14.4e9
     assert stated["parameters"] == 528_093_120 == sum(
         int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
-    # the JSON's statement is the PARENT's program (PR 39, the scan in
-    # jax.numpy: 12.511 GB) and a benchmark PR's to correct (PERF.md section
-    # 7); with the kernels the scan's [128, 128] temporaries and autodiff's
-    # residuals of them are gone
+    # the JSON states the program before the scan's kernels (the scan in
+    # jax.numpy: 12.511 GB), a benchmark change's to correct (PERF.md
+    # section 7); with the scan's kernels its [128, 128] temporaries and
+    # autodiff's residuals of them went (10.864 GB), with the taps' kernels
+    # the float32 copies of xBC that autodiff's backward of the taps wrote
     assert stated["live_gb"] == 12.511
-    assert live / 1e9 == pytest.approx(LIVE_GB_WITH_THE_SCANS_KERNELS,
+    assert live / 1e9 == pytest.approx(LIVE_GB_WITH_THE_TAPS_KERNELS,
                                        rel=0.02)
 
 
-LIVE_GB_WITH_THE_SCANS_KERNELS = 10.864
+LIVE_GB_WITH_THE_TAPS_KERNELS = 10.056
 
 
 def test_the_latent_attention_cells_step_and_the_memory_it_states(
@@ -677,3 +696,32 @@ def test_the_scan_call_alone_fits(topo, pallas_branch, rows, remat):
                        text)
     assert sorted(calls) == ["ssd_scan_bwd"] + ["ssd_scan_fwd"] * remat + [
         "ssd_scan_fwd_states"], calls
+
+
+@pytest.mark.parametrize("rows", [2, 1], ids=["the-cells-rows",
+                                              "the-checks-one-row"])
+def test_the_taps_call_alone_fits(topo, pallas_branch, rows):
+    """Forward and backward of ``causal_conv_silu`` by itself over the
+    state-space cell's in-projection (8,192 tokens, 10,304 columns, X, B
+    and C 4096, 1024, 1024 from column 4096, 4 taps, bfloat16) at the blocks
+    ``taps_form`` answers: whether they fit the chip's VMEM is the
+    compiler's word."""
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.parallel import ssd
+    shape, parts = (rows, 8192, 10304), (4096, 1024, 1024)
+    assert ssd.taps_form(shape, 4, parts, 4096)["form"] == "kernel"
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def taps(u, w, b):
+        return sum(jnp.sum(jnp.square(o.astype(jnp.float32))) for o in
+                   ssd.causal_conv_silu(u, w, b, parts, 4096, jnp.bfloat16))
+
+    text = jax.jit(jax.value_and_grad(taps, range(3))).lower(
+        of(shape, jnp.bfloat16), of((4, 6144), jnp.float32),
+        of((6144,), jnp.float32)).compile().as_text()
+    calls = re.findall(r"%\w*(ssm_taps_(?:fwd|bwd))\w*?(?:\.\d+)? = [^\n]*"
+                       r"custom-call\(", text)
+    assert sorted(calls) == ["ssm_taps_bwd", "ssm_taps_fwd"], calls

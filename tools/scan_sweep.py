@@ -26,11 +26,23 @@ The shape is the state-space cell's: ``--rows`` 2 of ``--tokens`` 8,192,
 ``--heads`` 64 of ``--head`` 64 over ``--groups`` 8, state ``--state`` 128,
 chunk ``--chunk`` 128, bfloat16 operands. ``--rehearse``: a tiny shape with
 the kernels interpreted on the CPU: a test of the script.
+
+``--taps``: instead, the taps in front of the scan (``causal_conv_silu``,
+``taps_form``): the ``jax.numpy`` form and the kernels at each ``(rows of T,
+channels)`` block of ``--blocks``, timed forward and forward + backward over
+the cell's in-projection but dt, ``[2, 8192, 10240]`` in bfloat16 (X, B, C:
+4096, 1024, 1024 columns from 4096; 4 taps), and each held against the
+``jax.numpy`` form on the same operands (the outputs and the cotangents of
+the projection, the taps and the bias). The fastest block is the answer
+``ssd.TAPS_BLOCKS`` records::
+
+    python tools/scan_sweep.py --taps --out <file>.json
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -163,6 +175,59 @@ DEFECTS = {"none": None, "state_in_bfloat16": state_in_bfloat16,
            "chunked_form_apart": lambda ref: chunked_form(ref, apart=True)}
 
 
+def taps_sweep(args) -> dict:
+    """The taps alone: ms and relative L2 against the ``jax.numpy`` form,
+    a form and block at a time."""
+    parts, start, taps = (4096, 1024, 1024), 4096, 4
+    rows, tokens = args.rows, args.tokens
+    if args.rehearse:
+        parts, start, rows, tokens = (256, 128, 128), 128, 2, 768
+    # (the step's projection has dt's 64 columns after C; as a jitted
+    # function's argument a width no multiple of 128 is copied before the
+    # call, which in the step the projection's own output is not)
+    width, bf16 = start + sum(parts), jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(args.seed), 6)
+    # drawn as the cell's are in scale: the taps as _norm_init draws them,
+    # the bias uniform in +-taps ** -0.5
+    xs = (jax.random.normal(ks[0], (rows, tokens, width)).astype(bf16),
+          jax.random.normal(ks[1], (taps, sum(parts))) * taps ** -0.5,
+          jax.random.uniform(ks[2], (sum(parts),), jnp.float32, -1.0, 1.0)
+          * taps ** -0.5)
+    gs = [jax.random.normal(k, (rows, tokens, p)).astype(bf16)
+          for k, p in zip(jax.random.split(ks[3], 3), parts)]
+
+    def passes(form):
+        def weighed(*xs_):
+            return sum(jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
+                       for o, g in zip(form(*xs_), gs))
+        return {"forward": jax.jit(form),
+                "both": jax.jit(jax.grad(weighed, range(3)))}
+
+    forms = {"jnp": lambda *xs_: ssd._taps_numpy(*xs_, parts, start, bf16)}
+    for bt, bc in args.blocks:
+        forms["%dx%d" % (bt, bc)] = functools.partial(
+            lambda *xs_, blocks: ssd.taps_kernels(
+                *xs_, parts, start, bf16, blocks, interpret=args.rehearse),
+            blocks=(bt, bc))
+    report = {"device": jax.devices()[0].device_kind, "rows": rows,
+              "tokens": tokens, "parts": parts, "start": start,
+              "form_here": ssd.taps_form((rows, tokens, width), taps, parts,
+                                         start), "ms": {}, "off": {}}
+    names = ("X", "B", "C", "du", "dw", "db")
+    want = None
+    for label, form in forms.items():
+        runs = passes(form)
+        report["ms"][label] = {k: timed(fn, *xs, repeats=args.repeats)
+                               for k, fn in runs.items()}
+        got = (*runs["forward"](*xs), *runs["both"](*xs))
+        want = want or got
+        report["off"][label] = {k: off(g_, w_) for k, g_, w_ in
+                                zip(names, got, want)}
+        print(label, json.dumps({k: report[k][label] for k in ("ms", "off")}),
+              flush=True)
+    return report
+
+
 def planted(seeds, rehearse: bool, out: str) -> int:
     """The cell's check of the scan under each of ``DEFECTS``."""
     bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
@@ -176,11 +241,7 @@ def planted(seeds, rehearse: bool, out: str) -> int:
             found = nemotron3_defects.scan_readings(seed, defect, rehearse)
             report["readings"]["%s seed %d" % (name, seed)] = found
             nemotron3_defects.say("scan %s seed %d" % (name, seed), found)
-    if out:
-        os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
-        with open(out, "w") as fh:
-            json.dump(report, fh, indent=1)
-    return 0
+    return write(report, out)
 
 
 def main() -> int:
@@ -192,7 +253,12 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default="")
     ap.add_argument("--defects", default="")
+    ap.add_argument("--taps", action="store_true")
+    ap.add_argument("--blocks", nargs="*", default=[
+        "256x512", "512x512", "1024x512", "2048x512", "512x256", "1024x256",
+        "256x1024", "512x1024", "1024x1024"])
     args = ap.parse_args()
+    args.blocks = [tuple(int(v) for v in b.split("x")) for b in args.blocks]
     if not args.rehearse and jax.default_backend() != "tpu":
         print("no TPU here: --rehearse runs the script on the CPU")
         return 2
@@ -202,6 +268,10 @@ def main() -> int:
     if args.rehearse:
         args.rows, args.tokens, args.heads, args.groups = 1, 256, 4, 2
         args.repeats = 1
+    if args.taps:
+        if args.rehearse:
+            args.blocks = [(256, 128)]
+        return write(taps_sweep(args), args.out)
 
     def kernels(*xs):
         return ssd.ssd_kernels(*xs, args.chunk, interpret=args.rehearse)
@@ -237,9 +307,13 @@ def main() -> int:
                                 zip(names, got, want)}
         print(label, json.dumps({k: report[k][label] for k in ("ms", "off")}),
               flush=True)
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-        with open(args.out, "w") as fh:
+    return write(report, args.out)
+
+
+def write(report: dict, out: str) -> int:
+    if out:
+        os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
+        with open(out, "w") as fh:
             json.dump(report, fh, indent=1)
     return 0
 
