@@ -655,7 +655,7 @@ def child_kernels(args) -> None:
     # attention: [B, H, T, D] at the flagship head size (toy in rehearsal)
     d = 16 if args.rehearse else 128
 
-    def attention(t, under_remat, ring):
+    def attention(t, ring, recomputed=False):
         q, k, v, w = (jax.random.normal(kk, (1, 2, t, d), jnp.float32)
                       .astype(jnp.bfloat16) * 0.5
                       for kk in jax.random.split(key, 4))
@@ -679,9 +679,8 @@ def child_kernels(args) -> None:
         else:
             def fn(q, k, v):
                 return flash_attention_local(q, k, v, causal=True,
-                                             layout="bhtk",
-                                             under_remat=under_remat)
-            if under_remat:     # the backward runs the forward again
+                                             layout="bhtk")
+            if recomputed:      # the backward runs the forward again
                 fn = jax.checkpoint(fn)
 
         def loss(f):
@@ -699,23 +698,20 @@ def child_kernels(args) -> None:
 
     t = 128 if args.rehearse else 2048
     if not args.rehearse:
-        for under_remat in (False, True):
-            took = attention_kernel((1, 2, t, d), (1, 2, t, d), True,
-                                    under_remat)
-            if (took["kernel"], took["fused_bwd"]) != ("splash", "1"):
-                raise AssertionError(
-                    "the flagship shape no longer takes splash with the "
-                    f"fused backward (under_remat={under_remat}): {took}")
-    check(f"splash attention fwd+bwd T{t}",
-          lambda: attention(t, False, False))
+        took = attention_kernel((1, 2, t, d), (1, 2, t, d), True)
+        if (took["kernel"], took["fused_bwd"]) != ("splash", "1"):
+            raise AssertionError(
+                "the flagship shape no longer takes splash with the fused "
+                f"backward: {took}")
+    check(f"splash attention fwd+bwd T{t}", lambda: attention(t, False))
     check(f"splash attention under remat fwd+bwd T{t}",
-          lambda: attention(t, True, False))
+          lambda: attention(t, False, recomputed=True))
     # a length splash does not take (not a multiple of 1024): the stock
     # flash kernel at its own 128 blocks
     check(f"flash attention fwd+bwd T{3 * t // 4}",
-          lambda: attention(3 * t // 4, False, False))
+          lambda: attention(3 * t // 4, False))
     check(f"ring segment kernels fwd+bwd T{t // 2}",
-          lambda: attention(t // 2, False, True))
+          lambda: attention(t // 2, True))
     if failed:
         log(f"kernels FAILED: {failed}")
         sys.exit(1)

@@ -278,7 +278,7 @@ def main():
         for mixer, n in by_mixer.items():
             registry().gauge("hvd_tpu_lm_layers").set(n, mixer=mixer)
         scan = taps = None
-        if cfg.has_mamba:
+        if by_mixer["mamba2"]:
             registry().gauge("hvd_tpu_lm_scan_chunks").set(
                 args.seq // cfg.ssm_chunk, chunk=str(cfg.ssm_chunk))
             # which form of the scan the step runs on this backend: a
@@ -312,14 +312,12 @@ def main():
                        if k.mixer == "attention"} if cfg.layers else {0}
             for window in sorted(windows):
                 attn = attention_kernel(local, local_kv, causal=True,
-                                        under_remat=cfg.remat != "none",
                                         window=window)
                 registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
-            if cfg.has_mla:     # q/k heads of one size, v heads of another
+            if by_mixer["mla"]:     # q/k heads of one size, v heads of another
                 latent = (rows, cfg.n_heads, args.seq,
                           cfg.qk_nope_dim + cfg.qk_rope_dim)
                 attn = attention_kernel(latent, latent, causal=True,
-                                        under_remat=cfg.remat != "none",
                                         v_head_size=cfg.v_head_dim)
                 registry().gauge("hvd_tpu_attn_kernel").set(1, **attn)
         if cfg.mtp_depth:

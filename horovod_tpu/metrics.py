@@ -276,8 +276,8 @@ METRIC_SPECS: Dict[str, Tuple[str, str]] = {
                  "in VMEM, flash_attention.backward_form; splash_fused: "
                  "the stock kernel's dq partials that XLA sums), else the "
                  "kernel's own (flash, materialized). A "
-                 "function of the call's shape, causal, under_remat and "
-                 "window alone; examples/transformer_lm.py sets it when it "
+                 "function of the call's shape, causal and window "
+                 "alone; examples/transformer_lm.py sets it when it "
                  "has built its step, once for each kind of layer of a "
                  "per-layer pattern"),
     # models/transformer.py routing_stats (ISSUE 32 routed-expert layers)

@@ -456,7 +456,7 @@ _ring.defvjp(_ring_fwd, _ring_bwd)
 
 def ring_attention_p(q, k, v, axis_name: str, axis_size: int,
                      causal: bool = True, layout: str = "contiguous",
-                     force_ring: bool = False, under_remat: bool = False):
+                     force_ring: bool = False):
     """Blockwise ring attention over mesh axis ``axis_name``.
 
     Args:
@@ -482,8 +482,7 @@ def ring_attention_p(q, k, v, axis_name: str, axis_size: int,
         # degenerate ring: route to the tuned single-shard kernel (Pallas
         # flash/splash on TPU, materialized elsewhere)
         from .flash_attention import flash_attention_local
-        return flash_attention_local(q, k, v, causal=causal,
-                                     under_remat=under_remat)
+        return flash_attention_local(q, k, v, causal=causal)
     if layout == "zigzag" and q.shape[1] % 2:
         raise ValueError("zigzag layout needs an even local block length")
     eff_layout = layout if causal else "contiguous"

@@ -24,7 +24,7 @@ from .flash_attention import flash_attention_local
 
 
 def ulysses_attention_p(q, k, v, axis_name: str, axis_size: int,
-                        causal: bool = True, under_remat: bool = False):
+                        causal: bool = True):
     """All-to-all sequence-parallel attention over ``axis_name``.
 
     Args:
@@ -38,8 +38,7 @@ def ulysses_attention_p(q, k, v, axis_name: str, axis_size: int,
     """
     n = axis_size
     if n == 1:
-        return flash_attention_local(q, k, v, causal=causal,
-                                     under_remat=under_remat)
+        return flash_attention_local(q, k, v, causal=causal)
     heads = q.shape[2]
     if heads % n != 0:
         raise ValueError(
@@ -65,6 +64,5 @@ def ulysses_attention_p(q, k, v, axis_name: str, axis_size: int,
     # plain single-shard attention, so it takes the tuned Pallas
     # flash/splash kernel on TPU (materialized fallback elsewhere / for
     # 128-unaligned lengths)
-    oh = flash_attention_local(qh, kh, vh, causal=causal,
-                               under_remat=under_remat)
+    oh = flash_attention_local(qh, kh, vh, causal=causal)
     return heads_to_seq(oh)

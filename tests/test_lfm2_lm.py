@@ -589,15 +589,15 @@ def on_tpu(monkeypatch):
 
 
 def test_heads_of_64_reach_splash_at_the_causal_blocks(on_tpu):
-    found = fa.attention_kernel((2, 32, 8192, 64), (2, 8, 8192, 64),
-                                under_remat=True)
+    found = fa.attention_kernel((2, 32, 8192, 64), (2, 8, 8192, 64))
     assert found == {"kernel": "splash", "block_q": "1024",
                      "block_kv": "1024", "fused_bwd": "1", "window": "0",
                      "head_size": "64", "v_head_size": "64",
                      "bwd": "splash_fused"}
-    assert fa._select_kernel((2, 32, 8192, 96), (2, 8, 8192, 96)) \
+    assert fa._kernel_and_why((2, 32, 8192, 96), (2, 8, 8192, 96))[0] \
         == "materialized"
-    assert fa._select_kernel((2, 32, 8192, 32), (2, 32, 8192, 32)) == "flash"
+    assert fa._kernel_and_why((2, 32, 8192, 32), (2, 32, 8192, 32))[0] \
+        == "flash"
 
 
 def test_heads_of_64_through_splash_against_float32(on_tpu, monkeypatch):

@@ -3,6 +3,7 @@ single-device computation — the SPMD analog of the reference's rule that
 distributed training reproduce serial numerics."""
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -274,9 +275,9 @@ def test_splash_gating_and_kernel_construction():
     assert not _splash_ok(sq, (1, 4, 2048, 128))  # rectangular q/kv
     for t in (1024, 2048, 3072):
         for causal in (True, False):
-            for under_remat in (True, False):
+            for grouped in (False, True):
                 # construction validates the blocks against T
-                assert _splash_kernel(2, t, 128, causal, under_remat)
+                assert _splash_kernel(2, t, 128, causal, grouped=grouped)
     _splash_kernel.cache_clear()
 
 
@@ -573,3 +574,44 @@ def test_train_step_looped_gradient_is_exact_in_float64(mesh_name):
         _, got, _ = tfm.make_train_step(mesh, cfg, opt)(
             params, opt.init(params), inputs, targets)
         _assert_same_bits(got, want, jnp.float64)
+
+
+# -- one record per mixer ----------------------------------------------------
+
+# every field a kind of layer of the table may read, at rehearsal widths
+_EVERY_MIXER = dict(
+    vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, head_size=8,
+    n_layers=2, d_ff=48, max_seq=16, dtype=jnp.float32, attention="flash",
+    positions="rope", ffn="swiglu", conv_kernel=3, ssm_heads=4,
+    ssm_head_dim=8, ssm_state=8, ssm_groups=2, ssm_chunk=8, n_experts=4,
+    moe_top_k=2, d_ff_expert=16, n_shared_experts=1, q_lora_rank=16,
+    kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8)
+_KINDS = [(name, ffn) for name, mixer in tfm._MIXER_KINDS.items()
+          for ffn in mixer.stacks]
+
+
+@pytest.mark.parametrize("name, ffn", _KINDS, ids=[
+    tfm._MIXER_KINDS[name].stacks[ffn][0] for name, ffn in _KINDS])
+def test_every_stack_of_the_table_inits_shards_and_mixes_under_its_scope(
+        name, ffn):
+    """A pattern of two layers of one kind of the table: ``init_params``
+    and ``param_specs`` give the same tree, the kind's stack holds both
+    layers, and its mix runs (and only its) under the record's scope."""
+    mixer = tfm._MIXER_KINDS[name]
+    cfg = tfm.TransformerConfig(
+        **_EVERY_MIXER, layers=(tfm.LayerKind(mixer=name, experts=ffn),) * 2)
+    stack = mixer.stacks[ffn][0]
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    specs = tfm.param_specs(cfg)
+    assert jax.tree_util.tree_structure(params) == \
+        jax.tree_util.tree_structure(
+            specs, is_leaf=lambda s: isinstance(s, P))
+    assert {leaf.shape[0] for leaf in params[stack].values()} == {2}
+    assert tfm.layer_rows(cfg) == [(stack, 0), (stack, 1)]
+    tokens = jnp.zeros((2, cfg.max_seq), jnp.int32)
+    forward = jax.jit(lambda p, t: tfm.forward_block(p, t, cfg))
+    text = forward.lower(params, tokens).as_text(debug_info=True)
+    scoped = {m.scope: re.search(f'["/]{m.scope}/', text) is not None
+              for m in tfm._MIXER_KINDS.values() if m.scope}
+    assert scoped == {scope: scope == mixer.scope for scope in scoped}
+    assert bool(jnp.all(jnp.isfinite(forward(params, tokens))))

@@ -423,8 +423,7 @@ def chosen(shape: dict) -> tuple:
     """The forward's blocks ``splash_geometry`` chooses for the shape."""
     from horovod_tpu.parallel.flash_attention import splash_geometry
     t = shape["t"]
-    g = splash_geometry(t, shape.get("head", HEAD), shape["causal"],
-                        shape["remat"])
+    g = splash_geometry(t, shape.get("head", HEAD), shape["causal"])
     return tuple(tuple(min(b, t) for b in blocks) for blocks in (
         (g.block_q, g.block_kv, g.block_kv_compute),
         (g.block_q_dkv, g.block_kv_dkv, g.block_kv_dkv_compute)))
