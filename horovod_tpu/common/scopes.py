@@ -77,6 +77,15 @@ MLA_KV = "mla_kv"               # ... wkv_a, its norm, wkv_b, the one
 ATTN_LATENT = "attn_latent"     # ... the attention call (q/k heads of
 #                                 qk_nope_dim + qk_rope_dim, v heads of
 #                                 v_head_dim); 'rope' stands beside these
+# a layer whose mixer is the gated delta rule (LayerKind.mixer "gdn";
+# models/transformer.py _gdn_mix, parallel/gated_delta.py)
+GDN_MIXER = "gdn_mixer"         # where such a layer has attn: rmsnorm, the
+#                                 products with gdn_qkvz, gdn_ba and
+#                                 gdn_out, the decay and write strength,
+#                                 the residual
+GDN_CONV = "gdn_conv"           # inside it: the taps and the SiLU over q|k|v
+GDN_SCAN = "gdn_scan"           # ... everything of gated_delta_chunked
+GDN_GATE_NORM = "gdn_gate_norm"  # ... the norm over each head, then the gate
 # the multi-token-prediction module (cfg.mtp_depth; _mtp_module): its
 # block's scopes, 'head' and 'loss' keep their names inside it
 MTP = "mtp"                     # the whole module

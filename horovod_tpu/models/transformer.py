@@ -43,6 +43,7 @@ from ..parallel.moe import (MoEParams, moe_capacity, moe_combine,
                             router_bias_update, row_sum_form, row_sum_rows,
                             topk_buffer_rows, topk_moe_held, topk_route)
 from ..parallel.flash_attention import flash_attention_local
+from ..parallel.gated_delta import gated_delta_chunked
 from ..parallel.ssd import causal_conv_silu, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p, local_attention
 from ..parallel.ulysses import ulysses_attention_p
@@ -64,7 +65,8 @@ class LayerKind:
     ``mixer``: what mixes the tokens of a layer, a name of
     :data:`_MIXER_KINDS`: "attention" (the block as it was), "conv"
     (:func:`_conv_mix`), "mamba2" (:func:`mamba_mix`), "mla" (latent
-    attention, :func:`_mla_mix`), or "none": the layer is its FFN ALONE. A
+    attention, :func:`_mla_mix`), "gdn" (the gated delta rule,
+    :func:`_gdn_mix`), or "none": the layer is its FFN ALONE. A
     layer of one sublayer is ``h + F(N(h))``: one norm leaf (``ln1`` of a
     mixer, ``ln2`` of an FFN) and one residual."""
     window: int = 0
@@ -123,9 +125,18 @@ class TransformerConfig:
     # ``lm_head`` [V, D] of its own.
     positions: str = "none"
     rope_theta: float = 10000.0
+    # ``rope_dim``: under ``positions="rope"`` an attention layer rotates
+    # the FIRST ``rope_dim`` columns of every q and k head (rotate-half
+    # within them) and passes the rest; 0: the whole head.
+    rope_dim: int = 0
     ffn: str = "gelu"
     norm: str = "pre"
     norm_eps: float = 1e-6
+    # ``zero_centred_norms``: the learned scale ``w`` of the block's norms
+    # (``ln1``, ``ln2``, their ``_post`` twins, ``ln_f``, ``q_norm``,
+    # ``k_norm``) multiplies as ``1 + w`` and starts at 0 (:func:`_norm`);
+    # False: as ``w``, starting at 1.
+    zero_centred_norms: bool = False
     tie_embeddings: bool = True
     # Passes over the whole stack with the same weights (a looped /
     # universal transformer). Every pass ends in the final norm, and the
@@ -210,6 +221,21 @@ class TransformerConfig:
     router_bias_rate: float = 0.0
     # what ``route_norm`` adds to the sum of the chosen scores
     route_eps: float = 1e-20
+    # ``route_score``: the router's scores, "sigmoid" of every logit, or
+    # "softmax" over all ``n_experts`` logits (both in fp32; the choice,
+    # the weights and ``route_norm`` as above). ``shared_gate``: the shared
+    # experts' output times ``sigmoid(x shared_gate)``, a leaf [D] a layer.
+    route_score: str = "sigmoid"
+    shared_gate: bool = False
+    # ``LayerKind.mixer == "gdn"`` (:func:`_gdn_mix`), all four or none:
+    # ``gdn_key_heads`` q and k heads of ``gdn_key_dim``, ``gdn_value_heads``
+    # v (and gate) heads of ``gdn_value_dim``, value head ``j`` reading key
+    # head ``j // (gdn_value_heads / gdn_key_heads)``; the taps are
+    # ``conv_kernel``, the chunk ``parallel/gated_delta.py CHUNK``.
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
     # ``LayerKind.mixer == "mla"`` (:func:`_mla_mix`), all five or none:
     # ``c_q = N(x wq_a)`` of ``q_lora_rank``, q ``n_heads`` heads of
     # ``qk_nope_dim + qk_rope_dim`` from it; ``x wkv_a`` is ``kv_lora_rank
@@ -239,6 +265,7 @@ class TransformerConfig:
     def __post_init__(self):
         for name, known in (("positions", ("none", "rope")),
                             ("ffn", ("gelu", "swiglu")),
+                            ("route_score", ("sigmoid", "softmax")),
                             ("expert_ffn", ("swiglu", "relu2")),
                             ("norm", ("pre", "sandwich"))):
             if getattr(self, name) not in known:
@@ -252,6 +279,11 @@ class TransformerConfig:
                              f"ffn={self.ffn!r} cannot be combined with it")
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError("positions='rope' needs an even head size")
+        if self.rope_dim and (self.rope_dim % 2
+                              or self.rope_dim > self.head_dim):
+            raise ValueError(f"rope_dim {self.rope_dim}: the rotated part "
+                             f"of a head is even and at most the head "
+                             f"({self.head_dim})")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"n_kv_heads {self.n_kv_heads} must divide "
                              f"n_heads {self.n_heads}")
@@ -287,6 +319,9 @@ class TransformerConfig:
                 "the multi-token-prediction module (mtp_depth) follows a "
                 "per-layer pattern (layers) and runs one more block of the "
                 "kind of its last layer: a mixer with its FFN")
+        if self.shared_gate and not self.n_shared_experts:
+            raise ValueError("shared_gate gates the shared experts: it "
+                             "needs n_shared_experts")
         if self.has_experts and not (
                 0 < self.moe_top_k <= self.n_experts and self.d_ff_expert
                 and self.first_expert + self.held <= self.n_experts):
@@ -426,8 +461,8 @@ def _new_attn_leaves(key, cfg: TransformerConfig, n: int) -> dict:
     D, H, Dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     out = {}
     if cfg.qk_norm:
-        out.update({"q_norm": jnp.ones((n, Dh), jnp.float32),
-                    "k_norm": jnp.ones((n, Dh), jnp.float32)})
+        out.update({"q_norm": _scale_init(cfg, (n, Dh)),
+                    "k_norm": _scale_init(cfg, (n, Dh))})
     if cfg.attn_gate:
         out["wgate"] = _norm_init(jax.random.fold_in(key, 7), (n, D, H, Dh),
                                   D)
@@ -490,6 +525,23 @@ def _mla_leaves(cfg: TransformerConfig, k, n: int) -> dict:
             "wo": _norm_init(ks[4], (n, H, dv, D), H * dv)}
 
 
+def _gdn_leaves(cfg: TransformerConfig, k, n: int) -> dict:
+    # A_log = log U(0, 16), dt_bias 1, the gated norm's scale 1 (as
+    # Qwen3-Next publishes its initialisation)
+    D, hv = cfg.d_model, cfg.gdn_value_heads
+    qk, vv, taps = (cfg.gdn_key_heads * cfg.gdn_key_dim,
+                    hv * cfg.gdn_value_dim, cfg.conv_kernel)
+    ks = jax.random.split(jax.random.fold_in(k, 11), 5)
+    return {"gdn_qkvz": _norm_init(ks[0], (n, D, 2 * qk + 2 * vv), D),
+            "gdn_ba": _norm_init(ks[1], (n, D, 2 * hv), D),
+            "gdn_conv_w": _norm_init(ks[2], (n, taps, 2 * qk + vv), taps),
+            "gdn_A_log": jnp.log(jax.random.uniform(
+                ks[3], (n, hv), jnp.float32, 0.0, 16.0)),
+            "gdn_dt_bias": jnp.ones((n, hv), jnp.float32),
+            "gdn_norm": jnp.ones((n, cfg.gdn_value_dim), jnp.float32),
+            "gdn_out": _norm_init(ks[4], (n, vv, D), vv)}
+
+
 def _init_patterned(key, cfg: TransformerConfig) -> dict:
     """The stacks of a per-layer pattern (:data:`_MIXER_KINDS`), those that
     have a layer: a kind of layer's leaves stacked on a leading axis. A held
@@ -500,8 +552,7 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
     def norms(n, sublayers):    # "ln1" a mixer's, "ln2" an FFN's
         posts = [ln + "_post" for ln in sublayers] \
             if cfg.norm == "sandwich" else []
-        return {ln: jnp.ones((n, D), jnp.float32)
-                for ln in sublayers + posts}
+        return {ln: _scale_init(cfg, (n, D)) for ln in sublayers + posts}
 
     def swiglu(k, lead, f, prefix=""):
         ks = jax.random.split(k, 3)
@@ -530,6 +581,9 @@ def _init_patterned(key, cfg: TransformerConfig) -> dict:
         if cfg.n_shared_experts:
             leaves.update(expert_ffn(k_shared, (n,), cfg.shared_width,
                                      "shared_"))
+        if cfg.shared_gate:
+            leaves["shared_gate"] = _norm_init(
+                jax.random.fold_in(k_shared, 1), (n, D), D)
         return leaves
 
     def leaves_of(mixer, experts, n, k):    # n layers of one kind, stacked
@@ -581,7 +635,7 @@ def init_params(key, cfg: TransformerConfig):
     params = {
         "embed": norm_init(k_embed, (cfg.vocab_size, D), D) * (D ** 0.5) * 0.02,
         **stacks,
-        "ln_f": jnp.ones((D,), jnp.float32),
+        "ln_f": _scale_init(cfg, (D,)),
     }
     # the fields below draw from keys the defaults never used, so a default
     # configuration keeps its weights seed for seed
@@ -602,7 +656,7 @@ def _init_layers(k_layers, cfg: TransformerConfig) -> dict:
     n_keys = 7 if cfg.use_moe else 6   # dense init stays seed-compatible
     ks = jax.random.split(k_layers, n_keys * L).reshape(L, n_keys, 2)
     layers = {
-        "ln1": jnp.ones((L, D), jnp.float32),
+        "ln1": _scale_init(cfg, (L, D)),
         "wq": jnp.stack([norm_init(ks[i, 0], (D, H, Dh), D) for i in range(L)]),
         "wk": jnp.stack([norm_init(ks[i, 1], (D, Hkv, Dh), D)
                          for i in range(L)]),
@@ -610,7 +664,7 @@ def _init_layers(k_layers, cfg: TransformerConfig) -> dict:
                          for i in range(L)]),
         "wo": jnp.stack([norm_init(ks[i, 3], (H, Dh, D), H * Dh)
                          for i in range(L)]),
-        "ln2": jnp.ones((L, D), jnp.float32),
+        "ln2": _scale_init(cfg, (L, D)),
         **_new_attn_leaves(k_layers, cfg, L),
     }
     if cfg.use_moe:
@@ -642,8 +696,8 @@ def _init_layers(k_layers, cfg: TransformerConfig) -> dict:
                              for i in range(L)]),
         })
     if cfg.norm == "sandwich":
-        layers.update({"ln1_post": jnp.ones((L, D), jnp.float32),
-                       "ln2_post": jnp.ones((L, D), jnp.float32)})
+        layers.update({"ln1_post": _scale_init(cfg, (L, D)),
+                       "ln2_post": _scale_init(cfg, (L, D))})
     return layers
 
 
@@ -674,6 +728,8 @@ def _layer_specs(cfg: TransformerConfig, experts: Optional[bool],
             layers.update({"shared_wg": P(None, None, TENSOR_AXIS),
                            "shared_wu": P(None, None, TENSOR_AXIS),
                            "shared_wd": P(None, TENSOR_AXIS)})
+        if cfg.shared_gate:
+            layers["shared_gate"] = P()
         if cfg.expert_ffn == "relu2":       # no gate
             del layers["ewg"]
             layers.pop("shared_wg", None)
@@ -724,10 +780,26 @@ def _rmsnorm(x, scale, eps: float = 1e-6):
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+def _norm(x, scale, cfg: TransformerConfig):
+    """A norm of the block (``ln1``, ``ln2``, their ``_post`` twins,
+    ``ln_f``, ``q_norm``, ``k_norm``): :func:`_rmsnorm` times the learned
+    ``scale``, or times ``1 + scale`` under ``cfg.zero_centred_norms``."""
+    return _rmsnorm(x, 1.0 + scale if cfg.zero_centred_norms else scale,
+                    cfg.norm_eps)
+
+
+def _scale_init(cfg: TransformerConfig, shape) -> jax.Array:
+    """What the learned scale of a norm of the block (:func:`_norm`)
+    starts as: the identity, 1, or 0 under ``cfg.zero_centred_norms``."""
+    return (jnp.zeros if cfg.zero_centred_norms else jnp.ones)(
+        shape, jnp.float32)
+
+
 def _rope_tables(cfg: TransformerConfig, t_local: int,
                  seq_size: Optional[int]):
     """``(cos, sin)`` [T_local, head_dim / 2] (under latent attention
-    ``qk_rope_dim / 2``: the part of the head that rotates) in fp32 at the
+    ``qk_rope_dim / 2``, under ``rope_dim`` half of it: the part of the
+    head that rotates) in fp32 at the
     GLOBAL
     positions of the tokens this shard holds: block ``r`` of the sequence
     under the contiguous layout, stripes ``(r, 2n-1-r)`` under zigzag
@@ -738,7 +810,8 @@ def _rope_tables(cfg: TransformerConfig, t_local: int,
         return None
     with jax.named_scope(scopes.ROPE):
         half = (cfg.qk_rope_dim if any(kind.mixer == "mla" for kind in
-                                       cfg.layers) else cfg.head_dim) // 2
+                                       cfg.layers)
+                else cfg.rope_dim or cfg.head_dim) // 2
         inv_freq = cfg.rope_theta ** (
             -jnp.arange(half, dtype=jnp.float32) / half)
         pos = jnp.arange(t_local)
@@ -795,7 +868,8 @@ def _attn_mix(x, lp, *, cfg: TransformerConfig, rope=None,
     """Attention over the normed ``x [B, T, D]`` with one layer's leaves
     ``lp``: the q/k/v projections (``cfg.kv_heads`` K and V heads), q and k
     normed over the head (``cfg.qk_norm``), RoPE by ``rope``
-    (:func:`_rope_tables`), the attention itself (ring or Ulysses under
+    (:func:`_rope_tables`; over the first ``cfg.rope_dim`` of the head
+    where that is set), the attention itself (ring or Ulysses under
     sequence parallelism, the flash kernel, or materialized; ``window``:
     :class:`LayerKind`), the output gate (``cfg.attn_gate``), the output
     projection, and its psum over ``tensor`` inside a shard_map. ``scope``
@@ -813,14 +887,20 @@ def _attn_mix(x, lp, *, cfg: TransformerConfig, rope=None,
     v = jnp.einsum(qkv_eq, x, lp["wv"].astype(dt))
     if cfg.qk_norm:
         with jax.named_scope(scopes.QK_NORM):
-            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+            q = _norm(q, lp["q_norm"], cfg)
+            k = _norm(k, lp["k_norm"], cfg)
     if rope is not None:
         with jax.named_scope(scopes.ROPE):
             cos, sin = rope
             if not flash:       # q, k are [B, T, H, K]
                 cos, sin = cos[:, None, :], sin[:, None, :]
-            q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+            if cfg.rope_dim:
+                # the first rope_dim of the head rotate, the rest pass
+                q, k = (jnp.concatenate([_rope(x[..., :cfg.rope_dim], cos,
+                                               sin), x[..., cfg.rope_dim:]],
+                                        axis=-1) for x in (q, k))
+            else:
+                q, k = _rope(q, cos, sin), _rope(k, cos, sin)
     if sharded_seq:
         if window or cfg.kv_heads != cfg.n_heads:
             raise ValueError(
@@ -1024,6 +1104,71 @@ def mamba_mix(x, lp, *, cfg: TransformerConfig,
     return jnp.einsum("bte,ed->btd", y, lp["ssm_out"].astype(dt_))
 
 
+def _gdn_mix(x, lp, *, cfg: TransformerConfig,
+             seq_size: Optional[int] = None,
+             tensor_size: Optional[int] = None):
+    """The gated delta rule's mixer (Gated DeltaNet as Qwen3-Next has it)
+    over the normed ``x [B, T, D]`` with one layer's leaves ``lp``. With
+    ``Hk, dk = cfg.gdn_key_heads, cfg.gdn_key_dim`` and ``Hv, dv =
+    cfg.gdn_value_heads, cfg.gdn_value_dim``: ``[q | k | v | z] = x
+    gdn_qkvz`` (``Hk dk``, ``Hk dk``, ``Hv dv``, ``Hv dv`` columns: this
+    program's layout, one fixed permutation of a checkpoint's per-key-head
+    groups); ``[b | a] = x gdn_ba``; ``q, k, v = silu(conv(q | k | v))``,
+    depthwise and causal over ``cfg.conv_kernel`` taps with NO bias
+    (:func:`~horovod_tpu.parallel.ssd.causal_conv_silu` with a bias of
+    zeros, its form as ``ssd.taps_form`` answers); ``beta = sigmoid(b)``,
+    ``g = -exp(gdn_A_log) * softplus(a + gdn_dt_bias)``; the rule
+    (:func:`~horovod_tpu.parallel.gated_delta.gated_delta_chunked`, which
+    norms q and k); ``y = RMSNorm(o) * gdn_norm * silu(z)`` over each head's
+    ``dv``, the norm BEFORE the gate, a plain scale shared by the heads;
+    ``y gdn_out``. The three products and the rule's in ``cfg.dtype`` with
+    float32 accumulation; the taps, the decays, the write strength and the
+    gated norm in float32. Under ``remat="block"`` the rule is checkpointed
+    on its own as well.
+
+    Under ``seq > 1`` and ``tensor > 1`` it refuses: the state and the
+    taps' last tokens would have to cross the shards, and ``gdn_qkvz``
+    holds q, k, v and z side by side."""
+    if seq_size is not None and seq_size > 1:
+        raise ValueError(
+            "the gdn mixer under sequence parallelism (seq > 1): the state "
+            "and the last conv_kernel - 1 tokens of the shard before would "
+            "have to cross to it, and nothing here sends them")
+    if tensor_size is not None and tensor_size > 1:
+        raise ValueError(
+            "the gdn mixer under tensor parallelism (tensor > 1): its "
+            "gdn_qkvz leaf holds q, k, v and z side by side, and heads are "
+            "not split over the axis")
+    dt_, f32 = cfg.dtype, jnp.float32
+    (bsz, t, _), hk, hv = x.shape, cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    qk, vv = hk * dk, hv * dv
+    qkvz = jnp.einsum("btd,de->bte", x, lp["gdn_qkvz"].astype(dt_))
+    ba = jnp.einsum("btd,de->bte", x, lp["gdn_ba"].astype(dt_)).astype(f32)
+    with jax.named_scope(scopes.GDN_CONV):
+        q, k, v = causal_conv_silu(qkvz, lp["gdn_conv_w"],
+                                   jnp.zeros((qk + qk + vv,), f32),
+                                   (qk, qk, vv), 0, dt_)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(ba[..., hv:]
+                                                     + lp["gdn_dt_bias"])
+    rule = gated_delta_chunked
+    if cfg.remat == "block":
+        # the rule keeps its operands alone and computes its chunks'
+        # intermediates again in its own backward, after the layer's
+        # experts have given theirs back: 1.4 GB less at the cell's shape
+        # (the v5e compiler's memory_analysis, 14.97 -> 13.56 GB live)
+        rule = jax.checkpoint(rule)
+    o = rule(q.reshape(bsz, t, hk, dk), k.reshape(bsz, t, hk, dk),
+             v.reshape(bsz, t, hv, dv), g, beta)
+    with jax.named_scope(scopes.GDN_GATE_NORM):
+        y = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * lp["gdn_norm"]
+        z = qkvz[..., 2 * qk + vv:].astype(f32).reshape(bsz, t, hv, dv)
+        y = (y * jax.nn.silu(z)).reshape(bsz, t, vv).astype(dt_)
+    return jnp.einsum("bte,ed->btd", y, lp["gdn_out"].astype(dt_))
+
+
 def _bind_attn(cfg: TransformerConfig, kind: Optional[LayerKind], rope,
                causal: bool, **mesh):
     # (kind None: the homogeneous stack's layer, no window, rotated, and no
@@ -1055,6 +1200,15 @@ def _check_mamba(cfg: TransformerConfig) -> None:
         raise ValueError(
             "a mamba2 layer needs ssm_heads, ssm_head_dim, ssm_state, and "
             "ssm_groups that divide the heads")
+
+
+def _check_gdn(cfg: TransformerConfig) -> None:
+    if not (cfg.gdn_key_heads and cfg.gdn_value_heads and cfg.gdn_key_dim
+            and cfg.gdn_value_dim
+            and cfg.gdn_value_heads % cfg.gdn_key_heads == 0):
+        raise ValueError(
+            "a gdn layer needs gdn_key_heads, gdn_value_heads (a multiple "
+            "of the key heads), gdn_key_dim and gdn_value_dim, all four")
 
 
 class _Mixer(NamedTuple):
@@ -1122,6 +1276,14 @@ _MIXER_KINDS = {
             _mla_mix, cfg=cfg, rope=rope if kind.rope else None,
             causal=causal, scope=scopes.ATTN_LATENT, **mesh),
         scope=scopes.MLA_MIXER, per_kind=True, check=_check_mla),
+    "gdn": _Mixer(
+        stacks={True: ("gdn_layers", 22)}, leaves=_gdn_leaves,
+        specs=lambda cfg: dict.fromkeys(
+            ("gdn_qkvz", "gdn_ba", "gdn_conv_w", "gdn_A_log", "gdn_dt_bias",
+             "gdn_norm", "gdn_out"), P()),
+        bind=lambda cfg, kind, rope, causal, **mesh: functools.partial(
+            _gdn_mix, cfg=cfg, **mesh), scope=scopes.GDN_MIXER,
+        check=_check_gdn),
     "none": _Mixer(stacks={True: ("expert_ffns", 18)},
                    leaves=lambda cfg, k, n: {}, specs=lambda cfg: {},
                    bind=None, scope=None),
@@ -1132,7 +1294,7 @@ def _residual(h, out, lp, post: str, cfg: TransformerConfig):
     """How a sublayer's output joins the stream: ``h + out``, under
     sandwich norms ``h + N'(out)`` with the scale ``lp[post]``."""
     if cfg.norm == "sandwich":
-        out = _rmsnorm(out, lp[post], cfg.norm_eps)
+        out = _norm(out, lp[post], cfg)
     return h + out
 
 
@@ -1142,7 +1304,7 @@ def _attn_sublayer(h, lp, cfg: TransformerConfig, mix,
     ``scope``; ``mix`` is a mixer's (:data:`_MIXER_KINDS`) with its
     caller's arguments bound."""
     with jax.named_scope(scope):
-        out = mix(_rmsnorm(h, lp["ln1"], cfg.norm_eps), lp)
+        out = mix(_norm(h, lp["ln1"], cfg), lp)
         return _residual(h, out, lp, "ln1_post", cfg)
 
 
@@ -1206,7 +1368,8 @@ def _moe_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
 
 def _expert_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
     """The routed-expert FFN of a ``LayerKind.experts`` layer over the
-    normed ``x [B, T, D]``: ``(Shared(x) + sum over the chosen AND held
+    normed ``x [B, T, D]``: ``(Shared(x) [times its gate,
+    ``cfg.shared_gate``] + sum over the chosen AND held
     experts of w_e Expert_e(x), this layer's ExpertRoutes)``. The layer is told
     which experts it holds (``cfg.first_expert``, the leading axis of
     ``lp["ewg"]``); the chosen experts it does not hold add nothing here.
@@ -1227,7 +1390,8 @@ def _expert_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
     b, t, d = x.shape
     tok = x.reshape(b * t, d)
     route = topk_route(tok, lp["router"], lp["router_bias"], cfg.moe_top_k,
-                       cfg.route_scale, cfg.route_norm, cfg.route_eps)
+                       cfg.route_scale, cfg.route_norm, cfg.route_eps,
+                       cfg.route_score)
     if cfg.held < cfg.n_experts:
         route = route._replace(weight=lax.stop_gradient(route.weight))
     # (no ``ewg`` leaf: relu2 experts, two products and no gate)
@@ -1235,8 +1399,15 @@ def _expert_ffn(x, lp, cfg: TransformerConfig, tensor_size: Optional[int]):
                         cfg.first_expert).reshape(b, t, d)
     if cfg.n_shared_experts:
         with jax.named_scope(scopes.SHARED_EXPERT):
-            out = out + _dense_ffn(x, lp, cfg, tensor_size, "shared_",
-                                   reduce=False)
+            shared = _dense_ffn(x, lp, cfg, tensor_size, "shared_",
+                                reduce=False)
+            if cfg.shared_gate:
+                # (a factor a token: the shards' partial sums stay partial)
+                shared = shared * jax.nn.sigmoid(jnp.einsum(
+                    "btd,d->bt", x, lp["shared_gate"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32))[..., None].astype(
+                        shared.dtype)
+            out = out + shared
     if tensor_size is not None:
         out = lax.psum(out, TENSOR_AXIS)
     return out, ExpertRoutes(route.expert.reshape(b, t, -1), route.counts)
@@ -1284,7 +1455,7 @@ def _block(cfg: TransformerConfig, rope, seq_size: Optional[int] = None,
     def ffn_sublayer(h, aux_sum, lp):
         routes = None
         with jax.named_scope(scopes.FFN):
-            x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
+            x = _norm(h, lp["ln2"], cfg)
             if experts:
                 out, routes = _expert_ffn(x, lp, cfg, tensor_size)
             elif cfg.use_moe:
@@ -1327,7 +1498,7 @@ def _embed(params, tokens, cfg: TransformerConfig):
 def _final_norm(params, h, cfg: TransformerConfig):
     """What a pass over the stack ends in, and what the head takes."""
     with jax.named_scope(scopes.HEAD):
-        return _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+        return _norm(h, params["ln_f"], cfg)
 
 
 def _run_pattern(params, h, cfg: TransformerConfig, block, grad_axes):
@@ -2389,7 +2560,7 @@ def make_moe_ep_train_step(engine, cfg: TransformerConfig, optimizer):
         h = _attn_sublayer(h, lp, cfg, functools.partial(
             _attn_mix, cfg=cfg, rope=_rope_tables(cfg, h.shape[1], None)))
         with jax.named_scope(scopes.FFN):
-            x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
+            x = _norm(h, lp["ln2"], cfg)
             disp, aux, route = moe_dispatch(
                 x.reshape(-1, x.shape[-1]), lp["router"], capacity)
         # [E, C, D] is already the exchange layout: dim0 chunk k (global

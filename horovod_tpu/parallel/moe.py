@@ -193,17 +193,20 @@ class TopKRoute(NamedTuple):
 
 
 def topk_route(x, router, bias, k: int, route_scale: float = 1.0,
-               route_norm: bool = True, eps: float = 1e-20) -> TopKRoute:
-    """``s = sigmoid(float32(x) router)`` over all ``E`` experts; the choice
+               route_norm: bool = True, eps: float = 1e-20,
+               score: str = "sigmoid") -> TopKRoute:
+    """``s = sigmoid(float32(x) router)`` over all ``E`` experts (``score``
+    "softmax": the softmax of those ``E`` logits); the choice
     is the ``k`` largest of ``s + bias``; the weights are ``s`` of the chosen
     (``bias`` enters the choice only, and takes no gradient), divided by
     their sum plus ``eps`` under ``route_norm``, times ``route_scale``. fp32
     at the highest matmul precision: a near-tie should fall as it does in
     exact arithmetic."""
     with jax.named_scope(scopes.ROUTER):
-        s = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), router.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        s = (jax.nn.sigmoid(logits) if score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
         _, expert = lax.top_k(s + lax.stop_gradient(bias), k)
         # the chosen scores and the counts by comparison, not by index: one
         # non-zero term a sum, so take_along_axis and a scatter-add of ones

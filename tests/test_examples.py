@@ -192,6 +192,34 @@ def test_transformer_lm_example_state_space_pattern():
             "'block_c': '0'}") in r.stdout, r.stdout
 
 
+def test_transformer_lm_example_gated_delta_pattern():
+    """A hybrid linear-attention sparse-expert decoder from the example's
+    flags (the kind of benchmark/configs/qwen3-next-80b-a3b.json): three
+    gated delta rule layers and a gated attention layer with a quarter of
+    each head rotated, softmax routing and a gated shared expert; the layers
+    by kind of mixer and the rule's form are reported with the loss."""
+    r = _run([os.path.join(EXAMPLES, "transformer_lm.py"),
+              "--mesh", "data=2", "--d-model", "32", "--n-layers", "4",
+              "--n-heads", "4", "--kv-heads", "2", "--head-size", "16",
+              "--vocab", "128", "--seq", "64", "--batch", "4", "--steps",
+              "2", "--attention", "flash", "--positions", "rope",
+              "--rope-theta", "1e7", "--rope-dim", "4", "--qk-norm",
+              "--attn-gate", "--zero-centred-norms", "--ffn", "swiglu",
+              "--remat", "block", "--untied-head", "--pattern", "ggga",
+              "--dense-layers", "0", "--gdn-key-heads", "2",
+              "--gdn-value-heads", "4", "--gdn-key-dim", "8",
+              "--gdn-value-dim", "8", "--conv-kernel", "4", "--experts",
+              "8", "--top-k", "2", "--expert-ff", "16", "--shared-experts",
+              "1", "--shared-gate", "--route-score", "softmax",
+              "--experts-held", "4"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "'layers_by_mixer': {'attention': 1, 'gdn': 3}" in r.stdout, \
+        r.stdout
+    assert "'delta_rule': {'form': 'chunked', 'chunk': '64'}" in r.stdout, \
+        r.stdout
+    assert "'dropped': 0.0" in r.stdout and "'head_size': '16'" in r.stdout
+
+
 def test_transformer_lm_example_looped():
     """The looped decoder from the example's flags: RoPE, SwiGLU, sandwich
     norms, an untied head and four passes under remat, the sequence split

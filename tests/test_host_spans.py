@@ -184,7 +184,10 @@ def test_grouped_steps_recorded_then_replayed(engine, traced):
 
 
 def test_a_fresh_builder_is_compile_dispatch_then_dispatch(engine, traced):
-    x = jnp.arange(11.0)        # a shape no other test of this file reduces
+    # a builder is fresh only once a process: another file's test that ran
+    # in this process before may have reduced the same shape already
+    engine._builders.clear()
+    x = jnp.arange(11.0)
     spans = traced(lambda: [
         engine.allreduce(x, name=f"fresh.{i}").synchronize()
         for i in range(2)])

@@ -626,6 +626,77 @@ LIVE_GB_WITH_THE_RESIDENT_DQ = 11.721
 TEMPORARIES_GB_WITH_THE_RESIDENT_DQ = 5.820
 
 
+def test_the_hybrid_cells_step_and_the_memory_it_states(topo, pallas_branch):
+    """``qwen3next-spmd-1chip-ep32share-8k``'s own program, whole (three
+    Gated DeltaNet layers as ONE scan and one gated attention layer, every
+    layer with 16 of 512 experts held, 2 rows of 8,192 tokens,
+    ``remat="block"``) for the v5e: the attention kernel at 16 query over
+    2 KV heads of 256 through splash's MQA form, forward twice (the run of
+    one's recomputation is real: ``remat_barrier``) and its fused backward
+    once; the taps' kernels over q | k | v with no bias (a bias of zeros),
+    forward twice and backward once in the scan's body; the rule's scan
+    over the chunks under ``gdn_scan``; the experts' grouped products
+    through megablox at 512; and the compiler's ``memory_analysis`` under
+    the 14.4 GB (90% of the chip) a cell may reach and within 2% of what
+    ``benchmark/configs/qwen3-next-80b-a3b.json`` states."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [p for p in (os.path.join(bench, "readers"), bench)
+                    if p not in sys.path]
+    import files
+    config, cell = "qwen3-next-80b-a3b", "qwen3next-spmd-1chip-ep32share-8k"
+    model = files.load_module(os.path.join(
+        bench, "configs", config + ".py"), "bench_config_qwen3next")
+    spec = files.load_json(files.config_path(config))
+    traffic = files.load_json(files.traffic_path(files.cell(cell)["traffic"]))
+    cfg = model.transformer_config(spec, traffic, False)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
+                (tfm.DATA_AXIS, tfm.SEQ_AXIS, tfm.TENSOR_AXIS))
+    opt = model.optimizer()
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    held = NamedSharding(mesh, P())
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=held), tree)
+
+    tok = jax.ShapeDtypeStruct(
+        (traffic["rows_per_chip"], cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(tfm.DATA_AXIS, tfm.SEQ_AXIS)))
+    compiled = tfm.make_train_step(mesh, cfg, opt).lower(
+        on_mesh(shapes), on_mesh(jax.eval_shape(opt.init, shapes)), tok,
+        tok).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%((?:splash|flash|gmm|tgmm|ragged|\w*mha_bwd)"
+                       r"[\w\-]*?)(?:\.\d+)? = ", text)
+    assert calls.count("splash_mqa_fwd_residuals") == 2, calls
+    assert calls.count("splash_mqa_dkv_no_residuals") == 1, calls
+    assert "gmm" in calls and "tgmm" in calls
+    assert not [c for c in calls if "_dq" in c or "flash" in c
+                or "mha" in c or "ragged" in c], calls
+    assert re.search(r"%splash_mqa_fwd_residuals[.\d]* = [^\n]*"
+                     r"bf16\[2,2,8,8192,256\]", text)
+    taps = re.findall(r"%\w*(ssm_taps_(?:fwd|bwd))\w*?(?:\.\d+)? = [^\n]*"
+                      r"custom-call\([^\n]*op_name=\"([^\"]*)\"", text)
+    assert sorted(name for name, _ in taps) == [
+        "ssm_taps_bwd"] + ["ssm_taps_fwd"] * 2, taps
+    for _, op_name in taps:
+        assert "/gdn_mixer/gdn_conv/" in op_name, op_name
+    assert re.search(r"while\(.*op_name=\"[^\"]*gdn_scan", text)
+    assert re.search(r"%gmm[.\d]* = bf16\[\d+,512\]", text)
+    found = compiled.memory_analysis()
+    stated = spec["memory_analysis"]["rows_%d" % traffic["rows_per_chip"]]
+    live = found.argument_size_in_bytes + found.temp_size_in_bytes
+    assert live < 14.4e9
+    assert stated["parameters"] == 424_342_592 == sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    assert live / 1e9 == pytest.approx(stated["live_gb"], rel=0.02)
+    assert found.temp_size_in_bytes / 1e9 == pytest.approx(
+        stated["temporaries_gb"], rel=0.02)
+
+
 @pytest.mark.parametrize("rows, h, t, d, dv, remat", [
     (2, 32, 8192, 192, 128, True),
     (1, 16, 4096, 128, 128, True),

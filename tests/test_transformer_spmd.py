@@ -585,7 +585,8 @@ _EVERY_MIXER = dict(
     positions="rope", ffn="swiglu", conv_kernel=3, ssm_heads=4,
     ssm_head_dim=8, ssm_state=8, ssm_groups=2, ssm_chunk=8, n_experts=4,
     moe_top_k=2, d_ff_expert=16, n_shared_experts=1, q_lora_rank=16,
-    kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8)
+    kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+    gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8)
 _KINDS = [(name, ffn) for name, mixer in tfm._MIXER_KINDS.items()
           for ffn in mixer.stacks]
 

@@ -103,6 +103,7 @@ SECTIONS = [
     ("", "horovod_tpu.parallel.flash_attention", ["flash_attention_local",
                                                    "attention_kernel"]),
     ("", "horovod_tpu.parallel.ssd", ["ssd_chunked"]),
+    ("", "horovod_tpu.parallel.gated_delta", ["gated_delta_chunked"]),
     ("", "horovod_tpu.parallel.moe", ["moe_layer_p", "MoEParams"]),
     ("", "horovod_tpu.parallel.pipeline", []),
     ("Ops", "horovod_tpu.ops.sync_batch_norm", []),
